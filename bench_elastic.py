@@ -167,9 +167,9 @@ def _parent_main(args):
         cmd += ["--platform", args.platform]
     return run_child_with_retries(
         cmd, os.path.dirname(here), args.timeouts, METRIC, UNIT,
-        use_cache=args.platform is None,
-        cache_match={"dim": args.dim, "hidden": args.hidden,
-                     "batch": args.batch})
+        record=args.platform is None,
+        match={"dim": args.dim, "hidden": args.hidden,
+               "batch": args.batch})
 
 
 def _parse_args(argv):

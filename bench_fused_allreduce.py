@@ -208,8 +208,8 @@ def _parent_main(args):
         cmd += ["--platform", args.platform]
     return run_child_with_retries(
         cmd, os.path.dirname(here), args.timeouts, METRIC, UNIT,
-        use_cache=args.platform is None,
-        cache_match={"n_leaves_config": f"{args.n_layers}x{args.d_model}",
+        record=args.platform is None,
+        match={"n_leaves_config": f"{args.n_layers}x{args.d_model}",
                      "wire_dtype": args.wire_dtype or "fp32"})
 
 

@@ -9,7 +9,7 @@ consumer (prefetch into a slot ring), so the training step never waits
 on host gather — on the 1-core container the visible ratio also folds
 in thread-scheduling overhead, making it a conservative lower bound.
 
-Prints ONE JSON line (bench contract); records to BENCH_MEASURED.json.
+Prints ONE JSON line (bench contract); records to the run history (BENCH_HISTORY.json).
 """
 
 import argparse

@@ -267,9 +267,9 @@ def _parent_main(args):
         cmd += ["--platform", args.platform]
     return run_child_with_retries(
         cmd, os.path.dirname(here), args.timeouts, METRIC, UNIT,
-        use_cache=args.platform is None,
-        cache_match={"requests": args.requests, "slots": args.slots,
-                     "max_new": args.max_new, "rounds": args.rounds})
+        record=args.platform is None,
+        match={"requests": args.requests, "slots": args.slots,
+               "max_new": args.max_new, "rounds": args.rounds})
 
 
 def _parse_args(argv):

@@ -400,11 +400,11 @@ def main(argv):
         cmd += ["--platform", args.platform]
     return run_child_with_retries(
         cmd, os.path.dirname(here), args.timeouts, METRIC, UNIT,
-        use_cache=args.platform is None,
-        cache_match={"requests": args.requests, "slots": args.slots,
-                     "horizon": args.horizon, "d_model": args.d_model,
-                     "n_layers": args.n_layers, "max_new": args.max_new,
-                     "arrival_ms": args.arrival_ms, "seed": args.seed})
+        record=args.platform is None,
+        match={"requests": args.requests, "slots": args.slots,
+               "horizon": args.horizon, "d_model": args.d_model,
+               "n_layers": args.n_layers, "max_new": args.max_new,
+               "arrival_ms": args.arrival_ms, "seed": args.seed})
 
 
 if __name__ == "__main__":

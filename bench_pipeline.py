@@ -201,10 +201,10 @@ def _parent_main(args):
         cmd += ["--platform", args.platform]
     return run_child_with_retries(
         cmd, os.path.dirname(here), args.timeouts, METRIC, UNIT,
-        use_cache=args.platform is None,
-        cache_match={"host_delay_ms": args.host_delay_ms,
-                     "batch": args.batch,
-                     "steps_per_execution": args.steps_per_execution})
+        record=args.platform is None,
+        match={"host_delay_ms": args.host_delay_ms,
+               "batch": args.batch,
+               "steps_per_execution": args.steps_per_execution})
 
 
 def _parse_args(argv):

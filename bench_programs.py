@@ -203,9 +203,9 @@ def _parent_main(args):
         cmd += ["--platform", args.platform]
     return run_child_with_retries(
         cmd, os.path.dirname(here), args.timeouts, METRIC, UNIT,
-        use_cache=args.platform is None,
-        cache_match={"batch": args.batch, "dim": args.dim,
-                     "hidden": args.hidden, "iters": args.iters},
+        record=args.platform is None,
+        match={"batch": args.batch, "dim": args.dim,
+               "hidden": args.hidden, "iters": args.iters},
         # an off/on overhead ratio: 1.0 is free, higher is overhead
         check=args.check, check_direction="lower")
 
@@ -234,7 +234,7 @@ def _parse_args(argv):
     p.add_argument("--platform", default=None)
     p.add_argument("--check", action="store_true",
                    help="perf-regression sentinel: score the fresh "
-                        "record against BENCH_MEASURED.json history "
+                        "record against the run history "
                         "(exit 1 on a regression verdict)")
     p.add_argument("--timeouts", type=int, nargs="+", default=[480])
     return p.parse_args(argv)

@@ -3,8 +3,7 @@ held-out-perplexity target, WITH a mid-run interruption + resume.
 
 The reference's examples were judged by train-to-accuracy runs (15-min
 ImageNet etc.); this is the transformer-LM counterpart, packaged as a
-bench so the babysitter (`bench_session.py`) executes it unattended the
-moment a live TPU window opens:
+bench:
 
 1. generate a deterministic pseudo-book corpus (Zipf word frequencies,
    sentence/paragraph structure — enough statistical texture that
@@ -31,7 +30,7 @@ import subprocess
 import sys
 import time
 
-from _bench_common import pin_platform, run_child_with_retries
+from _bench_common import run_child_with_retries
 
 METRIC = "lm_quality_heldout_byte_ppl"
 UNIT = "perplexity"
@@ -75,8 +74,7 @@ def make_corpus(path: str, target_bytes: int, seed: int = 0) -> int:
 def _run_train(args_list, platform, timeout_s=1400):
     """One train_lm phase with its OWN timeout and process-group kill:
     if the outer bench timeout fired instead, it would kill only the
-    direct child and orphan train_lm still holding the TPU device —
-    wedging every later probe of the session."""
+    direct child and orphan train_lm still holding the TPU device."""
     import signal
 
     env = dict(os.environ)
@@ -116,8 +114,7 @@ def run(corpus_mb=4.0, steps=400, tok_vocab=8192, d_model=256,
                             d_model, n_layers, seq, batch, platform)
     finally:
         if own_workdir:
-            # the babysitter re-runs this on a heartbeat: checkpoints
-            # with Adam moments would otherwise pile up in /tmp
+            # checkpoints with Adam moments would otherwise pile up
             shutil.rmtree(workdir, ignore_errors=True)
 
 
@@ -191,7 +188,8 @@ def main(argv):
                  n_layers=2, seq=64, batch=8))
 
     if args.child:
-        pin_platform(args.platform)
+        # this middle process only launches train_lm / generate: it
+        # stays off JAX, so the grandchild that needs the chip gets it
         print("BENCH_RESULT " + json.dumps(
             run(platform=args.platform, **size)))
         return 0
@@ -203,9 +201,9 @@ def main(argv):
         cmd += ["--platform", args.platform]
     return run_child_with_retries(
         cmd, os.path.dirname(here), args.timeouts, METRIC, UNIT,
-        use_cache=args.platform is None,
-        cache_match={"steps": size["steps"],
-                     "tokenizer_vocab": size["tok_vocab"]})
+        record=args.platform is None,
+        match={"steps": size["steps"],
+               "tokenizer_vocab": size["tok_vocab"]})
 
 
 if __name__ == "__main__":

@@ -211,8 +211,8 @@ def _parent_main(args):
         cmd += ["--platform", args.platform]
     return run_child_with_retries(
         cmd, os.path.dirname(here), args.timeouts, METRIC, UNIT,
-        use_cache=args.platform is None,
-        cache_match={"batch": args.batch, "dim": args.dim})
+        record=args.platform is None,
+        match={"batch": args.batch, "dim": args.dim})
 
 
 def _parse_args(argv):

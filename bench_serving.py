@@ -24,8 +24,7 @@ per request and recorded (the engine's exactness guarantee: scheduling
 must never change anyone's tokens).
 
 The model is the serving engine's MiniLM reference backend (the
-flagship transformer refuses to construct on pre-vma jax; the engine
-machinery under test is identical).  Prints ONE JSON line {"metric",
+engine machinery under test is identical to the flagship's).  Prints ONE JSON line {"metric",
 "value", "unit", "vs_baseline", ...}: value = continuous/static
 tokens-per-sec ratio (unit "x", >1 means continuous batching wins).
 Same hermetic child-process pattern as bench.py.
@@ -728,11 +727,11 @@ def main(argv):
         cmd += ["--platform", args.platform]
     return run_child_with_retries(
         cmd, os.path.dirname(here), args.timeouts, METRIC, UNIT,
-        use_cache=args.platform is None,
-        cache_match={"requests": args.requests, "slots": args.slots,
-                     "horizon": args.horizon, "d_model": args.d_model,
-                     "n_layers": args.n_layers, "max_new": args.max_new,
-                     "seed": args.seed})
+        record=args.platform is None,
+        match={"requests": args.requests, "slots": args.slots,
+               "horizon": args.horizon, "d_model": args.d_model,
+               "n_layers": args.n_layers, "max_new": args.max_new,
+               "seed": args.seed})
 
 
 if __name__ == "__main__":

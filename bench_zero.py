@@ -253,9 +253,9 @@ def _parent_main(args):
         cmd += ["--platform", args.platform]
     return run_child_with_retries(
         cmd, os.path.dirname(here), args.timeouts, METRIC, UNIT,
-        use_cache=args.platform is None,
-        cache_match={"model_config":
-                     f"{args.n_layers}x{args.d_model}x{args.vocab}"},
+        record=args.platform is None,
+        match={"model_config":
+               f"{args.n_layers}x{args.d_model}x{args.vocab}"},
         check=args.check)
 
 
@@ -278,7 +278,7 @@ def _parse_args(argv):
     p.add_argument("--timeouts", type=int, nargs="+", default=[480])
     p.add_argument("--check", action="store_true",
                    help="perf-regression sentinel: score the fresh "
-                        "record against BENCH_MEASURED.json's prior "
+                        "record against the run history's prior "
                         "same-workload runs; the verdict rides the "
                         "JSON line under 'check' and the exit code is "
                         "1 on a regression verdict")

@@ -111,28 +111,16 @@ def init_distributed(
     host runs and tests can call it unconditionally).
     """
     import jax
+    from jax._src import xla_bridge
 
-    # Idempotence: jax.distributed.initialize raises if called twice, and
-    # its message wording varies by version — test the runtime state, not
-    # the error string.  The state probes live in jax._src, so guard them:
-    # if a future JAX moves them, fall back to calling initialize and
-    # swallowing only the single-host "too late / again" RuntimeErrors.
-    probes_ok = True
-    try:
-        from jax._src import distributed, xla_bridge
-
-        if distributed.global_state.client is not None:
-            return
-        backend_up = xla_bridge.backends_are_initialized()
-    except Exception:
-        probes_ok = False
-        backend_up = False
+    if jax.distributed.is_initialized():
+        return
     # Single-host convenience: with no explicit cluster spec there is
     # nothing to coordinate, and jax.distributed.initialize would raise if
     # the XLA backend is already up — let unconditional calls in tests and
     # single-process runs fall through to a no-op in that case.
     single_host = num_processes in (None, 1) and coordinator_address is None
-    if single_host and backend_up:
+    if single_host and xla_bridge.backends_are_initialized():
         return
 
     kwargs = {}
@@ -144,14 +132,7 @@ def init_distributed(
         kwargs["process_id"] = process_id
     if local_device_ids is not None:
         kwargs["local_device_ids"] = local_device_ids
-    try:
-        jax.distributed.initialize(**kwargs)
-    except RuntimeError:
-        if probes_ok or not single_host:
-            raise
-        # probes unavailable on this JAX version and this is a single-host
-        # call: a RuntimeError here means "already initialized" or
-        # "backend already up", both of which are the documented no-op case
+    jax.distributed.initialize(**kwargs)
 
 
 __all__.append("init_distributed")

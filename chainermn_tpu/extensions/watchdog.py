@@ -2,12 +2,8 @@
 
 The reference had NO failure detection (SURVEY §5: fault tolerance was
 checkpoint + full restart); a rank wedged inside a collective stalled
-the whole job silently until an operator noticed.  This repo has already
-paid that cost for real: the PJRT-plugin hang diagnosed in VERDICT r5
-sat in a ~1,505 s internal retry budget with nothing at runtime to say
-*where* it was stuck — ``hang_doctor.py`` reconstructs such hangs
-post-mortem, offline.  :class:`TrainingWatchdog` is the runtime
-subsystem: a daemon monitor thread fed step-boundary heartbeats that, on
+the whole job silently until an operator noticed.
+:class:`TrainingWatchdog` is the runtime subsystem: a daemon monitor thread fed step-boundary heartbeats that, on
 a stall longer than the threshold,
 
 1. dumps ALL thread stacks via :mod:`faulthandler` (the C-level-safe

@@ -31,6 +31,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from chainermn_tpu.parallel._compat import pcast
 from chainermn_tpu.parallel.ring_attention import (
     _NEG,
     _pv_mix,
@@ -60,8 +61,7 @@ def _vary(x, *axes):
     """Mark ``x`` varying over ``axes`` (no-op for already-varying) —
     block params are pipe-sharded even at pipe size 1, so everything they
     touch must carry the pipe axis in its vma type."""
-    need = tuple(a for a in axes if a not in jax.typeof(x).vma)
-    return lax.pcast(x, need, to="varying") if need else x
+    return pcast(x, axes, to="varying")
 
 
 def _dense_q(dense, x, blk, name, cd):
@@ -100,8 +100,11 @@ def _decode_block(cfg: TransformerConfig, h, blk, caches, pos,
     scales carry a trailing singleton so every write below treats
     values and scales identically; ``pos``: scalar GLOBAL position of
     the chunk's FIRST token (Tq > 1 requires ``pos == 0`` — the
-    prefill contract).  ``write_mask`` (scalar bool) gates the cache
-    update — pipe-parallel phases where this device does NOT own the
+    prefill contract), or a ``(B,)`` vector of PER-ROW first positions
+    (the serving engine's ragged rounds: origin-0 rows, each on its
+    own clock — K/V then land by per-row scatter and every row masks
+    the cache at its own position).  ``write_mask`` (scalar bool) gates
+    the cache update — pipe-parallel phases where this device does NOT own the
     running stage must leave their cache untouched, and masking the
     written slice here is O(written) instead of the O(cache) select a
     whole-buffer ``where`` would cost per phase.
@@ -135,7 +138,13 @@ def _decode_block(cfg: TransformerConfig, h, blk, caches, pos,
         kv = _dense_q(column_parallel_dense, x, blk, "wkv", cd
                       ).reshape(B, Tq, 2, Hkvl, cfg.d_head)
         k_new, v_new = kv[:, :, 0], kv[:, :, 1]
-    qpos = pos + jnp.arange(Tq)                           # (Tq,)
+    ragged = jnp.ndim(pos) == 1
+    if ragged and (R > 1 or pos_offset is not None):
+        raise ValueError(
+            "per-row positions need a seq=1 mesh and origin-0 rows "
+            "(no pos_offset)")
+    # (Tq,) shared by the batch, or (B, Tq) per row
+    qpos = jnp.asarray(pos)[..., None] + jnp.arange(Tq)
     if cfg.pos_embedding == "rope":
         if pos_offset is None:
             rpos = qpos
@@ -211,6 +220,20 @@ def _decode_block(cfg: TransformerConfig, h, blk, caches, pos,
         ck, cv = blk_write(ck, k_new), blk_write(cv, v_new)
         if ck_s is not None:
             ck_s, cv_s = blk_write(ck_s, k_sc), blk_write(cv_s, v_sc)
+    elif ragged:
+        # rows advance raggedly, so no single dynamic_update_slice start
+        # exists: per-row scatter.  Out-of-range positions drop — which
+        # is also how a non-owning pipe stage leaves its cache alone
+        wpos = qpos if write_mask is None \
+            else jnp.where(write_mask, qpos, Tl)
+        brow = jnp.arange(B)[:, None]
+
+        def row_write(cache, new):
+            return cache.at[brow, wpos].set(new, mode="drop")
+
+        ck, cv = row_write(ck, k_new), row_write(cv, v_new)
+        if ck_s is not None:
+            ck_s, cv_s = row_write(ck_s, k_sc), row_write(cv_s, v_sc)
     else:
         if R > 1:
             # member pos // Tl owns this position; everyone computes
@@ -256,17 +279,17 @@ def _decode_block(cfg: TransformerConfig, h, blk, caches, pos,
         kpos = jnp.arange(Tl)
         if R > 1:
             kpos = kpos + lax.axis_index("seq") * Tl
-        allow = kpos[None, :] <= qpos[:, None]            # (Tq, Tl)
+        allow = kpos <= qpos[..., None]        # (Tq, Tl) | (B, Tq, Tl)
         if cfg.attention_window:
             # slot distance == per-row token distance (both ends shift
             # by the same pad offset), so the window needs no offset
-            allow &= (qpos[:, None] - kpos[None, :]) \
-                < cfg.attention_window
+            allow &= (qpos[..., None] - kpos) < cfg.attention_window
         if pos_offset is not None:
             # per-row validity: slots before the row's first real
             # token hold pad K/V — no query may attend them
             allow = allow[None] \
                 & (kpos[None, None, :] >= pos_offset[:, None, None])
+        if allow.ndim == 3:
             s = jnp.where(allow[:, None], s, _NEG)        # (B,H,Tq,Tl)
         else:
             s = jnp.where(allow[None, None], s, _NEG)     # (B,H,Tq,Tl)
@@ -328,8 +351,9 @@ def _decode_step(cfg: TransformerConfig, params, caches, tok, pos,
                  with_logits: bool = True, all_logits: bool = False,
                  chunk_attends_cache: bool = False, pos_offset=None):
     """Next-token logits for ``tok`` — (B,) in the generation loop, or
-    a (B, Tq) chunk starting at ``pos`` for batched prefill (Tq prompt
-    tokens through ONE MXU-shaped pass instead of Tq per-token
+    a (B, Tq) chunk starting at ``pos`` (a scalar, or a (B,) vector of
+    per-row starts — see :func:`_decode_block`) for batched prefill
+    (Tq prompt tokens through ONE MXU-shaped pass instead of Tq per-token
     dispatches; ``with_logits=False`` skips the LM head entirely, since
     prefill only needs the cache filled).  Updates the
     (L_local, B, kv_len_local, Hkv_local, Dh) cache pair.
@@ -376,7 +400,7 @@ def _decode_step(cfg: TransformerConfig, params, caches, tok, pos,
         # overhangs the table (speculative decode's final round) must
         # corrupt only its own out-of-range rows — dynamic_slice clamps
         # the whole slice START, silently shifting every position
-        idx = pos + jnp.arange(Tq)
+        idx = jnp.asarray(pos)[..., None] + jnp.arange(Tq)
         if pos_offset is not None:
             # left-padded rows: per-row token numbers (pad slots clip
             # to 0; their values are masked out of attention anyway)
@@ -384,8 +408,8 @@ def _decode_step(cfg: TransformerConfig, params, caches, tok, pos,
         rows = jnp.take(
             params["pos"],
             jnp.clip(idx, 0, params["pos"].shape[0] - 1), axis=0)
-        h = h + (rows if pos_offset is not None
-                 else rows[None]).astype(cd)
+        # (Tq, D) shared by the batch, or (B, Tq, D) per row
+        h = h + (rows if rows.ndim == 3 else rows[None]).astype(cd)
     h = h.astype(cd)
     h = _vary(h, "pipe")
     caches = tuple(jax.tree.map(lambda c: _vary(c, "pipe"), caches))

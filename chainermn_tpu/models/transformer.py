@@ -37,6 +37,8 @@ from jax.sharding import PartitionSpec as P
 from chainermn_tpu.ops.pallas_attention import (
     flash_attention,
     flash_attention_supported,
+    interpret_kernels,
+    tracing_for_mesh,
 )
 from chainermn_tpu.parallel.expert import expert_parallel_moe
 from chainermn_tpu.parallel.fsdp import fsdp_gather
@@ -52,7 +54,6 @@ from chainermn_tpu.parallel.ring_attention import (
     ring_attention,
 )
 from chainermn_tpu.parallel._compat import (
-    HAS_VMA as _HAS_VMA,
     all_gather_invariant as _all_gather_invariant,
 )
 from chainermn_tpu.parallel.tensor import (
@@ -185,12 +186,6 @@ class TransformerConfig:
         return jax.checkpoint
 
     def __post_init__(self):
-        if not _HAS_VMA:
-            raise RuntimeError(
-                "chainermn_tpu's transformer requires a jax whose "
-                "ShapedArray carries .vma (shard_map varying-axes "
-                "typing, jax >= 0.4.34): _lm_head's custom VJP uses it "
-                "to place the embed-gradient psum. Upgrade jax.")
         if self.attention_window < 0:
             raise ValueError(
                 f"attention_window {self.attention_window} must be >= 0")
@@ -553,24 +548,15 @@ def _lm_head(cd, h, embed):
                       preferred_element_type=jnp.float32)
 
 
-def _psum_over_vma(grad, fn_name: str, exclude: tuple = ()):
+def _psum_over_vma(grad, exclude: tuple = ()):
     """Shared tail of every custom-VJP head backward: psum ``grad``
     over the mesh axes its local partial is varying on (size-1 axes
     and the single-device oracle fold to identity), excluding
     ``exclude`` (a vocab-shard axis whose per-member gradients are
     distinct and must NOT be summed).  custom_vjp hides the einsum
     transpose's linearity from the vma checker, so the reduction must
-    be explicit.  No silent fallback: on a jax too old for vma typing
-    the reduction CANNOT be reconstructed, and skipping it would mean
-    unreduced grads — fail instead."""
-    try:
-        vma = tuple(jax.typeof(grad).vma)
-    except AttributeError:  # pragma: no cover - older jax: no vma typing
-        raise RuntimeError(
-            f"{fn_name} needs jax.typeof(...).vma (shard_map varying-"
-            "axes typing) to place its gradient psum; this jax version "
-            "does not expose it") from None
-    vma = tuple(a for a in vma if a not in exclude)
+    be explicit."""
+    vma = tuple(a for a in jax.typeof(grad).vma if a not in exclude)
     return lax.psum(grad, vma) if vma else grad
 
 
@@ -592,7 +578,7 @@ def _lm_head_bwd(cd, res, g):
     # the SUM of the per-member partials, which the standard einsum
     # transpose would emit as shard_map's automatic psum (see
     # _psum_over_vma's contract)
-    dw = _psum_over_vma(dw, "_lm_head")
+    dw = _psum_over_vma(dw)
     return dh, dw
 
 
@@ -675,7 +661,7 @@ def _head_nll_bwd(cd, chunk, res, g):
     dw = dw.astype(embed.dtype)
     # single psum for the whole accumulated embed cotangent — a
     # per-chunk psum would multiply the (V, D) all-reduce volume by C
-    dw = _psum_over_vma(dw, "_head_nll")
+    dw = _psum_over_vma(dw)
     return dh, dw, None
 
 
@@ -756,7 +742,7 @@ def _vp_head_bwd(cd, axis_name, res, g):
     # the embed SHARD's cotangent psums over the batch-like axes it is
     # invariant on — but NOT over the vocab axis (each member's shard
     # gradient is distinct; summing them would be wrong)
-    dw = _psum_over_vma(dw, "_vp_head", exclude=(axis_name,))
+    dw = _psum_over_vma(dw, exclude=(axis_name,))
     return dh, dw
 
 
@@ -874,7 +860,7 @@ def _vp_head_nll_bwd(cd, axis_name, chunk, res, g):
     dw = dw.astype(embed_local.dtype)
     # single psum over the batch-like axes, NOT the vocab axis (each
     # member's shard gradient is distinct) — once, never per chunk
-    dw = _psum_over_vma(dw, "_vp_head_nll", exclude=(axis_name,))
+    dw = _psum_over_vma(dw, exclude=(axis_name,))
     return dh, dw, None
 
 
@@ -991,7 +977,7 @@ def _attention(cfg: TransformerConfig, h, blk):
                            bwd_block_q=cfg.flash_bwd_block_q or None,
                            bwd_block_k=cfg.flash_bwd_block_k or None,
                            layout=cfg.seq_layout,
-                           interpret=jax.default_backend() != "tpu")
+                           interpret=interpret_kernels())
     elif cfg.attention == "ulysses":
         # after the head<->seq exchange each device holds the FULL
         # sequence for its head subset — the flash kernel slots straight
@@ -1002,7 +988,7 @@ def _attention(cfg: TransformerConfig, h, blk):
             fa = partial(flash_attention,
                          bwd_block_q=cfg.flash_bwd_block_q or None,
                          bwd_block_k=cfg.flash_bwd_block_k or None,
-                         interpret=jax.default_backend() != "tpu")
+                         interpret=interpret_kernels())
             o = ulysses_attention(q, k, v, axis_name="seq", causal=True,
                                   window=win,
                                   attn_fn=fa)
@@ -1013,8 +999,8 @@ def _attention(cfg: TransformerConfig, h, blk):
         o = local_attention(q, k, v, causal=True,
                             window=win)
     elif cfg.attention == "flash":
-        # Pallas kernel (TPU); non-TPU backends run the same kernel
-        # through the Pallas interpreter so one config works everywhere.
+        # Pallas kernel: compiled when the step was built for TPU
+        # devices, interpreted otherwise (interpret_kernels)
         if lax.axis_size("seq") != 1:
             raise ValueError(
                 'attention="flash" covers only the unsharded-sequence '
@@ -1022,20 +1008,22 @@ def _attention(cfg: TransformerConfig, h, blk):
                 f'{lax.axis_size("seq")}); use attention="ring" to '
                 "shard the sequence")
         if not flash_attention_supported(T, T):
-            # kernel contract: lengths must divide the (clamped) blocks —
-            # fall back to the XLA path instead of erroring at trace time
-            # (grouped-KV read in place; no broadcast)
-            o = local_attention(q, k, v, causal=True,
-                                window=win)
-        else:
-            # kernel wants matching head counts
-            k, v = broadcast_kv(k, v, q.shape[2] // k.shape[2])
-            o = flash_attention(
-                q, k, v, causal=True,
-                window=win,
-                bwd_block_q=cfg.flash_bwd_block_q or None,
-                bwd_block_k=cfg.flash_bwd_block_k or None,
-                interpret=jax.default_backend() != "tpu")
+            # no silent stand-in: a run that asked for the kernel and
+            # got the XLA attention would be measured as the kernel
+            raise ValueError(
+                f'attention="flash" cannot tile a sequence of {T}: '
+                "lengths must be multiples of 8 and either fit one "
+                "block or divide by a power-of-two block >= 128 "
+                '(flash_attention_supported); use attention="local" '
+                "for the XLA path")
+        # kernel wants matching head counts
+        k, v = broadcast_kv(k, v, q.shape[2] // k.shape[2])
+        o = flash_attention(
+            q, k, v, causal=True,
+            window=win,
+            bwd_block_q=cfg.flash_bwd_block_q or None,
+            bwd_block_k=cfg.flash_bwd_block_k or None,
+            interpret=interpret_kernels())
     else:
         raise ValueError(cfg.attention)
     # named for the "dots" remat policy: saving the attention-core
@@ -1380,7 +1368,7 @@ def make_forward_fn(mesh_cfg, cfg: TransformerConfig):
 
     return jax.jit(
         jax.shard_map(
-            fwd,
+            tracing_for_mesh(mesh_cfg.mesh, fwd),
             mesh=mesh_cfg.mesh,
             in_specs=(param_specs(cfg), _BATCH_SPEC),
             out_specs=P(("data", "expert"), "seq"),
@@ -1425,7 +1413,7 @@ def make_train_step(mesh_cfg, cfg: TransformerConfig, optimizer):
             f"got {cfg.pipeline_schedule!r}")
 
     grad_fn = jax.shard_map(
-        grad_body,
+        tracing_for_mesh(mesh_cfg.mesh, grad_body),
         mesh=mesh_cfg.mesh,
         in_specs=(specs, _BATCH_SPEC, _BATCH_SPEC),
         out_specs=(P(), specs),

@@ -3,16 +3,22 @@
 pinned-memory arenas + CuPy pack kernels in ``_memory_utility.py``,
 unverified — mount empty, see SURVEY.md).
 
-The shared library is built lazily with ``g++`` on first use and cached
-next to the source; everything degrades to a documented pure-Python
+The shared library is built lazily with ``g++`` on first use and kept
+next to the source under a name that carries the source's hash, so the
+library that runs is always the one built from ``loader.cpp`` as it
+stands (whatever the files' mtimes say); everything degrades to a documented pure-Python
 fallback when no compiler is available (``native_available()``).
 """
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
+import tempfile
 import threading
 from typing import Optional, Sequence, Tuple
 
@@ -27,22 +33,43 @@ __all__ = [
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "loader.cpp")
-_LIB_PATH = os.path.join(_DIR, "_libcmn_native.so")
+_LIB_GLOB = os.path.join(_DIR, "_libcmn_native.*.so")
 _lock = threading.Lock()
 _lib = None
 _build_error: Optional[str] = None
 
 
-def _build() -> Optional[str]:
+def _lib_path() -> str:
+    """``_libcmn_native.<hash of loader.cpp>.so`` (git-ignored)."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"_libcmn_native.{digest}.so")
+
+
+def _build(lib_path: str) -> Optional[str]:
+    # build beside the target and rename: concurrent builders (xdist
+    # workers) each publish a complete library or nothing
+    fd, tmp = tempfile.mkstemp(dir=_DIR, prefix="_libcmn_native.",
+                               suffix=".tmp")
+    os.close(fd)
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-pthread", "-std=c++17",
-           _SRC, "-o", _LIB_PATH]
+           _SRC, "-o", tmp]
     try:
-        proc = subprocess.run(
-            cmd, capture_output=True, text=True, timeout=300)
-    except (FileNotFoundError, subprocess.TimeoutExpired) as e:
-        return f"{type(e).__name__}: {e}"
-    if proc.returncode != 0:
-        return proc.stderr[-2000:]
+        try:
+            proc = subprocess.run(
+                cmd, capture_output=True, text=True, timeout=300)
+        except (FileNotFoundError, subprocess.TimeoutExpired) as e:
+            return f"{type(e).__name__}: {e}"
+        if proc.returncode != 0:
+            return proc.stderr[-2000:]
+        os.replace(tmp, lib_path)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+    for stale in glob.glob(_LIB_GLOB):      # built from an older source
+        if stale != lib_path:
+            with contextlib.suppress(FileNotFoundError):
+                os.remove(stale)
     return None
 
 
@@ -51,12 +78,12 @@ def _load():
     with _lock:
         if _lib is not None or _build_error is not None:
             return _lib
-        if not os.path.exists(_LIB_PATH) or (
-                os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC)):
-            _build_error = _build()
+        lib_path = _lib_path()
+        if not os.path.exists(lib_path):
+            _build_error = _build(lib_path)
             if _build_error is not None:
                 return None
-        lib = ctypes.CDLL(_LIB_PATH)
+        lib = ctypes.CDLL(lib_path)
         lib.cmn_loader_create.restype = ctypes.c_void_p
         lib.cmn_loader_create.argtypes = [
             ctypes.POINTER(ctypes.c_void_p),

@@ -53,7 +53,6 @@ from chainermn_tpu.parallel._compat import (
     all_gather_invariant as _all_gather_invariant,
     axis_size as _axis_size,
     pcast as _pcast,
-    typeof as _typeof,
 )
 
 __all__ = [
@@ -340,12 +339,6 @@ def _ensure_varying(x, axis_name):
     """Retype ``x`` varying over ``axis_name`` if the vma type system
     considers it invariant: psum_scatter of N identical copies divided
     by N is still the right mean, so both typings reduce correctly."""
-    try:
-        vma = _typeof(x).vma
-    except AttributeError:  # pragma: no cover - pre-vma jax
-        return x
-    if axis_name in vma:
-        return x
     return _pcast(x, axis_name, to="varying")
 
 

@@ -34,6 +34,7 @@ Design (flash-attention v2 schedule, TPU-shaped):
 
 from __future__ import annotations
 
+import contextvars
 import functools
 from typing import Optional
 
@@ -42,9 +43,40 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention", "flash_attention_supported"]
+__all__ = ["flash_attention", "flash_attention_supported",
+           "interpret_kernels", "tracing_for_mesh"]
 
 _NEG = -1e30
+
+# Platform of the devices the program being traced was built for; set
+# by :func:`tracing_for_mesh` around a shard_map body.  ``None`` outside
+# one: the process default backend decides.
+_TRACE_PLATFORM = contextvars.ContextVar(
+    "chainermn_tpu_trace_platform", default=None)
+
+
+def tracing_for_mesh(mesh, fn):
+    """Wrap ``fn`` (a ``shard_map`` body over ``mesh``) so kernels traced
+    inside it compile for the platform of ``mesh``'s devices, not for
+    the process's default backend — a step built on TPU devices holds
+    the compiled kernel whatever ``jax.default_backend()`` says."""
+    platform = mesh.devices.flat[0].platform
+
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        token = _TRACE_PLATFORM.set(platform)
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            _TRACE_PLATFORM.reset(token)
+
+    return traced
+
+
+def interpret_kernels() -> bool:
+    """True when Pallas kernels traced now must run in the interpreter:
+    the target platform (see :func:`tracing_for_mesh`) is not a TPU."""
+    return (_TRACE_PLATFORM.get() or jax.default_backend()) != "tpu"
 _LANE = 128  # TPU lane width: trailing dim of lse/delta and vector scratch
 
 

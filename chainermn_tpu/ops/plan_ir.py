@@ -71,15 +71,9 @@ __all__ = [
 ]
 
 def _pin(x):
-    """``lax.optimization_barrier`` where the running jax supports it
-    inside ``shard_map``.  Pre-vma shard_map (jax 0.4.x ``check_rep``)
-    has no replication rule for the primitive and crashes on it, so
-    there the pin degrades to identity — XLA may then widen a wire
-    cast back to the source dtype, which costs bytes (on hardware
-    that matters; probes measure it) but never correctness."""
-    from chainermn_tpu.parallel._compat import HAS_VMA
-
-    return lax.optimization_barrier(x) if HAS_VMA else x
+    """Keep XLA from widening a wire cast back to the source dtype
+    across this point."""
+    return lax.optimization_barrier(x)
 
 
 # the primitive step vocabulary — a program is a sequence of these

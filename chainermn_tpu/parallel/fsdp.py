@@ -169,10 +169,7 @@ def fsdp_gather(params, dims, axis_name: str = "data", wire_dtype=None,
             # cast-back) and the wire silently widens to the param
             # dtype — verified in HLO: f32-wide gathers barrier-less.
             # optimization_barrier transposes to itself, so the
-            # gradient reduce-scatter stays at wire_dtype too.  (On
-            # pre-vma jax the pin degrades to identity — shard_map's
-            # check_rep has no rule for the primitive; see
-            # ops.plan_ir._pin.)
+            # gradient reduce-scatter stays at wire_dtype too.
             leaf = _pin(leaf.astype(eff))
         out = lax.all_gather(leaf, axis_name, axis=dim, tiled=True)
         if narrowed:

@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from chainermn_tpu.parallel._compat import pcast
+
 __all__ = ["stack_stage_params", "pipeline_apply", "pipeline_train_1f1b",
            "pipeline_train_interleaved", "unstack_stage_params"]
 
@@ -192,8 +194,8 @@ def pipeline_apply(
     # initial carries are zeros that must carry the UNION of the input's
     # varying axes (data/seq/... under composition) plus the pipe axis —
     # deriving them from mbs inherits the vma, the multiply folds away
-    act0 = lax.pcast(mbs[0] * 0, (axis_name,), to="varying")
-    outs0 = lax.pcast(mbs * 0, (axis_name,), to="varying")
+    act0 = pcast(mbs[0] * 0, (axis_name,), to="varying")
+    outs0 = pcast(mbs * 0, (axis_name,), to="varying")
     aux0 = jnp.sum(act0 * 0, dtype=jnp.float32)
     (_, outputs, aux_acc), _ = lax.scan(
         tick, (act0, outs0, aux0), jnp.arange(M + S - 1))
@@ -366,12 +368,12 @@ def pipeline_train_1f1b(
 
     # zero carries derived from real tensors so they inherit the varying
     # mesh axes (vma discipline, as in pipeline_apply)
-    mb0 = lax.pcast(mbs[0] * 0, (axis_name,), to="varying")
+    mb0 = pcast(mbs[0] * 0, (axis_name,), to="varying")
     stash0 = jnp.broadcast_to(mb0, (K, *mb0.shape)) * 1
     gp0 = jax.tree.map(lambda a: a * 0, params)
     glp0 = jax.tree.map(
-        lambda a: lax.pcast(a * 0, (axis_name,), to="varying"), loss_params)
-    dx0 = lax.pcast(mbs * 0, (axis_name,), to="varying")
+        lambda a: pcast(a * 0, (axis_name,), to="varying"), loss_params)
+    dx0 = pcast(mbs * 0, (axis_name,), to="varying")
     loss0 = jnp.sum(mb0 * 0, dtype=jnp.float32)
 
     (_, _, _, gp, glp, dx_bank, loss_acc, aux_acc), _ = lax.scan(
@@ -621,12 +623,12 @@ def pipeline_train_interleaved(
         loss_acc = loss_acc + jnp.where(ba & seed, l_b, 0.0)
         return (y, dx, stash, gp, glp, dx_bank, loss_acc, aux_acc), None
 
-    mb0 = lax.pcast(mbs[0] * 0, (axis_name,), to="varying")
+    mb0 = pcast(mbs[0] * 0, (axis_name,), to="varying")
     stash0 = jnp.broadcast_to(mb0, (V * K, *mb0.shape)) * 1
     gp0 = jax.tree.map(lambda a: a * 0, params)
     glp0 = jax.tree.map(
-        lambda a: lax.pcast(a * 0, (axis_name,), to="varying"), loss_params)
-    dx0 = lax.pcast(mbs * 0, (axis_name,), to="varying")
+        lambda a: pcast(a * 0, (axis_name,), to="varying"), loss_params)
+    dx0 = pcast(mbs * 0, (axis_name,), to="varying")
     loss0 = jnp.sum(mb0 * 0, dtype=jnp.float32)
 
     (_, _, _, gp, glp, dx_bank, loss_acc, aux_acc), _ = lax.scan(

@@ -91,7 +91,7 @@ from jax import lax
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from chainermn_tpu.parallel._compat import pcast, typeof
+from chainermn_tpu.parallel._compat import pcast
 from chainermn_tpu.utils.metrics import get_registry
 from chainermn_tpu.utils.programs import (
     get_accountant,
@@ -110,10 +110,9 @@ __all__ = ["Completion", "Request", "ServingEngine", "TransformerAdapter"]
 
 
 def _vary(x, *axes):
-    """Type ``x`` varying over ``axes`` on vma jax; identity pre-vma
-    (``pcast``/``typeof`` resolve through the compat shims)."""
-    need = tuple(a for a in axes if a not in typeof(x).vma)
-    return pcast(x, need, to="varying") if need else x
+    """Type ``x`` varying over ``axes`` (those it is not varying on
+    yet)."""
+    return pcast(x, axes, to="varying")
 
 
 @dataclasses.dataclass(eq=False)     # identity equality: ndarray fields
@@ -220,9 +219,7 @@ class TransformerAdapter:
 
     Shards like ``make_generate_fn``: batch over ``data×expert``,
     heads over ``model``, layers+cache over ``pipe``; params via
-    ``param_specs``.  Requires vma-typed jax (``TransformerConfig``
-    refuses to construct without it); on older jaxes use
-    :class:`~chainermn_tpu.serving.minilm.MiniLMAdapter`.  MoE configs
+    ``param_specs``.  MoE configs
     are rejected — router capacity depends on batch composition, which
     would break the engine's token-identity guarantee — and ``seq``
     meshes are rejected like every ``pos_offset`` path.
@@ -300,26 +297,19 @@ class TransformerAdapter:
 
     def step_ragged(self, params, caches, tok, t):
         """Per-row-position decode step (the ragged-round engine
-        contract; see ``MiniLMAdapter.step_ragged``).  The flagship
-        ``_decode_step`` advances every row at one scalar position, so
-        the ragged form needs per-row position support in
-        ``models.decoding``'s vma path — not landed yet."""
-        raise NotImplementedError(
-            "TransformerAdapter does not implement the ragged decode "
-            "step: models.decoding._decode_step takes one scalar "
-            "position for the whole batch.  Ragged serving needs the "
-            "per-row-position decode path (future models.decoding "
-            "work); MiniLMAdapter is the runnable ragged reference.")
+        contract; see ``MiniLMAdapter.step_ragged``): ``tok`` (B,),
+        ``t`` (B,) — row ``b``'s token sits at cache position ``t[b]``
+        of its origin-0 row (so there is no ``pos_offset``; a vector
+        position makes ``_decode_step`` scatter K/V per row).  Returns
+        ``(logits (B, V), caches)``."""
+        return self.step(params, caches, tok, t, None)
 
     def verify_ragged(self, params, caches, tok_chunk, t,
                       with_logits=True):
-        """Per-row-start chunk verify (ragged speculation); same gap
-        as :meth:`step_ragged`."""
-        raise NotImplementedError(
-            "TransformerAdapter does not implement the ragged chunk "
-            "verify: models.decoding's chunk path takes one scalar "
-            "start position.  MiniLMAdapter is the runnable ragged "
-            "reference.")
+        """Chunk step at per-row start positions: row ``b``'s chunk
+        occupies ``[t[b], t[b]+C)`` (ragged speculation)."""
+        return self.verify(params, caches, tok_chunk, t, None,
+                           with_logits=with_logits)
 
 
 def _fcfs(queue: Sequence[Request], engine) -> Request:

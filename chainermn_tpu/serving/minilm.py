@@ -4,14 +4,11 @@ A compact MQA causal LM (pre-LN residual blocks, learned positions,
 one shared KV head) whose step/prefill functions follow the engine's
 decode-adapter protocol.  It exists for two reasons:
 
-- **Portability.**  The flagship transformer deliberately refuses to
-  construct on pre-vma jax (its training VJPs need varying-axes
-  typing), which means every engine test and the serving bench would
-  be dead on the jaxes this repo still supports.  MiniLM is written
-  with plain ``jnp`` — no vma typing, no custom VJPs, no axis-name
-  queries — so the engine has a live backend (and the parity suite a
-  runnable oracle) everywhere.  The flagship path rides the same
-  engine through :class:`~chainermn_tpu.serving.TransformerAdapter`.
+- **A plain oracle.**  MiniLM is written with plain ``jnp`` — no
+  custom VJPs, no axis-name queries, no mesh — so the engine's parity
+  suite has a small backend whose every step can be read off the
+  page.  The flagship path rides the same engine through
+  :class:`~chainermn_tpu.serving.TransformerAdapter`.
 - **Protocol example.**  The adapter surface is exactly what a decode
   backend owes the engine: ``make_cache``/``prefill``/``step`` with
   the per-row position-origin (``pos_offset``) contract, plus the

@@ -11,11 +11,9 @@ decode-adapter protocol instead of ``TransformerConfig`` internals:
 
 - **Any adapter pair.**  Drafter and target are two decode adapters
   (``make_cache`` / ``prefill`` / ``step`` / ``verify``).  Two MiniLM
-  configs make the whole subsystem runnable pre-vma — the parity
-  suite's oracle world — while
+  configs are the parity suite's oracle world;
   :class:`~chainermn_tpu.serving.engine.TransformerAdapter` carries
-  the same ``verify`` surface for the flagship (vma-marked, like
-  every ``TransformerConfig`` path).
+  the same ``verify`` surface for the flagship.
 - **Exactness ladder.**  Greedy target ⇒ the output is exactly the
   target-only greedy decode: only verified argmax matches commit, and
   the corrective/bonus token is the target's own argmax (the

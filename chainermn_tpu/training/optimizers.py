@@ -208,11 +208,7 @@ def _grad_accumulation(
         # the invariant typing at negligible cost
         step = state.step
         if axis_name is not None:
-            try:
-                vma = jax.typeof(step).vma
-            except AttributeError:  # pragma: no cover - older jax
-                vma = ()
-            if axis_name in vma:
+            if axis_name in jax.typeof(step).vma:
                 # the counter is identical on every member; pmax is an
                 # EXACT int32 way to restore the replication typing the
                 # cond predicate needs (a float pmean would lose integer
@@ -272,6 +268,7 @@ def _ceil_div(a: int, b: int) -> int:
 
 from chainermn_tpu.parallel._compat import (
     all_gather_invariant as _all_gather_invariant,
+    pcast as _pcast,
 )
 from chainermn_tpu.utils.programs import ledger_jit
 
@@ -280,13 +277,7 @@ def _ensure_varying(x, axis_name):
     """Mark ``x`` varying over ``axis_name`` if the type system considers
     it invariant (pre-reduced grads): psum_scatter of N identical copies
     divided by N is still the right mean, so both typings are correct."""
-    try:
-        vma = jax.typeof(x).vma
-    except AttributeError:  # pragma: no cover - older jax: no vma typing
-        return x
-    if axis_name in vma:
-        return x
-    return jax.lax.pcast(x, axis_name, to="varying")
+    return _pcast(x, axis_name, to="varying")
 
 
 def _leaf_shard(leaf, idx, n: int):

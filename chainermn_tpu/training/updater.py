@@ -51,8 +51,7 @@ def fuse_steps(step_fn, n_steps: int, *, scan_batches: bool = False,
                unroll: int = 1):
     """Fuse ``n_steps`` training steps into ONE XLA program.
 
-    Each host→device dispatch costs fixed latency (notably over remote
-    TPU tunnels, where it is milliseconds); running the step under
+    Each host→device dispatch costs fixed latency; running the step under
     ``lax.scan`` amortises that cost over ``n_steps`` and lets XLA keep
     the whole loop resident on device — the TPU-native analogue of
     "steps_per_execution" loops.  The reference had no equivalent: its
@@ -388,14 +387,13 @@ class StandardUpdater:
             if accum == 1:
                 def global_loss(p):
                     # pmean INSIDE the differentiated function: the
-                    # reported loss is the global mean, and on vma-typed
-                    # jax shard_map's AD psums the cotangents of the
-                    # replicated params so grads leave as the global
-                    # mean too.  On pre-vma jax grads leave device-local
-                    # instead — either way the multi-node optimizer's
-                    # idempotent cross_replica_mean / ZeRO
-                    # reduce-scatter settles the exchange (this is where
-                    # ChainerMN's multi_node_mean_grad went).
+                    # reported loss is the global mean, and shard_map's
+                    # AD psums the cotangents of the replicated params
+                    # so grads leave as the global mean too; the
+                    # multi-node optimizer's idempotent
+                    # cross_replica_mean / ZeRO reduce-scatter settles
+                    # the exchange either way (this is where ChainerMN's
+                    # multi_node_mean_grad went).
                     if stateful:
                         loss, new_model_state = loss_fn(p, state, *batch)
                         return jax.lax.pmean(loss, ax), new_model_state
@@ -409,9 +407,9 @@ class StandardUpdater:
                 # inside the loop body (assert_accum_collectives pins
                 # this on the compiled HLO).  Differentiating the raw
                 # local loss (the mean moved OUT of the differentiated
-                # function) keeps cotangents device-local; on vma-typed
-                # jax the pcast makes that explicit by differentiating
-                # w.r.t. the varying retype of params (identity pre-vma).
+                # function) keeps cotangents device-local; the pcast
+                # makes that explicit by differentiating w.r.t. the
+                # varying retype of params.
                 p_local = jax.tree.map(
                     lambda x: _pcast(x, ax, to="varying"), params)
 

@@ -69,8 +69,7 @@ class Profiler:
     def time_block(self, name: str, nbytes: int = 0, sync=None):
         """Time a block.  ``sync`` (optional callable or array) is invoked /
         materialised before the clock stops, so async-dispatched device
-        work is actually included (block_until_ready alone can return
-        early on experimental backends — anchor on a host transfer).
+        work is actually included.
 
         Disabled → truly zero-cost: no clock reads, and crucially no
         ``device_get`` materialisation — a disabled profiler must never
@@ -128,12 +127,10 @@ def _nbytes(x) -> int:
 
 
 def _materialise(out) -> None:
-    """Force async-dispatched results to the host (the sync anchor
+    """Wait for async-dispatched results (the sync anchor
     ``time_block``'s finally performs) — used when only the flight
     recorder is timing, so its span still covers real completion."""
-    jax.tree.map(
-        lambda a: np.asarray(jax.device_get(a))
-        if hasattr(a, "dtype") else a, out)
+    jax.block_until_ready(out)
 
 
 _COLLECTIVES = (
@@ -220,12 +217,9 @@ def trace(logdir: str, *, host_tracer_level: int = 2):
         with profiling.trace("/tmp/trace"):
             train_some_steps()
     """
-    if hasattr(jax.profiler, "ProfileOptions"):
-        opts = jax.profiler.ProfileOptions()
-        opts.host_tracer_level = host_tracer_level
-        jax.profiler.start_trace(logdir, profiler_options=opts)
-    else:  # older jax: no per-trace options; default tracer levels
-        jax.profiler.start_trace(logdir)
+    opts = jax.profiler.ProfileOptions()
+    opts.host_tracer_level = host_tracer_level
+    jax.profiler.start_trace(logdir, profiler_options=opts)
     try:
         yield
     finally:

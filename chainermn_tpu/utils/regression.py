@@ -1,10 +1,7 @@
 """Perf regression sentinel — noise-aware verdicts over bench history.
 
-``BENCH_MEASURED.json`` accumulates every successful bench record, but
-until now nothing READ that history: a perf regression was discovered
-by a human eyeballing two JSON lines, or not at all.  This module is
-the first piece of perf CI — the missing start of the bench
-trajectory: given a fresh bench record and the history of prior runs
+``_bench_common`` appends every successful unpinned bench record to a
+run history (``BENCH_HISTORY.json``, written at run time).  Given a fresh bench record and the history of prior runs
 of the same metric (and the same workload — batch size, sequence
 length; a toy debug run must never anchor the bound), it computes a
 **noise-aware acceptance bound** and emits a machine-readable verdict.
@@ -63,17 +60,15 @@ REL_SLACK = 0.05
 NOISE_K = 3.0
 MIN_HISTORY = 2
 
-#: Timestamped history entries older than this never anchor a bound —
-#: the same cutoff the measurement cache's fallback applies
-#: (``_bench_common.MAX_CACHE_AGE_DAYS``): a verdict against a
-#: baseline measured on weeks-old code is not a verdict about this
-#: tree.  Legacy un-timestamped entries pass (the leniency that
+#: Timestamped history entries older than this never anchor a bound:
+#: a verdict against a baseline measured on weeks-old code is not a
+#: verdict about this tree.  Legacy un-timestamped entries pass (the leniency that
 #: retires itself).
 MAX_HISTORY_AGE_DAYS = 14.0
 
 
 def load_history(path: str) -> List[dict]:
-    """The run list from a ``BENCH_MEASURED.json``-shaped file
+    """The run list from a ``BENCH_HISTORY.json``-shaped file
     (``{"runs": [...]}``); an unreadable/absent file is an empty
     history, never a crash — the sentinel must degrade to
     ``no_history``, not kill a bench."""
@@ -91,12 +86,10 @@ def history_values(runs: Sequence[dict], metric: str,
                    max_age_days: Optional[float] =
                    MAX_HISTORY_AGE_DAYS) -> List[float]:
     """Values of prior runs of ``metric`` whose recorded workload
-    fields agree with ``match`` (the ``freshest_cached`` convention:
-    a run that predates the recording of a matched field passes —
-    the leniency covers legacy entries and retires itself).  Runs
-    served FROM the cache (``"cached": true``) are replays of an
-    earlier entry, not independent evidence, and are skipped — as are
-    runs the sentinel itself scored ``regression``
+    fields agree with ``match`` (a run that predates the recording of
+    a matched field passes — the leniency covers legacy entries and
+    retires itself).  Runs the sentinel itself scored ``regression``
+    are skipped
     (``"check_verdict": "regression"``): a sustained real regression
     re-run by CI must not pull the baseline down until the gate
     self-normalizes green (an INTENTIONAL perf change re-anchors by
@@ -109,8 +102,6 @@ def history_values(runs: Sequence[dict], metric: str,
     out = []
     for run in runs:
         if run.get("metric") != metric or run.get("value") is None:
-            continue
-        if run.get("cached"):
             continue
         if run.get("check_verdict") == "regression":
             continue

@@ -84,6 +84,10 @@ def main():
     import optax
 
     import chainermn_tpu as cmn
+    from chainermn_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()      # before the first jit
+
     from chainermn_tpu.models import (
         ResNetConfig, init_resnet, resnet_apply, softmax_cross_entropy,
         accuracy,
@@ -152,13 +156,15 @@ def main():
 
     converter = None
     if args.loader == "native":
-        from chainermn_tpu.native import NativeBatchIterator, \
-            native_available
+        from chainermn_tpu import native
+        from chainermn_tpu.native import NativeBatchIterator
 
-        if comm.rank == 0:
-            backend = ("ACTIVE" if native_available()
-                       else "unavailable (pure-python fallback)")
-            print(f"native loader: C++ backend {backend}")
+        if not native.native_available():
+            # asked for by name: the pure-python stand-in would be
+            # measured as the C++ loader
+            raise SystemExit(
+                "--loader native: the C++ loader could not be built "
+                f"({native._build_error}); use --loader serial")
         # the native loader batches memory-resident field arrays:
         # materialise this process's scattered shard once up front —
         # bounded, because a full-size synthetic shard would be tens of

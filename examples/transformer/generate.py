@@ -106,6 +106,11 @@ def main():
         jax.config.update("jax_platforms", args.platform)
 
     import jax
+
+    from chainermn_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()      # before the first jit
+
     import jax.numpy as jnp
 
     from chainermn_tpu.models import (

@@ -11,7 +11,7 @@ model (see ``chainermn_tpu.utils.comm_model``):
    against the closed-form volume formulas (the validation step — a
    formula that can't reproduce the parser's numbers is wrong);
 3. apply the validated formulas at benchmark scale, combine with the
-   measured single-chip step times (BENCH_MEASURED.json) and the
+   single-chip step times measured on 2026-07-29 (constants below) and the
    interconnect's published bandwidth, and predict scaling efficiency.
 
 Writes SCALING_RAW.json; SCALING.md narrates the result.  Pure CPU —

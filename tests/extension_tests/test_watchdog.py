@@ -44,7 +44,11 @@ class TestWatchdogUnit:
         wd.start()
         try:
             wd.heartbeat(iteration=7)
-            deadline = time.monotonic() + 0.4 + 0.1 + 0.3  # +slack
+            # the wait is the TEST's patience, not the claim: on a host
+            # shared with five other xdist workers the monitor thread
+            # can wake late, and a tight wall-clock deadline here made
+            # this test fail for reasons that are not the watchdog's
+            deadline = time.monotonic() + 10.0
             while not reports and time.monotonic() < deadline:
                 time.sleep(0.02)
         finally:
@@ -53,7 +57,9 @@ class TestWatchdogUnit:
         rep = reports[0]
         assert rep["kind"] == "local-stall"
         assert rep["iteration"] == 7
-        assert rep["seconds_since_heartbeat"] > 0.4
+        # fired on the monitor's own clock at the first wake past the
+        # timeout (one check interval late at most, plus host jitter)
+        assert 0.4 < rep["seconds_since_heartbeat"] < 5.0
         # the structured report carries every thread's Python stack
         assert any("MainThread" in k for k in rep["threads"])
         on_disk = json.load(open(tmp_path / "stall.json"))

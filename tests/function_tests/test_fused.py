@@ -326,10 +326,12 @@ class TestCollectiveBudget:
         baseline = stackmap(mesh, lambda g: jax.tree.map(
             lambda a: jax.lax.pmean(a, AX), g))
         base_stats = collective_stats(baseline.lower(tree).compile())
-        # XLA may merge some per-leaf pmeans; the point is the fused
-        # path is structurally bounded while the baseline scales with
-        # the leaf count
-        assert base_stats["all-reduce"].count > observed
+        # the point is that the fused path is structurally bounded
+        # (above).  What the per-leaf baseline compiles to is XLA's
+        # choice: on this jax XLA:CPU's combiner merges all 120 pmeans
+        # into ONE all-reduce, so the baseline can only be asserted
+        # not to undercut the fused count
+        assert base_stats["all-reduce"].count >= observed
 
     def test_budget_violation_raises(self, mesh):
         tree = self.big_tree(mesh.devices.size, n_leaves=16)

@@ -1,0 +1,185 @@
+"""The main path's kernels and the 300M train step, compiled for a
+described (not attached) TPU v5e at the sizes ``chip_smoke.py`` runs.
+
+Nothing executes here: the TPU compiler is asked whether the program is
+legal and how much device memory it needs.  Every other test in the
+suite runs the Pallas kernels through the interpreter, which accepts
+tilings, VMEM budgets and SMEM operands the chip's compiler refuses.
+
+The topology is described inside a module-scoped fixture (never at
+import): only the xdist worker that runs this file loads the TPU
+library.  This is the ONLY test file that does.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+from chainermn_tpu.ops.pallas_attention import flash_attention
+
+HBM_BYTES = 16 * 2 ** 30        # one v5e chip
+B, T, H, D = 8, 2048, 16, 64    # chip_smoke's attention shape
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-device executable cannot be read back from the
+    # persistent cache without a chip; keep these compiles out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _qkv(sharding, t=T):
+    s = jax.ShapeDtypeStruct((B, t, H, D), jnp.bfloat16,
+                             sharding=sharding)
+    return s, s, s
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), \
+        "the Pallas kernel is not in the compiled program"
+    return compiled
+
+
+@pytest.mark.parametrize("window", [None, 1024], ids=["full", "window1024"])
+def test_flash_forward_compiles(one_chip, window):
+    _compile(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window), *_qkv(one_chip))
+
+
+def test_flash_backward_compiles(one_chip):
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(one_chip))
+
+
+def test_flash_ring_call_form_compiles(one_chip):
+    """The per-pair call ring attention makes: lse returned and
+    differentiated, global offsets as TRACED scalars (SMEM operands)."""
+    off = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    def loss(q, k, v, q_off, k_off):
+        o, lse = flash_attention(
+            q, k, v, causal=True, q_offset=q_off, k_offset=k_off,
+            return_lse=True)
+        return o.astype(jnp.float32).sum() + lse.sum()
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)),
+             *_qkv(one_chip, t=512), off, off)
+
+
+@pytest.mark.parametrize("layout", ["contiguous", "zigzag"])
+def test_ring_pair_compiles_on_four_chips(topo, layout):
+    """The whole ring (kernel pair + ppermute scan) under shard_map over
+    the four described devices, forward and backward, GQA 16q/4kv."""
+    from chainermn_tpu.parallel.ring_attention import ring_attention
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("seq",))
+    spec = P(None, "seq")
+    sh = NamedSharding(mesh, spec)
+    q = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16, sharding=sh)
+    kv = jax.ShapeDtypeStruct((B, T, 4, D), jnp.bfloat16, sharding=sh)
+
+    def loss(q, k, v):
+        o = jax.shard_map(
+            lambda q, k, v: ring_attention(
+                q, k, v, axis_name="seq", causal=True, use_flash=True,
+                layout=layout),
+            mesh=mesh, in_specs=(spec,) * 3, out_specs=spec)(q, k, v)
+        return o.astype(jnp.float32).sum()
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv).as_text()
+    assert "collective-permute" in text
+
+
+def _smoke_step(devices, mesh_axes, **cfg_kw):
+    """chip_smoke's own 300M train step compiled for described devices
+    (shapes only: there is no device to hold an array)."""
+    import chip_smoke
+    from chainermn_tpu.models import (
+        init_transformer, make_train_step, param_specs,
+    )
+    from chainermn_tpu.parallel import MeshConfig
+
+    cfg = chip_smoke.transformer_config(tiny=False, **cfg_kw)
+    mc = MeshConfig(devices=devices, **mesh_axes)
+    opt = chip_smoke.transformer_optimizer()
+    shapes = jax.eval_shape(
+        lambda: init_transformer(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=mc.sharding(*s)),
+        shapes, param_specs(cfg))
+    opt_shapes = jax.eval_shape(opt.init, params)
+    # Adam's moments follow their parameter's sharding (what
+    # shard_opt_state pins); scalars are replicated
+    by_shape = {}
+    for leaf in jax.tree.leaves(params):
+        by_shape.setdefault(leaf.shape, leaf.sharding)
+    opt_state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype,
+            sharding=by_shape.get(a.shape, mc.replicated())),
+        opt_shapes)
+    tok = jax.ShapeDtypeStruct(
+        (chip_smoke.LM_BATCH, chip_smoke.LM_SEQ), jnp.int32,
+        sharding=mc.sharding(("data", "expert"), "seq"))
+    step = make_train_step(mc, cfg, opt)
+    return step.lower(params, opt_state, tok, tok).compile()
+
+
+def _device_bytes(compiled):
+    m = compiled.memory_analysis()
+    return m.argument_size_in_bytes + m.temp_size_in_bytes
+
+
+def test_300m_train_step_fits_one_chip(topo):
+    """The whole train-transformer phase of chip_smoke.py on one
+    described chip: the kernel is in the program (not the interpreter,
+    not the XLA attention) and arguments + temporaries fit 16 GiB under
+    the remat policy the smoke uses."""
+    compiled = _smoke_step(topo.devices[:1], dict(data=1))
+    assert "tpu_custom_call" in compiled.as_text()
+    need = _device_bytes(compiled)
+    assert need < HBM_BYTES, f"{need / 2**30:.1f} GiB > 16 GiB"
+
+
+@pytest.mark.parametrize("axes,cfg_kw", [
+    (dict(data=4), dict(fsdp=True)),
+    (dict(data=1, seq=4), dict(attention="ring")),
+], ids=["fsdp-data4", "ring-seq4"])
+def test_300m_train_step_four_chips(topo, axes, cfg_kw):
+    """chip_smoke.py --chips 4's two transformer programs on the four
+    described devices: the kernel and the collectives are in, and each
+    device's share fits."""
+    compiled = _smoke_step(topo.devices, axes, **cfg_kw)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("all-gather" in text) if "fsdp" in cfg_kw \
+        else ("collective-permute" in text)
+    assert _device_bytes(compiled) < HBM_BYTES

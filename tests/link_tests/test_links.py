@@ -16,14 +16,6 @@ from chainermn_tpu.links import (
     multi_node_batch_normalization,
 )
 
-from chainermn_tpu.testing import requires_vma as _requires_vma
-
-# Pre-vma shard_map (old check_rep) cannot express what these tests pin:
-# grads of replicated outputs taken inside shard_map over-count by the
-# axis size, replicated out_specs can't be inferred through gathers, and
-# scan carries may not gain replication.  vma typing (jax >= 0.7) is the
-# semantic fix; on older jax the cases below are undefined, not wrong.
-requires_vma = _requires_vma("requires vma-typed shard_map AD semantics")
 
 AX = "world"
 
@@ -82,7 +74,6 @@ class TestMultiNodeBatchNorm:
             rtol=1e-4, atol=1e-5)
         assert new_state is state
 
-    @requires_vma
     def test_gradients_flow(self, mesh):
         params, state = init_batch_norm(4)
         x = np.random.RandomState(2).randn(16, 4).astype(np.float32)

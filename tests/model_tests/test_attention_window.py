@@ -21,14 +21,6 @@ from chainermn_tpu.parallel.ring_attention import (
     ring_attention,
 )
 
-from chainermn_tpu.testing import requires_vma as _requires_vma
-
-# Pre-vma shard_map (old check_rep) cannot express what these tests pin:
-# grads of replicated outputs taken inside shard_map over-count by the
-# axis size, replicated out_specs can't be inferred through gathers, and
-# scan carries may not gain replication.  vma typing (jax >= 0.7) is the
-# semantic fix; on older jax the cases below are undefined, not wrong.
-requires_vma = _requires_vma("requires vma-typed shard_map AD semantics")
 
 W = 5
 VOCAB, B, T = 64, 4, 16
@@ -62,7 +54,6 @@ def test_local_attention_window_matches_oracle():
         local_attention(q, k, v, window=W)
 
 
-@requires_vma
 def test_flash_kernel_window_fwd_bwd():
     """Kernel (interpret mode) vs oracle, values AND grads — the block
     skipping must not drop in-window contributions."""
@@ -86,7 +77,6 @@ def test_flash_kernel_window_fwd_bwd():
                                    rtol=1e-4, atol=1e-4)
 
 
-@requires_vma
 def test_flash_kernel_window_with_offsets():
     """The offset+window block-skip arithmetic (the ring-flash pairing's
     riskiest inequality): kernel with global offsets vs the XLA core at
@@ -174,7 +164,6 @@ def window_cfg(**kw):
     (dict(seq=4, data=2), dict(attention="ring")),
     (dict(seq=2, data=4), dict(attention="ulysses")),
 ], ids=["ring", "ulysses"])
-@requires_vma
 def test_windowed_model_sharded_matches_single(axes, kw):
     cfg = window_cfg(**kw)
     params = init_transformer(jax.random.PRNGKey(0), cfg)
@@ -193,7 +182,6 @@ def test_windowed_model_sharded_matches_single(axes, kw):
                                rtol=3e-4, atol=3e-4)
 
 
-@requires_vma
 def test_windowed_decode_matches_forward():
     from tests.model_tests.test_decoding import (
         _cached_logits_all_positions)
@@ -210,7 +198,6 @@ def test_windowed_decode_matches_forward():
                                rtol=2e-4, atol=2e-4)
 
 
-@requires_vma
 def test_negative_window_rejected():
     with pytest.raises(ValueError, match="attention_window"):
         window_cfg(attention_window=-1)

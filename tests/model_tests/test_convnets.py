@@ -14,14 +14,6 @@ from chainermn_tpu.models import (
 )
 from chainermn_tpu.parallel import MeshConfig
 
-from chainermn_tpu.testing import requires_vma as _requires_vma
-
-# Pre-vma shard_map (old check_rep) cannot express what these tests pin:
-# grads of replicated outputs taken inside shard_map over-count by the
-# axis size, replicated out_specs can't be inferred through gathers, and
-# scan carries may not gain replication.  vma typing (jax >= 0.7) is the
-# semantic fix; on older jax the cases below are undefined, not wrong.
-requires_vma = _requires_vma("requires vma-typed shard_map AD semantics")
 
 B, HW, C = 8, 32, 8
 
@@ -44,7 +36,6 @@ def test_unknown_arch_rejected():
         ConvNetConfig(arch="resnext")
 
 
-@requires_vma
 def test_dp_step_reduces_loss():
     import optax
 

@@ -20,14 +20,6 @@ from chainermn_tpu.models import (
 )
 from chainermn_tpu.parallel import MeshConfig
 
-from chainermn_tpu.testing import requires_vma as _requires_vma
-
-# Pre-vma shard_map (old check_rep) cannot express what these tests pin:
-# grads of replicated outputs taken inside shard_map over-count by the
-# axis size, replicated out_specs can't be inferred through gathers, and
-# scan carries may not gain replication.  vma typing (jax >= 0.7) is the
-# semantic fix; on older jax the cases below are undefined, not wrong.
-requires_vma = _requires_vma("requires vma-typed shard_map AD semantics")
 
 VOCAB, B, T = 64, 8, 16
 
@@ -113,7 +105,6 @@ RESUME_TARGETS = [
 @pytest.mark.parametrize(
     "name,cfg_kw,axes", RESUME_TARGETS,
     ids=[t[0] for t in RESUME_TARGETS])
-@requires_vma
 def test_elastic_resume_matches_uninterrupted(name, cfg_kw, axes):
     """Train on a data=4 mesh, snapshot mid-run, reshard to a different
     topology and continue: the loss trajectory must match the

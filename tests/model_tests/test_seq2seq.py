@@ -18,15 +18,6 @@ from chainermn_tpu.models.seq2seq import (
 from chainermn_tpu.parallel import MeshConfig
 
 
-from chainermn_tpu.testing import requires_vma as _requires_vma
-
-# Pre-vma shard_map (old check_rep) cannot express what these tests pin:
-# grads of replicated outputs taken inside shard_map over-count by the
-# axis size, replicated out_specs can't be inferred through gathers, and
-# scan carries may not gain replication.  vma typing (jax >= 0.7) is the
-# semantic fix; on older jax the cases below are undefined, not wrong.
-requires_vma = _requires_vma("requires vma-typed shard_map AD semantics")
-
 CFG = Seq2seqConfig(
     src_vocab=20, tgt_vocab=20, d_embed=16, d_hidden=16, n_layers=2)
 
@@ -92,7 +83,6 @@ def test_reverse_task_converges_and_translates():
             assert (row[hit[0] + 1:] == PAD).all()
 
 
-@requires_vma
 def test_dp_grads_match_single_device_on_ragged_batch():
     """The reference's 'variable-length allreduce': data-sharded ragged
     batches produce the same *weighted* global gradient as one device.

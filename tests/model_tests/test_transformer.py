@@ -17,14 +17,6 @@ from chainermn_tpu.models import (
 )
 from chainermn_tpu.parallel import MeshConfig
 
-from chainermn_tpu.testing import requires_vma as _requires_vma
-
-# The flagship transformer's custom VJPs read jax.typeof(...).vma to
-# place their psums; TransformerConfig deliberately refuses to construct
-# on pre-vma jax (models/transformer.py).  Nothing in this module can
-# run without it.
-pytestmark = _requires_vma(
-    "requires vma-typed shard_map (TransformerConfig refuses pre-vma jax)")
 
 VOCAB, B, T = 64, 8, 16
 
@@ -224,6 +216,17 @@ def test_flash_attention_matches_oracle():
     out = make_forward_fn(mc, cfg)(shard_params(mc, cfg, params), toks)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4)
+
+
+def test_flash_unsupported_length_is_an_error():
+    """attention="flash" never swaps in the XLA attention silently: a
+    length the kernel cannot tile is refused (name "local" instead)."""
+    cfg = tiny_cfg(attention="flash", max_seq=12)
+    params = init_transformer(jax.random.PRNGKey(0), cfg)
+    mc = MeshConfig(data=1, devices=jax.devices()[:1])
+    with pytest.raises(ValueError, match='attention="local"'):
+        make_forward_fn(mc, cfg)(
+            shard_params(mc, cfg, params), tokens()[:1, :12])
 
 
 def test_flash_bwd_block_override_train_step_exact():

@@ -22,14 +22,6 @@ from chainermn_tpu.models import (
 from chainermn_tpu.models.transformer import lm_loss, param_specs
 from chainermn_tpu.parallel import MeshConfig
 
-from chainermn_tpu.testing import requires_vma as _requires_vma
-
-# The flagship transformer's custom VJPs read jax.typeof(...).vma to
-# place their psums; TransformerConfig deliberately refuses to construct
-# on pre-vma jax (models/transformer.py).  Nothing in this module can
-# run without it.
-pytestmark = _requires_vma(
-    "requires vma-typed shard_map (TransformerConfig refuses pre-vma jax)")
 
 VOCAB, B, T = 64, 8, 16
 

@@ -25,7 +25,7 @@ def _free_port() -> int:
 
 def _worker_env(devices_per_proc: int = 1) -> dict:
     env = dict(os.environ)
-    # plain CPU devices; scrub TPU-plugin and parent-test mesh settings
+    # plain CPU devices; scrub TPU and parent-test mesh settings
     # so each worker builds its own world
     for k in list(env):
         if k.startswith(("TPU_", "LIBTPU", "PJRT_", "JAX_", "XLA_")):

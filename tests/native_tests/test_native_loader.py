@@ -36,6 +36,25 @@ def collect_epoch(it):
     return np.concatenate(xs), np.concatenate(ys)
 
 
+def test_library_follows_the_source_not_mtimes(tmp_path, monkeypatch):
+    """The library that runs is the one built from loader.cpp as it
+    stands: its file name carries the source's hash, so an edited
+    source is rebuilt even when a stale binary is NEWER than it (a
+    copied tree, a checkout) — and an untouched source is not."""
+    import os
+    import shutil
+
+    src = tmp_path / "loader.cpp"
+    shutil.copy(native._SRC, src)
+    monkeypatch.setattr(native, "_SRC", str(src))
+    same = native._lib_path()
+    assert same == native._lib_path()
+    with open(src, "a") as f:
+        f.write("\n// edited\n")
+    os.utime(src, (0, 0))           # older than any binary on disk
+    assert native._lib_path() != same
+
+
 def test_sequential_coverage_and_order():
     x, y = fields()
     it = NativeBatchIterator([x, y], BS, shuffle=False)

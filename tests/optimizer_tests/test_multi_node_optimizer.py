@@ -219,14 +219,6 @@ class TestGradientAccumulation:
     @pytest.mark.parametrize("zero1", [False, True])
     @pytest.mark.parametrize("inner", ["sgd", "adam"])
     def test_two_micro_steps_equal_one_big(self, comm, zero1, inner):
-        from chainermn_tpu.parallel._compat import HAS_VMA
-
-        if not zero1 and not HAS_VMA:
-            # the pmean path's accumulation scan carry gains replication
-            # the first time the mean fires; old check_rep forbids a
-            # rep-gaining carry (the zero1 arm's reduce-scatter typing
-            # stays varying, so it runs everywhere)
-            pytest.skip("pmean accumulation scan requires vma typing")
         make = {"sgd": lambda: optax.sgd(0.5),
                 "adam": lambda: optax.adam(1e-2)}[inner]
         n = comm.size

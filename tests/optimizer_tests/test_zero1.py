@@ -23,14 +23,6 @@ from chainermn_tpu.training.optimizers import (
     zero1_optimizer,
 )
 
-from chainermn_tpu.testing import requires_vma as _requires_vma
-
-# Pre-vma shard_map (old check_rep) cannot express what these tests pin:
-# grads of replicated outputs taken inside shard_map over-count by the
-# axis size, replicated out_specs can't be inferred through gathers, and
-# scan carries may not gain replication.  vma typing (jax >= 0.7) is the
-# semantic fix; on older jax the cases below are undefined, not wrong.
-requires_vma = _requires_vma("requires vma-typed shard_map AD semantics")
 
 AX = "world"
 
@@ -81,7 +73,6 @@ def _run_steps(comm, opt, params, grads_per_rank, n_steps=3):
 
 
 @pytest.mark.parametrize("inner", ["adam", "sgd_momentum", "adamw"])
-@requires_vma
 def test_matches_replicated_path(comm, inner):
     n = comm.size
     make = {
@@ -123,7 +114,6 @@ def test_state_is_sharded(comm):
     assert shapes["s"] == (-(-1 // n),)
 
 
-@requires_vma
 def test_bf16_wire(comm):
     n = comm.size
     params, grads = _params(), _grads_per_rank(n)
@@ -181,7 +171,6 @@ def test_persistent_state_across_jit_boundaries(comm):
     np.testing.assert_allclose(params["w"], w_true, atol=0.05)
 
 
-@requires_vma
 def test_create_multi_node_optimizer_zero1_double_buffering(comm):
     n = comm.size
     params, grads = _params(), _grads_per_rank(n)

@@ -37,14 +37,6 @@ from chainermn_tpu.training.optimizers import (
     zero2_optimizer,
 )
 
-from chainermn_tpu.testing import requires_vma as _requires_vma
-
-# Pre-vma shard_map (old check_rep) cannot express what these tests pin:
-# scan carries may not gain replication and grads of replicated outputs
-# over-count by the axis size.  vma typing (jax >= 0.7) is the semantic
-# fix; on older jax the cases below are undefined, not wrong.  The
-# external-loop tests below cover the same parity claims un-gated.
-requires_vma = _requires_vma("requires vma-typed shard_map AD semantics")
 
 AX = "world"
 
@@ -93,7 +85,6 @@ def _run_steps(comm, opt, params, grads_per_rank, n_steps=3):
 
 
 @pytest.mark.parametrize("inner", ["adam", "sgd_momentum", "adamw"])
-@requires_vma
 def test_matches_replicated_path(comm, inner):
     n = comm.size
     make = {
@@ -112,13 +103,6 @@ def test_matches_replicated_path(comm, inner):
         for i in range(1, n):
             np.testing.assert_array_equal(g[i], g[0])
         np.testing.assert_allclose(g[0], r[0], rtol=2e-5, atol=2e-6)
-
-
-# --------------------------------------------------------------------- #
-# the un-gated parity drill: jitted step called in a Python loop with a
-# world-stacked state carry (the real-training pattern, expressible on
-# pre-vma shard_map)
-# --------------------------------------------------------------------- #
 
 
 def _train(comm, make_opt, sharded, n_steps=4):

@@ -1,10 +1,9 @@
-"""Regression tests for ``parallel/_compat.py`` — the one-place
-version-compat layer.  The failure mode it guards: an import chain
+"""``parallel/_compat.py`` — the one place the package's shard_map
+vocabulary is resolved.  The failure mode it guards: an import chain
 (`models.transformer` → `_compat`) raising ImportError on the installed
-jax took 35 of 158 test files down *at collection* (the
-``all_gather_invariant`` import had no fallback for jaxes that predate
-the primitive).  These tests pin that every compat symbol resolves and
-behaves on whatever jax is installed."""
+jax takes every test file that imports the models down *at collection*
+(``all_gather_invariant`` is not public in jax 0.9.0).  These tests pin
+that every symbol resolves and behaves on the installed jax."""
 
 import jax
 import jax.numpy as jnp
@@ -17,9 +16,9 @@ AX = "world"
 
 
 def test_models_transformer_imports_cleanly():
-    """THE regression: this exact import is the one 35 test files died
-    on when _compat had no third fallback.  Run in a fresh interpreter
-    so a warm ``sys.modules`` can't mask an import-time failure."""
+    """THE regression: this exact import is the one 35 test files once
+    died on.  Run in a fresh interpreter so a warm ``sys.modules``
+    can't mask an import-time failure."""
     import os
     import subprocess
     import sys
@@ -40,9 +39,8 @@ def test_compat_exports_resolve():
         assert getattr(_compat, name) is not None
 
 
-def test_jax_namespace_shims_installed():
-    """Call sites across the package use the modern spellings directly —
-    they must resolve regardless of jax version."""
+def test_jax_namespace_has_the_spellings_the_package_uses():
+    """Call sites across the package use these spellings directly."""
     assert callable(jax.shard_map)
     assert callable(jax.typeof)
     assert callable(jax.lax.axis_size)
@@ -50,7 +48,7 @@ def test_jax_namespace_shims_installed():
 
 
 def test_all_gather_invariant_gathers(comm):
-    """The shim (or the real primitive) gathers a varying value into the
+    """The primitive gathers a varying value into the
     identical full array on every member — and the result types as
     replicated (out_specs P() must be accepted)."""
     n = comm.size
@@ -82,14 +80,20 @@ def test_axis_size_is_static(comm):
 
 
 def test_pcast_and_typeof_roundtrip(comm):
-    """pcast retypes (or is the identity pre-vma) without changing
-    values; typeof always exposes a ``vma`` set."""
+    """pcast retypes without changing values — also a value that is
+    ALREADY varying over the axis, which ``lax.pcast`` on jax 0.9
+    refuses (callers cannot know how their operand arrives); typeof
+    exposes the ``vma`` set."""
     x = np.random.RandomState(1).randn(comm.size, 4).astype(np.float32)
 
     def body(s):
+        assert comm.axis_name in _compat.typeof(s).vma  # arrives varying
         v = _compat.pcast(s, (comm.axis_name,), to="varying")
-        assert isinstance(_compat.typeof(v).vma, (frozenset, set, tuple))
-        return v
+        inv = jnp.zeros(s.shape, s.dtype)               # arrives invariant
+        assert comm.axis_name not in _compat.typeof(inv).vma
+        inv = _compat.pcast(inv, comm.axis_name, to="varying")
+        assert comm.axis_name in _compat.typeof(inv).vma
+        return v + inv
 
     out = jax.jit(jax.shard_map(
         body, mesh=comm.mesh, in_specs=P(comm.axis_name),

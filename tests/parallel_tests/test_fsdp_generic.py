@@ -18,15 +18,6 @@ from chainermn_tpu.parallel import (
 )
 from chainermn_tpu.training import shard_opt_state
 
-from chainermn_tpu.testing import requires_vma as _requires_vma
-
-# Pre-vma shard_map (old check_rep) cannot express what these tests pin:
-# grads of replicated outputs taken inside shard_map over-count by the
-# axis size, replicated out_specs can't be inferred through gathers, and
-# scan carries may not gain replication.  vma typing (jax >= 0.7) is the
-# semantic fix; on older jax the cases below are undefined, not wrong.
-requires_vma = _requires_vma("requires vma-typed shard_map AD semantics")
-
 
 def test_fsdp_dims_selection():
     params = {
@@ -119,7 +110,6 @@ def _train(use_fsdp, wire_dtype=None, steps=4):
         lambda a: np.asarray(jax.device_get(a)), params), params
 
 
-@requires_vma
 def test_fsdp_mlp_matches_replicated():
     losses_d, final_d, _ = _train(False)
     losses_f, final_f, placed = _train(True)
@@ -139,7 +129,6 @@ def test_fsdp_mlp_at_rest_and_moments_sharded():
         == (16, 8)
 
 
-@requires_vma
 def test_fsdp_mlp_bf16_wire_trains():
     losses, _, _ = _train(True, wire_dtype=jnp.bfloat16, steps=6)
     assert losses[-1] < losses[0]

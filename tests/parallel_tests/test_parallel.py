@@ -25,14 +25,6 @@ from chainermn_tpu.parallel import (
 from chainermn_tpu.parallel.ring_attention import local_attention
 from chainermn_tpu.parallel.ulysses import ulysses_attention
 
-from chainermn_tpu.testing import requires_vma as _requires_vma
-
-# Pre-vma shard_map (old check_rep) cannot express what these tests pin:
-# grads of replicated outputs taken inside shard_map over-count by the
-# axis size, replicated out_specs can't be inferred through gathers, and
-# scan carries may not gain replication.  vma typing (jax >= 0.7) is the
-# semantic fix; on older jax the cases below are undefined, not wrong.
-requires_vma = _requires_vma("requires vma-typed shard_map AD semantics")
 
 AX = "world"
 
@@ -91,7 +83,6 @@ class TestTensorParallel:
                                    rtol=1e-4, atol=1e-5)
         assert n == mesh.devices.size
 
-    @requires_vma
     def test_tp_gradients_match(self, mesh):
         rng = np.random.RandomState(1)
         x = rng.randn(4, 8).astype(np.float32)
@@ -228,7 +219,6 @@ class TestRingAttention:
                                    rtol=2e-4, atol=2e-5)
 
     @pytest.mark.parametrize("causal", [False, True])
-    @requires_vma
     def test_gradients_match(self, mesh, causal):
         B, T, H, D = 1, 16, 2, 4
         q, k, v = _qkv((B, T, H, D), seed=8)

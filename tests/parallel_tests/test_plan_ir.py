@@ -49,8 +49,10 @@ def run_spmd(fn, tree, mesh=None, spec=None):
         return jax.tree.map(lambda a: a[None], out)
 
     stacked = jax.tree.map(lambda a: jnp.stack([a] * n), tree)
-    return jax.shard_map(body, mesh=mesh, in_specs=spec,
-                         out_specs=spec)(stacked)
+    # jitted, as every caller in the package is: eager shard_map on this
+    # jax asserts on a zero-size output (XLA reports it replicated)
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=spec,
+                                 out_specs=spec))(stacked)
 
 
 def assert_bitwise(got, want, label=""):

@@ -1,7 +1,6 @@
 """The flagship transformer behind the serving engine: token identity
 against ``make_generate_fn``'s own ragged static decode on a DP×TP
-mesh.  vma-gated like every TransformerConfig test (the engine itself
-is exercised everywhere through MiniLM)."""
+mesh — per-token ragged rounds and per-row speculative rounds."""
 
 import numpy as np
 import pytest
@@ -10,10 +9,7 @@ import jax
 
 from chainermn_tpu.parallel import MeshConfig
 from chainermn_tpu.serving import ServingEngine, TransformerAdapter
-from chainermn_tpu.testing import requires_vma
 
-pytestmark = requires_vma(
-    "requires vma-typed shard_map (TransformerConfig refuses pre-vma jax)")
 
 VOCAB, PMAX, NEW = 64, 8, 10
 
@@ -27,7 +23,7 @@ def _cfg():
         pos_embedding="rope", dtype="float32", remat=False)
 
 
-def test_engine_matches_static_generate_dp_tp():
+def _engine_matches_static_generate(speculative):
     from chainermn_tpu.models import (
         init_transformer, make_generate_fn, shard_params,
     )
@@ -50,14 +46,27 @@ def test_engine_matches_static_generate_dp_tp():
     ref = np.asarray(gen(params, batch, prompt_lens=np.asarray(lens)))
 
     adapter = TransformerAdapter(mc, cfg)
+    # self-draft speculation drives verify_ragged (per-row chunk starts)
+    # and must not move a token whatever the draft proposes
+    spec = dict(draft_adapter=adapter, draft_params=host, spec_k=3) \
+        if speculative else {}
     eng = ServingEngine(adapter, host, n_slots=4, horizon=64,
-                        max_prompt=PMAX, block=8, round_tokens=4)
+                        max_prompt=PMAX, block=8, round_tokens=4, **spec)
     rids = [eng.submit(p, max_new=NEW) for p in prompts]
     comps = {c.rid: c for c in eng.run(max_steps=500)}
     for b, rid in enumerate(rids):
+        assert comps[rid].status == "ok", comps[rid].detail
         np.testing.assert_array_equal(
             comps[rid].tokens, ref[b, PMAX:],
             err_msg=f"row {b} diverged from the static ragged decode")
+
+
+def test_engine_matches_static_generate_dp_tp():
+    _engine_matches_static_generate(speculative=False)
+
+
+def test_engine_self_draft_speculation_matches_static_generate_dp_tp():
+    _engine_matches_static_generate(speculative=True)
 
 
 def test_adapter_rejects_moe_and_seq():

@@ -20,14 +20,6 @@ from chainermn_tpu.utils import (
     wire_bytes_per_device,
 )
 
-from chainermn_tpu.testing import requires_vma as _requires_vma
-
-# These two compile real model steps (bench.py's ResNet DP step, the
-# flagship decode program); both need vma-typed shard_map — pre-vma
-# check_rep can't infer their replicated out_specs / the transformer
-# refuses to construct.
-requires_vma = _requires_vma("compiled step requires vma-typed shard_map")
-
 
 def _compile(fn, mesh, in_specs, out_specs, *args):
     return jax.jit(jax.shard_map(
@@ -209,17 +201,17 @@ def test_looped_collectives_and_accum_assert():
 
     def fused_shape(t):
         # accumulate locally, exchange once AFTER the scan
+        # (the local sums are varying over the axis, so the carry
+        # starts varying too)
         acc, _ = lax.scan(lambda a, x: (a + jnp.sum(x, 0), 0.0),
-                          jnp.zeros((16,), jnp.float32), t)
+                          lax.pcast(jnp.zeros((16,), jnp.float32),
+                                    "data", to="varying"), t)
         return lax.pmean(acc, "data")
 
     def per_micro_shape(t):
-        # exchange INSIDE the scan body: M collectives per window.  The
-        # carry init is psummed once OUTSIDE so its pre-vma replication
-        # type matches the in-loop psum's output (a rep-gaining carry
-        # refuses to compile on old check_rep); the loop placement is
-        # what the parser must see either way.
-        a0 = lax.psum(jnp.zeros((16,), jnp.float32), "data")
+        # exchange INSIDE the scan body: M collectives per window
+        # (the carry stays invariant: it only ever adds psums)
+        a0 = jnp.zeros((16,), jnp.float32)
 
         def body(a, x):
             g = lax.psum(jnp.sum(x, 0), "data")
@@ -268,7 +260,6 @@ def test_wire_formulas():
         wire_bytes_per_device("broadcast", 1, 2)
 
 
-@requires_vma
 def test_bench_resnet_dp_step_single_reduce():
     """Regression pin for the SCALING.md finding: bench.py's DP step
     must all-reduce each gradient ONCE.  The pre-fix step pmean'd grads
@@ -344,7 +335,6 @@ def test_axis_report_attributes_dp_gradient_allreduce():
         2 * n_params * 4 * 7 / 8
 
 
-@requires_vma
 def test_decode_program_parses_per_token_slices():
     """The decode factories expose their jitted program (`._jitted`) and
     the parser recovers the per-token collective slices the SCALING.md

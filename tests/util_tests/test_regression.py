@@ -67,7 +67,6 @@ class TestHistoryFiltering:
         {"metric": "m", "value": 101.0, "batch": 256},
         {"metric": "m", "value": 50.0, "batch": 4},      # toy debug run
         {"metric": "m", "value": None, "batch": 256},    # failed run
-        {"metric": "m", "value": 99.0, "cached": True},  # cache replay
         {"metric": "m", "value": 60.0, "batch": 256,
          "check_verdict": "regression"},  # sentinel-flagged regression
         {"metric": "other", "value": 7.0},
@@ -77,7 +76,7 @@ class TestHistoryFiltering:
     def test_workload_match_and_exclusions(self):
         vals = regression.history_values(self.RUNS, "m",
                                          match={"batch": 256})
-        # the toy run is excluded; the null, cached and
+        # the toy run is excluded; the null and
         # regression-flagged rows are excluded (a flagged regression
         # must not re-anchor the baseline); the legacy batch-less row
         # passes (leniency that retires itself)
@@ -97,8 +96,7 @@ class TestHistoryFiltering:
         assert out["verdict"] == "no_result"
 
     def test_stale_history_never_anchors(self):
-        """Timestamped runs past the age cutoff are excluded — the
-        same staleness rule the cache fallback applies: a verdict
+        """Timestamped runs past the age cutoff are excluded: a verdict
         against a weeks-old baseline is not a verdict about this
         tree.  Legacy un-timestamped entries pass."""
         import datetime
@@ -125,7 +123,7 @@ class TestHistoryFiltering:
 
 class TestBenchCheckWiring:
     """--check through run_child_with_retries against a scratch
-    cache: scored before recording, verdict on the line, exit code
+    history: scored before recording, verdict on the line, exit code
     red only on regression (the test_bench_contract driving style)."""
 
     @pytest.fixture()
@@ -135,8 +133,8 @@ class TestBenchCheckWiring:
             import _bench_common as bc
         finally:
             sys.path.pop(0)
-        monkeypatch.setattr(bc, "CACHE_PATH",
-                            str(tmp_path / "cache.json"))
+        monkeypatch.setattr(bc, "HISTORY_PATH",
+                            str(tmp_path / "history.json"))
         return bc
 
     @staticmethod
@@ -167,8 +165,8 @@ class TestBenchCheckWiring:
             check=True) == 0
         rec = json.loads(capsys.readouterr().out.strip())
         assert rec["check"]["verdict"] == "pass"
-        # the verdict never pollutes the cache entries
-        cache = json.load(open(bc.CACHE_PATH))
+        # the verdict never pollutes the history entries
+        cache = json.load(open(bc.HISTORY_PATH))
         assert all("check" not in r for r in cache["runs"])
 
     def test_regression_goes_red(self, bc, tmp_path, capsys):
@@ -182,11 +180,11 @@ class TestBenchCheckWiring:
         rec = json.loads(capsys.readouterr().out.strip())
         assert rec["check"]["verdict"] == "regression"
         assert rec["check"]["baseline_median"] == 100.0
-        # the regressed record is stamped in the cache, so CI
+        # the regressed record is stamped in the history, so CI
         # re-running the regressed tree CANNOT pull the baseline
         # down until the gate self-normalizes: every re-run keeps
         # scoring against the clean 100.0 history and stays red
-        cache = json.load(open(bc.CACHE_PATH))
+        cache = json.load(open(bc.HISTORY_PATH))
         assert cache["runs"][-1]["check_verdict"] == "regression"
         for _ in range(3):
             assert bc.run_child_with_retries(
@@ -199,7 +197,7 @@ class TestBenchCheckWiring:
             self._ok_cmd(80.0), str(tmp_path), [30], "m", "u") == 0
 
     def test_smoke_runs_are_never_gated(self, bc, tmp_path, capsys):
-        """A platform-pinned smoke run (use_cache=False) under --check
+        """A platform-pinned smoke run (record=False) under --check
         gets the non-gating "smoke" verdict: its records are excluded
         from the hardware history, so scoring it against that history
         would gate a toy CPU number on a foreign-device baseline."""
@@ -209,12 +207,12 @@ class TestBenchCheckWiring:
             capsys.readouterr()
         assert bc.run_child_with_retries(
             self._ok_cmd(2.0), str(tmp_path), [30], "m", "u",
-            use_cache=False, check=True) == 0
+            record=False, check=True) == 0
         rec = json.loads(capsys.readouterr().out.strip())
         assert rec["check"]["verdict"] == "smoke"
-        # and the smoke run left no cache entry behind
+        # and the smoke run left no history entry behind
         assert all(r["value"] != 2.0
-                   for r in json.load(open(bc.CACHE_PATH))["runs"])
+                   for r in json.load(open(bc.HISTORY_PATH))["runs"])
 
     def test_device_kind_joins_the_match(self, bc, tmp_path, capsys):
         """A fresh record carrying device_kind is only scored against
@@ -246,32 +244,30 @@ class TestBenchCheckWiring:
         assert rec["value"] is None
         assert rec["check"]["verdict"] == "no_result"
 
-    def test_cached_fallback_under_check(self, bc, tmp_path, capsys):
+    def test_history_is_never_served_for_a_live_failure(
+            self, bc, tmp_path, capsys):
         for v in (100.0, 101.0):
             assert bc.run_child_with_retries(
                 self._ok_cmd(v), str(tmp_path), [30], "m", "u") == 0
             capsys.readouterr()
         bad = [sys.executable, "-c", "raise SystemExit(3)"]
-        # live failure + a fresh cache: the cached record is served
-        # with the distinct NON-GATING verdict — green exit (the
-        # outage is not a perf regression), but never a "pass": a
-        # replayed record must not be scored against the history it
-        # was copied from (it would always pass, waving a real
-        # regression through a dead-chip window)
-        assert bc.run_child_with_retries(
-            bad, str(tmp_path), [30], "m", "u", check=True) == 0
-        rec = json.loads(capsys.readouterr().out.strip())
-        assert rec["cached"] is True
-        assert rec["check"]["verdict"] == "cached"
+        # live failure + a fresh history: the failure is reported as
+        # what it is — null, the diagnosis, a red exit code with or
+        # without --check — never an earlier run's value
+        for check in (True, False):
+            assert bc.run_child_with_retries(
+                bad, str(tmp_path), [30], "m", "u", check=check) == 1
+            rec = json.loads(capsys.readouterr().out.strip())
+            assert rec["value"] is None and "rc=3" in rec["error"]
+            assert "cached" not in rec
 
 
 def test_bench_scripts_wire_the_check_flag():
     """``bench.py --check`` (and bench_programs.py's) reach
     ``run_child_with_retries(check=...)`` — the one-line wiring that
-    makes any bench script self-verify.  Source-level pin (the full
-    child run is vma-gated on this host; the check semantics are
-    unit-tested above through the same run_child_with_retries
-    entrypoint the scripts call)."""
+    makes any bench script self-verify.  Source-level pin (the check
+    semantics are unit-tested above through the same
+    run_child_with_retries entrypoint the scripts call)."""
     for script in ("bench.py", "bench_programs.py"):
         src = open(os.path.join(_ROOT, script)).read()
         assert '"--check"' in src, script
