@@ -53,6 +53,7 @@ __all__ = [
     "apply_batch_policy",
     "assemble_window",
     "default_converter",
+    "pull_batch",
     "put_window",
 ]
 
@@ -188,6 +189,35 @@ def apply_batch_policy(arrays, world_size: int, drop_remainder: bool):
     return arrays
 
 
+def _n_examples(batch) -> int:
+    if isinstance(batch, tuple) and batch \
+            and isinstance(batch[0], np.ndarray):
+        return len(batch[0])        # pre-stacked columns
+    return len(batch)
+
+
+def pull_batch(iterator, converter, world_size: int,
+               drop_remainder: bool):
+    """Pull one batch, convert it, apply the divisibility policy: the
+    ONE place either feed obtains a batch, so the serial feed (under the
+    updater's ``step/host`` span) and the prefetch worker (on its own
+    thread) record the same two spans around the same work —
+    ``feed/pull`` is ``next(iterator)`` (dataset indexing, the list of
+    examples), ``feed/convert`` the converter's stack to one array per
+    field and :func:`apply_batch_policy`."""
+    tracer = get_recorder()
+    with tracer.span("feed/pull", cat="input") as span:
+        batch = next(iterator)
+        if tracer.enabled:
+            span.set(n=_n_examples(batch))
+    with tracer.span("feed/convert", cat="input") as span:
+        arrays = apply_batch_policy(converter(batch), world_size,
+                                    drop_remainder)
+        if tracer.enabled:
+            span.set(bytes=sum(a.nbytes for a in arrays))
+    return arrays
+
+
 def assemble_window(pull_fn, n_steps: int):
     """THE window-fill contract, shared by the serial updater feed and
     the prefetch worker (one definition → the prefetch-on/off bitwise
@@ -215,7 +245,10 @@ def put_window(window, pending, batch_sharding, stacked_sharding,
     """Transfer an assembled window: single batches go up under the
     per-example sharding, multi-step windows are stacked with the
     leading scan axis unsharded.  Returns ``(arrays, k, tail)`` —
-    shared by both feeds, like :func:`assemble_window`.
+    shared by both feeds, like :func:`assemble_window`, and timed as
+    one ``feed/put`` span in both (the owned-buffer copy, the
+    window-level stack and the ``device_put`` calls; whether the copy
+    itself has ended when ``device_put`` returns is the runtime's).
 
     Aliasing hazard: sharded ``device_put`` of a host array can DEFER
     the per-shard copy until first use, silently aliasing the source —
@@ -246,17 +279,23 @@ def put_window(window, pending, batch_sharding, stacked_sharding,
             for a in arrays)
 
     k = len(window)
-    if k == 1:
-        arrays = tuple(jax.device_put(a, batch_sharding)
-                       for a in _safe(window[0]))
-    else:
-        # the window-level np.stack already copies out of any staging
-        # buffers, so the stacked transfer can stay fully lazy
-        arrays = tuple(
-            jax.device_put(np.stack(cols), stacked_sharding)
-            for cols in zip(*window))
-    tail = None if pending is None else tuple(
-        jax.device_put(a, batch_sharding) for a in _safe(pending))
+    tracer = get_recorder()
+    with tracer.span("feed/put", cat="input", k=k) as span:
+        if k == 1:
+            arrays = tuple(jax.device_put(a, batch_sharding)
+                           for a in _safe(window[0]))
+        else:
+            # the window-level np.stack already copies out of any
+            # staging buffers, so the stacked transfer can stay fully
+            # lazy
+            arrays = tuple(
+                jax.device_put(np.stack(cols), stacked_sharding)
+                for cols in zip(*window))
+        tail = None if pending is None else tuple(
+            jax.device_put(a, batch_sharding) for a in _safe(pending))
+        if tracer.enabled:
+            span.set(bytes=sum(a.nbytes for batch in window for a in batch)
+                     + sum(a.nbytes for a in pending or ()))
     return arrays, k, tail
 
 
@@ -396,9 +435,8 @@ class PrefetchIterator:
         return self._base.state_dict() if self._can_rewind else None
 
     def _pull(self):
-        arrays = self._converter(next(self._base))
-        return apply_batch_policy(arrays, self._comm.size,
-                                  self._drop_remainder)
+        return pull_batch(self._base, self._converter, self._comm.size,
+                          self._drop_remainder)
 
     def _to_device(self, window, pending):
         arrays, k, tail = put_window(
@@ -426,21 +464,17 @@ class PrefetchIterator:
     def _worker(self):
         try:
             while not self._stop.is_set():
-                # re-resolved per window (like the consumer side): a
-                # set_recorder() swap mid-run must not strand this
-                # long-lived thread on the old recorder
-                tracer = get_recorder()
+                # the window's spans (feed/pull, feed/convert,
+                # feed/put) are recorded where the work is, in the
+                # helpers this worker shares with the serial feed
                 snap = self._snapshot()
                 try:
-                    with tracer.span("prefetch/assemble", cat="input"):
-                        window, pending = assemble_window(
-                            self._pull, self._n_steps)
+                    window, pending = assemble_window(
+                        self._pull, self._n_steps)
                 except StopIteration:
                     self._deliver(("stop", None, snap))
                     return
-                with tracer.span("prefetch/put", cat="input",
-                                 k=len(window)):
-                    rec = self._to_device(window, pending)
+                rec = self._to_device(window, pending)
                 if not self._deliver(("window", rec, snap)):
                     return
         except BaseException as e:  # noqa: BLE001 — propagate on next()

@@ -13,6 +13,8 @@ from typing import Optional
 
 import numpy as np
 
+from chainermn_tpu.utils.telemetry import get_recorder
+
 from .triggers import get_trigger
 
 __all__ = ["Trainer", "LogReport", "PrintReport", "make_extension"]
@@ -92,19 +94,33 @@ class Trainer:
                 trig_init(self)
         try:
             while not self._done():
+                # re-resolved per iteration, as the updater does; the
+                # trainer's spans carry the iteration update() began at,
+                # the ``step`` of that update's own spans
+                tracer = get_recorder()
+                step = getattr(self.updater, "iteration", None)
                 self.updater.update()
                 self.observation = dict(self.updater.observation)
                 self.elapsed_time = time.perf_counter() - self._start
-                for e in self._extensions:
-                    # extensions with an ``observe`` hook see EVERY
-                    # iteration's observation (LogReport interval
-                    # averaging); ``__call__`` still fires on the trigger
-                    obs_hook = getattr(e.ext, "observe", None)
-                    if obs_hook:
-                        obs_hook(self)
+                # where the host waits for the device in a serial loop:
+                # LogReport.observe reads float(loss) of the step just
+                # dispatched
+                with tracer.span("trainer/observe", cat="trainer",
+                                 step=step):
+                    for e in self._extensions:
+                        # extensions with an ``observe`` hook see EVERY
+                        # iteration's observation (LogReport interval
+                        # averaging); ``__call__`` still fires on the
+                        # trigger
+                        obs_hook = getattr(e.ext, "observe", None)
+                        if obs_hook:
+                            obs_hook(self)
                 for e in self._extensions:
                     if e.trigger(self):
-                        e.ext(self)
+                        with tracer.span("trainer/extension",
+                                         cat="trainer", step=step,
+                                         name=e.name):
+                            e.ext(self)
         finally:
             # finalize even when update() raises: an in-flight async
             # checkpoint write must not be lost to the crash it exists

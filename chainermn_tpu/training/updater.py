@@ -29,13 +29,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from chainermn_tpu.iterators.prefetch import (
     PrefetchIterator,
     StagingConverter,
-    apply_batch_policy,
     assemble_window,
     default_converter,
+    pull_batch,
     put_window,
 )
 from chainermn_tpu.utils.metrics import get_registry
-from chainermn_tpu.utils.profiling import get_profiler
 from chainermn_tpu.utils.programs import (
     get_accountant,
     get_ledger,
@@ -169,8 +168,8 @@ class StandardUpdater:
       exchange_probe_every: every this-many ``update()`` calls, re-time
         the optimizer's tuned exchange program in isolation (one extra
         exchange on a zeros grad tree, compiled once) and observe the
-        wall time as ``main/exchange_time`` (profiler row
-        ``updater/exchange_time``) — the window-end exchange cost the
+        wall time as ``main/exchange_time`` (span
+        ``step/exchange_probe``) — the window-end exchange cost the
         in-step fusion otherwise hides.  The observation also feeds the
         plan's drift guard (``plan_cell.observe``): when it departs
         from the plan's tuned time by the cell's ``drift_factor``,
@@ -179,15 +178,16 @@ class StandardUpdater:
         planned optimizer (``create_multi_node_optimizer(plan=...)``);
         0 (default) disables the probe.
 
-    Timing observations (``utils.profiling`` names in parentheses):
-    ``main/host_time`` (``updater/host_time``) is iterator pull +
-    convert + stack + ``device_put`` — for a prefetched feed, the
-    residual wait for the next ready window; ``main/device_time``
-    (``updater/device_time``) is the exposed wait retiring windows past
-    ``max_inflight``, i.e. blocking on the PREVIOUS window's result so
-    steady-state timing stays overlapped; ``main/step_time`` is their
-    per-iteration sum (the old value timed only the async dispatch
-    call — it measured neither).
+    Timing observations (flight-recorder spans in parentheses; the
+    spans are the per-event record, see docs/OBSERVABILITY.md):
+    ``main/host_time`` (``step/host``, and under it ``feed/pull``,
+    ``feed/convert``, ``feed/put``) is iterator pull + convert + stack
+    + ``device_put`` — for a prefetched feed, the residual wait for the
+    next ready window; ``main/device_time`` (``step/retire``) is the
+    exposed wait retiring windows past ``max_inflight``, i.e. blocking
+    on the PREVIOUS window's result so steady-state timing stays
+    overlapped; ``main/step_time`` is their per-iteration sum (the old
+    value timed only the async dispatch call — it measured neither).
 
     ZeRO-1 optimizers (``create_multi_node_optimizer(..., zero1=True)``)
     are detected from the transformation's type: their state is
@@ -252,6 +252,9 @@ class StandardUpdater:
             raise ValueError("max_inflight must be >= 1")
         self.max_inflight = max_inflight
         self._inflight: collections.deque = collections.deque()
+        # the iteration each in-flight window was dispatched at, for the
+        # step/retire span's ``retired``
+        self._inflight_steps: collections.deque = collections.deque()
         if self.prefetch:
             if isinstance(iterator, PrefetchIterator):
                 # a pre-built prefetcher must agree with this updater's
@@ -619,6 +622,7 @@ class StandardUpdater:
         # even when the new world returns to a previously-seen shape
         get_ledger().forget("train/")
         self._inflight.clear()
+        self._inflight_steps.clear()
         self._batch_sharding = NamedSharding(comm.mesh, P(comm.axis_name))
         self._stacked_sharding = NamedSharding(
             comm.mesh, P(None, comm.axis_name))
@@ -636,9 +640,8 @@ class StandardUpdater:
 
     def _next_arrays(self):
         """Pull one batch, convert, apply the divisibility policy."""
-        arrays = self.converter(next(self.iterator))
-        return apply_batch_policy(arrays, self.comm.size,
-                                  self.drop_remainder)
+        return pull_batch(self.iterator, self.converter, self.comm.size,
+                          self.drop_remainder)
 
     def _assemble_host_window(self):
         """The serial feed: pull, convert, stack and ``device_put`` the
@@ -801,22 +804,26 @@ class StandardUpdater:
         # the weighted window loss derives from every dispatched
         # program's output, so blocking on it retires the whole window
         self._inflight.append(window_loss)
+        self._inflight_steps.append(self.iteration)
         t0 = time.perf_counter()
         with tracer.span("step/retire", cat="step", step=self.iteration,
-                         inflight=len(self._inflight)):
+                         inflight=len(self._inflight)) as retire_span:
+            retired_step = None
             while len(self._inflight) > self.max_inflight:
                 retired = self._inflight.popleft()
+                retired_step = self._inflight_steps.popleft()
                 jax.block_until_ready(retired)
                 self._last_retired = retired
+            # the iteration whose window this span learned had ended on
+            # the device (None: it blocked on nothing) -- one of the
+            # moments that tie this clock to a device trace's
+            retire_span.set(retired=retired_step)
         device_time = time.perf_counter() - t0
 
         self.iteration += n_iters
         self.previous_epoch_detail = self.epoch_detail
         self.epoch_detail = getattr(
             self.iterator, "epoch_detail", self.iteration)
-        prof = get_profiler()
-        prof.record("updater/host_time", host_time)
-        prof.record("updater/device_time", device_time)
         if self.max_inflight > 1 and self._last_retired is not None:
             # pipelined: report the RETIRED window's loss (already
             # materialised) so a float()-per-iteration consumer —
@@ -844,7 +851,6 @@ class StandardUpdater:
             # amortisation visible (accum_time ≈ accum_steps × step_time
             # means the exchange really left the microbatch loop)
             accum_time = (host_time + device_time) / max(n_updates, 1)
-            prof.record("updater/accum_time", accum_time)
             self.observation["main/accum_time"] = accum_time
         self._updates_done += 1
         if self.exchange_probe_every and \
@@ -855,5 +861,4 @@ class StandardUpdater:
                              step=self.iteration) as probe_span:
                 exchange_time = self._probe_exchange_time()
                 probe_span.set(exchange_s=round(exchange_time, 6))
-            prof.record("updater/exchange_time", exchange_time)
             self.observation["main/exchange_time"] = exchange_time

@@ -579,8 +579,8 @@ def choose_prefetch_depth(host_time_s: float, device_time_s: float,
                           max_depth: int = 8) -> int:
     """Slot count for the prefetch ring (``PrefetchIterator(depth=...)``)
     from the measured host-assembly vs device-step times (the updater's
-    ``main/host_time`` / ``main/device_time``, or the ``updater/*``
-    profiler rows).
+    ``main/host_time`` / ``main/device_time``, or the flight
+    recorder's ``step/host`` / ``step/retire`` spans).
 
     The pipeline model: one background worker assembles windows at rate
     ``1/h`` while the device consumes at ``1/d``.  With ``rho = h/d``:

@@ -13,9 +13,15 @@ the capability the reference out-sourced to external tracers.
 Three layers:
 
 - :class:`TraceRecorder` — a bounded ring buffer of structured span
-  events (name, category, t0/duration, step, rank, thread, metadata).
-  Near-zero cost when disabled: ``span()`` returns a shared no-op
-  context manager (no allocation, one attribute read).  Exports:
+  events (name, category, t0/duration, step, rank, thread, metadata,
+  parent).  Near-zero cost when disabled: ``span()`` returns a shared
+  no-op context manager (no allocation, one attribute read).  A live
+  span knows the live span that encloses it on its thread (``parent``,
+  and ``step`` is inherited from it), so a layer's self time is its
+  span less its children; and it enters a
+  ``jax.profiler.TraceAnnotation`` of the same name, so a profile taken
+  with the host tracer on shows the program's spans on the profiler's
+  own clock, beside the device's ops.  Exports:
 
   * **Chrome trace-event JSON** (:meth:`export_chrome`) — load the file
     at https://ui.perfetto.dev (or ``chrome://tracing``).  Ranks map to
@@ -60,6 +66,7 @@ light.
 from __future__ import annotations
 
 import collections
+import functools
 import json
 import os
 import threading
@@ -95,13 +102,29 @@ def _default_rank() -> int:
         return 0
 
 
+@functools.lru_cache(maxsize=1)
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, or None without jax — looked
+    up at the first LIVE span, so the module imports without jax and a
+    disabled recorder never gets here."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
 class SpanEvent:
     """One recorded event.  ``dur`` is seconds for spans, ``None`` for
-    instants, and carries the counter value for counter events."""
+    instants, and carries the counter value for counter events.
+    ``parent`` is ``(name, t0)`` of the live span that enclosed this one
+    on its thread, or None."""
 
-    __slots__ = ("name", "cat", "ph", "t0", "dur", "step", "tid", "meta")
+    __slots__ = ("name", "cat", "ph", "t0", "dur", "step", "tid", "meta",
+                 "parent")
 
-    def __init__(self, name, cat, ph, t0, dur, step, tid, meta):
+    def __init__(self, name, cat, ph, t0, dur, step, tid, meta,
+                 parent=None):
         self.name = name
         self.cat = cat
         self.ph = ph
@@ -110,6 +133,7 @@ class SpanEvent:
         self.step = step
         self.tid = tid
         self.meta = meta
+        self.parent = parent
 
     def to_dict(self) -> dict:
         d = {"name": self.name, "cat": self.cat, "ph": self.ph,
@@ -122,6 +146,8 @@ class SpanEvent:
             d["tid"] = self.tid
         if self.meta:
             d["meta"] = self.meta
+        if self.parent is not None:
+            d["parent"] = list(self.parent)
         return d
 
 
@@ -145,7 +171,8 @@ _NULL_SPAN = _NullSpan()
 
 
 class _LiveSpan:
-    __slots__ = ("_rec", "_name", "_cat", "_step", "_meta", "_t0")
+    __slots__ = ("_rec", "_name", "_cat", "_step", "_meta", "_t0",
+                 "_parent", "_stack", "_annotation")
 
     def __init__(self, rec, name, cat, step, meta):
         self._rec = rec
@@ -155,6 +182,27 @@ class _LiveSpan:
         self._meta = meta
 
     def __enter__(self):
+        # what caused it: the live span that encloses this one on this
+        # thread, whose step it shares unless it was given its own
+        stack = self._stack = self._rec._live_stack()
+        if stack:
+            enclosing = stack[-1]
+            self._parent = (enclosing._name, enclosing._t0)
+            if self._step is None:
+                self._step = enclosing._step
+        else:
+            self._parent = None
+        stack.append(self)
+        # the same span on the profiler's clock, for any profile taken
+        # with the host tracer on (a no-op outside a profiler session)
+        annotation = _trace_annotation()
+        if annotation is None:
+            self._annotation = None
+        else:
+            self._annotation = (
+                annotation(self._name) if self._step is None
+                else annotation(self._name, step=self._step))
+            self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -169,9 +217,13 @@ class _LiveSpan:
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        if self._stack and self._stack[-1] is self:
+            self._stack.pop()
         self._rec._append(SpanEvent(
             self._name, self._cat, _PH_SPAN, self._t0, t1 - self._t0,
-            self._step, threading.get_ident(), self._meta))
+            self._step, threading.get_ident(), self._meta, self._parent))
         return False
 
 
@@ -218,6 +270,8 @@ class TraceRecorder:
         # on one never steals another's feed
         self._phase_channels: Dict[str, list] = {"": [None, {}]}
         self._thread_names: Dict[int, str] = {}
+        # each thread's stack of live spans (see _LiveSpan.__enter__)
+        self._local = threading.local()
         # wall-clock anchor: perf_counter is monotonic but arbitrary;
         # the pair lets exports (and merge across processes) place
         # events on the wall clock
@@ -248,13 +302,24 @@ class TraceRecorder:
     def __len__(self) -> int:
         return len(self._ring)
 
-    def span(self, name: str, cat: str = "default",
+    def span(self, name: str, /, cat: str = "default",
              step: Optional[int] = None, **meta):
         """Context manager timing a block into the ring.  Disabled →
-        returns the shared no-op singleton (zero allocation)."""
+        returns the shared no-op singleton (zero allocation).  The
+        event records the live span enclosing it on this thread as its
+        ``parent`` and, given no ``step``, takes that span's.  ``name``
+        is positional-only, so metadata may itself be called ``name``."""
         if not self.enabled:
             return _NULL_SPAN
         return _LiveSpan(self, name, cat, step, meta or None)
+
+    def _live_stack(self) -> list:
+        """This thread's stack of live spans, innermost last."""
+        try:
+            return self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+            return stack
 
     def record(self, name: str, duration: float, cat: str = "default",
                step: Optional[int] = None, t0: Optional[float] = None,

@@ -166,16 +166,12 @@ class TestPlannedOptimizer:
 
 
 class TestExchangeObservation:
-    def test_exchange_time_observed_with_profiler_row(self, comm,
-                                                      scratch_cache):
-        from chainermn_tpu.utils.profiling import get_profiler
-
+    def test_exchange_time_observed(self, comm, scratch_cache):
         upd = _make(comm, exchange_probe_every=2)
         upd.update()
         assert "main/exchange_time" not in upd.observation
         upd.update()      # 2nd window: probe fires
         assert upd.observation["main/exchange_time"] > 0
-        assert "updater/exchange_time" in get_profiler().stats
         # the observation fed the drift guard
         cell = upd.optimizer.plan_cell
         assert cell.observed_s == \

@@ -26,8 +26,9 @@ _CALL = re.compile(
     r"([a-z_]+/[a-z0-9_]+)['\"]")
 # a flight-recorder record call with a literal name.  ``.record`` is
 # deliberately excluded: the Profiler shares that method name
-# (prof.record("updater/host_time")) and its names are a different
-# (printed-table) namespace.
+# (``prof.record(name, seconds)``, e.g. ``profiled_communicator``'s
+# ``comm.<collective>`` rows; the updater's ``updater/*`` rows left
+# in PR 24) and its names are a different (printed-table) namespace.
 _SPAN_CALL = re.compile(
     r"\.(?:span|instant|counter)\(\s*\n?\s*['\"]"
     r"([a-z_]+/[a-z0-9_]+)['\"]")

@@ -151,6 +151,10 @@ class TestDisabled:
         rec.counter("c", 3)
         assert len(rec) == 0
         assert rec.drain_phase_stats() == {}
+        # no per-thread stack either: that is a live span's
+        assert not hasattr(rec._local, "stack")
+        # metadata called ``name`` (trainer/extension) changes nothing
+        assert rec.span("trainer/extension", name="LogReport") is a
 
     def test_enable_disable_toggle(self):
         rec = TraceRecorder(enabled=False)
@@ -161,6 +165,123 @@ class TestDisabled:
         with rec.span("y"):
             pass
         assert [e["name"] for e in rec.events()] == ["x"]
+
+    @pytest.mark.parametrize("prefetch", [0, 2],
+                             ids=["serial", "prefetch"])
+    def test_trainer_and_updater_build_nothing(self, comm, tmp_path,
+                                               monkeypatch, prefetch):
+        """Disabled, a whole ``Trainer.run`` (the updater's spans, the
+        feed's on either thread, the trainer's own) constructs no live
+        span, no ``TraceAnnotation`` and no per-thread stack."""
+        from chainermn_tpu.utils import telemetry
+
+        def refuse(*a, **k):
+            raise AssertionError("built on the disabled path")
+
+        monkeypatch.setattr(telemetry._LiveSpan, "__init__", refuse)
+        monkeypatch.setattr(telemetry, "_trace_annotation", refuse)
+        rec = TraceRecorder(enabled=False)
+        monkeypatch.setattr(rec, "_live_stack", refuse)
+        prev = set_recorder(rec)
+        try:
+            trainer = _make_trainer(comm, tmp_path, epochs=1,
+                                    prefetch=prefetch)
+            trainer.extend(cmn.LogReport(trigger=(2, "iteration")))
+            trainer.run()
+        finally:
+            set_recorder(prev)
+        assert trainer.updater.iteration == 4
+        assert len(rec) == 0 and not hasattr(rec._local, "stack")
+
+
+# ---------------------------------------------------------------------- #
+# a span knows what caused it
+# ---------------------------------------------------------------------- #
+
+class TestParent:
+    def test_nested_span_records_parent_and_inherits_step(self):
+        rec = TraceRecorder(enabled=True, rank=0)
+        with rec.span("outer", step=7):
+            with rec.span("inner"):
+                with rec.span("innermost", step=9):
+                    pass
+            with rec.span("second"):
+                pass
+        with rec.span("alone"):
+            pass
+        by = {e["name"]: e for e in rec.events()}
+        assert "parent" not in by["outer"] and "parent" not in by["alone"]
+        assert by["inner"]["parent"] == ["outer", by["outer"]["t0"]]
+        assert by["second"]["parent"] == ["outer", by["outer"]["t0"]]
+        assert by["innermost"]["parent"] == ["inner", by["inner"]["t0"]]
+        # step: inherited when given none, kept when given
+        assert by["inner"]["step"] == 7 and by["second"]["step"] == 7
+        assert by["innermost"]["step"] == 9
+        assert "step" not in by["alone"]
+        # the stack unwound
+        assert rec._live_stack() == []
+
+    def test_parent_is_per_thread(self):
+        import threading
+
+        rec = TraceRecorder(enabled=True, rank=0)
+        inside = threading.Event()
+        done = threading.Event()
+
+        def other():
+            inside.wait(5)
+            with rec.span("worker", step=3):
+                with rec.span("worker-child"):
+                    pass
+            done.set()
+
+        th = threading.Thread(target=other)
+        th.start()
+        with rec.span("main", step=1):
+            inside.set()
+            assert done.wait(5)
+            with rec.span("main-child"):
+                pass
+        th.join(5)
+        assert not th.is_alive()
+        by = {e["name"]: e for e in rec.events()}
+        # a span live on another thread is nobody's parent here
+        assert "parent" not in by["worker"] and by["worker"]["step"] == 3
+        assert by["worker-child"]["parent"][0] == "worker"
+        assert by["main-child"]["parent"][0] == "main"
+        assert by["main-child"]["step"] == 1
+        assert by["worker"]["tid"] != by["main"]["tid"]
+
+    def test_span_unwinds_on_exception(self):
+        rec = TraceRecorder(enabled=True, rank=0)
+        with pytest.raises(StopIteration):
+            with rec.span("outer"):
+                with rec.span("inner"):
+                    raise StopIteration
+        assert rec._live_stack() == []
+        with rec.span("after"):
+            pass
+        assert "parent" not in rec.events()[-1]
+
+    def test_metadata_may_be_called_name(self):
+        rec = TraceRecorder(enabled=True, rank=0)
+        with rec.span("trainer/extension", name="LogReport"):
+            pass
+        assert rec.events()[0]["meta"] == {"name": "LogReport"}
+
+    def test_self_time_is_span_less_children(self):
+        """What ``parent`` is for: a layer's own time."""
+        rec = TraceRecorder(enabled=True, rank=0)
+        with rec.span("step/host", step=0):
+            time.sleep(0.01)
+            with rec.span("feed/put"):
+                time.sleep(0.02)
+        host, = [e for e in rec.events() if e["name"] == "step/host"]
+        children = [e for e in rec.events()
+                    if e.get("parent") == ["step/host", host["t0"]]]
+        own = host["dur"] - sum(c["dur"] for c in children)
+        assert [c["name"] for c in children] == ["feed/put"]
+        assert 0.009 < own < host["dur"] - 0.019
 
 
 # ---------------------------------------------------------------------- #
@@ -314,14 +435,179 @@ class TestInstrumentation:
         trainer = _make_trainer(comm, tmp_path, epochs=1, prefetch=2)
         trainer.run()
         names = {e["name"] for e in recorder.events()}
-        assert {"prefetch/slot_wait", "prefetch/assemble",
-                "prefetch/put", "prefetch/occupancy"} <= names
+        assert {"prefetch/slot_wait", "feed/pull", "feed/convert",
+                "feed/put", "prefetch/occupancy"} <= names
         # worker-side spans carry the worker's tid, consumer spans the
         # main thread's — the trace separates the two lanes
         tid_of = {}
         for e in recorder.events():
             tid_of.setdefault(e["name"], set()).add(e.get("tid"))
-        assert tid_of["prefetch/assemble"] != tid_of["prefetch/slot_wait"]
+        for worker_span in ("feed/pull", "feed/convert", "feed/put"):
+            assert tid_of[worker_span].isdisjoint(
+                tid_of["prefetch/slot_wait"])
+        # the consumer's wait is the step/host span's child and shares
+        # its step; the worker's spans have no parent on their thread
+        by_name = {}
+        for e in recorder.events():
+            by_name.setdefault(e["name"], []).append(e)
+        assert all(e["parent"][0] == "step/host" and "step" in e
+                   for e in by_name["prefetch/slot_wait"])
+        assert all("parent" not in e for e in by_name["feed/put"])
+
+    @pytest.mark.parametrize("prefetch", [0, 2],
+                             ids=["serial", "prefetch"])
+    def test_both_feeds_emit_the_same_feed_spans(self, comm, recorder,
+                                                 tmp_path, prefetch):
+        """One window contract, one set of names: each batch is one
+        ``feed/pull`` and one ``feed/convert``, each window one
+        ``feed/put``, whichever thread did the work."""
+        trainer = _make_trainer(comm, tmp_path, epochs=1,
+                                prefetch=prefetch)
+        trainer.run()
+        events = recorder.events()
+        count = {n: sum(1 for e in events if e["name"] == n)
+                 for n in ("feed/pull", "feed/convert", "feed/put",
+                           "step/host")}
+        # 64 examples in batches of 16: four windows; a prefetching
+        # worker may have pulled ahead of the stop
+        assert count["step/host"] == 4
+        assert count["feed/put"] >= 4
+        assert count["feed/convert"] == count["feed/put"]
+        assert count["feed/pull"] >= count["feed/convert"]
+        put = next(e for e in events if e["name"] == "feed/put")
+        pull = next(e for e in events if e["name"] == "feed/pull")
+        conv = next(e for e in events if e["name"] == "feed/convert")
+        assert pull["meta"]["n"] == 16
+        assert conv["meta"]["bytes"] == 16 * (6 * 4 + 4)
+        assert put["meta"] == {"k": 1, "bytes": 16 * (6 * 4 + 4)}
+        # the worker's old names for the same work are gone
+        assert {e["name"] for e in events if e["name"].startswith(
+            "prefetch/")} <= {"prefetch/slot_wait", "prefetch/occupancy"}
+
+    def test_serial_feed_spans_lie_under_step_host(self, comm, recorder,
+                                                   tmp_path):
+        trainer = _make_trainer(comm, tmp_path, epochs=1)
+        trainer.run()
+        events = recorder.events()
+        hosts = {(e["name"], e["t0"]): e for e in events
+                 if e["name"] == "step/host"}
+        main_tid = next(iter(hosts.values()))["tid"]
+        for name in ("feed/pull", "feed/convert", "feed/put"):
+            found = [e for e in events if e["name"] == name
+                     and e["tid"] == main_tid]
+            assert len(found) == 4, name
+            for e in found:
+                host = hosts[tuple(e["parent"])]
+                assert e["step"] == host["step"]
+                assert host["t0"] <= e["t0"] \
+                    and e["t0"] + e["dur"] <= host["t0"] + host["dur"]
+        # the three children leave the span little of its own
+        host = next(iter(hosts.values()))
+        inside = sum(e["dur"] for e in events
+                     if e.get("parent") == ["step/host", host["t0"]])
+        assert 0 < inside <= host["dur"]
+
+    def test_fused_window_pulls_k_batches_under_one_put(
+            self, comm, recorder, tmp_path):
+        trainer = _make_trainer(comm, tmp_path, epochs=1,
+                                steps_per_execution=2)
+        trainer.run()
+        events = recorder.events()
+        puts = [e for e in events if e["name"] == "feed/put"]
+        assert [e["meta"]["k"] for e in puts] == [2, 2]
+        assert [e["step"] for e in puts] == [0, 2]
+        pulls = [e for e in events if e["name"] == "feed/pull"]
+        assert [e["step"] for e in pulls[:4]] == [0, 0, 2, 2]
+
+    def test_retire_names_the_window_it_blocked_on(self, comm, recorder,
+                                                   tmp_path):
+        """Serial updater, one window in flight: the first retire blocks
+        on nothing, each later one on its predecessor's window."""
+        trainer = _make_trainer(comm, tmp_path, epochs=1)
+        trainer.run()
+        retires = [e for e in recorder.events()
+                   if e["name"] == "step/retire"]
+        assert [e["step"] for e in retires] == [0, 1, 2, 3]
+        assert [e["meta"]["retired"] for e in retires] == [None, 0, 1, 2]
+
+    def test_retire_under_two_in_flight(self, comm, recorder, tmp_path):
+        trainer = _make_trainer(comm, tmp_path, epochs=1, max_inflight=2)
+        trainer.run()
+        retires = [e for e in recorder.events()
+                   if e["name"] == "step/retire"]
+        assert [e["meta"]["retired"] for e in retires] == \
+            [None, None, 0, 1]
+
+    def test_trainer_observe_once_per_iteration(self, comm, recorder,
+                                                tmp_path):
+        trainer = _make_trainer(comm, tmp_path, epochs=1)
+        trainer.extend(cmn.LogReport(trigger=(2, "iteration")))
+        trainer.run()
+        observes = [e for e in recorder.events()
+                    if e["name"] == "trainer/observe"]
+        assert [e["step"] for e in observes] == [0, 1, 2, 3]
+        assert all(e["cat"] == "trainer" and "parent" not in e
+                   for e in observes)
+        # it follows its own iteration's dispatch and retire
+        for obs in observes:
+            retire = next(e for e in recorder.events()
+                          if e["name"] == "step/retire"
+                          and e["step"] == obs["step"])
+            assert obs["t0"] >= retire["t0"] + retire["dur"]
+
+    def test_trainer_extension_once_per_fired_extension(
+            self, comm, recorder, tmp_path):
+        trainer = _make_trainer(comm, tmp_path, epochs=1)
+        trainer.extend(cmn.LogReport(trigger=(2, "iteration")))
+        seen = []
+        trainer.extend(lambda t: seen.append(t.updater.iteration),
+                       trigger=(1, "iteration"), name="every")
+        trainer.run()
+        fired = [(e["meta"]["name"], e["step"])
+                 for e in recorder.events()
+                 if e["name"] == "trainer/extension"]
+        # step is the iteration update() began at: LogReport (priority
+        # 50, after the default 100) fires after the updates that began
+        # at 1 and 3
+        assert fired == [("every", 0), ("every", 1), ("LogReport", 1),
+                         ("every", 2), ("every", 3), ("LogReport", 3)]
+        assert seen == [1, 2, 3, 4]
+
+    def test_spans_on_the_profilers_host_plane(self, comm, recorder,
+                                               tmp_path):
+        """Under a ``jax.profiler`` trace with the host tracer on, the
+        program's spans lie on the profiler's own clock: found on the
+        host plane under their names, with their ``step``."""
+        import glob
+
+        from jax.profiler import ProfileData
+
+        trainer = _make_trainer(comm, tmp_path / "out", epochs=1)
+        options = jax.profiler.ProfileOptions()
+        options.host_tracer_level = 1
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path / "trace"),
+                                 profiler_options=options)
+        try:
+            trainer.run()
+        finally:
+            jax.profiler.stop_trace()
+        path, = glob.glob(str(
+            tmp_path / "trace/plugins/profile/*/*.xplane.pb"))
+        found = {}
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name in ("step/host", "feed/put",
+                                   "trainer/observe"):
+                        stats = {k: v for k, v in ev.stats}
+                        found.setdefault(ev.name, []).append(
+                            stats.get("step"))
+        assert sorted(found["step/host"]) == [0, 1, 2, 3]
+        assert sorted(found["feed/put"]) == [0, 1, 2, 3]
+        assert sorted(found["trainer/observe"]) == [0, 1, 2, 3]
 
     def test_checkpoint_spans_recorded(self, comm, recorder, tmp_path):
         from chainermn_tpu.utils.serialization import (load_state,
