@@ -44,6 +44,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from chainermn_tpu.utils.metrics import get_registry
 from chainermn_tpu.utils.telemetry import get_recorder
 
 __all__ = [
@@ -100,19 +101,38 @@ class StagingConverter:
     Stacks each column directly into a preallocated staging buffer
     (``np.stack(col, out=buf)``) reused across steps when the column's
     (length, element shape, dtype) repeat — steady-state training hits
-    the same shapes every step, so after warmup batch assembly is one
-    memcpy into a recycled buffer instead of allocate + copy.
+    the same shapes every step, so after the ring's first lap batch
+    assembly is one pass into warm pages instead of allocate (and
+    first-touch every page) + copy.
 
-    Buffers rotate through a ring of ``n_buffers`` per column so the
-    last ``n_buffers - 1`` returned batches stay valid while in flight
-    (a fused window holds up to ``steps_per_execution + 1`` unstacked
-    batches during assembly, and ``jax.device_put`` may still be
-    reading single-step batches under async dispatch / prefetch).
-    Size the ring ≥ ``max(depth, steps_per_execution + 1) + 3``;
-    :class:`PrefetchIterator`'s default converter does this.
+    Buffers rotate through a ring of ``n_buffers`` per column key, so
+    the last ``n_buffers - 1`` returned batches stay valid; the ring
+    holds ``n_buffers × batch bytes`` of host memory for as long as the
+    converter lives.  Who may recycle a buffer that ``device_put`` was
+    given UNCOPIED (see :func:`put_window`):
 
-    Already-stacked array batches (fast-path iterators) pass through
-    untouched, same as :func:`default_converter`.
+    - the serial feed of an unfused ``StandardUpdater``, because its
+      ``update()`` retires a window (its step has run, so its batch
+      has been read to the end) before the ring comes round to its
+      buffer — the updater sizes the ring ``max_inflight + 1`` for
+      that (``StandardUpdater.staging_buffers_needed``).  A converter
+      given to such an updater is the updater's alone: any other
+      caller advances the ring behind its back;
+    - nobody else: :class:`PrefetchIterator`'s worker cannot see a
+      window retire, so :func:`put_window` copies what this converter
+      owns there (and the lone batch of a fused serial window), and
+      the ring only has to cover the unstacked window
+      (``steps_per_execution + 1``; the prefetcher's default is
+      ``max(depth, steps_per_execution + 1) + 3``).
+
+    ``last_reused_bytes`` / ``last_fresh_bytes`` say where the latest
+    batch landed: in recycled ring memory, or in memory allocated for
+    it (a ring buffer's first use; a mixed, ragged or non-array column,
+    which falls back to a plain ``np.stack``).  :func:`pull_batch`
+    puts them on the ``feed/convert`` span and into the metrics
+    registry.  Already-stacked array batches (fast-path iterators) pass
+    through untouched, same as :func:`default_converter`, and count as
+    neither.
     """
 
     def __init__(self, n_buffers: int = 4):
@@ -122,6 +142,8 @@ class StagingConverter:
         self._n_buffers = n_buffers
         self._rings: dict = {}      # key -> [buffers...]
         self._turn: dict = {}       # key -> next ring index
+        self.last_reused_bytes = 0
+        self.last_fresh_bytes = 0
 
     def _staging(self, key, shape, dtype):
         ring = self._rings.get(key)
@@ -131,16 +153,26 @@ class StagingConverter:
         i = self._turn[key]
         if len(ring) <= i:
             ring.append(np.empty(shape, dtype))
+            self.last_fresh_bytes += ring[i].nbytes
+        else:
+            self.last_reused_bytes += ring[i].nbytes
         self._turn[key] = (i + 1) % self._n_buffers
         return ring[i]
 
     def owns_buffers(self, arrays) -> bool:
-        """True if any of ``arrays`` IS one of this converter's ring
-        buffers (will be overwritten on ring wrap-around).  The feed
-        uses this to force such transfers to completion before the
-        buffer can be recycled — see :func:`put_window`."""
+        """True if any of ``arrays`` lies in this converter's ring
+        memory (will be overwritten on ring wrap-around): a ring buffer
+        itself or a view of one — :func:`apply_batch_policy`'s
+        ``a[:keep]`` when it drops a remainder.  Memory, not identity.
+        The feed uses this to copy such arrays before a transfer that
+        may outlive the ring's lap — see :func:`put_window`."""
         bufs = {id(b) for ring in self._rings.values() for b in ring}
-        return any(id(a) in bufs for a in arrays)
+        for a in arrays:
+            while a is not None:
+                if id(a) in bufs:
+                    return True
+                a = getattr(a, "base", None)
+        return False
 
     def _stack(self, col_idx, col):
         first = col[0]
@@ -154,11 +186,14 @@ class StagingConverter:
             return np.stack(col, out=buf)
         # mixed / non-array elements (python scalars, ragged): let numpy
         # decide the result dtype exactly as default_converter would
-        return np.stack(col)
+        out = np.stack(col)
+        self.last_fresh_bytes += out.nbytes
+        return out
 
     def __call__(self, batch):
         if not len(batch):
             raise ValueError("empty batch")
+        self.last_reused_bytes = self.last_fresh_bytes = 0
         if isinstance(batch, np.ndarray):
             return (batch,)
         if isinstance(batch, tuple) and all(
@@ -204,7 +239,16 @@ def pull_batch(iterator, converter, world_size: int,
     thread) record the same two spans around the same work —
     ``feed/pull`` is ``next(iterator)`` (dataset indexing, the list of
     examples), ``feed/convert`` the converter's stack to one array per
-    field and :func:`apply_batch_policy`."""
+    field and :func:`apply_batch_policy`.
+
+    ``feed/convert`` carries ``bytes`` (out) and ``reused``: the bytes
+    of this batch that a :class:`StagingConverter` stacked into
+    recycled ring memory — 0 on a ring's first lap, for mixed or
+    ragged columns, for batches that arrive stacked and under any other
+    converter.  The metrics registry counts the same as
+    ``feed/staging_reused_bytes`` beside ``feed/staging_fresh_bytes``
+    (what the converter had to allocate): in steady state the second
+    stands still."""
     tracer = get_recorder()
     with tracer.span("feed/pull", cat="input") as span:
         batch = next(iterator)
@@ -213,8 +257,14 @@ def pull_batch(iterator, converter, world_size: int,
     with tracer.span("feed/convert", cat="input") as span:
         arrays = apply_batch_policy(converter(batch), world_size,
                                     drop_remainder)
+        staging = isinstance(converter, StagingConverter)
         if tracer.enabled:
-            span.set(bytes=sum(a.nbytes for a in arrays))
+            span.set(bytes=sum(a.nbytes for a in arrays),
+                     reused=converter.last_reused_bytes if staging else 0)
+    if staging:
+        reg = get_registry()
+        reg.inc("feed/staging_reused_bytes", converter.last_reused_bytes)
+        reg.inc("feed/staging_fresh_bytes", converter.last_fresh_bytes)
     return arrays
 
 
@@ -241,7 +291,7 @@ def assemble_window(pull_fn, n_steps: int):
 
 
 def put_window(window, pending, batch_sharding, stacked_sharding,
-               converter=None, source=None):
+               converter=None, source=None, *, caller_retires=False):
     """Transfer an assembled window: single batches go up under the
     per-example sharding, multi-step windows are stacked with the
     leading scan axis unsharded.  Returns ``(arrays, k, tail)`` —
@@ -253,7 +303,8 @@ def put_window(window, pending, batch_sharding, stacked_sharding,
     Aliasing hazard: sharded ``device_put`` of a host array can DEFER
     the per-shard copy until first use, silently aliasing the source —
     and ``block_until_ready`` does NOT force it (the alias counts as
-    ready; measured on the CPU backend).  Harmless for arrays nobody
+    ready; measured on the CPU backend); on the TPU it returns while
+    the runtime still reads the host array.  Harmless for arrays nobody
     mutates (fast-path fancy-index gathers, fresh ``np.stack``
     outputs), fatal for a converter's recycled staging buffer — the
     ring wraps and rewrites a window already handed downstream — the
@@ -261,15 +312,38 @@ def put_window(window, pending, batch_sharding, stacked_sharding,
     (:class:`NativeBatchIterator` slot views).  When ``converter`` or
     ``source`` (the batch iterator) advertises its buffers
     (``owns_buffers``, see :class:`StagingConverter`), those arrays are
-    COPIED before the transfer — the one copy the direct-to-device path
-    fundamentally owes; staging still wins for fused windows, whose
-    window-level stack is the copy.  A custom converter or iterator
-    that reuses memory without advertising it must copy itself."""
+    COPIED before the transfer — a fresh batch-sized allocation, which
+    is why staging pays off under ``prefetch=`` only for fused windows,
+    whose window-level stack is the copy.  A custom converter or
+    iterator that reuses memory without advertising it must copy
+    itself.
+
+    ``caller_retires=True`` is the caller's guarantee for the
+    CONVERTER's buffers, and lets them go to ``device_put`` as they
+    are: every window handed over here has been read to the end before
+    the converter's ring comes round to its buffer.  The serial feed
+    gives it where one ``update()`` fills one ring buffer
+    (``StandardUpdater.__init__``), on every backend and with no wait
+    on the transferred array:
+
+    - with ``n`` buffers, the one filled in ``update()`` number *u* is
+      next written in ``update()`` *u + n*;
+    - by the end of ``update()`` *u + n - 1* the updater's retire loop
+      has blocked on every window older than the newest
+      ``max_inflight``: window *u* is among them when
+      ``n >= max_inflight + 1``;
+    - a retired window has run its step, so its batch was read to the
+      end — whether the runtime copied it late (the TPU) or aliased it
+      and never copied (the CPU backend).
+
+    The prefetch worker cannot see a window retire and never passes
+    it.  ``source``'s buffers are copied either way: the iterator
+    recycles those on its own schedule."""
     import jax
 
-    probes = [p for p in (getattr(converter, "owns_buffers", None),
-                          getattr(source, "owns_buffers", None))
-              if p is not None]
+    probes = [p for p in (
+        None if caller_retires else getattr(converter, "owns_buffers", None),
+        getattr(source, "owns_buffers", None)) if p is not None]
 
     def _safe(arrays):
         if not probes:

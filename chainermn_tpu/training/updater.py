@@ -100,6 +100,15 @@ class StandardUpdater:
         through the step).
       params: initial pytree (will be replicated via ``comm.bcast_data``).
       comm: communicator providing mesh + axis for batch sharding.
+      converter: batch → tuple of stacked host arrays.  The default
+        upgrades, in the serial feed as under ``prefetch``, to a
+        :class:`~chainermn_tpu.StagingConverter` ring the updater sizes
+        itself (``staging_buffers_needed`` × batch bytes of host
+        memory, held for the run): unfused, each batch is stacked into
+        a recycled buffer that goes to ``device_put`` uncopied, and
+        ``update()``'s retire loop keeps the reuse safe (see
+        ``__init__``, docs/PIPELINE.md).  An explicit converter is kept
+        as given; a ``StagingConverter`` smaller than that is refused.
       state: optional non-trainable model state pytree.  Must come out of
         ``loss_fn`` cross-replica reduced (e.g. sync-BN ``pmean``'d
         statistics) so it stays replicated.
@@ -124,7 +133,8 @@ class StandardUpdater:
         while dispatch runs ahead of the device.  Defaults to 2 with
         ``prefetch`` (one computing + one dispatched behind it), else 1
         (each update waits for its predecessor — the natural async-
-        dispatch overlap, now measured instead of destroyed).
+        dispatch overlap, now measured instead of destroyed).  Fixed at
+        construction: the serial feed's staging ring is sized from it.
       accum_steps: microbatched gradient accumulation with a
         window-fused exchange.  Each optimiser update consumes
         ``accum_steps`` microbatches inside ONE jitted donated-carry
@@ -239,18 +249,43 @@ class StandardUpdater:
             # beats the opaque crash of feeding DeviceWindows to the
             # serial converter path
             self.prefetch = iterator.depth
-        if isinstance(converter, StagingConverter) and \
-                converter._n_buffers < self.window_steps + 1:
-            raise ValueError(
-                f"StagingConverter(n_buffers={converter._n_buffers}) "
-                f"cannot hold a steps_per_execution × accum_steps = "
-                f"{self.window_steps} window (needs >= window + 1 "
-                f"buffers)")
         if max_inflight is None:
             max_inflight = 2 if self.prefetch else 1
         if max_inflight < 1:
             raise ValueError("max_inflight must be >= 1")
         self.max_inflight = max_inflight
+        # The serial feed vouches for the converter's staging ring
+        # (put_window's caller_retires) where one update() fills one
+        # ring buffer, i.e. window_steps == 1: the buffer of update u
+        # is next written in update u + n, and by the end of update
+        # u + n - 1 the retire loop below has blocked on every window
+        # older than the newest max_inflight, so n >= max_inflight + 1
+        # buffers mean window u has run its step — its batch was read
+        # to the end, copied late (TPU) or aliased (CPU backend) —
+        # before its buffer is rewritten.  A fused window's own
+        # np.stack is its copy; there the ring only has to hold the
+        # unstacked window (window_steps + 1) and put_window keeps
+        # copying the rare lone batch of an epoch's end.  The prefetch
+        # worker cannot see retirement and never vouches.
+        self._feed_retires = not self.prefetch and self.window_steps == 1
+        if not self.prefetch and converter is default_converter:
+            # as under prefetch=: the default converter upgrades to a
+            # staging ring (sized by the rule above, not a user's
+            # knob); an explicit converter is kept as given
+            self.converter = converter = StagingConverter(
+                n_buffers=self.staging_buffers_needed)
+        needed = self.staging_buffers_needed
+        if isinstance(converter, StagingConverter) and \
+                converter._n_buffers < needed:
+            why = (f"max_inflight + 1 with max_inflight={max_inflight}: "
+                   f"the serial feed hands its buffers to device_put "
+                   f"uncopied and recycles one only after its window "
+                   f"has retired") if self._feed_retires else (
+                   f"window + 1 to hold a steps_per_execution × "
+                   f"accum_steps = {self.window_steps} window unstacked")
+            raise ValueError(
+                f"StagingConverter(n_buffers={converter._n_buffers}) is "
+                f"too small: needs >= {needed} buffers ({why})")
         self._inflight: collections.deque = collections.deque()
         # the iteration each in-flight window was dispatched at, for the
         # step/retire span's ``retired``
@@ -638,6 +673,16 @@ class StandardUpdater:
         if isinstance(self.iterator, PrefetchIterator):
             self.iterator.close()
 
+    @property
+    def staging_buffers_needed(self) -> int:
+        """Ring size a :class:`StagingConverter` needs under this
+        updater: ``max_inflight + 1`` where the serial feed hands its
+        buffers to ``device_put`` uncopied (``window_steps == 1``; see
+        ``__init__``), else ``window_steps + 1`` for the unstacked
+        window."""
+        return (self.max_inflight if self._feed_retires
+                else self.window_steps) + 1
+
     def _next_arrays(self):
         """Pull one batch, convert, apply the divisibility policy."""
         return pull_batch(self.iterator, self.converter, self.comm.size,
@@ -649,12 +694,24 @@ class StandardUpdater:
         ``assemble_window``/``put_window`` helpers the prefetch worker
         runs — one window contract, so the prefetch-on/off bitwise
         parity cannot drift.  Returns ``(arrays, k, tail)`` in exactly
-        the layout :class:`PrefetchIterator` delivers ready-made."""
+        the layout :class:`PrefetchIterator` delivers ready-made.
+
+        With a :class:`StagingConverter` (the default converter's
+        upgrade) and ``window_steps == 1`` the batch is stacked into a
+        ring of ``max_inflight + 1`` host buffers the feed keeps for
+        the run (that many × batch bytes, instead of one transient
+        batch) and goes to ``device_put`` as it is.  ``update()``'s
+        retire loop is what makes the reuse safe (``__init__``); a
+        caller that drives this method itself and keeps the returned
+        arrays across more than ``max_inflight`` further calls sees
+        them rewritten where the backend aliases host memory (the
+        CPU's)."""
         window, pending = assemble_window(
             self._next_arrays, self.window_steps)
         return put_window(window, pending, self._batch_sharding,
                           self._stacked_sharding, converter=self.converter,
-                          source=self.iterator)
+                          source=self.iterator,
+                          caller_retires=self._feed_retires)
 
     def _dispatch_window(self, carry, arrays, k):
         """Run a ``k``-microbatch window through CACHED programs only.

@@ -3,11 +3,16 @@
 import numpy as np
 import pytest
 
+import chainermn_tpu as cmn
 from chainermn_tpu import (SerialIterator, StagingConverter,
                            create_communicator,
                            create_multi_node_iterator,
                            create_synchronized_iterator)
+from chainermn_tpu.iterators.prefetch import (apply_batch_policy,
+                                              pull_batch, put_window)
 from chainermn_tpu.training import default_converter
+from chainermn_tpu.utils.metrics import MetricsRegistry, set_registry
+from chainermn_tpu.utils.telemetry import TraceRecorder, set_recorder
 
 
 @pytest.fixture()
@@ -170,3 +175,146 @@ class TestMultiNodeIterator:
         a = create_synchronized_iterator(a, comm, seed=5)
         b = create_synchronized_iterator(b, comm, seed=5)
         assert next(a) == next(b)  # identical shuffle order after sync
+
+
+def _mlp_updater(comm, **kw):
+    """Batches of 16 (x fp32[6], y int32, both ndarrays) through a
+    small MLP: 448 bytes a batch."""
+    import jax
+    import optax
+
+    from chainermn_tpu.models import (init_mlp, mlp_apply,
+                                      softmax_cross_entropy)
+
+    rng = np.random.RandomState(0)
+    data = [(rng.randn(6).astype(np.float32), np.asarray(i % 3, np.int32))
+            for i in range(96)]
+    return cmn.StandardUpdater(
+        cmn.SerialIterator(data, 16, shuffle=False),
+        cmn.create_multi_node_optimizer(optax.sgd(0.05), comm),
+        lambda p, x, y: softmax_cross_entropy(mlp_apply(p, x), y),
+        init_mlp(jax.random.PRNGKey(0), [6, 12, 3]), comm, **kw)
+
+
+class TestSerialStagingRing:
+    """The serial feed under ``StandardUpdater``: the default converter
+    becomes a ring of ``max_inflight + 1`` host buffers that
+    ``put_window`` hands to ``device_put`` as they are."""
+
+    @pytest.fixture()
+    def recorder(self):
+        rec = TraceRecorder(capacity=4096, enabled=True, rank=0)
+        prev = set_recorder(rec)
+        yield rec
+        set_recorder(prev)
+
+    @pytest.fixture()
+    def registry(self):
+        reg = MetricsRegistry(enabled=True)
+        prev = set_registry(reg)
+        yield reg
+        set_registry(prev)
+
+    @pytest.mark.parametrize("max_inflight", [1, 2])
+    def test_addresses_repeat_and_nothing_fresh_after_first_lap(
+            self, comm, recorder, registry, max_inflight):
+        upd = _mlp_updater(comm, max_inflight=max_inflight)
+        n = max_inflight + 1
+        batch_bytes = 16 * (6 * 4 + 4)
+        seen = []
+        pull = upd._next_arrays
+        upd._next_arrays = lambda: seen.append(pull()) or seen[-1]
+        fresh_after = []
+        for _ in range(3 * n):
+            upd.update()
+            fresh_after.append(
+                registry.counter("feed/staging_fresh_bytes").value)
+        addr = [[a.ctypes.data for a in arrays] for arrays in seen]
+        assert len({tuple(a) for a in addr}) == n
+        assert addr[n:] == addr[:-n]            # period n
+        reused = [e["meta"]["reused"] for e in recorder.events()
+                  if e["name"] == "feed/convert"]
+        assert reused == [0] * n + [batch_bytes] * (2 * n)
+        assert fresh_after[n - 1:] == [n * batch_bytes] * (2 * n + 1)
+        assert registry.counter("feed/staging_reused_bytes").value \
+            == 2 * n * batch_bytes
+
+    def test_serial_put_window_gets_the_ring_buffer_uncopied(
+            self, comm, monkeypatch):
+        """The copy is gone where the updater vouches, and only there:
+        ``device_put`` sees the ring's own memory in the serial feed
+        and a copy of it in the prefetched one."""
+        import jax
+
+        put = []        # the host batches device_put was given
+        real = jax.device_put
+
+        def spy(a, s=None):
+            if isinstance(a, np.ndarray) and a.ndim:
+                put.append(a)
+            return real(a, s)
+
+        monkeypatch.setattr(jax, "device_put", spy)
+        serial = _mlp_updater(comm)
+        del put[:]              # whatever building the updater put
+        serial.update()
+        ring = serial.converter
+        assert put and all(ring.owns_buffers((a,)) for a in put)
+        pre = _mlp_updater(comm, prefetch=2)
+        del put[:]
+        pre.update()
+        pre.iterator.close()
+        ring = pre.iterator._converter
+        assert isinstance(ring, cmn.StagingConverter)
+        assert put and not any(ring.owns_buffers((a,)) for a in put)
+
+    def test_ragged_and_mixed_columns_count_as_fresh(self, registry):
+        sc = StagingConverter(n_buffers=2)
+        mixed = [(np.full(3, i, np.float32), i) for i in range(4)]
+        for _ in range(3):
+            x, y = pull_batch(iter([mixed]), sc, 1, True)
+        # x recycles from the third call on; the python ints never do
+        assert sc.last_reused_bytes == x.nbytes
+        assert sc.last_fresh_bytes == y.nbytes
+        passed = np.zeros((4, 3), np.float32)
+        assert pull_batch(iter([passed]), sc, 1, True)[0] is passed
+        assert sc.last_reused_bytes == sc.last_fresh_bytes == 0
+        assert registry.counter("feed/staging_fresh_bytes").value \
+            == 2 * x.nbytes + 3 * y.nbytes
+        # any other converter counts nothing
+        pull_batch(iter([mixed]), default_converter, 1, True)
+        assert registry.counter("feed/staging_fresh_bytes").value \
+            == 2 * x.nbytes + 3 * y.nbytes
+
+
+class TestOwnsBuffersByMemory:
+    def test_dropped_remainder_view_is_recognised(self):
+        """250 examples over a world of 8: ``apply_batch_policy``
+        returns the view ``a[:248]`` of the ring buffer, which the
+        prefetched feed must still copy before ``device_put``."""
+        sc = StagingConverter(n_buffers=2)
+        batch = [np.full(3, i, np.float32) for i in range(250)]
+        (whole,) = sc(batch)
+        (kept,) = apply_batch_policy((whole,), 8, drop_remainder=True)
+        assert kept.shape == (248, 3) and kept is not whole
+        assert np.shares_memory(kept, whole)
+        assert sc.owns_buffers((kept,))
+        assert sc.owns_buffers((kept[3:5].reshape(-1),))   # view of view
+        assert sc.owns_buffers((whole,))
+        assert not sc.owns_buffers((np.array(kept), np.stack(batch)))
+
+    def test_put_window_copies_the_view(self, comm):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        sc = StagingConverter(n_buffers=2)
+        n = 31 * comm.size + 2
+        batch = [np.full(3, i, np.float32) for i in range(n)]
+        arrays = pull_batch(iter([batch]), sc, comm.size, True)
+        sharding = NamedSharding(comm.mesh, P(comm.axis_name))
+        (dev,), k, tail = put_window([arrays], None, sharding, sharding,
+                                     converter=sc)
+        want = np.array(arrays[0])
+        sc(batch)[0][:] = -1        # lap the ring of 2
+        sc(batch)[0][:] = -1
+        assert k == 1 and tail is None
+        np.testing.assert_array_equal(np.asarray(dev), want)
