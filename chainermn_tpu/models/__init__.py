@@ -19,8 +19,11 @@ from .seq2seq import (
     seq2seq_translate,
 )
 from .transformer import (
+    AttentionKind,
     TransformerConfig,
     apply_rope,
+    expert_choices,
+    expert_load,
     init_transformer,
     make_forward_fn,
     make_train_step,
@@ -33,6 +36,7 @@ from .transformer import (
 )
 
 __all__ = [
+    "AttentionKind",
     "ConvNetConfig",
     "ResNetConfig",
     "convnet_apply",
@@ -40,6 +44,8 @@ __all__ = [
     "Seq2seqConfig",
     "TransformerConfig",
     "apply_rope",
+    "expert_choices",
+    "expert_load",
     "init_seq2seq",
     "seq2seq_loss",
     "seq2seq_translate",

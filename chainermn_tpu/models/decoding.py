@@ -477,6 +477,13 @@ def _decode_preamble(mesh_cfg, cfg: TransformerConfig, max_len: int):
     """Shared validation for the decode factories; returns the resolved
     ``(max_len, kv_len_local, kv_heads_local, layers_local)``."""
     _check_mesh(mesh_cfg, cfg)   # head/kv divisibility, clear errors
+    if cfg.training_only:
+        raise ValueError(
+            f"decoding does not implement {', '.join(cfg.training_only)}: "
+            "a layer pattern (a cache per attention kind), an expert "
+            "layer that holds a share or dispatches dropless, and an "
+            "untied head exist on the training path only "
+            "(make_train_step)")
     if cfg.fsdp:
         raise ValueError(
             "fsdp is a training-path layout (per-layer just-in-time "

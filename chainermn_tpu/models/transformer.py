@@ -24,11 +24,13 @@ Design rules (TPU-first):
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
@@ -40,7 +42,11 @@ from chainermn_tpu.ops.pallas_attention import (
     interpret_kernels,
     tracing_for_mesh,
 )
-from chainermn_tpu.parallel.expert import expert_parallel_moe
+from chainermn_tpu.parallel.expert import (
+    expert_parallel_moe,
+    expert_parallel_moe_dropless,
+    grouped_dense,
+)
 from chainermn_tpu.parallel.fsdp import fsdp_gather
 from chainermn_tpu.parallel.pipeline import (
     pipeline_apply,
@@ -63,14 +69,68 @@ from chainermn_tpu.parallel.tensor import (
 from chainermn_tpu.parallel.ulysses import ulysses_attention
 
 __all__ = [
+    "AttentionKind",
     "TransformerConfig",
     "apply_rope",
+    "expert_choices",
+    "expert_load",
     "init_transformer",
     "transformer_forward",
     "param_specs",
     "make_forward_fn",
     "make_train_step",
 ]
+
+
+@dataclass(frozen=True)
+class AttentionKind:
+    """One kind of attention layer of a model whose layers differ: its
+    window and its rotary parameters.  ``TransformerConfig.layer_pattern``
+    is a tuple of these, one per layer of a period."""
+    name: str                  # names the layer's scope: ``attn/<name>``
+    window: int = 0            # 0 => full causal; W>0 => (t-W, t]
+    rope_theta: float = 10000.0
+    # YaRN (Peng et al., arXiv:2309.00071), as published configs state
+    # it: frequencies whose wavelength exceeds the original context are
+    # divided by ``yarn_factor``, those that turn often within it are
+    # kept, with a linear blend between ``yarn_beta_fast`` and
+    # ``yarn_beta_slow`` turns; cos and sin are multiplied by
+    # ``attention_factor``.  ``yarn_factor == 0`` => plain rope.
+    yarn_factor: float = 0.0
+    yarn_original_max: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def __post_init__(self):
+        if not self.name or "/" in self.name:
+            raise ValueError(f"attention kind name {self.name!r}")
+        if self.window < 0:
+            raise ValueError(f"{self.name}: window {self.window} < 0")
+        if self.rope_theta <= 1:
+            raise ValueError(f"{self.name}: rope_theta {self.rope_theta}")
+        if self.yarn_factor and (self.yarn_factor < 1
+                                 or self.yarn_original_max < 1):
+            raise ValueError(
+                f"{self.name}: yarn needs factor >= 1 and the original "
+                f"context, got {self.yarn_factor}, {self.yarn_original_max}")
+
+    def inv_freq(self, d_head: int):
+        """The ``d_head / 2`` rotary frequencies, as float64 numpy
+        (constants of the compiled step)."""
+        half = d_head // 2
+        base = self.rope_theta ** (-np.arange(half, dtype=np.float64) / half)
+        if not self.yarn_factor:
+            return base
+
+        def turns_at(n):   # the dimension that turns n times in the context
+            return d_head * math.log(self.yarn_original_max / (
+                2 * math.pi * n)) / (2 * math.log(self.rope_theta))
+
+        lo = max(math.floor(turns_at(self.yarn_beta_fast)), 0)
+        hi = min(math.ceil(turns_at(self.yarn_beta_slow)), d_head - 1)
+        ramp = np.clip((np.arange(half) - lo) / max(hi - lo, 1e-3), 0, 1)
+        return ramp * base / self.yarn_factor + (1 - ramp) * base
 
 
 @dataclass(frozen=True)
@@ -99,6 +159,13 @@ class TransformerConfig:
     # composes with ring/zigzag sharding because each shard rotates by
     # its own global positions before any K/V movement)
     rope_theta: float = 10000.0
+    layer_pattern: tuple = ()  # () => every layer alike, by the three
+    # fields above.  Else the :class:`AttentionKind` of each layer of
+    # one period (e.g. sliding x3, full x1): layer l is of kind
+    # ``layer_pattern[l % len]``, each with its own window and rotary
+    # frequencies; the layer scan then runs over whole periods with the
+    # period's kinds unrolled in its body (a window is a static argument
+    # of the kernel).  Needs pos_embedding="rope"; training path only.
     seq_layout: str = "contiguous"  # "contiguous" | "zigzag" (ring only):
     # zigzag = Striped-ring causal load balance; feed tokens permuted by
     # parallel.ring_attention.zigzag_indices (targets through the same
@@ -108,6 +175,22 @@ class TransformerConfig:
     router_top_k: int = 1      # experts per token: 1 = Switch, 2 =
     # GShard-style top-2 with renormalised gates (capacity scales by k)
     capacity_factor: float = 1.25
+    moe_dispatch: str = "capacity"  # "capacity": slots of
+    # cf*k*N/E per expert through dense dispatch/combine one-hots,
+    # overflow dropped | "dropless": rows sorted by expert and grouped
+    # products over the rows really routed (parallel.expert); training
+    # path only
+    expert_act: str = "relu"   # "relu": w2(relu(w1 x)) | "swiglu":
+    # w2(silu(w1 x) * w3 x), the gated expert (dropless dispatch only)
+    experts_held: tuple = ()   # () => all n_experts.  (first, count):
+    # the mesh's expert group holds only experts [first, first+count)
+    # of the n_experts the router scores -- one member's share of an
+    # expert-parallel deployment.  Expert weights have ``count`` rows,
+    # the router keeps n_experts columns, gates are normalised over the
+    # k chosen among all of them, and the layer returns the held
+    # experts' part of its result (dropless dispatch only)
+    tie_embeddings: bool = True  # False => a separate output matrix
+    # ``head`` (vocab, d_model) beside ``embed``; training path only
     num_microbatches: int = 1  # GPipe M (>1 only useful when pipe > 1)
     pipeline_schedule: str = "gpipe"  # "gpipe" | "1f1b" | "interleaved"
     virtual_pipe: int = 1      # V model chunks per pipe device (Megatron
@@ -167,6 +250,21 @@ class TransformerConfig:
         return self.n_kv_heads or self.n_heads
 
     @property
+    def n_experts_held(self) -> int:
+        return self.experts_held[1] if self.experts_held else self.n_experts
+
+    @property
+    def training_only(self):
+        """The fields in use that only the training path implements, by
+        name: decoding and serving refuse a config that sets any."""
+        return [name for name, on in (
+            ("layer_pattern", bool(self.layer_pattern)),
+            ("experts_held", bool(self.experts_held)),
+            ("moe_dispatch='dropless'",
+             self.moe and self.moe_dispatch == "dropless"),
+            ("tie_embeddings=False", not self.tie_embeddings)) if on]
+
+    @property
     def checkpoint_fn(self):
         """The configured ``jax.checkpoint`` wrapper (identity when
         ``remat=False``)."""
@@ -206,6 +304,46 @@ class TransformerConfig:
         if self.loss_chunk < 0:
             raise ValueError(
                 f"loss_chunk={self.loss_chunk} must be >= 0")
+        if self.layer_pattern:
+            if not all(isinstance(k, AttentionKind)
+                       for k in self.layer_pattern):
+                raise ValueError("layer_pattern holds AttentionKind values")
+            if self.pos_embedding != "rope":
+                raise ValueError(
+                    'layer_pattern gives each kind its rotary parameters: '
+                    'it needs pos_embedding="rope"')
+            if self.attention_window:
+                raise ValueError(
+                    "with a layer_pattern the window is each kind's own; "
+                    f"attention_window={self.attention_window} is set too")
+            if self.n_layers % len(self.layer_pattern):
+                raise ValueError(
+                    f"n_layers={self.n_layers} is not whole periods of "
+                    f"the {len(self.layer_pattern)}-layer pattern")
+            if self.seq_layout != "contiguous":
+                raise ValueError("layer_pattern needs contiguous shards")
+        if self.moe_dispatch not in ("capacity", "dropless"):
+            raise ValueError(
+                f"moe_dispatch {self.moe_dispatch!r} not in "
+                "(capacity, dropless)")
+        if self.expert_act not in ("relu", "swiglu"):
+            raise ValueError(
+                f"expert_act {self.expert_act!r} not in (relu, swiglu)")
+        dropless = self.moe and self.moe_dispatch == "dropless"
+        if self.expert_act != "relu" and not dropless:
+            raise ValueError(
+                'expert_act="swiglu" is implemented by the dropless '
+                "expert layer only (moe=True, moe_dispatch='dropless')")
+        if self.experts_held:
+            if not dropless:
+                raise ValueError(
+                    "experts_held needs moe=True, moe_dispatch='dropless'")
+            first, count = self.experts_held
+            if not (0 <= first and 1 <= count
+                    and first + count <= self.n_experts):
+                raise ValueError(
+                    f"experts_held={self.experts_held} is not a range "
+                    f"(first, count) within n_experts={self.n_experts}")
         if self.moe and not 1 <= self.router_top_k <= self.n_experts:
             raise ValueError(
                 f"router_top_k={self.router_top_k} must be in "
@@ -266,10 +404,14 @@ def _init_block(key, cfg: TransformerConfig):
         block["wq"] = dense_init(ks[0], (D, H, Dh), D)
         block["wkv"] = dense_init(ks[5], (D, 2, cfg.kv_heads, Dh), D)
     if cfg.moe:
-        E = cfg.n_experts
+        # the router scores every expert; the weights are of those held
+        E, G = cfg.n_experts, cfg.n_experts_held
         block["router"] = dense_init(ks[2], (D, E), D)
-        block["w1"] = dense_init(ks[3], (E, D, F), D)
-        block["w2"] = dense_init(ks[4], (E, F, D), F)
+        block["w1"] = dense_init(ks[3], (G, D, F), D)
+        block["w2"] = dense_init(ks[4], (G, F, D), F)
+        if cfg.expert_act == "swiglu":
+            block["w3"] = dense_init(
+                jax.random.fold_in(key, 6), (G, D, F), D)
     else:
         block["w1"] = dense_init(ks[3], (D, F), D)
         block["w2"] = dense_init(ks[4], (F, D), F)
@@ -313,6 +455,10 @@ def init_transformer(key, cfg: TransformerConfig, pipe_size: int = 1):
     if cfg.pos_embedding == "learned":
         params["pos"] = jax.random.normal(
             k_pos, (cfg.max_seq, D), jnp.float32) * 0.02
+    if not cfg.tie_embeddings:
+        params["head"] = jax.random.normal(
+            jax.random.fold_in(k_emb, 1), (cfg.vocab_size, D),
+            jnp.float32) * 0.02
     return params
 
 
@@ -439,6 +585,8 @@ def _fsdp_dims(cfg: TransformerConfig):
         dims["wkv"] = 0
     if cfg.moe:
         dims.update({"router": 0, "w1": 1, "w2": 2})
+        if cfg.expert_act == "swiglu":
+            dims["w3"] = 1
     else:
         dims.update({"w1": 0, "w2": 1})
     return dims
@@ -480,6 +628,8 @@ def param_specs(cfg: TransformerConfig, quantized: bool = False):
         blk["router"] = P("pipe")
         blk["w1"] = P("pipe", None, "expert", None, "model")
         blk["w2"] = P("pipe", None, "expert", "model", None)
+        if cfg.expert_act == "swiglu":
+            blk["w3"] = blk["w1"]
     else:
         blk["w1"] = P("pipe", None, None, "model")
         blk["w2"] = P("pipe", None, "model", None)
@@ -523,6 +673,8 @@ def param_specs(cfg: TransformerConfig, quantized: bool = False):
         specs["embed_scale"] = emb
     if cfg.pos_embedding == "learned":
         specs["pos"] = P()
+    if not cfg.tie_embeddings:
+        specs["head"] = emb
     return specs
 
 
@@ -890,7 +1042,8 @@ def _shard_nll_sum(cfg, h_normed, embed, targets):
         logp, targets[..., None], axis=-1).sum(dtype=jnp.float32)
 
 
-def apply_rope(x, positions, theta: float = 10000.0):
+def apply_rope(x, positions, theta: float = 10000.0, inv_freq=None,
+               scale: float = 1.0):
     """Rotary embedding (rotate-half convention) on ``x`` (..., T, H, D)
     at absolute ``positions`` — ``(T,)`` shared across the batch, or
     ``(B, T)`` per-row (left-padded decoding gives each row its own
@@ -900,25 +1053,47 @@ def apply_rope(x, positions, theta: float = 10000.0):
     global position and relative attention falls out, with no position
     parameters to learn or extend.
 
+    ``inv_freq`` (``d_head/2`` values) replaces ``theta``'s frequencies
+    and ``scale`` multiplies cos and sin.
+
     The trig tables are (T, d_head/2) — negligible next to the T² score
     matrix, so they are recomputed per call (the layer-invariant parts
     are XLA CSE-hoistable) instead of threading a cache through every
     stage signature."""
     half = x.shape[-1] // 2
-    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        # a kind's own frequencies (AttentionKind.inv_freq) and the
+        # factor its cos and sin carry (YaRN's attention factor)
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     ang = positions.astype(jnp.float32)[..., None] * freqs  # (..., T, half)
-    cos = jnp.cos(ang)[..., None, :].astype(x.dtype)  # (..., T, 1, half)
-    sin = jnp.sin(ang)[..., None, :].astype(x.dtype)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
+    cos = cos[..., None, :].astype(x.dtype)           # (..., T, 1, half)
+    sin = sin[..., None, :].astype(x.dtype)
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
 
 
-def _attention(cfg: TransformerConfig, h, blk):
+def _attention(cfg: TransformerConfig, h, blk, kind=None):
     """Pre-LN attention: column-parallel QKV (heads sharded over ``model``),
-    seq-parallel core (ring/Ulysses over ``seq``), row-parallel output."""
+    seq-parallel core (ring/Ulysses over ``seq``), row-parallel output.
+    ``kind`` is the layer's :class:`AttentionKind` under a
+    ``layer_pattern``: its window and rotary parameters then stand in
+    for the config's, and the layer's ops carry ``attn/<kind.name>`` in
+    their ``op_name``."""
+    if kind is not None:
+        with jax.named_scope(f"attn/{kind.name}"):
+            return _attention_of_kind(cfg, h, blk, kind)
+    return _attention_of_kind(cfg, h, blk, None)
+
+
+def _attention_of_kind(cfg: TransformerConfig, h, blk, kind):
     cd = cfg.compute_dtype
-    win = cfg.attention_window or None
+    win = (kind.window if kind else cfg.attention_window) or None
     x = _rms_norm(h, blk["ln1"])
     B, T, D = x.shape
     if "wqkv" in blk:
@@ -960,8 +1135,10 @@ def _attention(cfg: TransformerConfig, h, blk):
         pos = _block_positions(
             lax.axis_index("seq"), T, lax.axis_size("seq"),
             cfg.seq_layout if cfg.attention == "ring" else "contiguous")
-        q = apply_rope(q, pos, cfg.rope_theta)
-        k = apply_rope(k, pos, cfg.rope_theta)
+        rope = dict(theta=cfg.rope_theta) if kind is None else dict(
+            inv_freq=kind.inv_freq(cfg.d_head), scale=kind.attention_factor)
+        q = apply_rope(q, pos, **rope)
+        k = apply_rope(k, pos, **rope)
     if cfg.attention == "ring":
         # flagship long-context path: ring schedule with the Pallas
         # kernel as the per-pair compute whenever the local block shape
@@ -1035,16 +1212,43 @@ def _attention(cfg: TransformerConfig, h, blk):
     return h + o
 
 
-def _mlp(cfg: TransformerConfig, h, blk):
+def _mlp(cfg: TransformerConfig, h, blk, with_chosen=False):
     """Pre-LN MLP: dense (column→row TP pair, one psum) or Switch-MoE
-    (expert all-to-alls; experts' FFNs are themselves TP-split)."""
+    (expert all-to-alls; experts' FFNs are themselves TP-split).
+    ``with_chosen`` (dropless dispatch) also returns the ``(B, T, k)``
+    experts each token chose, for :func:`expert_choices`."""
     cd = cfg.compute_dtype
     x = _rms_norm(h, blk["ln2"])
+    if with_chosen and not (cfg.moe and cfg.moe_dispatch == "dropless"):
+        raise ValueError("the choices are read from the dropless layer")
     if not cfg.moe:
         y = jax.nn.relu(column_parallel_dense(x, blk["w1"].astype(cd)))
         out = h + row_parallel_dense(y, blk["w2"].astype(cd))
         return out, jnp.zeros((), jnp.float32)
     B, T, D = x.shape
+    if cfg.moe_dispatch == "dropless":
+        def grouped_fn(p, rows, sizes):
+            # the experts' FFNs over rows sorted by expert, TP-split
+            # like the dense pair: column (no exchange), row (one psum)
+            y = grouped_dense(rows, p["w1"], sizes)
+            if cfg.expert_act == "swiglu":
+                y = jax.nn.silu(y) * grouped_dense(rows, p["w3"], sizes)
+            else:
+                y = jax.nn.relu(y)
+            return lax.psum(grouped_dense(y, p["w2"], sizes), "model")
+
+        out, aux, chosen = expert_parallel_moe_dropless(
+            x.reshape(B * T, D),
+            blk["router"],
+            {k: blk[k].astype(cd) for k in ("w1", "w2", "w3") if k in blk},
+            grouped_fn,
+            top_k=cfg.router_top_k,
+            first_expert=cfg.experts_held[0] if cfg.experts_held else 0,
+            axis_name="expert",
+        )
+        if with_chosen:
+            return h + out.reshape(B, T, D), aux, chosen.reshape(B, T, -1)
+        return h + out.reshape(B, T, D), aux
 
     def expert_fn(p, tokens):
         y = jax.nn.relu(column_parallel_dense(tokens, p["w1"]))
@@ -1062,11 +1266,43 @@ def _mlp(cfg: TransformerConfig, h, blk):
     return h + out.reshape(B, T, D), aux
 
 
-def _block(cfg: TransformerConfig, h, blk):
+def _block(cfg: TransformerConfig, h, blk, kind=None, with_chosen=False):
     if cfg.fsdp:
         blk = _fsdp_gather(cfg, blk)
-    h = _attention(cfg, h, blk)
-    return _mlp(cfg, h, blk)
+    h = _attention(cfg, h, blk, kind)
+    return _mlp(cfg, h, blk, with_chosen)
+
+
+def _scan_layers(cfg: TransformerConfig, layer_fn, carry, blocks):
+    """``lax.scan`` of ``layer_fn(carry, blk, kind) -> (carry, y)`` over
+    the leading (layer) axis of ``blocks``.  Under a ``layer_pattern``
+    one compiled body cannot serve every layer (a kind's window is a
+    static argument of the kernel), so the scan runs over whole periods
+    and its body unrolls the period's kinds; the stack keeps its
+    ``(layers, ...)`` layout and is only viewed as ``(periods, kinds,
+    ...)`` here.  ``y`` comes back stacked by layer either way."""
+    kinds = cfg.layer_pattern
+    if not kinds:
+        return lax.scan(lambda c, blk: layer_fn(c, blk, None), carry, blocks)
+    n = len(kinds)
+    layers = jax.tree.leaves(blocks)[0].shape[0]
+    if layers % n:
+        raise ValueError(
+            f"{layers} layers on this pipeline stage are not whole "
+            f"periods of the {n}-layer pattern")
+
+    def period(c, blks):
+        ys = []
+        for j, kind in enumerate(kinds):
+            c, y = layer_fn(c, jax.tree.map(lambda a: a[j], blks), kind)
+            ys.append(y)
+        return c, None if ys[0] is None else jax.tree.map(
+            lambda *a: jnp.stack(a), *ys)
+
+    carry, ys = lax.scan(period, carry, jax.tree.map(
+        lambda a: a.reshape(layers // n, n, *a.shape[1:]), blocks))
+    return carry, ys if ys is None else jax.tree.map(
+        lambda a: a.reshape(layers, *a.shape[2:]), ys)
 
 
 def _stage(cfg: TransformerConfig, stage_params, h):
@@ -1075,14 +1311,35 @@ def _stage(cfg: TransformerConfig, stage_params, h):
     stage's layers rides the schedule via ``pipeline_apply(with_aux=
     True)`` instead of being dropped."""
 
-    def body(carry, blk):
+    def body(carry, blk, kind):
         h, aux = carry
-        out, a = _block(cfg, h, blk)
+        out, a = _block(cfg, h, blk, kind)
         return (out, aux + a), None
 
     aux0 = jnp.sum(h * 0, dtype=jnp.float32)
-    (h, aux), _ = lax.scan(body, (h, aux0), stage_params)
+    (h, aux), _ = _scan_layers(cfg, body, (h, aux0), stage_params)
     return h, aux
+
+
+def _embed(cfg: TransformerConfig, params, tokens):
+    """Token rows (+ the learned positions' rows) in the compute dtype."""
+    cd = cfg.compute_dtype
+    B, T = tokens.shape
+    r = lax.axis_index("seq")
+
+    if cfg.vocab_parallel:
+        h = _vp_embed_lookup(params["embed"], tokens)  # (B, T, D) fp32
+    else:
+        h = params["embed"][tokens]                    # (B, T, D) fp32
+    if cfg.pos_embedding == "rope":
+        return h.astype(cd)       # rotations happen inside attention
+    if cfg.seq_layout == "zigzag":
+        # position rows follow the zigzag permutation of this shard
+        return (h + params["pos"][
+            _block_positions(r, T, lax.axis_size("seq"), "zigzag")]
+        ).astype(cd)
+    return (h + lax.dynamic_slice_in_dim(
+        params["pos"], r * T, T, axis=0)).astype(cd)
 
 
 def transformer_backbone(cfg: TransformerConfig, params, tokens):
@@ -1102,25 +1359,7 @@ def transformer_backbone(cfg: TransformerConfig, params, tokens):
         raise ValueError(
             'seq_layout="zigzag" is a ring-attention layout; '
             f'attention={cfg.attention!r} expects contiguous shards')
-    cd = cfg.compute_dtype
-    B, T = tokens.shape
-    r = lax.axis_index("seq")
-
-    if cfg.vocab_parallel:
-        h = _vp_embed_lookup(params["embed"], tokens)  # (B, T, D) fp32
-    else:
-        h = params["embed"][tokens]                    # (B, T, D) fp32
-    if cfg.pos_embedding == "rope":
-        h = h.astype(cd)          # rotations happen inside attention
-    elif cfg.seq_layout == "zigzag":
-        # position rows follow the zigzag permutation of this shard
-        h = (h + params["pos"][
-            _block_positions(r, T, lax.axis_size("seq"), "zigzag")]
-        ).astype(cd)
-    else:
-        h = (h + lax.dynamic_slice_in_dim(
-            params["pos"], r * T, T, axis=0)).astype(cd)
-
+    h = _embed(cfg, params, tokens)
     S = lax.axis_size("pipe")
     if cfg.virtual_pipe > 1:
         # forward-only traversal of the V chunk rings: chunk c of every
@@ -1157,9 +1396,9 @@ def transformer_backbone(cfg: TransformerConfig, params, tokens):
         blocks = jax.tree.map(
             lambda a: jnp.squeeze(a, axis=0), params["blocks"])
 
-        def body(carry, blk):
+        def body(carry, blk, kind):
             h, aux = carry
-            fn = cfg.checkpoint_fn(partial(_block, cfg))
+            fn = cfg.checkpoint_fn(partial(_block, cfg, kind=kind))
             h, a = fn(h, blk)
             return (h, aux + a), None
 
@@ -1169,11 +1408,17 @@ def transformer_backbone(cfg: TransformerConfig, params, tokens):
         # aux derives from h so it inherits the batch axes' variance too.
         vary = partial(lax.pcast, axis_name=("pipe",), to="varying")
         aux0 = jnp.sum(h * 0, dtype=jnp.float32)
-        (h, aux), _ = lax.scan(body, (vary(h), vary(aux0)), blocks)
+        (h, aux), _ = _scan_layers(
+            cfg, body, (vary(h), vary(aux0)), blocks)
         h = lax.psum(h, "pipe")
         aux = lax.psum(aux, "pipe")
 
     return _rms_norm(h, params["ln_f"]), aux
+
+
+def _head_matrix(cfg: TransformerConfig, params):
+    """The output matrix: the embedding itself when tied."""
+    return params["embed"] if cfg.tie_embeddings else params["head"]
 
 
 def transformer_forward(cfg: TransformerConfig, params, tokens):
@@ -1190,12 +1435,13 @@ def transformer_forward(cfg: TransformerConfig, params, tokens):
         # _vp_head, not _lm_head: the latter's custom VJP psums the
         # embed cotangent over every varying axis, which would wrongly
         # sum the DISTINCT vocab shards over model
-        logits = _vp_head(cfg.compute_dtype, "model", h, params["embed"])
+        logits = _vp_head(cfg.compute_dtype, "model", h,
+                          _head_matrix(cfg, params))
         # invariant gather: the full logits are identical on every
         # model member, and the vma type must say so for out_specs
         return _all_gather_invariant(
             logits, "model", axis=2, tiled=True), aux
-    return _lm_head(cfg.compute_dtype, h, params["embed"]), aux
+    return _lm_head(cfg.compute_dtype, h, _head_matrix(cfg, params)), aux
 
 
 # coefficient of the Switch-MoE balancing loss in the training objective
@@ -1207,8 +1453,46 @@ _AUX_WEIGHT = 0.01
 def lm_loss(cfg: TransformerConfig, params, inputs, targets):
     """Local-shard mean next-token cross-entropy (+0.01·aux)."""
     h, aux = transformer_backbone(cfg, params, inputs)
-    nll_sum = _shard_nll_sum(cfg, h, params["embed"], targets)
+    nll_sum = _shard_nll_sum(cfg, h, _head_matrix(cfg, params), targets)
     return nll_sum / targets.size + _AUX_WEIGHT * aux
+
+
+def expert_choices(mesh_cfg, cfg: TransformerConfig, params, tokens):
+    """``(n_layers, B, T, router_top_k)`` int32: the experts every
+    token chose in every layer, held here or not.  A forward pass
+    through the step's own blocks and router (dropless dispatch), for
+    counters and comparisons.  Unpipelined meshes only."""
+    _check_mesh(mesh_cfg, cfg)
+    if mesh_cfg.mesh.shape.get("pipe", 1) > 1 or cfg.virtual_pipe > 1:
+        raise ValueError("expert_choices reads an unpipelined layer stack")
+
+    def fwd(params, tokens):
+        h = _embed(cfg, params, tokens)
+        blocks = jax.tree.map(
+            lambda a: jnp.squeeze(a, axis=0), params["blocks"])
+
+        def body(h, blk, kind):
+            h, _, chosen = _block(cfg, h, blk, kind, with_chosen=True)
+            return h, chosen
+
+        vary = partial(lax.pcast, axis_name=("pipe",), to="varying")
+        _, chosen = _scan_layers(cfg, body, vary(h), blocks)
+        return lax.psum(chosen, "pipe")
+
+    return jax.jit(jax.shard_map(
+        tracing_for_mesh(mesh_cfg.mesh, fwd), mesh=mesh_cfg.mesh,
+        in_specs=(param_specs(cfg), _BATCH_SPEC),
+        out_specs=P(None, *_BATCH_SPEC),
+    ))(params, tokens)
+
+
+def expert_load(mesh_cfg, cfg: TransformerConfig, params, tokens):
+    """``(n_layers, n_experts)`` int32: the rows every layer's router
+    sent to each expert for these tokens (all ``router_top_k`` choices
+    of each token): what the grouped products of a step have to do."""
+    chosen = expert_choices(mesh_cfg, cfg, params, tokens)
+    return jax.vmap(lambda c: jnp.zeros((cfg.n_experts,), jnp.int32).at[
+        c.reshape(-1)].add(1))(chosen)
 
 
 # --------------------------------------------------------------------- #
@@ -1263,7 +1547,7 @@ def _make_1f1b_grad(cfg: TransformerConfig):
             hN = _rms_norm(y, lp["ln_f"])
             return _shard_nll_sum(cfg, hN, lp["embed"], tgt) / tgt.size
 
-        lp = {"ln_f": params["ln_f"], "embed": params["embed"]}
+        lp = {"ln_f": params["ln_f"], "embed": _head_matrix(cfg, params)}
         aux_kw = dict(with_aux=True, aux_weight=_AUX_WEIGHT) \
             if cfg.moe else {}
         if cfg.pipeline_schedule == "interleaved":
@@ -1284,12 +1568,12 @@ def _make_1f1b_grad(cfg: TransformerConfig):
             loss, g_blocks, g_lp, dx = out
         (d_ep,) = vjp_embed(dx)
 
-        grads = {
+        grads = {"blocks": g_blocks, "ln_f": g_lp["ln_f"]}
+        if cfg.tie_embeddings:
             # weight tying: embedding grads = lookup side + head side
-            "embed": d_ep["embed"] + g_lp["embed"],
-            "blocks": g_blocks,
-            "ln_f": g_lp["ln_f"],
-        }
+            grads["embed"] = d_ep["embed"] + g_lp["embed"]
+        else:
+            grads["embed"], grads["head"] = d_ep["embed"], g_lp["embed"]
         if cfg.pos_embedding == "learned":
             grads["pos"] = d_ep["pos"]
         # Normalisation: every parameter is REPLICATED over the
@@ -1336,6 +1620,11 @@ def _check_mesh(mesh_cfg, cfg: TransformerConfig):
         raise ValueError(
             f"vocab_parallel shards the vocab dim over the model axis: "
             f"vocab_size={cfg.vocab_size} must be divisible by {mp}")
+    ep = mesh_cfg.mesh.shape.get("expert", 1)
+    if cfg.moe and cfg.n_experts_held % ep:
+        raise ValueError(
+            f"the {cfg.n_experts_held} experts held must divide over the "
+            f"expert mesh axis ({ep})")
     dp = mesh_cfg.mesh.shape.get("data", 1)
     if cfg.fsdp and cfg.d_model % dp:
         raise ValueError(

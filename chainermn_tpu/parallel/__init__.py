@@ -46,7 +46,10 @@ from chainermn_tpu.parallel.tensor import (
     row_parallel_dense,
 )
 from chainermn_tpu.parallel.ulysses import ulysses_attention
-from chainermn_tpu.parallel.expert import expert_parallel_moe
+from chainermn_tpu.parallel.expert import (
+    expert_parallel_moe,
+    expert_parallel_moe_dropless,
+)
 from chainermn_tpu.parallel.fsdp import fsdp_dims, fsdp_gather, fsdp_specs
 from chainermn_tpu.parallel.sharded_state import (
     LayerGatherStream,
@@ -64,6 +67,7 @@ __all__ = [
     "ShardedState",
     "column_parallel_dense",
     "expert_parallel_moe",
+    "expert_parallel_moe_dropless",
     "fsdp_dims",
     "fsdp_gather",
     "fsdp_specs",

@@ -17,18 +17,32 @@ building block").  Shape of the strategy:
   inverse ``all_to_all`` brings results home to be gate-combined;
 - the load-balancing auxiliary loss (fraction·probability product) is
   returned for the trainer to add — ``psum``'d so it is the global value.
+
+:func:`expert_parallel_moe_dropless` is the second dispatch: no capacity
+and no ``(N, E, cap)`` tensor.  The (token, choice) rows are sorted by
+expert, the experts held here run as grouped products over exactly the
+rows routed to them (``lax.ragged_dot``), and no token is dropped
+whatever the imbalance.  It can be told that it holds only a contiguous
+share of the router's experts: it then routes over all of them and
+returns its own experts' part of the result.
 """
 
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["expert_parallel_moe"]
+__all__ = [
+    "expert_parallel_moe",
+    "expert_parallel_moe_dropless",
+    "grouped_dense",
+    "route_top_k",
+]
 
 
 def _a2a(v, axis_name: str, split_axis: int, concat_axis: int, plan):
@@ -154,3 +168,227 @@ def expert_parallel_moe(
         frac_probs = lax.pmean(frac_probs, axis_name)
     aux = E * jnp.sum(frac_tokens * frac_probs)
     return out, aux
+
+
+# --------------------------------------------------------------------- #
+# dropless dispatch
+# --------------------------------------------------------------------- #
+
+
+def route_top_k(x, router_w, top_k: int):
+    """``(probs, top_i, gates)`` of a linear softmax router, in float32
+    whatever the compute dtype (input, product and softmax): a choice
+    that flips between two near-equal experts moves a whole expert's
+    output, which rounding the logits to bf16 does far more often.
+    ``gates`` are the winners' probabilities renormalised over the k
+    chosen (``top_k == 1`` keeps the raw Switch gate, as the capacity
+    dispatch does)."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)             # (N, E)
+    top_p, top_i = lax.top_k(probs, top_k)              # (N, k)
+    gates = top_p if top_k == 1 else \
+        top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return probs, top_i, gates
+
+
+def grouped_dense(rows, w, group_sizes):
+    """``rows[g's rows] @ w[g]`` for consecutive groups of rows:
+    ``rows`` ``(R, K)`` sorted by group, ``w`` ``(G, K, M)``,
+    ``group_sizes`` ``(G,)`` int32.  On TPU ``lax.ragged_dot`` compiles
+    to a grouped-matmul kernel whose grid follows ``group_sizes``, so
+    the work is that of the rows really there; rows past the last group
+    come back undefined (see :func:`_experts_of_rows`)."""
+    return lax.ragged_dot(rows, w, group_sizes,
+                          preferred_element_type=rows.dtype)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_out(x, order, inv, k):
+    """Row ``r`` of the result is token ``order[r] // k``: the (token,
+    choice) rows in sorted order.  ``order`` is a permutation of the
+    ``N*k`` choices and ``inv`` its inverse, so the transpose is a
+    gather too (by ``inv``, then a sum over each token's k choices)
+    where AD's own would be a scatter-add of ``N*k`` rows."""
+    return x[order // k]
+
+
+def _rows_out_fwd(x, order, inv, k):
+    return x[order // k], inv
+
+
+def _rows_out_bwd(k, inv, g):
+    return g[inv].reshape(-1, k, g.shape[-1]).sum(axis=1), None, None
+
+
+_rows_out.defvjp(_rows_out_fwd, _rows_out_bwd)
+
+
+@jax.custom_vjp
+def _rows_back(ys, order, inv):
+    """The inverse move: sorted rows back to (token, choice) order."""
+    return ys[inv]
+
+
+def _rows_back_fwd(ys, order, inv):
+    return ys[inv], order
+
+
+def _rows_back_bwd(order, g):
+    return g[order], None, None
+
+
+_rows_back.defvjp(_rows_back_fwd, _rows_back_bwd)
+
+
+def _sort_by_group(key, n_groups: int):
+    """``(order, inv, sizes)``: the stable sort of ``key`` (values in
+    ``[0, n_groups]``; ``n_groups`` marks a row of no group, sorted
+    last), its inverse, and each group's row count."""
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    inv = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=jnp.int32))
+    sizes = jnp.zeros((n_groups + 1,), jnp.int32).at[key].add(1)
+    return order, inv, sizes[:n_groups]
+
+
+@jax.custom_vjp
+def _no_cotangent_past(rows, n):
+    """``rows``, with the cotangent of the rows from ``n`` on zeroed."""
+    return rows
+
+
+def _no_cotangent_past_bwd(n, g):
+    return jnp.where(
+        jnp.arange(g.shape[0], dtype=jnp.int32)[:, None] < n, g, 0), None
+
+
+_no_cotangent_past.defvjp(lambda rows, n: (rows, n), _no_cotangent_past_bwd)
+
+
+def _experts_of_rows(expert_fn, expert_params, rows, sizes):
+    """``expert_fn`` over the rows of the groups.  The grouped kernels
+    leave the rows past the last group undefined, forward and backward
+    alike.  Forward, those rows of the result are undefined too: they
+    belong to choices not held here, which the combine leaves out.
+    Backward, their cotangent is dropped here, before it reaches the
+    tokens."""
+    return expert_fn(
+        expert_params, _no_cotangent_past(rows, jnp.sum(sizes)), sizes)
+
+
+def _exchange(rows, sizes, expert_fn, expert_params, axis_name, S, cap):
+    """The grouped products of the rows each member sorted for the
+    whole group's experts, run where the experts live: one all-to-all
+    out (a member's rows for peer ``s`` are consecutive, since experts
+    are numbered peer by peer), the counts with them, a local re-sort
+    by expert, and the inverse all-to-all home.  Every peer's slot
+    holds ``cap`` rows, the most one member can route to one peer, so
+    nothing is dropped."""
+    R, D = rows.shape
+    e_local = sizes.shape[0] // S
+    peer_sizes = sizes.reshape(S, e_local)
+    peer_rows = peer_sizes.sum(axis=1)
+    peer_start = jnp.cumsum(peer_rows) - peer_rows
+    j = jnp.arange(cap, dtype=jnp.int32)
+    # a slot past the peer's rows repeats some other row: no expert
+    # reads it there and no cotangent comes back for it
+    send = rows[jnp.clip(peer_start[:, None] + j[None, :], 0, R - 1)]
+    recv = lax.all_to_all(send, axis_name, 0, 0, tiled=True)
+    recv_sizes = lax.all_to_all(peer_sizes, axis_name, 0, 0, tiled=True)
+    # slot j of peer p holds a row of my expert e when j falls in p's
+    # e-th run; past p's rows it holds nothing
+    ends = jnp.cumsum(recv_sizes, axis=1)               # (S, e_local)
+    key = jnp.sum(j[None, :, None] >= ends[:, None, :], axis=-1)
+    order, inv, mine = _sort_by_group(key.reshape(-1), e_local)
+    ys = _experts_of_rows(
+        expert_fn, expert_params, recv.reshape(S * cap, D)[order], mine)
+    home = lax.all_to_all(ys[inv].reshape(S, cap, D), axis_name, 0, 0,
+                          tiled=True)
+    # sorted row r went to peer s_r as its slot r - peer_start[s_r]
+    r = jnp.arange(R, dtype=jnp.int32)
+    s_r = jnp.sum(r[:, None] >= (peer_start + peer_rows)[None, :], axis=-1)
+    s_r = jnp.minimum(s_r, S - 1)
+    slot = jnp.clip(r - peer_start[s_r], 0, cap - 1)
+    return home.reshape(S * cap, D)[s_r * cap + slot]
+
+
+def expert_parallel_moe_dropless(
+    x,
+    router_w,
+    expert_params,
+    expert_fn: Callable,
+    *,
+    top_k: int,
+    first_expert: int = 0,
+    axis_name: str = "expert",
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Top-k mixture of experts without capacity: every (token, choice)
+    whose expert is held by this ``axis_name`` group is computed.
+    Call INSIDE ``shard_map``.
+
+    The router has ``E = router_w.shape[-1]`` outputs and a token's k
+    gates are normalised over its k choices among all ``E``.  The group
+    holds the ``G`` consecutive experts from ``first_expert`` (``G`` the
+    leading axis of ``expert_params`` times the axis size); with
+    ``G < E`` the result is the held experts' part of the layer's
+    output, and what the absent experts would have added is left out.
+    Shapes are static (the sorted buffer has all ``N*k`` rows, the most
+    that can be routed here); the grouped products' work follows the
+    rows really routed here.  With an axis of size ``S > 1`` member
+    ``r`` holds experts ``first_expert + [r*G/S, (r+1)*G/S)`` and rows
+    travel by all-to-all.
+
+    Args:
+      x: ``(N, D)`` local tokens.
+      router_w: ``(D, E)`` router weights, replicated.
+      expert_params: pytree with leading local-expert axis ``G/S``.
+      expert_fn: ``expert_fn(params, rows, group_sizes) -> rows``: the
+        experts' network over rows sorted by local expert
+        (:func:`grouped_dense` products).
+      top_k: experts per token (static; 1 <= k <= E).
+
+    Returns ``(out, aux, chosen)``: ``out`` ``(N, D)``; ``aux`` the
+    global balancing loss ``E * sum_e f_e * P_e`` over all ``E`` columns
+    (f from the first choice); ``chosen`` ``(N, k)`` int32, the experts
+    each local token chose, held here or not.
+    """
+    S = lax.axis_size(axis_name)
+    N, D = x.shape
+    E = router_w.shape[-1]
+    G = jax.tree.leaves(expert_params)[0].shape[0] * S
+    if not 1 <= top_k <= E:
+        raise ValueError(f"top_k={top_k} must be in [1, E={E}]")
+    if not 0 <= first_expert <= E - G:
+        raise ValueError(
+            f"experts [{first_expert}, {first_expert + G}) held, of {E}")
+
+    with jax.named_scope("moe/route"):
+        probs, top_i, gates = route_top_k(x, router_w, top_k)
+        choice = top_i.reshape(-1) - first_expert       # (N*k,)
+        held = (choice >= 0) & (choice < G)
+        order, inv, sizes = _sort_by_group(jnp.where(held, choice, G), G)
+        rows = _rows_out(x, order, inv, top_k)          # (N*k, D)
+
+    with jax.named_scope("moe/experts"):
+        if S == 1:
+            ys = _experts_of_rows(expert_fn, expert_params, rows, sizes)
+        else:
+            ys = _exchange(rows, sizes, expert_fn, expert_params,
+                           axis_name, S, N * min(top_k, G // S))
+
+    with jax.named_scope("moe/combine"):
+        ys = _rows_back(ys, order, inv).reshape(N, top_k, D)
+        held = held.reshape(N, top_k, 1)
+        # a where, not a product by a zero gate: the rows of choices
+        # not held here are whatever the grouped kernels left there
+        out = jnp.sum(jnp.where(held, ys, 0) * gates[..., None].astype(
+            ys.dtype), axis=1)
+
+    frac_tokens = jax.nn.one_hot(top_i[:, 0], E, dtype=jnp.float32).mean(0)
+    frac_probs = probs.mean(axis=0)
+    if S > 1:
+        frac_tokens = lax.pmean(frac_tokens, axis_name)
+        frac_probs = lax.pmean(frac_probs, axis_name)
+    aux = E * jnp.sum(frac_tokens * frac_probs)
+    return out, aux, top_i

@@ -230,6 +230,13 @@ class TransformerAdapter:
     def __init__(self, mesh_cfg, cfg, *, quantized: bool = False):
         from chainermn_tpu.models.decoding import _decode_preamble
 
+        if cfg.training_only:
+            raise ValueError(
+                "the serving engine does not implement "
+                f"{', '.join(cfg.training_only)}: these fields exist on "
+                "the training path only (make_train_step); serving needs "
+                "a cache per attention kind and a sparse decode step "
+                "first")
         if cfg.moe:
             raise ValueError(
                 "MoE decode under continuous batching is not supported: "

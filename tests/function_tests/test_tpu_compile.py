@@ -117,18 +117,13 @@ def test_ring_pair_compiles_on_four_chips(topo, layout):
     assert "collective-permute" in text
 
 
-def _smoke_step(devices, mesh_axes, **cfg_kw):
-    """chip_smoke's own 300M train step compiled for described devices
+def _compile_step(mc, cfg, opt, batch, seq):
+    """``make_train_step`` compiled for the described devices of ``mc``
     (shapes only: there is no device to hold an array)."""
-    import chip_smoke
     from chainermn_tpu.models import (
         init_transformer, make_train_step, param_specs,
     )
-    from chainermn_tpu.parallel import MeshConfig
 
-    cfg = chip_smoke.transformer_config(tiny=False, **cfg_kw)
-    mc = MeshConfig(devices=devices, **mesh_axes)
-    opt = chip_smoke.transformer_optimizer()
     shapes = jax.eval_shape(
         lambda: init_transformer(jax.random.PRNGKey(0), cfg))
     params = jax.tree.map(
@@ -147,10 +142,22 @@ def _smoke_step(devices, mesh_axes, **cfg_kw):
             sharding=by_shape.get(a.shape, mc.replicated())),
         opt_shapes)
     tok = jax.ShapeDtypeStruct(
-        (chip_smoke.LM_BATCH, chip_smoke.LM_SEQ), jnp.int32,
+        (batch, seq), jnp.int32,
         sharding=mc.sharding(("data", "expert"), "seq"))
     step = make_train_step(mc, cfg, opt)
     return step.lower(params, opt_state, tok, tok).compile()
+
+
+def _smoke_step(devices, mesh_axes, **cfg_kw):
+    """chip_smoke's own 300M train step compiled for described devices."""
+    import chip_smoke
+    from chainermn_tpu.parallel import MeshConfig
+
+    return _compile_step(
+        MeshConfig(devices=devices, **mesh_axes),
+        chip_smoke.transformer_config(tiny=False, **cfg_kw),
+        chip_smoke.transformer_optimizer(),
+        chip_smoke.LM_BATCH, chip_smoke.LM_SEQ)
 
 
 def _device_bytes(compiled):
@@ -183,3 +190,49 @@ def test_300m_train_step_four_chips(topo, axes, cfg_kw):
     assert ("all-gather" in text) if "fsdp" in cfg_kw \
         else ("collective-permute" in text)
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_mellum_cell_step_fits_and_holds_no_capacity_tensor(topo):
+    """The benchmark's Mellum cell at its real size (2 x 8,192 tokens,
+    four typed layers, 16 of 64 experts held), through the cell's own
+    files and its driver's mapping: the flash kernels of both kinds and
+    the grouped expert kernels are in the program, no tensor has the
+    capacity dispatch's ``(N, E, cap)`` shape, and the compiled step
+    needs between 10 and 14.5 GiB of the chip's 16."""
+    import re
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.lib import cells, scopes
+    from benchmarks.lib.harness import build_optimizer, program_bytes
+    from chainermn_tpu.parallel import MeshConfig
+
+    cell, cfg, job = cells.load_cell("mellum2-12b-l4-ep4-train-2x8192")
+    pcfg = cells.module("drivers", job["driver"])._program_config(cfg, job)
+    compiled = _compile_step(
+        MeshConfig(devices=topo.devices[:cell["chips"]], **job["mesh"]),
+        pcfg, build_optimizer(cfg["optimizer"]), job["batch"], job["seq"])
+    text = compiled.as_text()
+
+    by_scope = {}
+    for name, scope in scopes.instruction_scopes(text).items():
+        by_scope.setdefault(scope, []).append(name)
+    assert any("pallas_call" in line for line in text.splitlines()
+               if "attn/sliding" in line and "tpu_custom_call" in line)
+    assert any("pallas_call" in line for line in text.splitlines()
+               if "attn/full" in line and "tpu_custom_call" in line)
+    # three grouped products forward, three recomputed, six backward,
+    # in each of four layers
+    grouped = [n for n in by_scope["moe/experts"]
+               if n.startswith("ragged-dot-none")]
+    assert len(grouped) == 4 * 12
+    tokens, experts = job["batch"] * job["seq"], cfg["router_experts"]
+    for dims in set(re.findall(r"\[([\d,]+)\]", text)):
+        dims = [int(d) for d in dims.split(",")]
+        assert not (len(dims) == 3 and dims[0] == tokens
+                    and dims[1] == experts), dims
+    gib = program_bytes(compiled) / 2 ** 30
+    assert 10 <= gib <= 14.5, f"{gib:.2f} GiB"
