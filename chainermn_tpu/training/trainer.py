@@ -6,6 +6,7 @@ an observation dict per iteration, and rank-0-aware reporting.
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 import time
@@ -13,6 +14,7 @@ from typing import Optional
 
 import numpy as np
 
+from chainermn_tpu.utils.metrics import get_registry
 from chainermn_tpu.utils.telemetry import get_recorder
 
 from .triggers import get_trigger
@@ -102,11 +104,17 @@ class Trainer:
                 self.updater.update()
                 self.observation = dict(self.updater.observation)
                 self.elapsed_time = time.perf_counter() - self._start
-                # where the host waits for the device in a serial loop:
-                # LogReport.observe reads float(loss) of the step just
-                # dispatched
+                # main/loss is the loss of the step just dispatched and
+                # is still being computed: a hook that reads it
+                # (float()) waits out the step and puts feed, copy and
+                # step back in series.  LogReport.observe does not: it
+                # keeps the value until the device has finished it.
+                # FailOnNonNumber and ObservationAggregator do, by
+                # contract.  ``pending`` counts the device values the
+                # hooks left unread.
                 with tracer.span("trainer/observe", cat="trainer",
-                                 step=step):
+                                 step=step) as observe_span:
+                    pending = 0
                     for e in self._extensions:
                         # extensions with an ``observe`` hook see EVERY
                         # iteration's observation (LogReport interval
@@ -115,6 +123,8 @@ class Trainer:
                         obs_hook = getattr(e.ext, "observe", None)
                         if obs_hook:
                             obs_hook(self)
+                            pending += getattr(e.ext, "pending", 0)
+                    observe_span.set(pending=pending)
                 for e in self._extensions:
                     if e.trigger(self):
                         with tracer.span("trainer/extension",
@@ -137,10 +147,28 @@ class Trainer:
                 up_fin()
 
 
+def _ready(value) -> bool:
+    """Whether ``float(value)`` returns without waiting for a device:
+    a value that does not answer ``is_ready()`` (a Python or numpy
+    number, a string) lives on the host already."""
+    return not hasattr(value, "is_ready") or value.is_ready()
+
+
 class LogReport:
     """Collects observations into ``out/log`` (JSON list), averaging scalar
     entries over the report interval — rank-0 printing stays the user's
-    choice exactly as in the reference examples."""
+    choice exactly as in the reference examples.
+
+    ``observe`` never waits for the device.  An observation that holds
+    a device value still being computed (``jax.Array.is_ready()`` is
+    false: the loss of the step just dispatched) is kept whole, and
+    summed once the device has finished it: observations are summed in
+    arrival order, each with the same additions as an eager ``float()``
+    an iteration, so the sums, the order of their keys and the log are
+    bit for bit what an eager read gives.  In steady state the one or
+    two newest observations are pending, however long the interval.
+    Whatever needs the sums (the trigger's ``__call__``,
+    ``state_dict``) reads everything pending first, blocking once."""
 
     def __init__(self, trigger=(1, "epoch"), filename: str = "log"):
         self.trigger = trigger
@@ -148,19 +176,42 @@ class LogReport:
         self._filename = filename
         self._accum = {}
         self._count = 0
+        # observations not yet summed, oldest first: [(key, value), ...]
+        self._pending = collections.deque()
         self.log = []
+
+    @property
+    def pending(self) -> int:
+        """Device values observed and not yet read."""
+        return sum(hasattr(v, "is_ready")
+                   for items in self._pending for _, v in items)
 
     def observe(self, trainer):
         """Called by the trainer every iteration (interval accumulation)."""
-        for k, v in trainer.observation.items():
-            try:
-                f = float(v)
-            except (TypeError, ValueError):
-                continue
-            self._accum[k] = self._accum.get(k, 0.0) + f
-        self._count += 1
+        items = list(trainer.observation.items())
+        self._pending.append(items)
+        self._sum_pending(wait=False)
+        # oldest first: anything still pending means this one is
+        get_registry().inc("trainer/observe_deferred" if self._pending
+                           else "trainer/observe_read", len(items))
+
+    def _sum_pending(self, wait: bool) -> None:
+        """Sum the pending observations, oldest first: all of them if
+        ``wait``, else up to the first that holds a value the device
+        has not finished."""
+        while self._pending:
+            if not wait and not all(_ready(v) for _, v in self._pending[0]):
+                break
+            for k, v in self._pending.popleft():
+                try:
+                    f = float(v)
+                except (TypeError, ValueError):
+                    continue
+                self._accum[k] = self._accum.get(k, 0.0) + f
+            self._count += 1
 
     def state_dict(self) -> dict:
+        self._sum_pending(wait=True)
         return {"log": list(self.log), "accum": dict(self._accum),
                 "count": self._count}
 
@@ -168,8 +219,11 @@ class LogReport:
         self.log = [dict(e) for e in st["log"]]
         self._accum = {k: float(v) for k, v in st["accum"].items()}
         self._count = int(st["count"])
+        # observed on the timeline this state replaces
+        self._pending.clear()
 
     def __call__(self, trainer):
+        self._sum_pending(wait=True)
         # average of every observation since the last fire
         entry = {k: v / max(self._count, 1) for k, v in self._accum.items()}
         # plus values produced at trigger time by earlier-priority

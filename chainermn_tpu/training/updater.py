@@ -883,12 +883,16 @@ class StandardUpdater:
             self.iterator, "epoch_detail", self.iteration)
         if self.max_inflight > 1 and self._last_retired is not None:
             # pipelined: report the RETIRED window's loss (already
-            # materialised) so a float()-per-iteration consumer —
-            # LogReport.observe, PrintReport — never stalls the
-            # pipeline on the in-flight window.  Lags by max_inflight
-            # updates; the serial path keeps the current (async) loss.
+            # materialised), so a consumer that calls float() on it
+            # every iteration (FailOnNonNumber, ObservationAggregator,
+            # a user's own hook) never stalls the pipeline on the
+            # in-flight window.  Lags by max_inflight updates.
             obs_loss = self._last_retired
         else:
+            # one window in flight: the loss of the window just
+            # dispatched, still being computed.  Reading it waits out
+            # the step; LogReport.observe keeps it until it is ready
+            # (is_ready()) and so may look every iteration for nothing.
             obs_loss = window_loss
         self.observation = {
             "main/loss": obs_loss,
