@@ -1,8 +1,7 @@
 """Shared plumbing for the root-level benchmark scripts.
 
-All bench scripts (``bench.py`` ResNet-50, ``bench_transformer.py``,
-``bench_attention.py``, ``bench_decode.py``, ``bench_seq2seq.py``, ...)
-share three pieces:
+The bench scripts that are left (ROADMAP D1 names the cell whose PR
+deletes each; this file goes with the last of them) share three pieces:
 
 - the per-chip peak bf16 FLOP/s table (MFU denominator); a device that
   is not in the table is an error, not a default,
@@ -102,8 +101,8 @@ def record_measurement(result: dict) -> None:
 
 
 def run_check(record: dict, match=None, direction="higher"):
-    """The perf-regression sentinel hook (``bench.py --check`` — any
-    bench script can pass ``check=True`` through
+    """The perf-regression sentinel hook (``bench_programs.py --check`` —
+    any bench script can pass ``check=True`` through
     ``run_child_with_retries``): score ``record`` against the run
     history's PRIOR runs of the same metric and workload
     (``utils/regression.py`` noise-aware bounds) and return the

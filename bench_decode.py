@@ -6,7 +6,8 @@ exists (the reference's generation path was a greedy LSTM loop), so
 ``vs_baseline`` is per-SEQUENCE tokens/sec divided by 500 — an
 order-of-magnitude, batch-independent yardstick for a ~300M-param bf16
 decoder on one chip, not an upstream measurement (``value`` stays the
-batch-aggregate rate).  Same hermetic child-process pattern as bench.py.
+batch-aggregate rate).  One child under a timeout, the parent off JAX
+(``_bench_common``).
 """
 
 import argparse
@@ -303,20 +304,21 @@ def _kv_heads(d_model: int) -> int:
 
 
 def analyze(batch=4, max_len=512, d_model=1024, n_layers=8, n_heads=16,
-            n_kv_heads=4, int8=False, kv_int8=False, device_kind="v5e"):
+            n_kv_heads=4, int8=False, kv_int8=False,
+            device_kind="TPU v5e"):
     """First-principles decode roofline (no hardware needed): each
     generated step reads the full weights once (amortized over the
     batch) plus every row's ALLOCATED cache (static shapes — the
     per-token step scores max_len slots under a mask), so the HBM
     floor is (weight_bytes + cache_bytes_per_step) / bandwidth.  The
     number the measured tokens/sec row is judged against when the
-    chip answers — the decode twin of bench_breakdown --analyze-only.
+    chip answers.
     """
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from bench_breakdown import _hbm_gbps
+    from benchmarks.lib.peaks import peaks
     from chainermn_tpu.models import TransformerConfig, init_transformer
 
     cfg = TransformerConfig(
@@ -343,7 +345,7 @@ def analyze(batch=4, max_len=512, d_model=1024, n_layers=8, n_heads=16,
                      + (n_layers * max_len * kvh * 2 * 4
                         if kv_int8 else 0))   # fp32 scales
     step_bytes = wbytes + batch * cache_per_row
-    bw = _hbm_gbps(device_kind) * 1e9
+    bw = peaks(device_kind)["hbm_bytes_per_s"]
     floor_tok_s = batch / (step_bytes / bw)
     return {
         "metric": FLOOR_METRIC,

@@ -19,7 +19,8 @@ down ×4 for the small point) so the cost's scaling with state size is
 recorded, not assumed.  Prints ONE JSON line {"metric", "value",
 "unit", "vs_baseline", ...}: value = relayout resume time ÷ exact
 resume time at the LARGE size ("x"; ~1 = re-layout is as cheap as the
-exact path).  Same hermetic child-process pattern as bench.py.
+exact path).  One child under a timeout, the parent off JAX
+(``_bench_common``).
 """
 
 import argparse

@@ -38,8 +38,8 @@ queue migration must all reuse the warmed programs) and reported as
 
 Prints ONE JSON line {"metric", "value", "unit", "vs_baseline", ...}:
 value = prefix/oblivious goodput-under-SLO ratio (unit "x", >1 means
-the prefix signal wins).  Same hermetic child-process pattern as
-bench.py.
+the prefix signal wins).  One child under a timeout, the parent off JAX
+(``_bench_common``).
 """
 
 import argparse

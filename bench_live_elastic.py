@@ -23,7 +23,7 @@ assumed on the 8-device virtual pod:
   restart would pay it too).
 
 Prints ONE JSON line {"metric", "value", "unit", "vs_baseline", ...}.
-Same hermetic child-process pattern as bench.py.
+One child under a timeout, the parent off JAX (``_bench_common``).
 """
 
 import argparse
@@ -124,7 +124,7 @@ def _measure_async_hit(comm, dim, hidden, classes, batch, n_examples,
       stream).  This is the half a CPU mesh can measure honestly.
     - ``loop_*`` — whole-loop step time.  XLA:CPU computes on the same
       cores the writer thread streams on, so the overlap win is NOT
-      expected to show here (the bench_overlap situation: the
+      expected to show here (the
       wire/IO-hiding half needs hardware whose compute does not share
       the writer's cores); the figure is recorded so the CPU-mesh
       overhead is known, not hidden.

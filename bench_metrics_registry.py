@@ -20,7 +20,8 @@ registry is free).  ``overhead_pct`` = (value − 1) × 100 and
 ``within_bar`` reports the <1% acceptance bar the docs promise
 (docs/OBSERVABILITY.md "Metrics").  Arms are interleaved
 order-alternating best-of-rounds so a noisy host cannot fake an
-overhead.  Same hermetic child-process pattern as bench_telemetry.py.
+overhead.  One child under a timeout, the parent off JAX
+(``_bench_common``).
 """
 
 import argparse

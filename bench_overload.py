@@ -29,8 +29,8 @@ change WHO is served, never WHAT.
 
 Prints ONE JSON line {"metric", "value", "unit", "vs_baseline", ...}:
 value = shed/fcfs goodput-under-SLO ratio (unit "x", >1 means the
-admission layer wins).  Same hermetic child-process pattern as
-bench.py.
+admission layer wins).  One child under a timeout, the parent off JAX
+(``_bench_common``).
 """
 
 import argparse

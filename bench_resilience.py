@@ -24,8 +24,8 @@ DIRECTLY and amortised into a measured step time:
 
 Prints ONE JSON line {"metric", "value", "unit", "vs_baseline", ...}:
 value = combined watchdog+checksum overhead as percent of step time
-(unit "%"; the acceptance bar is <2).  Same hermetic child-process
-timeout/retry pattern as bench.py.
+(unit "%"; the acceptance bar is <2).  One child under a timeout, the
+parent off JAX (``_bench_common``).
 """
 
 import argparse

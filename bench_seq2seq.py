@@ -9,8 +9,8 @@ target tokens through the masked LSTM encoder-decoder train step.
 
 No upstream number exists for this config (the reference published only
 ResNet figures), so ``vs_baseline`` uses a 100k-tokens/sec yardstick —
-order-of-magnitude for a 2×256-unit LSTM NMT step on one chip.  Same
-hermetic child-process pattern as bench.py.
+order-of-magnitude for a 2×256-unit LSTM NMT step on one chip.  One
+child under a timeout, the parent off JAX (``_bench_common``).
 """
 
 import argparse

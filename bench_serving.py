@@ -27,7 +27,7 @@ The model is the serving engine's MiniLM reference backend (the
 engine machinery under test is identical to the flagship's).  Prints ONE JSON line {"metric",
 "value", "unit", "vs_baseline", ...}: value = continuous/static
 tokens-per-sec ratio (unit "x", >1 means continuous batching wins).
-Same hermetic child-process pattern as bench.py.
+One child under a timeout, the parent off JAX (``_bench_common``).
 
 **Decode-tier arms** (ISSUE 14; ``--decode-tier 0`` skips them) ride
 the same record:
@@ -537,8 +537,7 @@ def run(args):
     engine.warm()
 
     # interleaved rounds, best round per arm: the 2-core container's
-    # scheduler noise swamps a single ~0.3 s replay (same reasoning as
-    # bench_fused_allreduce's min-of-rounds)
+    # scheduler noise swamps a single ~0.3 s replay
     arms = {}
     per_arm_tokens = {}
     order = (("continuous", False), ("static", True))

@@ -146,8 +146,8 @@ class TransformerConfig:
     attention: str = "ring"    # "ring" | "ulysses" | "local" | "flash"
     flash_bwd_block_q: int = 0  # 0 = kernel default; >0 retunes the
     # flash BACKWARD kernels' tiling independently of the forward
-    # (gradients are tiling-exact; bench_attention.py --sweep picks
-    # the winning pair on hardware, this knob adopts it per-model)
+    # (gradients are tiling-exact; flash.ms_per_step in the OPT cells
+    # of benchmarks/ reads a pair on the chip, this knob adopts it)
     flash_bwd_block_k: int = 0
     attention_window: int = 0  # 0 => full causal; W>0 => sliding causal
     # window (token t attends to (t-W, t]): Mistral-style local
@@ -225,8 +225,8 @@ class TransformerConfig:
     # in backward (one psum for the accumulated embed grad).  Must
     # divide the per-shard sequence length.  Composes with
     # vocab_parallel (live logits (B, chunk, V/M) — both savings
-    # multiply; see _vp_head_nll).  Trade measured by
-    # bench_breakdown.py's lm_head_loss vs lm_head_loss_chunked rows.
+    # multiply; see _vp_head_nll).  No cell sets it yet: the trade is
+    # step.mfu_pct.lm against device.hbm_gib.lm in the OPT cells.
     kv_cache_dtype: str = ""  # decode-time KV cache storage: "" =>
     # compute dtype; "int8" => values int8 with a per-(token, head)
     # absmax scale — halves cache HBM traffic and doubles the context
@@ -743,7 +743,7 @@ def _head_nll(cd, chunk, h, embed, targets):
     chunks of ``chunk`` so the full ``(B, T, V)`` fp32 logits are never
     resident — live logits memory is ``(B, chunk, V)``.
 
-    The classic chunked-vocab cross-entropy (SPEED.md candidate #1):
+    The classic chunked-vocab cross-entropy:
     forward keeps only the per-chunk NLL partial sums; backward
     recomputes each chunk's logits, forms ``(softmax - onehot)·g``
     in-registers (XLA fuses the one-hot iota-compare into the subtract),

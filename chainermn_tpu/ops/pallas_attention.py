@@ -348,8 +348,8 @@ def _flash_bwd(scale, causal, window, fwd_block_q, fwd_block_k,
                block_q, block_k, interpret, res, cts):
     # the backward kernels tile on their OWN block sizes: dq's q-outer
     # grid and dkv's k-outer revisit pattern have different optimal
-    # shapes than the forward (the retune lever bench_attention.py
-    # --sweep measures); the fwd blocks arrive first in the nondiff
+    # shapes than the forward (a retune is read in the OPT cells'
+    # flash.ms_per_step); the fwd blocks arrive first in the nondiff
     # tuple and are unused here
     q3, k3, v3, offs, o, lse = res
     do, dlse = cts
@@ -474,7 +474,7 @@ def flash_attention(q, k, v, *, causal: bool = False, window=None,
     independently of the forward (default: the forward blocks) — the
     dq kernel's q-outer grid and the dkv kernel's k-outer revisit
     pattern peak at different shapes, and gradients are exact for any
-    valid tiling (``bench_attention.py --sweep`` measures the retune).
+    valid tiling (the OPT cells' ``flash_roofline`` reads a retune).
     """
     B, Tq, H, D = q.shape
     Tk = k.shape[1]

@@ -6,7 +6,7 @@ container has ONE real chip, so the equivalent evidence chain here is
 analytic: walk a compiled step's HLO for collective ops, count the bytes
 each moves, convert to wire time with the standard ring formulas and the
 interconnect's published bandwidth, and compare against the measured
-single-chip step time.  ``SCALING.md`` assembles the result.
+single-chip step time.  The four-chip cell reads ``exchange.exposed_ms``.
 
 Axis attribution: a composed-mesh HLO doesn't name mesh axes, so
 :func:`axis_collective_report` compiles the SAME step on single-active-
@@ -364,8 +364,8 @@ def collective_stats(compiled) -> Dict[str, CollectiveStats]:
     with at least one other instruction between the halves bumps its
     kind's ``.async_depth`` — the count of collectives the backend
     actually overlaps with other work, as opposed to merely emitting
-    (:func:`assert_overlap_collectives` and ``bench_overlap.py`` read
-    this alongside the schedule-position evidence).
+    (:func:`assert_overlap_collectives` reads this alongside the
+    schedule-position evidence).
     """
     out: Dict[str, CollectiveStats] = {}
     for text in _hlo_texts(compiled):

@@ -27,7 +27,7 @@ cost metrics.  Fewer than ``min_history`` matching runs is
 ``"no_history"`` — evidence, not a verdict (green for gating: a new
 bench's first run cannot fail against nothing).
 
-``bench.py --check`` (and any script passing ``check=True`` through
+``bench_programs.py --check`` (any script passing ``check=True`` through
 ``_bench_common.run_child_with_retries``) self-verifies: the fresh
 record is scored against history BEFORE it is appended (a run must
 not anchor its own bound), the verdict rides the printed JSON line

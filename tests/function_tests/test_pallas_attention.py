@@ -56,8 +56,8 @@ def test_grads_match_oracle(causal):
 def test_bwd_block_retune_grads_exact(bwd_q, bwd_k):
     """Backward kernels tiled independently of the forward must give
     the same gradients for ANY valid tiling — the correctness side of
-    the bwd block retune lever (bench_attention.py --sweep measures
-    the perf side)."""
+    the bwd block retune lever (the OPT cells' flash.ms_per_step
+    reads the perf side)."""
     q, k, v = qkv(3)
 
     def loss(bq, bk):

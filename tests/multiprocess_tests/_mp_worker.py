@@ -1496,6 +1496,9 @@ def scenario_resize_live(comm):
     # only the LAST rank posts the intent — every rank must still see
     # it (external tooling posts from wherever it runs)
     assert ctrl._kv_intent(comm) is None
+    # every rank has looked before the poster posts: without this
+    # barrier a slow rank reads the fast poster's intent as "stale"
+    _kv_barrier(comm, boot)
     if me == n - 1:
         post_resize_intent(n, reason="mp drill")
     _kv_barrier(comm, boot)

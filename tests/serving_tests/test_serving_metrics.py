@@ -116,14 +116,17 @@ class TestSLOReport:
 
     def test_multi_arm_render_and_json(self, engine, tmp_path):
         slo = SLOReport(percentiles=(50, 99))
+        records = {}
         _run_trace(engine, np.random.RandomState(5), n=6)
-        slo.add_arm("continuous", engine.request_records())
+        records["continuous"] = engine.request_records()
+        slo.add_arm("continuous", records["continuous"])
         engine.gang = True
         try:
             _run_trace(engine, np.random.RandomState(5), n=6)
         finally:
             engine.gang = False
-        slo.add_arm("static", engine.request_records())
+        records["static"] = engine.request_records()
+        slo.add_arm("static", records["static"])
         assert slo.arms == ("continuous", "static")
         table = slo.render()
         for token in ("continuous", "static", "ttft", "p99_ms"):
@@ -133,14 +136,15 @@ class TestSLOReport:
         path = slo.write_json(str(tmp_path / "slo.json"))
         doc = json.load(open(path))
         assert set(doc["arms"]) == {"continuous", "static"}
-        assert doc["arms"]["static"]["ttft"]["count"] == 6
-        # gang mode queues harder: its typical queue wait is no
-        # better.  MEDIANS, not means — one loaded-host scheduling
-        # burst against a single continuous-arm request skews a
-        # 6-sample mean past any margin (observed in CI)
-        cont = slo.summary()["continuous"]["queue_wait"]["p50"]
-        stat = slo.summary()["static"]["queue_wait"]["p50"]
-        assert stat >= cont * 0.5   # sanity, not a perf claim
+        # each arm reports ITS OWN six records and nothing of the
+        # other's.  (Which arm waited longer is the host's clock on six
+        # requests that all fit the eight slots at once: not asserted.)
+        for arm, recs in records.items():
+            for field in ("queue_wait", "ttft"):
+                got = doc["arms"][arm][field]
+                assert got["count"] == 6
+                assert got["p50"] == pytest.approx(float(np.percentile(
+                    [getattr(r, field) for r in recs], 50)), rel=1e-9)
 
     def test_dict_records_accepted(self):
         slo = SLOReport(percentiles=(50,))

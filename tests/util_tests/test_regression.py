@@ -1,5 +1,5 @@
 """Perf regression sentinel (ISSUE 15): noise-aware verdict math over
-bench history, workload matching, and the ``bench.py --check`` wiring
+bench history, workload matching, and the ``--check`` wiring
 through ``_bench_common.run_child_with_retries`` — fresh records are
 scored BEFORE they join the history, verdicts ride the one JSON line,
 and the exit code goes red only on a regression."""
@@ -263,12 +263,11 @@ class TestBenchCheckWiring:
 
 
 def test_bench_scripts_wire_the_check_flag():
-    """``bench.py --check`` (and bench_programs.py's) reach
+    """``bench_programs.py --check`` reaches
     ``run_child_with_retries(check=...)`` — the one-line wiring that
     makes any bench script self-verify.  Source-level pin (the check
     semantics are unit-tested above through the same
     run_child_with_retries entrypoint the scripts call)."""
-    for script in ("bench.py", "bench_programs.py"):
-        src = open(os.path.join(_ROOT, script)).read()
-        assert '"--check"' in src, script
-        assert "check=args.check" in src, script
+    src = open(os.path.join(_ROOT, "bench_programs.py")).read()
+    assert '"--check"' in src
+    assert "check=args.check" in src
