@@ -37,6 +37,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from chainermn_tpu.ops.pallas_attention import (
+    FLASH_RESIDUAL_NAMES,
     flash_attention,
     flash_attention_supported,
     interpret_kernels,
@@ -235,10 +236,13 @@ class TransformerConfig:
     # (quantize_params_int8); the two compose.  Training never reads
     # this field.
     remat: bool = True
-    remat_policy: str = "full"  # "full" | "dots": with "dots" the block
-    # checkpoint saves matmul outputs (jax dots_with_no_batch_dims_saveable)
-    # and recomputes only the cheap elementwise/norm ops — most of full
-    # remat's memory saving at a fraction of its ~33% recompute cost
+    remat_policy: str = "full"  # "full" | "dots": what the block's
+    # checkpoint keeps besides the block's input.  Both keep the flash
+    # kernel's o and lse where the block runs it (checkpoint_fn); "dots"
+    # also keeps the matmul outputs (jax dots_with_no_batch_dims_saveable)
+    # and the attention output, and recomputes only the cheap
+    # elementwise/norm ops — at 16.0 GB of temporaries for the 300M
+    # model at 8 x 2,048 (sandbox compile, PR 21) it fits no cell
     dtype: str = "bfloat16"    # compute dtype (params stay fp32)
 
     @property
@@ -267,21 +271,32 @@ class TransformerConfig:
     @property
     def checkpoint_fn(self):
         """The configured ``jax.checkpoint`` wrapper (identity when
-        ``remat=False``)."""
+        ``remat=False``).  Under either policy it keeps the flash
+        kernel's two residual outputs (``FLASH_RESIDUAL_NAMES``: ``o``
+        as the kernel wrote it and the ``(B·H, T)`` log-sum-exp), so the
+        backward pass launches dq and dkv and does not run the forward
+        kernel again: a custom call is invisible to the dots policy, and
+        plain ``jax.checkpoint`` rebuilds every residual.  A block
+        without the kernel has no such names and remats as before.
+
+        Not under ``attention="ring"``: a block's policy reaches into
+        the ``jax.checkpoint`` the ring puts around each pair, and would
+        keep every pair's output (three kernels fewer in the 300M step
+        on ``seq=4`` for 2.8 GiB more a device, sandbox compile, PR 29).
+        Memory that grows with the ring is the wrong default at the
+        lengths the ring is for; its pairs keep rematerialising."""
         if not self.remat:
             return lambda f: f
+        cp = jax.checkpoint_policies
+        kept = () if self.attention == "ring" else FLASH_RESIDUAL_NAMES
         if self.remat_policy == "dots":
-            # matmul outputs AND the attention-core output: the flash
-            # kernel is a custom call, invisible to the dots policy, so
-            # without the named save the whole fwd kernel re-runs in
-            # backward (~9% of the step at 2k context, measured)
-            cp = jax.checkpoint_policies
-            return partial(
-                jax.checkpoint,
-                policy=cp.save_from_both_policies(
-                    cp.dots_with_no_batch_dims_saveable,
-                    cp.save_only_these_names("attn_out")))
-        return jax.checkpoint
+            # "attn_out": see _attention
+            policy = cp.save_from_both_policies(
+                cp.dots_with_no_batch_dims_saveable,
+                cp.save_only_these_names(*kept, "attn_out"))
+        else:
+            policy = cp.save_only_these_names(*kept)
+        return partial(jax.checkpoint, policy=policy)
 
     def __post_init__(self):
         if self.attention_window < 0:
@@ -1203,9 +1218,13 @@ def _attention_of_kind(cfg: TransformerConfig, h, blk, kind):
             interpret=interpret_kernels())
     else:
         raise ValueError(cfg.attention)
-    # named for the "dots" remat policy: saving the attention-core
-    # output keeps the (expensive, custom-call) kernel out of backward
-    # recompute while the cheap elementwise neighbourhood still remats
+    # named for the "dots" remat policy, which saves it as the input of
+    # the output projection's backward.  It never kept the flash kernel
+    # out of the recompute (the kernel's residuals are its own o and
+    # lse: FLASH_RESIDUAL_NAMES, saved by checkpoint_fn); counted in the
+    # traced gradient, what it spares a layer is the p·v product under
+    # "local", the exchange back under "ulysses", and a transpose (for a
+    # second copy of o) under "flash" and "ring"
     o = checkpoint_name(o, "attn_out")
     o = row_parallel_dense(
         o.reshape(B, T, -1), blk["wo"].reshape(-1, D).astype(cd))

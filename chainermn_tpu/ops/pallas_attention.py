@@ -40,11 +40,17 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "flash_attention_supported",
-           "interpret_kernels", "tracing_for_mesh"]
+           "interpret_kernels", "tracing_for_mesh", "FLASH_RESIDUAL_NAMES"]
+
+# The names on the forward kernel's two residual outputs (see
+# ``_flash_fwd``): a ``jax.checkpoint`` whose policy saves them keeps
+# the kernel out of its backward recompute.
+FLASH_RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 _NEG = -1e30
 
@@ -341,6 +347,14 @@ def _flash_fwd(q3, k3, v3, offs, scale, causal, window, block_q, block_k,
                bwd_block_q, bwd_block_k, interpret):
     o, lse = _fwd(q3, k3, v3, offs, scale, causal, window, block_q,
                   block_k, interpret)
+    # named so that an enclosing jax.checkpoint can keep them: they are
+    # the only residuals the forward kernel produces, and a policy that
+    # saves both (TransformerConfig.checkpoint_fn) leaves the backward
+    # pass with dq and dkv alone.  The name sits on the (BH, Tq) slice
+    # of lse, not on the kernel's 128-lane copy.  Inert under any other
+    # policy, plain jax.checkpoint and no checkpoint at all.
+    o = checkpoint_name(o, FLASH_RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse, FLASH_RESIDUAL_NAMES[1])
     return (o, lse), (q3, k3, v3, offs, o, lse)
 
 

@@ -165,28 +165,42 @@ def _device_bytes(compiled):
     return m.argument_size_in_bytes + m.temp_size_in_bytes
 
 
+def _flash_kernels(text, scope=""):
+    """The flash kernels' call sites in a compiled program's text (a
+    scanned layer's count once), optionally those under one scope."""
+    return sum('custom_call_target="tpu_custom_call"' in line
+               and "pallas_call" in line and scope in line
+               for line in text.splitlines())
+
+
 def test_300m_train_step_fits_one_chip(topo):
     """The whole train-transformer phase of chip_smoke.py on one
     described chip: the kernel is in the program (not the interpreter,
-    not the XLA attention) and arguments + temporaries fit 16 GiB under
-    the remat policy the smoke uses."""
+    not the XLA attention), three times a layer (forward, dq, dkv: the
+    block's checkpoint keeps the forward's output, so the backward pass
+    does not run it again), and arguments + temporaries fit 16 GiB
+    under the remat policy the smoke uses."""
     compiled = _smoke_step(topo.devices[:1], dict(data=1))
-    assert "tpu_custom_call" in compiled.as_text()
+    assert _flash_kernels(compiled.as_text()) == 3
     need = _device_bytes(compiled)
     assert need < HBM_BYTES, f"{need / 2**30:.1f} GiB > 16 GiB"
 
 
-@pytest.mark.parametrize("axes,cfg_kw", [
-    (dict(data=4), dict(fsdp=True)),
-    (dict(data=1, seq=4), dict(attention="ring")),
+@pytest.mark.parametrize("axes,cfg_kw,kernels", [
+    (dict(data=4), dict(fsdp=True), 3),
+    # the ring's pairs keep rematerialising (TransformerConfig.
+    # checkpoint_fn): forward, its remat by the block and by the pair's
+    # own checkpoint, dq and dkv for the visiting pairs; forward, one
+    # remat, dq and dkv for the self pair
+    (dict(data=1, seq=4), dict(attention="ring"), 9),
 ], ids=["fsdp-data4", "ring-seq4"])
-def test_300m_train_step_four_chips(topo, axes, cfg_kw):
+def test_300m_train_step_four_chips(topo, axes, cfg_kw, kernels):
     """chip_smoke.py --chips 4's two transformer programs on the four
-    described devices: the kernel and the collectives are in, and each
-    device's share fits."""
+    described devices: the kernel, as often as counted, and the
+    collectives are in, and each device's share fits."""
     compiled = _smoke_step(topo.devices, axes, **cfg_kw)
     text = compiled.as_text()
-    assert "tpu_custom_call" in text
+    assert _flash_kernels(text) == kernels
     assert ("all-gather" in text) if "fsdp" in cfg_kw \
         else ("collective-permute" in text)
     assert _device_bytes(compiled) < HBM_BYTES
@@ -220,10 +234,9 @@ def test_mellum_cell_step_fits_and_holds_no_capacity_tensor(topo):
     by_scope = {}
     for name, scope in scopes.instruction_scopes(text).items():
         by_scope.setdefault(scope, []).append(name)
-    assert any("pallas_call" in line for line in text.splitlines()
-               if "attn/sliding" in line and "tpu_custom_call" in line)
-    assert any("pallas_call" in line for line in text.splitlines()
-               if "attn/full" in line and "tpu_custom_call" in line)
+    # forward, dq and dkv in each layer, and no second forward
+    assert _flash_kernels(text, "attn/sliding") == 3 * 3
+    assert _flash_kernels(text, "attn/full") == 3
     # three grouped products forward, three recomputed, six backward,
     # in each of four layers
     grouped = [n for n in by_scope["moe/experts"]

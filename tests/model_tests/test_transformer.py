@@ -2,6 +2,9 @@
 single-device oracle (the multi-axis run must be numerically identical —
 SPMD sharding is an implementation detail, not a semantics change)."""
 
+import collections
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -73,31 +76,68 @@ def test_forward_matches_oracle(axes):
         np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4)
 
 
-def test_remat_policy_grad_equivalence():
-    """remat_policy='dots' must be a pure scheduling choice: grads equal
-    the remat='full' and remat=False paths bit-for-bit (fp32)."""
+def _census(jaxpr, found=None):
+    """``{primitive name: equations}`` over ``jaxpr`` and every jaxpr
+    nested in it (a scan's body counts once, as it is traced once)."""
+    found = collections.Counter() if found is None else found
+    for eqn in jaxpr.eqns:
+        found[eqn.primitive.name] += 1
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _census(sub, found)
+    return found
+
+
+def _mesh(**axes):
+    n = int(np.prod(list(axes.values()) or [1]))
+    return MeshConfig(devices=jax.devices()[:n], **(axes or dict(data=1)))
+
+
+def _lm_grad(cfg, x, y, **axes):
+    """``(gradient of lm_loss on the mesh, the census of its traced
+    program)``; the blocks run under whatever ``cfg.checkpoint_fn`` is
+    when called."""
     from jax.sharding import PartitionSpec as P
 
     from chainermn_tpu.models.transformer import lm_loss, param_specs
+    from chainermn_tpu.ops.pallas_attention import tracing_for_mesh
 
+    mc = _mesh(**axes)
+    params = shard_params(
+        mc, cfg, init_transformer(jax.random.PRNGKey(0), cfg))
+    specs = param_specs(cfg)
+    batch_spec = P(("data", "expert"), "seq")
+    grad_fn = jax.shard_map(
+        tracing_for_mesh(mc.mesh, lambda p, xx, yy: jax.grad(
+            lambda q: lm_loss(cfg, q, xx, yy))(p)),
+        mesh=mc.mesh, in_specs=(specs, batch_spec, batch_spec),
+        out_specs=specs)
+    traced = jax.jit(grad_fn).trace(params, x, y)
+    return traced.lower().compile()(params, x, y), _census(
+        traced.jaxpr.jaxpr)
+
+
+def _same(a, b):
+    jax.tree.map(lambda u, v: np.testing.assert_array_equal(
+        np.asarray(u), np.asarray(v)), a, b)
+
+
+@pytest.mark.parametrize("attention,kernels", [("local", 0), ("flash", 3)])
+def test_remat_policy_grad_equivalence(attention, kernels):
+    """remat_policy is a pure scheduling choice: grads equal the
+    remat=False path (fp32), and under every setting the traced gradient
+    holds the kernels of remat=False (forward, dq, dkv) and no more."""
     toks = tokens()
     x, y = toks[:, :T], toks[:, 1:]
-    batch_spec = P(("data", "expert"), "seq")
-    one = MeshConfig(data=1, devices=jax.devices()[:1])
     grads = {}
     for name, kw in (("none", dict(remat=False)),
                      ("full", dict(remat=True)),
                      ("dots", dict(remat=True, remat_policy="dots"))):
-        cfg = tiny_cfg(**kw)
-        params = init_transformer(jax.random.PRNGKey(0), cfg)
-        specs = param_specs(cfg)
-        grad_fn = jax.jit(jax.shard_map(
-            lambda p, xx, yy: jax.grad(
-                lambda q: lm_loss(cfg, q, xx, yy))(p),
-            mesh=one.mesh,
-            in_specs=(specs, batch_spec, batch_spec),
-            out_specs=specs))
-        grads[name] = grad_fn(params, x, y)
+        grads[name], census = _lm_grad(
+            tiny_cfg(attention=attention, **kw), x, y)
+        assert census["pallas_call"] == kernels, name
     for name in ("full", "dots"):
         jax.tree.map(
             lambda a, b: np.testing.assert_allclose(
@@ -106,6 +146,114 @@ def test_remat_policy_grad_equivalence():
 
     with pytest.raises(ValueError, match="remat_policy"):
         tiny_cfg(remat_policy="everything")
+
+
+def _typed_kw():
+    from chainermn_tpu.models import AttentionKind
+
+    return dict(pos_embedding="rope", layer_pattern=(
+        AttentionKind("sliding", window=8, rope_theta=5e5),
+        AttentionKind("full", rope_theta=5e5)))
+
+
+@pytest.mark.parametrize("policy", ["full", "dots"])
+@pytest.mark.parametrize("layers", ["uniform", "window+full"])
+def test_flash_forward_runs_once_under_remat(monkeypatch, layers, policy):
+    """The block's checkpoint keeps the kernel's own ``o`` and ``lse``:
+    the traced gradient of the scanned blocks holds three kernels a
+    layer kind (forward, dq, dkv), where plain ``jax.checkpoint`` holds
+    four (the forward again), and the gradients are those of
+    ``remat=False`` and of plain ``jax.checkpoint`` to the last bit."""
+    kw = dict(attention="flash", **(_typed_kw() if "+" in layers else {}))
+    kinds = len(kw.get("layer_pattern", (None,)))
+    toks = tokens()
+    x, y = toks[:, :T], toks[:, 1:]
+    cfg = tiny_cfg(remat=True, remat_policy=policy, **kw)
+    kept, n_kept = _lm_grad(cfg, x, y)
+    none, n_none = _lm_grad(tiny_cfg(**kw), x, y)
+    monkeypatch.setattr(TransformerConfig, "checkpoint_fn",
+                        property(lambda self: jax.checkpoint))
+    plain, n_plain = _lm_grad(cfg, x, y)
+    assert [n["pallas_call"] for n in (n_none, n_kept, n_plain)] == [
+        3 * kinds, 3 * kinds, 4 * kinds]
+    _same(kept, none)
+    _same(kept, plain)
+
+
+@pytest.mark.parametrize("policy", ["full", "dots"])
+def test_ring_block_remats_as_before(monkeypatch, policy):
+    """``attention="ring"`` is left out of the named save (a block's
+    policy reaches into the checkpoint around each pair and would keep
+    every pair's output): its traced gradient is the one plain
+    ``jax.checkpoint`` (``"dots"``: the dots policy with ``attn_out``)
+    gives, equation for equation.  Here the pairs are XLA's; the kernel
+    in the ring is counted in ``test_tpu_compile.py``."""
+    cp = jax.checkpoint_policies
+    before = jax.checkpoint if policy == "full" else partial(
+        jax.checkpoint, policy=cp.save_from_both_policies(
+            cp.dots_with_no_batch_dims_saveable,
+            cp.save_only_these_names("attn_out")))
+    cfg = tiny_cfg(attention="ring", remat=True, remat_policy=policy)
+    toks = tokens()
+    x, y = toks[:, :T], toks[:, 1:]
+    kept, n_kept = _lm_grad(cfg, x, y, seq=4, data=2)
+    monkeypatch.setattr(TransformerConfig, "checkpoint_fn",
+                        property(lambda self: before))
+    plain, n_plain = _lm_grad(cfg, x, y, seq=4, data=2)
+    assert n_kept == n_plain and n_kept["ppermute"]
+    _same(kept, plain)
+
+
+def _saved_by_one_block(cfg, T, **axes):
+    """What ``jax.ad_checkpoint.print_saved_residuals`` lists for one
+    block under ``cfg.checkpoint_fn``, the block's arguments left out.
+    Traced inside the mesh's ``shard_map``: the block reads axis sizes."""
+    import contextlib
+    import io
+
+    from jax.sharding import PartitionSpec as P
+
+    from chainermn_tpu.models.transformer import _block
+    from chainermn_tpu.ops.pallas_attention import tracing_for_mesh
+
+    mc = _mesh(**axes)
+    blk = jax.tree.map(lambda a: a[0, 0], init_transformer(
+        jax.random.PRNGKey(0), cfg)["blocks"])
+    h = jnp.ones((2, T, cfg.d_model), cfg.compute_dtype)
+    out = io.StringIO()
+
+    def body(h, blk):
+        fn = cfg.checkpoint_fn(partial(_block, cfg, kind=None))
+        with contextlib.redirect_stdout(out):
+            jax.ad_checkpoint.print_saved_residuals(fn, h, blk)
+        return fn(h, blk)[0]
+
+    jax.make_jaxpr(jax.shard_map(
+        tracing_for_mesh(mc.mesh, body), mesh=mc.mesh,
+        in_specs=(P(None, "seq"), P()), out_specs=P(None, "seq"),
+        check_vma=False))(h, blk)
+    return [line for line in out.getvalue().splitlines()
+            if "from the argument" not in line]
+
+
+@pytest.mark.parametrize("attention,axes,saved", [
+    ("flash", {}, ["bf16[8,16,8]", "f32[8,16]"]),   # (B·H, T, D), (B·H, T)
+    ("local", {}, []),
+    ("ring", dict(seq=4), []),
+])
+def test_what_one_block_saves_under_full_remat(attention, axes, saved):
+    """Under ``remat_policy="full"`` a block keeps its arguments and,
+    where it runs the kernel, ``o`` as the kernel wrote it in the
+    compute dtype and the fp32 log-sum-exp WITHOUT its 128 lanes:
+    nothing else of the attention (no q3/k3/v3), and nothing at all of
+    the XLA attention or of the ring's pairs."""
+    lines = _saved_by_one_block(
+        tiny_cfg(attention=attention, remat=True, dtype="bfloat16"), T,
+        **axes)
+    assert [line.split()[0] for line in lines] == saved, lines
+    assert all("pallas_attention.py" in line for line in lines), lines
+    if saved:
+        assert "flash_lse" in lines[1], lines
 
 
 def test_ulysses_matches_oracle():
