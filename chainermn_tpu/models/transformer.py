@@ -25,6 +25,7 @@ Design rules (TPU-first):
 from __future__ import annotations
 
 import math
+from contextlib import nullcontext
 from dataclasses import dataclass
 from functools import partial
 
@@ -86,11 +87,19 @@ __all__ = [
 @dataclass(frozen=True)
 class AttentionKind:
     """One kind of attention layer of a model whose layers differ: its
-    window and its rotary parameters.  ``TransformerConfig.layer_pattern``
-    is a tuple of these, one per layer of a period."""
+    window, its rotary parameters and its query heads.
+    ``TransformerConfig.layer_pattern`` is a tuple of these, one per
+    layer of a period (``leading_layers`` one per layer before them)."""
     name: str                  # names the layer's scope: ``attn/<name>``
     window: int = 0            # 0 => full causal; W>0 => (t-W, t]
     rope_theta: float = 10000.0
+    n_heads: int = 0           # 0 => the config's n_heads.  Else this
+    # kind's own query heads over the config's key-value heads: its
+    # layers' wq, wo and gate have that many and no more
+    rotary_share: float = 1.0  # the leading part of each head that is
+    # rotated (a partial rotary factor); the rest passes through.  The
+    # frequencies, YaRN's ramp included, are those of a head of that
+    # many dimensions
     # YaRN (Peng et al., arXiv:2309.00071), as published configs state
     # it: frequencies whose wavelength exceeds the original context are
     # divided by ``yarn_factor``, those that turn often within it are
@@ -115,10 +124,20 @@ class AttentionKind:
             raise ValueError(
                 f"{self.name}: yarn needs factor >= 1 and the original "
                 f"context, got {self.yarn_factor}, {self.yarn_original_max}")
+        if self.n_heads < 0:
+            raise ValueError(f"{self.name}: n_heads {self.n_heads} < 0")
+        if not 0 < self.rotary_share <= 1:
+            raise ValueError(
+                f"{self.name}: rotary_share {self.rotary_share} not in (0, 1]")
+
+    def rotary_dim(self, d_head: int) -> int:
+        """How many leading dimensions of a head are rotated."""
+        return int(d_head * self.rotary_share)
 
     def inv_freq(self, d_head: int):
-        """The ``d_head / 2`` rotary frequencies, as float64 numpy
+        """The ``rotary_dim / 2`` rotary frequencies, as float64 numpy
         (constants of the compiled step)."""
+        d_head = self.rotary_dim(d_head)
         half = d_head // 2
         base = self.rope_theta ** (-np.arange(half, dtype=np.float64) / half)
         if not self.yarn_factor:
@@ -167,6 +186,15 @@ class TransformerConfig:
     # frequencies; the layer scan then runs over whole periods with the
     # period's kinds unrolled in its body (a window is a static argument
     # of the kernel).  Needs pos_embedding="rope"; training path only.
+    leading_layers: tuple = ()  # the :class:`AttentionKind` of each layer
+    # that comes BEFORE the periods of ``layer_pattern`` (a model whose
+    # first layers differ from the rest: a dense MLP before the sparse
+    # ones).  They run ahead of the layer scan, each under the block's
+    # checkpoint, and live in the tree beside the scanned stack
+    # (``params["leading"]``, one block per layer at its own shapes);
+    # ``n_layers`` counts them.  Unpipelined meshes; training path only.
+    leading_mlp: str = "dense"  # "dense" | "sparse": the leading layers'
+    # MLP where the model is sparse (moe=True); the periods' is sparse
     seq_layout: str = "contiguous"  # "contiguous" | "zigzag" (ring only):
     # zigzag = Striped-ring causal load balance; feed tokens permuted by
     # parallel.ring_attention.zigzag_indices (targets through the same
@@ -190,6 +218,22 @@ class TransformerConfig:
     # the router keeps n_experts columns, gates are normalised over the
     # k chosen among all of them, and the layer returns the held
     # experts' part of its result (dropless dispatch only)
+    router_score: str = "softmax"  # "softmax" | "sigmoid": how the
+    # dropless layer's router scores the experts (route_top_k)
+    router_scale: float = 1.0  # multiplies the chosen gates (a routed
+    # scaling factor; dropless dispatch only)
+    shared_expert_d_ff: int = 0  # >0 => beside the routed experts, one
+    # expert of this width that every token meets (its activation is
+    # ``expert_act``), ungated, whole on every member of the expert
+    # group for the member's own tokens (dropless dispatch only)
+    dense_act: str = "relu"    # the dense MLP: "relu": w2(relu(w1 x)) |
+    # "swiglu": w2(silu(w1 x) * w3 x); training path only
+    dense_d_ff: int = 0        # 0 => d_ff.  Width of the dense MLP where
+    # d_ff is the experts' (a sparse model's leading dense layers)
+    attn_gate: str = ""        # "" | "per_head": o_j <- sigmoid(x W_g)_j
+    # o_j between the attention core and the output projection, one
+    # scalar a query head from the layer's normed input (``wg``, riding
+    # the fused q/k/v product); training path only
     tie_embeddings: bool = True  # False => a separate output matrix
     # ``head`` (vocab, d_model) beside ``embed``; training path only
     num_microbatches: int = 1  # GPipe M (>1 only useful when pipe > 1)
@@ -257,15 +301,39 @@ class TransformerConfig:
     def n_experts_held(self) -> int:
         return self.experts_held[1] if self.experts_held else self.n_experts
 
+    def heads_of(self, kind) -> int:
+        """Query heads of a layer of ``kind`` (None: an untyped layer)."""
+        return (kind.n_heads if kind else 0) or self.n_heads
+
+    @property
+    def leading_sparse(self) -> bool:
+        return self.moe and self.leading_mlp == "sparse"
+
+    @property
+    def blocks_by_position(self) -> bool:
+        """Whether the layers of a period differ in parameter SHAPES (a
+        kind with query heads of its own).  One stacked array a leaf
+        cannot hold them: ``params["blocks"]`` is then a tuple with one
+        stack over the periods for each position of the pattern,
+        ``(pipe, periods/pipe, ...)`` a leaf, each at its own shapes."""
+        return len({self.heads_of(k) for k in self.layer_pattern}) > 1
+
     @property
     def training_only(self):
         """The fields in use that only the training path implements, by
         name: decoding and serving refuse a config that sets any."""
         return [name for name, on in (
             ("layer_pattern", bool(self.layer_pattern)),
+            ("leading_layers", bool(self.leading_layers)),
+            ("AttentionKind.n_heads", any(
+                k.n_heads for k in self.layer_pattern + self.leading_layers)),
+            ("attn_gate", bool(self.attn_gate)),
             ("experts_held", bool(self.experts_held)),
             ("moe_dispatch='dropless'",
              self.moe and self.moe_dispatch == "dropless"),
+            ("shared_expert_d_ff", bool(self.shared_expert_d_ff)),
+            ("router_score='sigmoid'", self.router_score == "sigmoid"),
+            ("dense_act='swiglu'", self.dense_act == "swiglu"),
             ("tie_embeddings=False", not self.tie_embeddings)) if on]
 
     @property
@@ -331,12 +399,47 @@ class TransformerConfig:
                 raise ValueError(
                     "with a layer_pattern the window is each kind's own; "
                     f"attention_window={self.attention_window} is set too")
-            if self.n_layers % len(self.layer_pattern):
+            scanned = self.n_layers - len(self.leading_layers)
+            if scanned < 1 or scanned % len(self.layer_pattern):
                 raise ValueError(
-                    f"n_layers={self.n_layers} is not whole periods of "
-                    f"the {len(self.layer_pattern)}-layer pattern")
+                    f"n_layers={self.n_layers} less the "
+                    f"{len(self.leading_layers)} leading is not whole "
+                    f"periods of the {len(self.layer_pattern)}-layer pattern")
             if self.seq_layout != "contiguous":
                 raise ValueError("layer_pattern needs contiguous shards")
+            if not all(isinstance(k, AttentionKind)
+                       for k in self.leading_layers):
+                raise ValueError("leading_layers holds AttentionKind values")
+            for k in self.layer_pattern + self.leading_layers:
+                if self.heads_of(k) % self.kv_heads:
+                    raise ValueError(
+                        f"{k.name}: n_heads={k.n_heads} must be a multiple "
+                        f"of n_kv_heads={self.kv_heads}")
+                if k.rotary_dim(self.d_head) % 2 \
+                        or not k.rotary_dim(self.d_head):
+                    raise ValueError(
+                        f"{k.name}: rotary_share {k.rotary_share} of "
+                        f"d_head={self.d_head} is not an even number of "
+                        "dimensions")
+        elif self.leading_layers:
+            raise ValueError(
+                "leading_layers come before the periods of a "
+                "layer_pattern, which is empty")
+        if self.leading_mlp not in ("dense", "sparse"):
+            raise ValueError(
+                f"leading_mlp {self.leading_mlp!r} not in (dense, sparse)")
+        if self.dense_act not in ("relu", "swiglu"):
+            raise ValueError(
+                f"dense_act {self.dense_act!r} not in (relu, swiglu)")
+        if self.attn_gate not in ("", "per_head"):
+            raise ValueError(
+                f"attn_gate {self.attn_gate!r} not in ('', per_head)")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"router_score {self.router_score!r} not in "
+                "(softmax, sigmoid)")
+        if min(self.dense_d_ff, self.shared_expert_d_ff) < 0:
+            raise ValueError("dense_d_ff and shared_expert_d_ff are >= 0")
         if self.moe_dispatch not in ("capacity", "dropless"):
             raise ValueError(
                 f"moe_dispatch {self.moe_dispatch!r} not in "
@@ -349,6 +452,12 @@ class TransformerConfig:
             raise ValueError(
                 'expert_act="swiglu" is implemented by the dropless '
                 "expert layer only (moe=True, moe_dispatch='dropless')")
+        if (self.router_score != "softmax" or self.router_scale != 1.0
+                or self.shared_expert_d_ff) and not dropless:
+            raise ValueError(
+                "router_score, router_scale and shared_expert_d_ff are "
+                "the dropless expert layer's (moe=True, "
+                "moe_dispatch='dropless')")
         if self.experts_held:
             if not dropless:
                 raise ValueError(
@@ -399,8 +508,14 @@ class TransformerConfig:
 # --------------------------------------------------------------------- #
 
 
-def _init_block(key, cfg: TransformerConfig):
-    D, H, Dh, F = cfg.d_model, cfg.n_heads, cfg.d_head, cfg.d_ff
+def _init_block(key, cfg: TransformerConfig, kind=None, sparse=None):
+    """One layer's parameters at its own shapes: ``kind`` gives the
+    query heads (None: the config's), ``sparse`` the MLP (None: the
+    config's ``moe``)."""
+    D, Dh = cfg.d_model, cfg.d_head
+    H = cfg.heads_of(kind)
+    sparse = cfg.moe if sparse is None else sparse
+    F = cfg.d_ff if sparse else cfg.dense_d_ff or cfg.d_ff
     ks = jax.random.split(key, 6)
 
     def dense_init(k, shape, fan_in):
@@ -418,19 +533,48 @@ def _init_block(key, cfg: TransformerConfig):
         # (consecutive grouping: query head h reads kv head h//(H/Hkv))
         block["wq"] = dense_init(ks[0], (D, H, Dh), D)
         block["wkv"] = dense_init(ks[5], (D, 2, cfg.kv_heads, Dh), D)
-    if cfg.moe:
+    if cfg.attn_gate:
+        block["wg"] = dense_init(jax.random.fold_in(key, 7), (D, H), D)
+    gated = cfg.expert_act if sparse else cfg.dense_act
+    if sparse:
         # the router scores every expert; the weights are of those held
         E, G = cfg.n_experts, cfg.n_experts_held
         block["router"] = dense_init(ks[2], (D, E), D)
         block["w1"] = dense_init(ks[3], (G, D, F), D)
         block["w2"] = dense_init(ks[4], (G, F, D), F)
-        if cfg.expert_act == "swiglu":
+        if gated == "swiglu":
             block["w3"] = dense_init(
                 jax.random.fold_in(key, 6), (G, D, F), D)
+        Fs = cfg.shared_expert_d_ff
+        if Fs:
+            block["ws1"] = dense_init(jax.random.fold_in(key, 8), (D, Fs), D)
+            block["ws2"] = dense_init(
+                jax.random.fold_in(key, 9), (Fs, D), Fs)
+            if gated == "swiglu":
+                block["ws3"] = dense_init(
+                    jax.random.fold_in(key, 10), (D, Fs), D)
     else:
         block["w1"] = dense_init(ks[3], (D, F), D)
         block["w2"] = dense_init(ks[4], (F, D), F)
+        if gated == "swiglu":
+            block["w3"] = dense_init(jax.random.fold_in(key, 6), (D, F), D)
     return block
+
+
+def _stack_blocks(blocks, cfg: TransformerConfig, pipe_size: int):
+    """Layers of one shape, in order, as one ``(pipe, L/pipe, ...)``
+    array a leaf (``(pipe, V, L/(pipe*V), ...)`` under ``virtual_pipe``)."""
+    V = cfg.virtual_pipe
+    n = len(blocks)
+    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *blocks)
+    if V > 1:
+        lpc = n // (pipe_size * V)  # layers per chunk
+        return jax.tree.map(
+            lambda a: a.reshape(V, pipe_size, lpc, *a.shape[1:])
+            .swapaxes(0, 1), stacked)
+    return jax.tree.map(
+        lambda a: a.reshape(pipe_size, n // pipe_size, *a.shape[1:]),
+        stacked)
 
 
 def init_transformer(key, cfg: TransformerConfig, pipe_size: int = 1):
@@ -439,27 +583,32 @@ def init_transformer(key, cfg: TransformerConfig, pipe_size: int = 1):
     locally.  With ``virtual_pipe = V > 1`` the block stack is
     ``(pipe_size, V, L/(pipe·V), ...)``: chunk ``c`` of device ``s`` is
     virtual stage ``g = c·pipe + s`` holding the ``g``-th layer slice
-    (Megatron interleaved assignment)."""
+    (Megatron interleaved assignment).
+
+    Where the layers of a period differ in shape
+    (``cfg.blocks_by_position``) ``blocks`` is a tuple of such stacks,
+    one per position of the pattern, each over the periods; the
+    ``leading_layers`` are a tuple of single blocks under ``leading``."""
     V = cfg.virtual_pipe
-    if cfg.n_layers % (pipe_size * V):
+    n_lead, n = len(cfg.leading_layers), len(cfg.layer_pattern)
+    scanned = cfg.n_layers - n_lead
+    if scanned % (pipe_size * V * (n if cfg.blocks_by_position else 1)):
         raise ValueError(
-            f"{cfg.n_layers} layers not divisible by "
-            f"pipe·virtual_pipe = {pipe_size}·{V}")
+            f"{scanned} layers not divisible by "
+            f"pipe·virtual_pipe = {pipe_size}·{V}"
+            + (f" in whole periods of {n}" if cfg.blocks_by_position
+               else ""))
     k_emb, k_pos, k_blocks = jax.random.split(key, 3)
+    keys = jax.random.split(k_blocks, cfg.n_layers)
     blocks = [
-        _init_block(k, cfg)
-        for k in jax.random.split(k_blocks, cfg.n_layers)
+        _init_block(k, cfg, cfg.layer_pattern[i % n] if n else None)
+        for i, k in enumerate(keys[n_lead:])
     ]
-    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *blocks)
-    if V > 1:
-        lpc = cfg.n_layers // (pipe_size * V)  # layers per chunk
-        stacked = jax.tree.map(
-            lambda a: a.reshape(V, pipe_size, lpc, *a.shape[1:])
-            .swapaxes(0, 1), stacked)
+    if cfg.blocks_by_position:
+        stacked = tuple(_stack_blocks(blocks[j::n], cfg, pipe_size)
+                        for j in range(n))
     else:
-        lps = cfg.n_layers // pipe_size
-        stacked = jax.tree.map(
-            lambda a: a.reshape(pipe_size, lps, *a.shape[1:]), stacked)
+        stacked = _stack_blocks(blocks, cfg, pipe_size)
     D = cfg.d_model
     params = {
         "embed": jax.random.normal(
@@ -467,6 +616,10 @@ def init_transformer(key, cfg: TransformerConfig, pipe_size: int = 1):
         "blocks": stacked,
         "ln_f": jnp.ones((D,), jnp.float32),
     }
+    if n_lead:
+        params["leading"] = tuple(
+            _init_block(k, cfg, kind, cfg.leading_sparse)
+            for k, kind in zip(keys, cfg.leading_layers))
     if cfg.pos_embedding == "learned":
         params["pos"] = jax.random.normal(
             k_pos, (cfg.max_seq, D), jnp.float32) * 0.02
@@ -585,25 +738,22 @@ def reshard_train_state(mc, cfg: TransformerConfig, optimizer, params,
     return new_params, new_opt
 
 
-def _fsdp_dims(cfg: TransformerConfig):
+def _fsdp_dims(mha: bool, sparse: bool):
     """Leaf → axis (into the BASE per-layer shapes, i.e. after scan has
     stripped the pipe/chunk/layer prefixes) that FSDP shards over
-    ``data``.  One rule everywhere: **the d_model dim** — it exists in
-    every matrix leaf and is never claimed by TP (``model`` shards
-    head/ff dims) or EP (``expert`` shards the expert dim), so the two
-    shardings compose without collisions.  Norm scales are omitted."""
-    dims = {"wo": 2}
-    if cfg.kv_heads == cfg.n_heads:
-        dims["wqkv"] = 0
+    ``data``, for a block with fused q/k/v (``mha``) or grouped heads,
+    and a ``sparse`` or a dense MLP; a leaf the block lacks is ignored.
+    One rule everywhere: **the d_model dim** — it exists in every matrix
+    leaf and is never claimed by TP (``model`` shards head/ff dims) or
+    EP (``expert`` shards the expert dim), so the two shardings compose
+    without collisions.  Norm scales are omitted."""
+    dims = {"wo": 2, "wg": 0}
+    dims.update({"wqkv": 0} if mha else {"wq": 0, "wkv": 0})
+    if sparse:
+        dims.update({"router": 0, "w1": 1, "w2": 2, "w3": 1,
+                     "ws1": 0, "ws2": 1, "ws3": 0})
     else:
-        dims["wq"] = 0
-        dims["wkv"] = 0
-    if cfg.moe:
-        dims.update({"router": 0, "w1": 1, "w2": 2})
-        if cfg.expert_act == "swiglu":
-            dims["w3"] = 1
-    else:
-        dims.update({"w1": 0, "w2": 1})
+        dims.update({"w1": 0, "w2": 1, "w3": 0})
     return dims
 
 
@@ -614,40 +764,46 @@ def _fsdp_gather(cfg: TransformerConfig, blk):
     reduce-scatter — no hand-written backward.  Mechanics live in
     :func:`...parallel.fsdp.fsdp_gather`; this only binds the
     transformer's dim map (norm scales get ``None`` → pass through)."""
-    dims = _fsdp_dims(cfg)
+    dims = _fsdp_dims("wqkv" in blk, "router" in blk)
     return fsdp_gather(blk, {k: dims.get(k) for k in blk},
                        "data", cfg.fsdp_wire_dtype or None)
 
 
-def param_specs(cfg: TransformerConfig, quantized: bool = False):
-    """PartitionSpec pytree matching :func:`init_transformer`'s output.
-
-    TP shards head/ff dims over ``model``, EP shards experts over
-    ``expert``, PP shards the stage axis over ``pipe``; embeddings and
-    norms replicate.  With ``quantized=True`` the tree additionally
-    carries ``<name>_scale`` specs matching
-    :func:`...quantization.quantize_params_int8`'s output (the weight's
-    spec with its contraction axes dropped).
-    """
+def _block_specs(cfg: TransformerConfig, kind, sparse: bool,
+                 quantized: bool = False):
+    """PartitionSpecs of one stack of blocks of one shape (``kind``'s
+    query heads, a ``sparse`` or a dense MLP), with the stack's leading
+    ``(pipe, layers)`` axes."""
     blk = {
         "ln1": P("pipe"),
         "ln2": P("pipe"),
         "wo": P("pipe", None, "model", None, None),
     }
-    if cfg.kv_heads == cfg.n_heads:
+    mha = cfg.kv_heads == cfg.heads_of(kind)
+    if mha:
         blk["wqkv"] = P("pipe", None, None, None, "model", None)
     else:
         blk["wq"] = P("pipe", None, None, "model", None)
         blk["wkv"] = P("pipe", None, None, None, "model", None)
-    if cfg.moe:
+    if cfg.attn_gate:
+        blk["wg"] = P("pipe", None, None, "model")
+    gated = (cfg.expert_act if sparse else cfg.dense_act) == "swiglu"
+    if sparse:
         blk["router"] = P("pipe")
         blk["w1"] = P("pipe", None, "expert", None, "model")
         blk["w2"] = P("pipe", None, "expert", "model", None)
-        if cfg.expert_act == "swiglu":
+        if gated:
             blk["w3"] = blk["w1"]
+        if cfg.shared_expert_d_ff:
+            blk["ws1"] = P("pipe", None, None, "model")
+            blk["ws2"] = P("pipe", None, "model", None)
+            if gated:
+                blk["ws3"] = blk["ws1"]
     else:
         blk["w1"] = P("pipe", None, None, "model")
         blk["w2"] = P("pipe", None, "model", None)
+        if gated:
+            blk["w3"] = blk["w1"]
     if cfg.virtual_pipe > 1:
         # blocks carry an extra local chunk axis after pipe: (pipe, V,
         # layers_per_chunk, ...) — replicate over it, shift the rest
@@ -657,7 +813,9 @@ def param_specs(cfg: TransformerConfig, quantized: bool = False):
         # dim (see _fsdp_dims).  Skipped for quantized (decode) trees —
         # decoding wants resident weights, not per-token gathers.
         prefix = 2 + (1 if cfg.virtual_pipe > 1 else 0)
-        for name, dim in _fsdp_dims(cfg).items():
+        for name, dim in _fsdp_dims(mha, sparse).items():
+            if name not in blk:
+                continue
             full = list(blk[name])
             idx = prefix + dim
             full += [None] * (idx + 1 - len(full))
@@ -678,12 +836,38 @@ def param_specs(cfg: TransformerConfig, quantized: bool = False):
             if name in blk and name not in ("router",):
                 blk[name + "_scale"] = scale_spec(
                     blk[name], base_rank, base_axes, prefix + base_rank)
+    return blk
+
+
+def param_specs(cfg: TransformerConfig, quantized: bool = False):
+    """PartitionSpec pytree matching :func:`init_transformer`'s output.
+
+    TP shards head/ff dims over ``model``, EP shards experts over
+    ``expert``, PP shards the stage axis over ``pipe``; embeddings and
+    norms replicate.  With ``quantized=True`` the tree additionally
+    carries ``<name>_scale`` specs matching
+    :func:`...quantization.quantize_params_int8`'s output (the weight's
+    spec with its contraction axes dropped).
+    """
+    kinds = cfg.layer_pattern
+    if cfg.blocks_by_position:
+        blk = tuple(_block_specs(cfg, k, cfg.moe, quantized) for k in kinds)
+    else:
+        blk = _block_specs(
+            cfg, kinds[0] if kinds else None, cfg.moe, quantized)
     emb = P("model") if cfg.vocab_parallel else P()
     specs = {
         "embed": emb,
         "blocks": blk,
         "ln_f": P(),
     }
+    if cfg.leading_layers:
+        # single blocks, on every pipeline member alike: a stack's specs
+        # less its two leading axes
+        specs["leading"] = tuple(
+            {k: P(*v[2:]) for k, v in _block_specs(
+                cfg, kind, cfg.leading_sparse, quantized).items()}
+            for kind in cfg.leading_layers)
     if quantized:
         specs["embed_scale"] = emb
     if cfg.pos_embedding == "learned":
@@ -1068,8 +1252,10 @@ def apply_rope(x, positions, theta: float = 10000.0, inv_freq=None,
     global position and relative attention falls out, with no position
     parameters to learn or extend.
 
-    ``inv_freq`` (``d_head/2`` values) replaces ``theta``'s frequencies
-    and ``scale`` multiplies cos and sin.
+    ``inv_freq`` replaces ``theta``'s frequencies and ``scale``
+    multiplies cos and sin.  Fewer than ``d_head/2`` of them rotate the
+    leading ``2·len(inv_freq)`` dimensions of each head (rotate-half
+    within that part) and pass the rest through: a partial rotary.
 
     The trig tables are (T, d_head/2) — negligible next to the T² score
     matrix, so they are recomputed per call (the layer-invariant parts
@@ -1082,6 +1268,11 @@ def apply_rope(x, positions, theta: float = 10000.0, inv_freq=None,
         # a kind's own frequencies (AttentionKind.inv_freq) and the
         # factor its cos and sin carry (YaRN's attention factor)
         freqs = jnp.asarray(inv_freq, jnp.float32)
+        if freqs.shape[0] < half:
+            half = freqs.shape[0]
+            return jnp.concatenate([
+                apply_rope(x[..., :2 * half], positions, inv_freq=inv_freq,
+                           scale=scale), x[..., 2 * half:]], axis=-1)
     ang = positions.astype(jnp.float32)[..., None] * freqs  # (..., T, half)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     if scale != 1.0:
@@ -1117,6 +1308,8 @@ def _attention_of_kind(cfg: TransformerConfig, h, blk, kind):
             x, blk["wqkv"].reshape(D, -1).astype(cd))
         qkv = qkv.reshape(B, T, 3, Hl, cfg.d_head)
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        gate = column_parallel_dense(x, blk["wg"].astype(cd)) \
+            if "wg" in blk else None
     else:
         # GQA/MQA: H/Hkv query heads share each K/V head.  K/V stay at
         # their natural (shared) width all the way through the attention
@@ -1136,13 +1329,17 @@ def _attention_of_kind(cfg: TransformerConfig, h, blk, kind):
         # training shapes, and removes a dispatch on the decode path.
         # The at-rest params stay separate (their TP/FSDP specs differ).
         dq = Hl * cfg.d_head
+        dkv = 2 * Hkvl * cfg.d_head
+        # the per-head gate's projection (Hl more columns) rides it too
         fused = jnp.concatenate(
-            [blk["wq"].reshape(D, -1), blk["wkv"].reshape(D, -1)],
+            [blk["wq"].reshape(D, -1), blk["wkv"].reshape(D, -1)]
+            + ([blk["wg"]] if "wg" in blk else []),
             axis=1).astype(cd)
         qkv = column_parallel_dense(x, fused)
         q = qkv[..., :dq].reshape(B, T, Hl, cfg.d_head)
-        kv = qkv[..., dq:].reshape(B, T, 2, Hkvl, cfg.d_head)
+        kv = qkv[..., dq:dq + dkv].reshape(B, T, 2, Hkvl, cfg.d_head)
         k, v = kv[:, :, 0], kv[:, :, 1]
+        gate = qkv[..., dq + dkv:] if "wg" in blk else None
     if cfg.pos_embedding == "rope":
         # rotate by each local token's GLOBAL position BEFORE any ring
         # rotation / Ulysses exchange — relative attention then holds
@@ -1218,6 +1415,12 @@ def _attention_of_kind(cfg: TransformerConfig, h, blk, kind):
             interpret=interpret_kernels())
     else:
         raise ValueError(cfg.attention)
+    if gate is not None:
+        # o_j <- sigmoid(x W_g)_j o_j, one scalar a local query head.
+        # Its backward reads the core's o, which the block's checkpoint
+        # already keeps where the core is the flash kernel
+        o = o * jax.nn.sigmoid(
+            gate.astype(jnp.float32)).astype(o.dtype)[..., None]
     # named for the "dots" remat policy, which saves it as the input of
     # the output projection's backward.  It never kept the flash kernel
     # out of the recompute (the kernel's residuals are its own o and
@@ -1231,18 +1434,37 @@ def _attention_of_kind(cfg: TransformerConfig, h, blk, kind):
     return h + o
 
 
-def _mlp(cfg: TransformerConfig, h, blk, with_chosen=False):
-    """Pre-LN MLP: dense (column→row TP pair, one psum) or Switch-MoE
-    (expert all-to-alls; experts' FFNs are themselves TP-split).
-    ``with_chosen`` (dropless dispatch) also returns the ``(B, T, k)``
-    experts each token chose, for :func:`expert_choices`."""
+def _gated(cfg: TransformerConfig, act: str, x, w1, w3, w2):
+    """A dense MLP as a column→row TP pair: ``w2(relu(w1 x))`` or the
+    gated ``w2(silu(w1 x) * w3 x)``, by ``act``."""
     cd = cfg.compute_dtype
+    y = column_parallel_dense(x, w1.astype(cd))
+    if act == "swiglu":
+        y = jax.nn.silu(y) * column_parallel_dense(x, w3.astype(cd))
+    else:
+        y = jax.nn.relu(y)
+    return row_parallel_dense(y, w2.astype(cd))
+
+
+def _mlp(cfg: TransformerConfig, h, blk, with_chosen=False, sparse=None,
+         scoped=False):
+    """Pre-LN MLP: dense (column→row TP pair, one psum) or Switch-MoE
+    (expert all-to-alls; experts' FFNs are themselves TP-split), by
+    ``sparse`` (None: the config's ``moe``).  ``with_chosen`` (dropless
+    dispatch) also returns the ``(B, T, k)`` experts each token chose,
+    for :func:`expert_choices`.  ``scoped``: a typed layer, whose dense
+    MLP and shared expert carry ``mlp/dense`` and ``moe/shared`` in
+    their ``op_name`` (the dropless layer names its own parts)."""
+    cd = cfg.compute_dtype
+    sparse = cfg.moe if sparse is None else sparse
+    scope = jax.named_scope if scoped else (lambda _: nullcontext())
     x = _rms_norm(h, blk["ln2"])
-    if with_chosen and not (cfg.moe and cfg.moe_dispatch == "dropless"):
+    if with_chosen and not (sparse and cfg.moe_dispatch == "dropless"):
         raise ValueError("the choices are read from the dropless layer")
-    if not cfg.moe:
-        y = jax.nn.relu(column_parallel_dense(x, blk["w1"].astype(cd)))
-        out = h + row_parallel_dense(y, blk["w2"].astype(cd))
+    if not sparse:
+        with scope("mlp/dense"):
+            out = h + _gated(cfg, cfg.dense_act, x, blk["w1"],
+                             blk.get("w3"), blk["w2"])
         return out, jnp.zeros((), jnp.float32)
     B, T, D = x.shape
     if cfg.moe_dispatch == "dropless":
@@ -1263,11 +1485,20 @@ def _mlp(cfg: TransformerConfig, h, blk, with_chosen=False):
             grouped_fn,
             top_k=cfg.router_top_k,
             first_expert=cfg.experts_held[0] if cfg.experts_held else 0,
+            score=cfg.router_score,
+            scale=cfg.router_scale,
             axis_name="expert",
         )
+        out = out.reshape(B, T, D)
+        if "ws1" in blk:
+            # the expert every token meets: whole on each member of the
+            # expert group, for the member's own tokens; no gate
+            with scope("moe/shared"):
+                out = out + _gated(cfg, cfg.expert_act, x, blk["ws1"],
+                                   blk.get("ws3"), blk["ws2"])
         if with_chosen:
-            return h + out.reshape(B, T, D), aux, chosen.reshape(B, T, -1)
-        return h + out.reshape(B, T, D), aux
+            return h + out, aux, chosen.reshape(B, T, -1)
+        return h + out, aux
 
     def expert_fn(p, tokens):
         y = jax.nn.relu(column_parallel_dense(tokens, p["w1"]))
@@ -1285,11 +1516,12 @@ def _mlp(cfg: TransformerConfig, h, blk, with_chosen=False):
     return h + out.reshape(B, T, D), aux
 
 
-def _block(cfg: TransformerConfig, h, blk, kind=None, with_chosen=False):
+def _block(cfg: TransformerConfig, h, blk, kind=None, with_chosen=False,
+           sparse=None):
     if cfg.fsdp:
         blk = _fsdp_gather(cfg, blk)
     h = _attention(cfg, h, blk, kind)
-    return _mlp(cfg, h, blk, with_chosen)
+    return _mlp(cfg, h, blk, with_chosen, sparse, scoped=kind is not None)
 
 
 def _scan_layers(cfg: TransformerConfig, layer_fn, carry, blocks):
@@ -1297,29 +1529,39 @@ def _scan_layers(cfg: TransformerConfig, layer_fn, carry, blocks):
     the leading (layer) axis of ``blocks``.  Under a ``layer_pattern``
     one compiled body cannot serve every layer (a kind's window is a
     static argument of the kernel), so the scan runs over whole periods
-    and its body unrolls the period's kinds; the stack keeps its
+    and its body unrolls the period's kinds.  One stack keeps its
     ``(layers, ...)`` layout and is only viewed as ``(periods, kinds,
-    ...)`` here.  ``y`` comes back stacked by layer either way."""
+    ...)`` here; where the kinds differ in shape ``blocks`` is a tuple
+    of stacks over the periods, one per position
+    (``cfg.blocks_by_position``).  ``y`` comes back stacked by layer
+    either way."""
     kinds = cfg.layer_pattern
     if not kinds:
         return lax.scan(lambda c, blk: layer_fn(c, blk, None), carry, blocks)
     n = len(kinds)
-    layers = jax.tree.leaves(blocks)[0].shape[0]
-    if layers % n:
-        raise ValueError(
-            f"{layers} layers on this pipeline stage are not whole "
-            f"periods of the {n}-layer pattern")
+    if isinstance(blocks, tuple):
+        periods = jax.tree.leaves(blocks)[0].shape[0]
+        layers, by_period = periods * n, blocks
+    else:
+        layers = jax.tree.leaves(blocks)[0].shape[0]
+        if layers % n:
+            raise ValueError(
+                f"{layers} layers on this pipeline stage are not whole "
+                f"periods of the {n}-layer pattern")
+        by_period = jax.tree.map(
+            lambda a: a.reshape(layers // n, n, *a.shape[1:]), blocks)
 
     def period(c, blks):
         ys = []
         for j, kind in enumerate(kinds):
-            c, y = layer_fn(c, jax.tree.map(lambda a: a[j], blks), kind)
+            blk = blks[j] if isinstance(blks, tuple) else jax.tree.map(
+                lambda a: a[j], blks)
+            c, y = layer_fn(c, blk, kind)
             ys.append(y)
         return c, None if ys[0] is None else jax.tree.map(
             lambda *a: jnp.stack(a), *ys)
 
-    carry, ys = lax.scan(period, carry, jax.tree.map(
-        lambda a: a.reshape(layers // n, n, *a.shape[1:]), blocks))
+    carry, ys = lax.scan(period, carry, by_period)
     return carry, ys if ys is None else jax.tree.map(
         lambda a: a.reshape(layers, *a.shape[2:]), ys)
 
@@ -1426,9 +1668,15 @@ def transformer_backbone(cfg: TransformerConfig, params, tokens):
         # over the size-1 axis is a free re-replication (vma discipline).
         # aux derives from h so it inherits the batch axes' variance too.
         vary = partial(lax.pcast, axis_name=("pipe",), to="varying")
-        aux0 = jnp.sum(h * 0, dtype=jnp.float32)
+        aux = jnp.sum(h * 0, dtype=jnp.float32)
+        # the layers that lead run ahead of the scan, each under the
+        # same checkpoint (their blocks are not pipe-sharded)
+        for blk, kind in zip(params.get("leading", ()), cfg.leading_layers):
+            h, a = cfg.checkpoint_fn(partial(
+                _block, cfg, kind=kind, sparse=cfg.leading_sparse))(h, blk)
+            aux = aux + a
         (h, aux), _ = _scan_layers(
-            cfg, body, (vary(h), vary(aux0)), blocks)
+            cfg, body, (vary(h), vary(aux)), blocks)
         h = lax.psum(h, "pipe")
         aux = lax.psum(aux, "pipe")
 
@@ -1477,10 +1725,11 @@ def lm_loss(cfg: TransformerConfig, params, inputs, targets):
 
 
 def expert_choices(mesh_cfg, cfg: TransformerConfig, params, tokens):
-    """``(n_layers, B, T, router_top_k)`` int32: the experts every
-    token chose in every layer, held here or not.  A forward pass
-    through the step's own blocks and router (dropless dispatch), for
-    counters and comparisons.  Unpipelined meshes only."""
+    """``(sparse layers, B, T, router_top_k)`` int32: the experts every
+    token chose in every sparse layer (a dense layer has no row), held
+    here or not.  A forward pass through the step's own blocks and
+    router (dropless dispatch), for counters and comparisons.
+    Unpipelined meshes only."""
     _check_mesh(mesh_cfg, cfg)
     if mesh_cfg.mesh.shape.get("pipe", 1) > 1 or cfg.virtual_pipe > 1:
         raise ValueError("expert_choices reads an unpipelined layer stack")
@@ -1489,6 +1738,12 @@ def expert_choices(mesh_cfg, cfg: TransformerConfig, params, tokens):
         h = _embed(cfg, params, tokens)
         blocks = jax.tree.map(
             lambda a: jnp.squeeze(a, axis=0), params["blocks"])
+        leading = []
+        for blk, kind in zip(params.get("leading", ()), cfg.leading_layers):
+            out = _block(cfg, h, blk, kind, with_chosen=cfg.leading_sparse,
+                         sparse=cfg.leading_sparse)
+            h = out[0]
+            leading += out[2:]      # a dense layer chose nothing
 
         def body(h, blk, kind):
             h, _, chosen = _block(cfg, h, blk, kind, with_chosen=True)
@@ -1496,7 +1751,9 @@ def expert_choices(mesh_cfg, cfg: TransformerConfig, params, tokens):
 
         vary = partial(lax.pcast, axis_name=("pipe",), to="varying")
         _, chosen = _scan_layers(cfg, body, vary(h), blocks)
-        return lax.psum(chosen, "pipe")
+        chosen = lax.psum(chosen, "pipe")
+        return jnp.concatenate([jnp.stack(leading), chosen]) \
+            if leading else chosen
 
     return jax.jit(jax.shard_map(
         tracing_for_mesh(mesh_cfg.mesh, fwd), mesh=mesh_cfg.mesh,
@@ -1506,9 +1763,10 @@ def expert_choices(mesh_cfg, cfg: TransformerConfig, params, tokens):
 
 
 def expert_load(mesh_cfg, cfg: TransformerConfig, params, tokens):
-    """``(n_layers, n_experts)`` int32: the rows every layer's router
-    sent to each expert for these tokens (all ``router_top_k`` choices
-    of each token): what the grouped products of a step have to do."""
+    """``(sparse layers, n_experts)`` int32: the rows every sparse
+    layer's router sent to each expert for these tokens (all
+    ``router_top_k`` choices of each token): what the grouped products
+    of a step have to do."""
     chosen = expert_choices(mesh_cfg, cfg, params, tokens)
     return jax.vmap(lambda c: jnp.zeros((cfg.n_experts,), jnp.int32).at[
         c.reshape(-1)].add(1))(chosen)
@@ -1620,14 +1878,30 @@ def _check_mesh(mesh_cfg, cfg: TransformerConfig):
         raise ValueError(
             f"n_heads={cfg.n_heads} must be divisible by the model mesh "
             f"axis ({mp})")
+    for kind in cfg.layer_pattern + cfg.leading_layers:
+        if cfg.heads_of(kind) % mp:
+            raise ValueError(
+                f"{kind.name}: n_heads={kind.n_heads} must be divisible "
+                f"by the model mesh axis ({mp})")
+    if (cfg.leading_layers or cfg.blocks_by_position) and (
+            mesh_cfg.mesh.shape.get("pipe", 1) > 1 or cfg.virtual_pipe > 1
+            or cfg.num_microbatches > 1
+            or cfg.pipeline_schedule != "gpipe"):
+        raise ValueError(
+            "leading_layers and layer kinds with query heads of their own "
+            "(a stack per position of the pattern) run on an unpipelined "
+            "mesh only: no pipeline schedule gives its first stage the "
+            "leading layers or splits a tuple of stacks into stages yet "
+            "(pipe axis 1, num_microbatches 1, virtual_pipe 1, gpipe)")
     if cfg.kv_heads % mp:
         raise ValueError(
             f"n_kv_heads={cfg.kv_heads} must be divisible by the model "
             f"mesh axis ({mp}); raise n_kv_heads or shrink the model "
             "axis (shared kv heads shard over the same axis as query "
             "heads)")
-    if cfg.attention == "ulysses" and sp > 1 \
-            and (cfg.n_heads // mp) % sp:
+    if cfg.attention == "ulysses" and sp > 1 and any(
+            (cfg.heads_of(k) // mp) % sp
+            for k in (None,) + cfg.layer_pattern + cfg.leading_layers):
         raise ValueError(
             f"attention='ulysses' splits query heads over the seq axis: "
             f"n_heads/model ({cfg.n_heads}/{mp}) must be divisible by "

@@ -175,20 +175,37 @@ def expert_parallel_moe(
 # --------------------------------------------------------------------- #
 
 
-def route_top_k(x, router_w, top_k: int):
-    """``(probs, top_i, gates)`` of a linear softmax router, in float32
-    whatever the compute dtype (input, product and softmax): a choice
-    that flips between two near-equal experts moves a whole expert's
-    output, which rounding the logits to bf16 does far more often.
-    ``gates`` are the winners' probabilities renormalised over the k
-    chosen (``top_k == 1`` keeps the raw Switch gate, as the capacity
-    dispatch does)."""
+def route_top_k(x, router_w, top_k: int, score: str = "softmax",
+                scale: float = 1.0):
+    """``(probs, top_i, gates)`` of a linear router, in float32 whatever
+    the compute dtype (input, product and score): a choice that flips
+    between two near-equal experts moves a whole expert's output, which
+    rounding the logits to bf16 does far more often.
+
+    ``score="softmax"``: ``probs`` is the softmax over the experts and
+    ``gates`` the winners' probabilities renormalised over the k chosen
+    (``top_k == 1`` keeps the raw Switch gate, as the capacity dispatch
+    does).  ``score="sigmoid"``: every expert is scored on its own,
+    ``s = sigmoid(logits)``; the k largest win, ``gates`` are their
+    scores normalised over the k chosen, and ``probs`` is ``s`` over its
+    sum across the experts, the distribution the balancing loss needs.
+    ``scale`` multiplies the gates (a routed scaling factor)."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)             # (N, E)
-    top_p, top_i = lax.top_k(probs, top_k)              # (N, k)
-    gates = top_p if top_k == 1 else \
-        top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if score == "sigmoid":
+        s = jax.nn.sigmoid(logits)                      # (N, E)
+        top_s, top_i = lax.top_k(s, top_k)              # (N, k)
+        gates = top_s / jnp.sum(top_s, axis=-1, keepdims=True)
+        probs = s / jnp.sum(s, axis=-1, keepdims=True)
+    elif score == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)         # (N, E)
+        top_p, top_i = lax.top_k(probs, top_k)          # (N, k)
+        gates = top_p if top_k == 1 else \
+            top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    else:
+        raise ValueError(f"router score {score!r} not in (softmax, sigmoid)")
+    if scale != 1.0:
+        gates = gates * scale
     return probs, top_i, gates
 
 
@@ -321,6 +338,8 @@ def expert_parallel_moe_dropless(
     *,
     top_k: int,
     first_expert: int = 0,
+    score: str = "softmax",
+    scale: float = 1.0,
     axis_name: str = "expert",
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Top-k mixture of experts without capacity: every (token, choice)
@@ -347,6 +366,8 @@ def expert_parallel_moe_dropless(
         experts' network over rows sorted by local expert
         (:func:`grouped_dense` products).
       top_k: experts per token (static; 1 <= k <= E).
+      score, scale: the router's score function and the factor its
+        gates carry (:func:`route_top_k`).
 
     Returns ``(out, aux, chosen)``: ``out`` ``(N, D)``; ``aux`` the
     global balancing loss ``E * sum_e f_e * P_e`` over all ``E`` columns
@@ -364,7 +385,8 @@ def expert_parallel_moe_dropless(
             f"experts [{first_expert}, {first_expert + G}) held, of {E}")
 
     with jax.named_scope("moe/route"):
-        probs, top_i, gates = route_top_k(x, router_w, top_k)
+        probs, top_i, gates = route_top_k(
+            x, router_w, top_k, score, scale)
         choice = top_i.reshape(-1) - first_expert       # (N*k,)
         held = (choice >= 0) & (choice < G)
         order, inv, sizes = _sort_by_group(jnp.where(held, choice, G), G)

@@ -249,3 +249,48 @@ def test_mellum_cell_step_fits_and_holds_no_capacity_tensor(topo):
                     and dims[1] == experts), dims
     gib = program_bytes(compiled) / 2 ** 30
     assert 10 <= gib <= 14.5, f"{gib:.2f} GiB"
+
+
+def test_laguna_cell_step_fits_and_pads_no_heads(topo):
+    """The benchmark's Laguna cell at its real size (2 x 8,192 tokens, a
+    leading dense layer and four sparse ones, 32 of 256 experts held),
+    through the cell's own files and its driver's mapping: each layer's
+    flash kernels are there once at its own head count (no tensor of a
+    48-head layer has 64 heads), the grouped expert kernels and both
+    new scopes are in the program, and the compiled step needs between
+    10 and 14.5 GiB of the chip's 16 at the traffic file's
+    ``loss_chunk`` (the sizing rule of ISSUE 30)."""
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.lib import cells, scopes, scopes_mixed
+    from benchmarks.lib.harness import build_optimizer, program_bytes
+    from chainermn_tpu.parallel import MeshConfig
+
+    cell, cfg, job = cells.load_cell("laguna-xs2-l5-ep8-train-seq8192")
+    pcfg = cells.module("drivers", job["driver"])._program_config(cfg, job)
+    assert pcfg.blocks_by_position and len(pcfg.leading_layers) == 1
+    compiled = _compile_step(
+        MeshConfig(devices=topo.devices[:cell["chips"]], **job["mesh"]),
+        pcfg, build_optimizer(cfg["optimizer"]), job["batch"], job["seq"])
+    text = compiled.as_text()
+    # forward, dq and dkv in each layer, and no second forward: three
+    # sliding layers, the leading full layer and the period's
+    assert _flash_kernels(text, "attn/sliding") == 3 * 3
+    assert _flash_kernels(text, "attn/full") == 2 * 3
+    grouped = [n for n, scope in scopes.instruction_scopes(text).items()
+               if scope == "moe/experts" and n.startswith("ragged-dot-none")]
+    assert len(grouped) == 4 * 12
+    assert set(scopes_mixed.instruction_scopes(text).values()) == {
+        "moe/shared", "mlp/dense"}
+    # the kernels' operands: (batch x heads, 8192, 128) at 64 and at 48
+    for line in text.splitlines():
+        if "pallas_call" in line and "tpu_custom_call" in line:
+            heads = 96 if "attn/full" in line else 128
+            assert f"bf16[{heads},8192,128]" in line, line[:200]
+    gib = program_bytes(compiled) / 2 ** 30
+    assert 10 <= gib <= 14.5, f"{gib:.2f} GiB"
+

@@ -156,16 +156,34 @@ def _typed_kw():
         AttentionKind("full", rope_theta=5e5)))
 
 
+def _gated_kw():
+    """A leading layer, then periods whose kinds differ in query heads
+    (2 = the key-value heads: fused q/k/v; 4: grouped), every layer with
+    the gate a head between the kernel and the output projection."""
+    from chainermn_tpu.models import AttentionKind
+
+    full = AttentionKind("full", rope_theta=5e5, rotary_share=0.5)
+    return dict(pos_embedding="rope", n_layers=3, n_kv_heads=2,
+                attn_gate="per_head", leading_layers=(full,),
+                layer_pattern=(AttentionKind(
+                    "sliding", window=8, rope_theta=5e5, n_heads=2), full))
+
+
 @pytest.mark.parametrize("policy", ["full", "dots"])
-@pytest.mark.parametrize("layers", ["uniform", "window+full"])
+@pytest.mark.parametrize("layers", ["uniform", "window+full",
+                                    "leading+gated+heads"])
 def test_flash_forward_runs_once_under_remat(monkeypatch, layers, policy):
     """The block's checkpoint keeps the kernel's own ``o`` and ``lse``:
     the traced gradient of the scanned blocks holds three kernels a
     layer kind (forward, dq, dkv), where plain ``jax.checkpoint`` holds
     four (the forward again), and the gradients are those of
     ``remat=False`` and of plain ``jax.checkpoint`` to the last bit."""
-    kw = dict(attention="flash", **(_typed_kw() if "+" in layers else {}))
-    kinds = len(kw.get("layer_pattern", (None,)))
+    kw = dict(attention="flash", **(
+        _gated_kw() if "gated" in layers
+        else _typed_kw() if "+" in layers else {}))
+    # the gate's backward reads the kernel's o, which is the saved one
+    kinds = len(kw.get("layer_pattern", (None,))) \
+        + len(kw.get("leading_layers", ()))
     toks = tokens()
     x, y = toks[:, :T], toks[:, 1:]
     cfg = tiny_cfg(remat=True, remat_policy=policy, **kw)
