@@ -171,8 +171,9 @@ class TransformerConfig:
     flash_bwd_block_k: int = 0
     attention_window: int = 0  # 0 => full causal; W>0 => sliding causal
     # window (token t attends to (t-W, t]): Mistral-style local
-    # attention; the flash kernel and the ring schedule skip fully
-    # out-of-window blocks, so long-context FLOPs scale with W not T
+    # attention; the flash kernels' grids walk the band's blocks alone
+    # and the ring schedule stops at the window's reach, so
+    # long-context FLOPs and grid steps scale with W not T
     pos_embedding: str = "learned"  # "learned" (absolute table, the
     # "pos" param) | "rope" (rotary on q/k per block — no position
     # parameters; the long-context default: relative by construction,

@@ -10,19 +10,31 @@ attention.  This kernel backs the flagship transformer's
 
 Design (flash-attention v2 schedule, TPU-shaped):
 
-- 3-D grid ``(B·H, T_q/block_q, T_k/block_k)`` with the K dimension
+- 3-D grid ``(B·H, T_q/block_q, width)`` with the K dimension
   innermost and ``arbitrary`` semantics: the Pallas pipeline
   double-buffers each K/V block's HBM→VMEM DMA behind the previous
   block's math, and only ``block_k`` tokens of K/V ever sit in VMEM (so
   context length is bounded by HBM, not the 16 MB of VMEM);
+- **the grid visits the block pairs the mask needs**
+  (:func:`_visit_plan`, one function for all three kernels): for a
+  query block the needed key blocks are one contiguous run.  With a
+  ``window`` the innermost extent ``width`` is the band's width in
+  blocks (2 of 8 at 8,192 tokens, 1,024-wide blocks and a window of
+  512 or 1,024) and step ``s`` reads block ``first + s``; without one
+  it stays ``T_k/block_k`` and the K/V index map holds at the run's
+  last block, so the steps past the diagonal issue no copy.  The
+  ``pl.when`` predicate is still the guard, on the unclamped block
+  index: a step held at an edge computes nothing;
 - **online softmax** in fp32 VMEM scratch (running max ``m``,
   normaliser ``l``, accumulator) — no (T, T) score matrix in HBM;
 - matmuls via ``jnp.dot(..., preferred_element_type=float32)`` so bf16
   inputs hit the MXU at full rate with fp32 accumulation;
-- causal masking in *global* positions: ``q_offset``/``k_offset`` ride
-  in SMEM, so they may be **traced values** (ring attention's rotating
-  block offsets) — fully-masked K blocks skip their FLOPs via
-  ``pl.when``;
+- causal masking in *global* positions: ``q_offset``/``k_offset`` are
+  the scalar-prefetch operand (SMEM, read by the index maps and the
+  kernels), so they may be **traced values** (ring attention's
+  rotating block offsets): the band's width does not depend on them,
+  only its place, and traced offsets get the widest band a window can
+  touch (3 blocks above) where Python ints get the exact one;
 - optionally returns the softmax log-sum-exp, with its own VJP path, so
   sequence-sharded callers can combine per-shard partial attentions
   exactly (``o = Σ o_i·exp(lse_i − lse)``);
@@ -36,13 +48,16 @@ from __future__ import annotations
 
 import contextvars
 import functools
-from typing import Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from chainermn_tpu.utils.metrics import get_registry
 
 __all__ = ["flash_attention", "flash_attention_supported",
            "interpret_kernels", "tracing_for_mesh", "FLASH_RESIDUAL_NAMES"]
@@ -96,34 +111,159 @@ def _positions(off, base, count):
 
 
 # --------------------------------------------------------------------- #
+# which block pairs the mask needs
+# --------------------------------------------------------------------- #
+
+
+def _block_needed(i, j, q_off, k_off, Bq, Bk, causal, window):
+    """Does (q block ``i``, k block ``j``) hold an allowed (query, key)
+    position?  Python ints or traced scalars."""
+    if not causal:
+        # a tautology that must stay TRACED in the kernels: an
+        # unconditioned kernel body trips the hlo-interpreter's vma
+        # check under shard_map (jax bug); pl.when(cond) routes
+        # discharge safely.
+        return j >= 0
+    # K blocks entirely in this q block's future contribute nothing
+    needed = q_off + (i + 1) * Bq - 1 >= k_off + j * Bk
+    if window is not None:
+        # nor do K blocks entirely BEFORE the window of every q row
+        needed &= k_off + (j + 1) * Bk - 1 >= q_off + i * Bq - (window - 1)
+    return needed
+
+
+class _VisitPlan(NamedTuple):
+    """The block pairs one kernel's grid visits, derived from the mask.
+
+    The grid is ``(B·H, n_outer, width)``: for outer block ``o`` the
+    needed inner blocks are the contiguous run ``[lo, hi] = run(o,
+    q_off, k_off)`` (unclamped: it may leave ``[0, n_inner)`` at the
+    sequence's edges), and step ``s`` stands for the inner block
+    ``first(o) + s`` — see :meth:`inner`.  ``pairs_computed`` (steps
+    whose in-kernel predicate holds) and ``pairs_needed`` (block pairs
+    with an allowed position) are per head, and ``None`` where the
+    offsets are traced."""
+    outer: str            # "q": key blocks innermost; "k": dkv's grid
+    n_outer: int
+    n_inner: int
+    width: int
+    narrow: bool          # width < n_inner: steps start at the band
+    run: Callable
+    pairs_computed: Optional[int]
+    pairs_needed: Optional[int]
+
+    @property
+    def steps(self) -> int:
+        return self.n_outer * self.width
+
+    def inner(self, o, s, q_off, k_off):
+        """``(u, blk)`` of grid step ``(o, s)``: the inner block index
+        the step stands for, and the block its operands are copied
+        from.  ``u`` is what the kernel's predicate and positions use;
+        ``blk`` is ``u`` held inside the run and the tensor, so a step
+        with nothing to compute maps to the block beside it and the
+        pipeline issues no copy for it."""
+        lo, hi = self.run(o, q_off, k_off)
+        u = (s + jnp.maximum(lo, 0)) if self.narrow else s
+        return u, jnp.clip(jnp.clip(u, lo, hi), 0, self.n_inner - 1)
+
+
+def _visit_plan(T_q, T_k, block_q, block_k, causal, window,
+                offsets=None, outer="q") -> _VisitPlan:
+    """The plan of the forward and dq grids (``outer="q"``: key blocks
+    innermost) or of dkv's (``outer="k"``).  ``offsets`` is ``(q_offset,
+    k_offset)`` as Python ints, or ``None`` where they are traced: the
+    band's width does not depend on them, only its place, so the extent
+    is then the most blocks a band can touch wherever it sits."""
+    nq, nk = T_q // block_q, T_k // block_k
+    n_outer, n_inner = (nq, nk) if outer == "q" else (nk, nq)
+    b_outer, b_inner = ((block_q, block_k) if outer == "q"
+                        else (block_k, block_q))
+    reach = None if window is None else window - 1
+
+    def run(o, q_off, k_off):
+        # first and last position, relative to the inner tensor's
+        # start, that any row of outer block ``o`` is allowed to meet
+        if not causal:
+            return 0, n_inner - 1
+        if outer == "q":
+            last = q_off - k_off + (o + 1) * block_q - 1
+            lo = 0 if reach is None else (
+                q_off - k_off + o * block_q - reach) // block_k
+            return lo, last // block_k
+        first = k_off - q_off + o * block_k
+        hi = n_inner - 1 if reach is None else (
+            k_off - q_off + (o + 1) * block_k - 1 + reach) // block_q
+        return first // block_q, hi
+
+    held = None             # each run inside the tensor, static offsets
+    if offsets is not None:
+        held = [(max(lo, 0), min(hi, n_inner - 1)) for lo, hi in
+                (run(o, *offsets) for o in range(n_outer))]
+    width = n_inner
+    if reach is not None:
+        if held is None:
+            width = -(-(b_outer + reach - 1) // b_inner) + 1
+        else:
+            width = max(1, max(hi - lo + 1 for lo, hi in held))
+        width = min(width, n_inner)
+    narrow = width < n_inner
+    computed = needed = None
+    if held is not None:
+        needed = sum(max(0, hi - lo + 1) for lo, hi in held)
+        # outer block o's steps stand for first .. first + width - 1
+        computed = sum(
+            max(0, min(hi, first + width - 1) - max(lo, first) + 1)
+            for lo, hi in held for first in [lo if narrow else 0])
+    return _VisitPlan(outer, n_outer, n_inner, width, narrow, run,
+                      computed, needed)
+
+
+def _count_visits(plan):
+    """``flash/grid_steps`` and ``flash/pairs_computed`` (a head, added
+    once for every kernel call site as it is traced): how often the
+    grid engages — 16 steps for 15 computed pairs where a windowed
+    kernel at 8 x 8 blocks once took 64."""
+    reg = get_registry()
+    reg.inc("flash/grid_steps", plan.steps)
+    if plan.pairs_computed is not None:
+        reg.inc("flash/pairs_computed", plan.pairs_computed)
+
+
+# --------------------------------------------------------------------- #
 # forward
 # --------------------------------------------------------------------- #
 
 
+def _step(plan, offs_ref, Bq, Bk, causal, window):
+    """What grid step ``(o, s)`` stands for: ``(first, last, i, j,
+    q_off, k_off, needed)``.  ``first``/``last`` mark the steps that
+    initialise and write the outer block's output (every outer block
+    has both, needed pairs or none); the inner index is the UNCLAMPED
+    one, so a step whose operands were held at the run's or the
+    tensor's edge computes nothing and no pair is counted twice."""
+    o, s = pl.program_id(1), pl.program_id(2)
+    q_off, k_off = offs_ref[0], offs_ref[1]
+    u, _ = plan.inner(o, s, q_off, k_off)
+    i, j = (o, u) if plan.outer == "q" else (u, o)
+    needed = _block_needed(i, j, q_off, k_off, Bq, Bk, causal, window)
+    if plan.narrow:
+        needed &= u < plan.n_inner
+    return s == 0, s == plan.width - 1, i, j, q_off, k_off, needed
+
+
 def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc_ref, l_ref, m_ref, *, scale, causal, window):
-    i, j = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+                acc_ref, l_ref, m_ref, *, scale, causal, window, plan):
     Bq, D = q_ref.shape[1:]
     Bk = k_ref.shape[1]
-    q_off, k_off = offs_ref[0], offs_ref[1]
+    first, last, i, j, q_off, k_off, needed = _step(
+        plan, offs_ref, Bq, Bk, causal, window)
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         l_ref[...] = jnp.zeros_like(l_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG)
-
-    # K blocks entirely in this q block's future contribute nothing
-    # Non-causal predicate is a tautology but must stay TRACED: an
-    # unconditioned kernel body trips the hlo-interpreter's vma check
-    # under shard_map (jax bug); pl.when(cond) routes discharge safely.
-    needed = (j >= 0) if not causal else (
-        q_off + (i + 1) * Bq - 1 >= k_off + j * Bk)
-    if window is not None:
-        # also skip K blocks entirely BEFORE the window of every q row
-        needed &= (k_off + (j + 1) * Bk - 1
-                   >= q_off + i * Bq - (window - 1))
 
     @pl.when(needed)
     def _():
@@ -159,7 +299,7 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_ref[...] = _bcast(l * alpha + p.sum(axis=-1))
         m_ref[...] = _bcast(m_new)
 
-    @pl.when(j == nk - 1)
+    @pl.when(last)
     def _():
         l = l_ref[:, 0]
         safe = jnp.maximum(l, 1e-30)   # fully-masked rows stay finite
@@ -187,25 +327,15 @@ def _recompute_p(q, kb, scale, lse, causal, window, q_off, k_off, i, j,
 
 
 def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, dq_acc, *, scale, causal, window):
-    i, j = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+               dq_ref, dq_acc, *, scale, causal, window, plan):
     Bq, D = q_ref.shape[1:]
     Bk = k_ref.shape[1]
-    q_off, k_off = offs_ref[0], offs_ref[1]
+    first, last, i, j, q_off, k_off, needed = _step(
+        plan, offs_ref, Bq, Bk, causal, window)
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
-
-    # Non-causal predicate is a tautology but must stay TRACED: an
-    # unconditioned kernel body trips the hlo-interpreter's vma check
-    # under shard_map (jax bug); pl.when(cond) routes discharge safely.
-    needed = (j >= 0) if not causal else (
-        q_off + (i + 1) * Bq - 1 >= k_off + j * Bk)
-    if window is not None:
-        needed &= (k_off + (j + 1) * Bk - 1
-                   >= q_off + i * Bq - (window - 1))
 
     @pl.when(needed)
     def _():
@@ -225,32 +355,23 @@ def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_acc[...] += jnp.dot(ds.astype(kb.dtype), kb,
                                preferred_element_type=jnp.float32)
 
-    @pl.when(j == nk - 1)
+    @pl.when(last)
     def _():
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal, window):
-    j, i = pl.program_id(1), pl.program_id(2)   # k block outer, q inner
-    nq = pl.num_programs(2)
+                dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal, window,
+                plan):
     Bk, D = k_ref.shape[1:]
     Bq = q_ref.shape[1]
-    q_off, k_off = offs_ref[0], offs_ref[1]
+    first, last, i, j, q_off, k_off, needed = _step(   # k outer, q inner
+        plan, offs_ref, Bq, Bk, causal, window)
 
-    @pl.when(i == 0)
+    @pl.when(first)
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
-
-    # Non-causal predicate is a tautology but must stay TRACED: an
-    # unconditioned kernel body trips the hlo-interpreter's vma check
-    # under shard_map (jax bug); pl.when(cond) routes discharge safely.
-    needed = (j >= 0) if not causal else (
-        q_off + (i + 1) * Bq - 1 >= k_off + j * Bk)
-    if window is not None:
-        needed &= (k_off + (j + 1) * Bk - 1
-                   >= q_off + i * Bq - (window - 1))
 
     @pl.when(needed)
     def _():
@@ -271,7 +392,7 @@ def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] += jnp.dot(ds.astype(q.dtype).T, q,
                                preferred_element_type=jnp.float32)
 
-    @pl.when(i == nq - 1)
+    @pl.when(last)
     def _():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -282,25 +403,17 @@ def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 # --------------------------------------------------------------------- #
 
 
-def _smem_spec():
-    return pl.BlockSpec(memory_space=pltpu.SMEM)
+def _outer_spec(block, width):
+    """Blocks that follow the grid's outer axis (the output's side)."""
+    return pl.BlockSpec((1, block, width), lambda b, o, s, offs: (b, o, 0))
 
 
-def _q_spec(block_q, D):
-    return pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
-
-
-def _k_spec(block_k, D):
-    return pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0))
-
-
-def _qvec_spec(block_q):
-    return pl.BlockSpec((1, block_q, _LANE), lambda b, i, j: (b, i, 0))
-
-
-def _params():
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+def _inner_spec(plan, block, width):
+    """Blocks that follow ``plan``'s walk of the inner axis; the offsets
+    are the scalar-prefetch operand, so the map may read them."""
+    return pl.BlockSpec(
+        (1, block, width),
+        lambda b, o, s, offs: (b, plan.inner(o, s, offs[0], offs[1])[1], 0))
 
 
 def _sds(shape, dtype, like):
@@ -309,17 +422,37 @@ def _sds(shape, dtype, like):
     return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
-def _fwd(q3, k3, v3, offs, scale, causal, window, block_q, block_k,
-         interpret):
+def _call(kernel, plan, BH, in_specs, out_specs, out_shape, scratch_shapes,
+          interpret):
+    """One kernel over ``plan``'s grid, the offsets prefetched to SMEM
+    ahead of the index maps."""
+    _count_visits(plan)
+    return pl.pallas_call(
+        functools.partial(kernel, plan=plan),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(BH, plan.n_outer, plan.width),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret)
+
+
+def _fwd(q3, k3, v3, offs, static_offs, scale, causal, window, block_q,
+         block_k, interpret):
     BH, Tq, D = q3.shape
     Tk = k3.shape[1]
-    o, lse = pl.pallas_call(
+    plan = _visit_plan(Tq, Tk, block_q, block_k, causal, window,
+                       static_offs)
+    q_spec, k_spec = _outer_spec(block_q, D), _inner_spec(plan, block_k, D)
+    o, lse = _call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           window=window),
-        grid=(BH, Tq // block_q, Tk // block_k),
-        in_specs=[_smem_spec(), _q_spec(block_q, D), _k_spec(block_k, D),
-                  _k_spec(block_k, D)],
-        out_specs=[_q_spec(block_q, D), _qvec_spec(block_q)],
+        plan, BH,
+        in_specs=[q_spec, k_spec, k_spec],
+        out_specs=[q_spec, _outer_spec(block_q, _LANE)],
         out_shape=[
             _sds((BH, Tq, D), q3.dtype, q3),
             _sds((BH, Tq, _LANE), jnp.float32, q3),
@@ -329,24 +462,23 @@ def _fwd(q3, k3, v3, offs, scale, causal, window, block_q, block_k,
             pltpu.VMEM((block_q, _LANE), jnp.float32),
             pltpu.VMEM((block_q, _LANE), jnp.float32),
         ],
-        compiler_params=_params(),
         interpret=interpret,
     )(offs, q3, k3, v3)
     return o, lse[..., 0]
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
-def _flash(q3, k3, v3, offs, scale, causal, window, block_q, block_k,
-           bwd_block_q, bwd_block_k, interpret):
-    return _fwd(q3, k3, v3, offs, scale, causal, window, block_q,
-                block_k, interpret)
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12))
+def _flash(q3, k3, v3, offs, static_offs, scale, causal, window, block_q,
+           block_k, bwd_block_q, bwd_block_k, interpret):
+    return _fwd(q3, k3, v3, offs, static_offs, scale, causal, window,
+                block_q, block_k, interpret)
 
 
-def _flash_fwd(q3, k3, v3, offs, scale, causal, window, block_q, block_k,
-               bwd_block_q, bwd_block_k, interpret):
-    o, lse = _fwd(q3, k3, v3, offs, scale, causal, window, block_q,
-                  block_k, interpret)
+def _flash_fwd(q3, k3, v3, offs, static_offs, scale, causal, window,
+               block_q, block_k, bwd_block_q, bwd_block_k, interpret):
+    o, lse = _fwd(q3, k3, v3, offs, static_offs, scale, causal, window,
+                  block_q, block_k, interpret)
     # named so that an enclosing jax.checkpoint can keep them: they are
     # the only residuals the forward kernel produces, and a policy that
     # saves both (TransformerConfig.checkpoint_fn) leaves the backward
@@ -358,8 +490,8 @@ def _flash_fwd(q3, k3, v3, offs, scale, causal, window, block_q, block_k,
     return (o, lse), (q3, k3, v3, offs, o, lse)
 
 
-def _flash_bwd(scale, causal, window, fwd_block_q, fwd_block_k,
-               block_q, block_k, interpret, res, cts):
+def _flash_bwd(static_offs, scale, causal, window, fwd_block_q,
+               fwd_block_k, block_q, block_k, interpret, res, cts):
     # the backward kernels tile on their OWN block sizes: dq's q-outer
     # grid and dkv's k-outer revisit pattern have different optimal
     # shapes than the forward (a retune is read in the OPT cells'
@@ -376,37 +508,30 @@ def _flash_bwd(scale, causal, window, fwd_block_q, fwd_block_k,
     delta = delta - dlse.astype(jnp.float32)
     delta = jnp.broadcast_to(delta[..., None], delta.shape + (_LANE,))
     lse3 = jnp.broadcast_to(lse[..., None], lse.shape + (_LANE,))
+    kernel_args = dict(scale=scale, causal=causal, window=window)
 
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          window=window),
-        grid=(BH, Tq // block_q, Tk // block_k),
-        in_specs=[
-            _smem_spec(),
-            _q_spec(block_q, D), _k_spec(block_k, D), _k_spec(block_k, D),
-            _q_spec(block_q, D), _qvec_spec(block_q), _qvec_spec(block_q),
-        ],
-        out_specs=_q_spec(block_q, D),
+    plan = _visit_plan(Tq, Tk, block_q, block_k, causal, window,
+                       static_offs)
+    q_spec, k_spec = _outer_spec(block_q, D), _inner_spec(plan, block_k, D)
+    qvec_spec = _outer_spec(block_q, _LANE)
+    dq = _call(
+        functools.partial(_dq_kernel, **kernel_args), plan, BH,
+        in_specs=[q_spec, k_spec, k_spec, q_spec, qvec_spec, qvec_spec],
+        out_specs=q_spec,
         out_shape=_sds((BH, Tq, D), q3.dtype, q3),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=_params(),
         interpret=interpret,
     )(offs, q3, k3, v3, do, lse3, delta)
 
-    # k outer / q inner grid: swap the roles of the index maps
-    kq_spec = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
-    qk_spec = pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0))
-    qkvec_spec = pl.BlockSpec(
-        (1, block_q, _LANE), lambda b, j, i: (b, i, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          window=window),
-        grid=(BH, Tk // block_k, Tq // block_q),
-        in_specs=[
-            _smem_spec(),
-            qk_spec, kq_spec, kq_spec, qk_spec, qkvec_spec, qkvec_spec,
-        ],
-        out_specs=[kq_spec, kq_spec],
+    # k outer / q inner grid: the two sides swap roles
+    plan = _visit_plan(Tq, Tk, block_q, block_k, causal, window,
+                       static_offs, outer="k")
+    k_spec, q_spec = _outer_spec(block_k, D), _inner_spec(plan, block_q, D)
+    qvec_spec = _inner_spec(plan, block_q, _LANE)
+    dk, dv = _call(
+        functools.partial(_dkv_kernel, **kernel_args), plan, BH,
+        in_specs=[q_spec, k_spec, k_spec, q_spec, qvec_spec, qvec_spec],
+        out_specs=[k_spec, k_spec],
         out_shape=[
             _sds((BH, Tk, D), k3.dtype, k3),
             _sds((BH, Tk, D), v3.dtype, v3),
@@ -415,7 +540,6 @@ def _flash_bwd(scale, causal, window, fwd_block_q, fwd_block_k,
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        compiler_params=_params(),
         interpret=interpret,
     )(offs, q3, k3, v3, do, lse3, delta)
     d_offs = jnp.zeros(offs.shape, jax.dtypes.float0)
@@ -471,7 +595,8 @@ def flash_attention(q, k, v, *, causal: bool = False, window=None,
 
     ``q_offset``/``k_offset`` are *global* position offsets of the local
     blocks for sequence-sharded callers — python ints or traced int
-    scalars (they ride to the kernel in SMEM); masking follows global
+    scalars (they ride to the kernel and its index maps in SMEM; python
+    ints also size a windowed grid exactly); masking follows global
     positions exactly like
     :func:`...parallel.ring_attention.local_attention`, with one
     deliberate divergence: a query row whose ENTIRE K range is masked
@@ -515,9 +640,14 @@ def flash_attention(q, k, v, *, causal: bool = False, window=None,
     offs = jnp.asarray(
         jnp.stack([jnp.asarray(q_offset, jnp.int32),
                    jnp.asarray(k_offset, jnp.int32)]))
+    # offsets known as the program is traced place the band exactly;
+    # traced ones (the ring's pairs) leave it room to sit anywhere
+    static_offs = None
+    if all(isinstance(x, (int, np.integer)) for x in (q_offset, k_offset)):
+        static_offs = (int(q_offset), int(k_offset))
     to3 = lambda x: x.transpose(0, 2, 1, 3).reshape(B * H, x.shape[1], D)
-    o, lse = _flash(to3(q), to3(k), to3(v), offs, D ** -0.5, causal,
-                    None if window is None else int(window),
+    o, lse = _flash(to3(q), to3(k), to3(v), offs, static_offs, D ** -0.5,
+                    causal, None if window is None else int(window),
                     block_q, block_k, bwd_bq, bwd_bk, interpret)
     o = o.reshape(B, H, Tq, D).transpose(0, 2, 1, 3)
     if return_lse:

@@ -7,6 +7,8 @@ import numpy as np
 import pytest
 
 from chainermn_tpu.ops.pallas_attention import (
+    _block_needed,
+    _visit_plan,
     flash_attention,
     flash_attention_supported,
 )
@@ -52,8 +54,12 @@ def test_grads_match_oracle(causal):
             np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-5)
 
 
-@pytest.mark.parametrize("bwd_q,bwd_k", [(16, 32), (32, 16), (64, 64)])
-def test_bwd_block_retune_grads_exact(bwd_q, bwd_k):
+@pytest.mark.parametrize("bwd_q,bwd_k,window", [
+    (16, 32, None), (32, 16, None), (64, 64, None),
+    # with a window the two backward grids are bands of their own
+    # widths (dq walks key blocks of bwd_k, dkv query blocks of bwd_q)
+    (16, 32, 24), (32, 16, 24), (16, 32, 48), (32, 16, 5)])
+def test_bwd_block_retune_grads_exact(bwd_q, bwd_k, window):
     """Backward kernels tiled independently of the forward must give
     the same gradients for ANY valid tiling — the correctness side of
     the bwd block retune lever (the OPT cells' flash.ms_per_step
@@ -63,7 +69,8 @@ def test_bwd_block_retune_grads_exact(bwd_q, bwd_k):
     def loss(bq, bk):
         def f(q, k, v):
             o = flash_attention(
-                q, k, v, causal=True, block_q=32, block_k=32,
+                q, k, v, causal=True, window=window, block_q=32,
+                block_k=32,
                 bwd_block_q=bq, bwd_block_k=bk, interpret=True)
             return jnp.sum(o * jnp.cos(o))
         return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
@@ -168,3 +175,213 @@ def test_fully_masked_rows_zero_partial_rows_exact():
     for a, b in zip(g_flash, g_ref):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-5)
+
+
+# ------------------------------------------------------------------ #
+# the visit plan: which block pairs each grid walks
+# ------------------------------------------------------------------ #
+
+
+def _rows_with_a_key(tq, tk, q_off, k_off, window):
+    qpos = q_off + np.arange(tq)[:, None]
+    kpos = k_off + np.arange(tk)[None, :]
+    allow = qpos >= kpos
+    if window is not None:
+        allow &= qpos - kpos < window
+    return allow.any(axis=1)
+
+
+# (T_q, T_k, block_q, block_k, window, q_offset, k_offset); blocks of 16
+# or 32 against windows below, at, one and a half times and far above a
+# block; both block orders; unequal lengths at offsets that put the
+# band's clamped steps at the first query block and the last key block,
+# rows with no key at all, and a band wholly outside the keys
+PLAN_CASES = [
+    (64, 64, 16, 16, 5, 0, 0),
+    (64, 64, 16, 16, 16, 0, 0),
+    (64, 64, 16, 16, 24, 0, 0),
+    (64, 64, 16, 16, 1000, 0, 0),
+    (64, 64, 16, 16, 1, 0, 0),
+    (64, 64, 16, 32, 24, 0, 0),
+    (64, 64, 32, 16, 24, 0, 0),
+    (64, 64, 8, 32, 5, 0, 0),
+    (32, 64, 16, 16, 24, 96, 64),
+    (64, 32, 16, 16, 24, 64, 64),
+    (64, 32, 16, 32, 40, 70, 64),
+    (32, 64, 16, 16, None, 80, 64),
+    (64, 64, 16, 16, 24, 0, 40),
+    (64, 64, 16, 16, 8, 200, 0),
+    (64, 64, 16, 16, None, 0, 64),
+]
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["static", "traced"])
+@pytest.mark.parametrize("tq,tk,bq,bk,window,q_off,k_off", PLAN_CASES)
+def test_band_grid_matches_oracle(tq, tk, bq, bk, window, q_off, k_off,
+                                  traced):
+    """Forward, lse and gradients over the band's grid against the XLA
+    oracle at the same global positions; offsets as Python ints (the
+    band placed exactly) and as traced scalars under jit (the ring's
+    call form: the band given room to sit anywhere).  Rows with no
+    allowed key are the documented divergence: zeros and lse ~ -1e30."""
+    rng = np.random.RandomState(7)
+    mk = lambda t: jnp.asarray(rng.randn(B, t, H, D).astype(np.float32) * .5)
+    q, k, v = mk(tq), mk(tk), mk(tk)
+    live = _rows_with_a_key(tq, tk, q_off, k_off, window)
+    rows = jnp.asarray(live, jnp.float32)[None, :, None, None]
+
+    def flash(q, k, v, q_off, k_off):
+        return flash_attention(
+            q, k, v, causal=True, window=window, q_offset=q_off,
+            k_offset=k_off, block_q=bq, block_k=bk, return_lse=True,
+            interpret=True)
+
+    def oracle(q, k, v, q_off, k_off):
+        return local_attention(q, k, v, causal=True, window=window,
+                               q_offset=q_off, k_offset=k_off)
+
+    def loss(f):
+        def inner(q, k, v, q_off, k_off):
+            o = f(q, k, v, q_off, k_off)
+            o = (o[0] if isinstance(o, tuple) else o) * rows
+            return jnp.sum(o * jnp.cos(o))
+        return inner
+
+    offs = (q_off, k_off)
+    if traced:
+        flash = jax.jit(flash)
+        offs = tuple(jnp.int32(x) for x in offs)
+    out, lse = flash(q, k, v, *offs)
+    ref = oracle(q, k, v, q_off, k_off)
+    np.testing.assert_allclose(
+        np.asarray(out)[:, live], np.asarray(ref)[:, live],
+        rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(out)[:, ~live], 0.0)
+    assert (np.asarray(lse)[:, ~live] < -1e29).all()
+    assert np.isfinite(np.asarray(lse)[:, live]).all()
+
+    grad = jax.grad(loss(flash), argnums=(0, 1, 2))
+    g_flash = (jax.jit(grad) if traced else grad)(q, k, v, *offs)
+    g_ref = jax.grad(loss(oracle), argnums=(0, 1, 2))(q, k, v, q_off, k_off)
+    for a, b in zip(g_flash, g_ref):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-5)
+
+
+def _brute_force_pairs(tq, tk, bq, bk, causal, window, q_off, k_off):
+    """Block pairs (q block, k block) holding an allowed position."""
+    qpos = q_off + np.arange(tq)[:, None]
+    kpos = k_off + np.arange(tk)[None, :]
+    allow = np.ones((tq, tk), bool)
+    if causal:
+        allow &= qpos >= kpos
+        if window is not None:
+            allow &= qpos - kpos < window
+    blocks = allow.reshape(tq // bq, bq, tk // bk, bk).any(axis=(1, 3))
+    return {(int(i), int(j)) for i, j in zip(*np.nonzero(blocks))}
+
+
+def _walk(plan, bq, bk, causal, window, q_off, k_off):
+    """Every step of ``plan``'s grid as the kernels read it: the pairs
+    computed (unclamped index in range and the predicate true), with
+    the block each step's operands were copied from."""
+    pairs, copies = [], 0
+    for o in range(plan.n_outer):
+        was = None
+        for s in range(plan.width):
+            u, blk = (int(x) for x in plan.inner(o, s, q_off, k_off))
+            assert 0 <= blk < plan.n_inner
+            copies += blk != was
+            was = blk
+            i, j = (o, u) if plan.outer == "q" else (u, o)
+            if 0 <= u < plan.n_inner and bool(_block_needed(
+                    i, j, q_off, k_off, bq, bk, causal, window)):
+                assert blk == u, "a computed step reads its own block"
+                pairs.append((i, j))
+    return pairs, copies
+
+
+@pytest.mark.parametrize("causal,window,width,pairs", [
+    (True, 1024, 2, 15), (True, 512, 2, 15), (True, None, 8, 36),
+    (False, None, 8, 64)])
+@pytest.mark.parametrize("outer", ["q", "k"])
+def test_visit_plan_on_the_cells_shapes(causal, window, width, pairs,
+                                        outer):
+    """8,192 tokens in 1,024-wide blocks, the typed cells' kernels: a
+    windowed grid is 8 x 2 for 15 computed pairs a head where it was
+    8 x 8, and a causal one keeps its extent."""
+    plan = _visit_plan(8192, 8192, 1024, 1024, causal, window, (0, 0),
+                       outer)
+    assert (plan.width, plan.steps) == (width, 8 * width)
+    assert plan.pairs_computed == plan.pairs_needed == pairs
+    got, copies = _walk(plan, 1024, 1024, causal, window, 0, 0)
+    assert len(got) == pairs
+    # steps with nothing to compute stay on the block beside them
+    assert copies <= pairs
+    # wherever the band sits it touches at most three blocks here
+    anywhere = _visit_plan(8192, 8192, 1024, 1024, causal, window, None,
+                           outer)
+    assert anywhere.width == (3 if window else 8)
+    assert anywhere.pairs_computed is None
+
+
+def test_visit_plan_walks_exactly_the_needed_pairs():
+    """Over a sweep of small shapes, both grids and both kinds of
+    offsets: the unclamped pairs the walk computes are, each once and
+    in increasing order, the block pairs that hold an allowed
+    position."""
+    n = 0
+    for tq, tk in [(32, 32), (16, 48), (48, 16), (64, 64)]:
+        for bq, bk in [(8, 8), (8, 16), (16, 8), (16, 16)]:
+            for causal, window in [(False, None), (True, None), (True, 1),
+                                   (True, 7), (True, 8), (True, 12),
+                                   (True, 20), (True, 500)]:
+                for q_off, k_off in [(0, 0), (32, 0), (0, 24), (18, 16),
+                                     (300, 0)]:
+                    want = _brute_force_pairs(tq, tk, bq, bk, causal,
+                                              window, q_off, k_off)
+                    for outer in ("q", "k"):
+                        for offsets in ((q_off, k_off), None):
+                            plan = _visit_plan(tq, tk, bq, bk, causal,
+                                               window, offsets, outer)
+                            got, _ = _walk(plan, bq, bk, causal, window,
+                                           q_off, k_off)
+                            assert got == sorted(want, key=(
+                                (lambda p: p) if outer == "q"
+                                else (lambda p: p[::-1]))), (
+                                tq, tk, bq, bk, causal, window, q_off,
+                                k_off, outer, offsets)
+                            if offsets:
+                                assert plan.pairs_computed == len(want)
+                                assert plan.pairs_needed == len(want)
+                            n += 1
+    assert n == 4 * 4 * 8 * 5 * 4
+
+
+@pytest.mark.parametrize("window,steps,pairs", [
+    (512, 16, 15), (1024, 16, 15), (None, 64, 36)])
+def test_grid_counters_after_a_traced_call(window, steps, pairs):
+    """``flash/grid_steps`` and ``flash/pairs_computed`` (a head, one
+    addition for each kernel call site as it is traced) at the typed
+    cells' shapes: forward alone, then forward, dq and dkv."""
+    from chainermn_tpu.utils.metrics import MetricsRegistry, set_registry
+
+    s = jax.ShapeDtypeStruct((1, 8192, 2, 128), jnp.bfloat16)
+
+    def attn(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               interpret=True)
+
+    reg = MetricsRegistry(enabled=True)
+    prev = set_registry(reg)
+    try:
+        jax.eval_shape(attn, s, s, s)
+        assert reg.counter("flash/grid_steps").value == steps
+        assert reg.counter("flash/pairs_computed").value == pairs
+        jax.eval_shape(jax.grad(
+            lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)), s, s, s)
+        assert reg.counter("flash/grid_steps").value == 4 * steps
+        assert reg.counter("flash/pairs_computed").value == 4 * pairs
+    finally:
+        set_registry(prev)

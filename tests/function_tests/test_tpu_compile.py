@@ -51,10 +51,20 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _qkv(sharding, t=T):
-    s = jax.ShapeDtypeStruct((B, t, H, D), jnp.bfloat16,
-                             sharding=sharding)
+def _qkv(sharding, shape=(B, T, H, D)):
+    s = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
     return s, s, s
+
+
+# the typed cells' windowed kernels: 2 x 8,192 tokens, 128-wide heads
+# (32 of them here), windows of 512 and 1,024 in 1,024-wide blocks, so
+# the grid is the band's two blocks and the index maps read the
+# prefetched offsets
+TYPED = (2, 8192, 32, 128)
+FLASH_SHAPES = pytest.mark.parametrize("shape,window", [
+    ((B, T, H, D), None), ((B, T, H, D), 1024), (TYPED, 512),
+    (TYPED, 1024)],
+    ids=["full", "window1024", "8192-window512", "8192-window1024"])
 
 
 def _compile(fn, *args):
@@ -64,33 +74,48 @@ def _compile(fn, *args):
     return compiled
 
 
-@pytest.mark.parametrize("window", [None, 1024], ids=["full", "window1024"])
-def test_flash_forward_compiles(one_chip, window):
+def _flash_kernels(text, scope=""):
+    """The flash kernels' call sites in a compiled program's text (a
+    scanned layer's count once), optionally those under one scope."""
+    return sum('custom_call_target="tpu_custom_call"' in line
+               and "pallas_call" in line and scope in line
+               for line in text.splitlines())
+
+
+@FLASH_SHAPES
+def test_flash_forward_compiles(one_chip, shape, window):
     _compile(lambda q, k, v: flash_attention(
-        q, k, v, causal=True, window=window), *_qkv(one_chip))
+        q, k, v, causal=True, window=window), *_qkv(one_chip, shape))
 
 
-def test_flash_backward_compiles(one_chip):
+@FLASH_SHAPES
+def test_flash_backward_compiles(one_chip, shape, window):
     def loss(q, k, v):
-        return flash_attention(q, k, v, causal=True).astype(
+        return flash_attention(q, k, v, causal=True, window=window).astype(
             jnp.float32).sum()
 
-    _compile(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(one_chip))
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                    *_qkv(one_chip, shape)).as_text()
+    assert _flash_kernels(text) == 3
 
 
-def test_flash_ring_call_form_compiles(one_chip):
+@pytest.mark.parametrize("t,window", [(512, None), (4096, 512)],
+                         ids=["full", "4096-window512"])
+def test_flash_ring_call_form_compiles(one_chip, t, window):
     """The per-pair call ring attention makes: lse returned and
-    differentiated, global offsets as TRACED scalars (SMEM operands)."""
+    differentiated, global offsets as TRACED scalars (the scalar-prefetch
+    operand the index maps read; with a window the grid is the three
+    blocks a band can touch wherever the offsets put it)."""
     off = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
 
     def loss(q, k, v, q_off, k_off):
         o, lse = flash_attention(
-            q, k, v, causal=True, q_offset=q_off, k_offset=k_off,
-            return_lse=True)
+            q, k, v, causal=True, window=window, q_offset=q_off,
+            k_offset=k_off, return_lse=True)
         return o.astype(jnp.float32).sum() + lse.sum()
 
     _compile(jax.grad(loss, argnums=(0, 1, 2)),
-             *_qkv(one_chip, t=512), off, off)
+             *_qkv(one_chip, (B, t, H, D)), off, off)
 
 
 @pytest.mark.parametrize("layout", ["contiguous", "zigzag"])
@@ -163,14 +188,6 @@ def _smoke_step(devices, mesh_axes, **cfg_kw):
 def _device_bytes(compiled):
     m = compiled.memory_analysis()
     return m.argument_size_in_bytes + m.temp_size_in_bytes
-
-
-def _flash_kernels(text, scope=""):
-    """The flash kernels' call sites in a compiled program's text (a
-    scanned layer's count once), optionally those under one scope."""
-    return sum('custom_call_target="tpu_custom_call"' in line
-               and "pallas_call" in line and scope in line
-               for line in text.splitlines())
 
 
 def test_300m_train_step_fits_one_chip(topo):

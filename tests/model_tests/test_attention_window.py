@@ -54,18 +54,25 @@ def test_local_attention_window_matches_oracle():
         local_attention(q, k, v, window=W)
 
 
-def test_flash_kernel_window_fwd_bwd():
-    """Kernel (interpret mode) vs oracle, values AND grads — the block
-    skipping must not drop in-window contributions."""
+# blocks of 8 keys: a window inside one block, of exactly one, of one
+# and a half, and wider than the sequence; then blocks that differ
+@pytest.mark.parametrize("window,block_q,block_k", [
+    (W, 8, 8), (8, 8, 8), (12, 8, 8), (100, 8, 8), (W, 8, 16),
+    (12, 16, 8)])
+def test_flash_kernel_window_fwd_bwd(window, block_q, block_k):
+    """Kernel (interpret mode) vs oracle, values AND grads — the grid
+    walks the band's blocks alone and must not drop in-window
+    contributions."""
     q, k, v = qkv(t=32)
 
     def loss_flash(q, k, v):
-        o = flash_attention(q, k, v, causal=True, window=W,
-                            block_q=8, block_k=8, interpret=True)
+        o = flash_attention(q, k, v, causal=True, window=window,
+                            block_q=block_q, block_k=block_k,
+                            interpret=True)
         return jnp.sum(o.astype(jnp.float32) ** 2)
 
     def loss_ref(q, k, v):
-        return jnp.sum(dense_banded_oracle(q, k, v, W) ** 2)
+        return jnp.sum(dense_banded_oracle(q, k, v, window) ** 2)
 
     gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
@@ -77,20 +84,28 @@ def test_flash_kernel_window_fwd_bwd():
                                    rtol=1e-4, atol=1e-4)
 
 
-def test_flash_kernel_window_with_offsets():
-    """The offset+window block-skip arithmetic (the ring-flash pairing's
+@pytest.mark.parametrize("traced", [False, True], ids=["static", "traced"])
+def test_flash_kernel_window_with_offsets(traced):
+    """The offset+window band arithmetic (the ring-flash pairing's
     riskiest inequality): kernel with global offsets vs the XLA core at
-    the same global positions, values and grads."""
+    the same global positions, values and grads; the offsets as Python
+    ints, and traced under jit as the ring passes them."""
     q, k, v = qkv(t=32)
     # staggered but never fully-masked: every q row keeps >=1 in-window
     # key (fully-masked rows are the documented kernel/XLA divergence)
     q_off, k_off = 66, 64
 
-    def loss_flash(q, k, v):
+    def flash(q, k, v, q_off, k_off):
         o = flash_attention(
             q, k, v, causal=True, window=W, q_offset=q_off,
             k_offset=k_off, block_q=8, block_k=8, interpret=True)
         return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    if traced:
+        loss_flash = lambda q, k, v: jax.jit(flash)(
+            q, k, v, jnp.int32(q_off), jnp.int32(k_off))
+    else:
+        loss_flash = lambda q, k, v: flash(q, k, v, q_off, k_off)
 
     def loss_ref(q, k, v):
         o = local_attention(q, k, v, causal=True, window=W,
