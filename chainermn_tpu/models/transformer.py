@@ -37,6 +37,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from chainermn_tpu.ops.kda import kda_chunked
 from chainermn_tpu.ops.pallas_attention import (
     FLASH_RESIDUAL_NAMES,
     flash_attention,
@@ -81,16 +82,46 @@ __all__ = [
     "param_specs",
     "make_forward_fn",
     "make_train_step",
+    "hold_selection_bias",
 ]
+
+# KDA's L2 norm of q and k: y * rsqrt(sum(y^2) + eps).  The published
+# config file carries no key for it; a benchmark driver holds its
+# reference's value to this one
+KDA_L2_NORM_EPS = 1e-6
 
 
 @dataclass(frozen=True)
 class AttentionKind:
-    """One kind of attention layer of a model whose layers differ: its
-    window, its rotary parameters and its query heads.
+    """One kind of token-mixing layer of a model whose layers differ:
+    its mixer, and for the mixer what only it has -- softmax attention
+    its window, rotary parameters and query heads; latent attention its
+    latent rank and the widths of its shared key part and its values;
+    the delta-rule layer its convolution.
     ``TransformerConfig.layer_pattern`` is a tuple of these, one per
-    layer of a period (``leading_layers`` one per layer before them)."""
+    layer of a period (``leading_layers`` one per layer before them).
+    Every field is read by the training path alone
+    (``_init_block``, ``_block_specs``, ``_attention_of_kind``)."""
     name: str                  # names the layer's scope: ``attn/<name>``
+    mixer: str = "softmax"     # "softmax": the config's attention core
+    # over q/k/v heads of d_head, rotated as below | "mla": multi-head
+    # latent attention without rotary (``mla_use_nope``): queries of
+    # d_head + d_shared_key straight from the input, keys and values
+    # from one normed latent of rank ``kv_latent``, the last
+    # ``d_shared_key`` key channels one vector shared by every head,
+    # values ``d_value`` wide; causal softmax with the scale of the
+    # whole key width, through the flash kernels (``attention="flash"``)
+    # or XLA (``"local"``) | "kda": Kimi Delta Attention
+    # (``ops/kda.py``): q, k, v through a causal depthwise convolution
+    # of ``conv_taps`` and SiLU, q and k L2-normed a head, a decay a
+    # channel and a step size a head from the input, the delta rule
+    # over a ``d_head x d_head`` state a head, a per-head RMSNorm and
+    # a sigmoid gate on the way out.  Neither takes positions: window,
+    # rotary and YaRN fields are the softmax mixer's
+    kv_latent: int = 0         # mla: rank of the key-value latent
+    d_shared_key: int = 0      # mla: key channels shared by the heads
+    d_value: int = 0           # mla: value head width; 0 => d_head
+    conv_taps: int = 4         # kda: taps of the short convolution
     window: int = 0            # 0 => full causal; W>0 => (t-W, t]
     rope_theta: float = 10000.0
     n_heads: int = 0           # 0 => the config's n_heads.  Else this
@@ -115,6 +146,24 @@ class AttentionKind:
     def __post_init__(self):
         if not self.name or "/" in self.name:
             raise ValueError(f"attention kind name {self.name!r}")
+        if self.mixer not in ("softmax", "mla", "kda"):
+            raise ValueError(
+                f"{self.name}: mixer {self.mixer!r} not in "
+                "(softmax, mla, kda)")
+        if self.mixer == "mla" and (self.kv_latent < 1
+                                    or self.d_shared_key < 0
+                                    or self.d_value < 0):
+            raise ValueError(
+                f"{self.name}: mla needs kv_latent >= 1 and widths >= 0, "
+                f"got {self.kv_latent}, {self.d_shared_key}, {self.d_value}")
+        if self.mixer == "kda" and self.conv_taps < 1:
+            raise ValueError(
+                f"{self.name}: kda needs conv_taps >= 1, got "
+                f"{self.conv_taps}")
+        if self.mixer != "softmax" and (self.window or self.yarn_factor):
+            raise ValueError(
+                f"{self.name}: window and rotary fields are the softmax "
+                f"mixer's; mixer={self.mixer!r} takes no positions")
         if self.window < 0:
             raise ValueError(f"{self.name}: window {self.window} < 0")
         if self.rope_theta <= 1:
@@ -129,6 +178,16 @@ class AttentionKind:
         if not 0 < self.rotary_share <= 1:
             raise ValueError(
                 f"{self.name}: rotary_share {self.rotary_share} not in (0, 1]")
+
+    @property
+    def tree(self):
+        """What of this kind decides its layer's parameter tree, beside
+        the query heads."""
+        if self.mixer == "mla":
+            return ("mla", self.kv_latent, self.d_shared_key, self.d_value)
+        if self.mixer == "kda":
+            return ("kda", self.conv_taps)
+        return ("softmax",)
 
     def rotary_dim(self, d_head: int) -> int:
         """How many leading dimensions of a head are rotated."""
@@ -223,6 +282,16 @@ class TransformerConfig:
     # dropless layer's router scores the experts (route_top_k)
     router_scale: float = 1.0  # multiplies the chosen gates (a routed
     # scaling factor; dropless dispatch only)
+    router_bias: str = ""      # "" | "selection": a bias an expert
+    # (``router_bias``, (E,), beside ``router``) added to the scores for
+    # the CHOICE of the k experts only; the gates are the winners'
+    # scores without it.  No gradient reaches it (stop_gradient in
+    # ``_mlp``), and the optimizer must not move it either (AdamW's
+    # weight decay would): ``make_train_step`` wraps its optimizer in
+    # ``hold_selection_bias``, and any other step that updates these
+    # parameters has to do the same.  Its own update rule, from the
+    # experts' load, is not implemented.  Dropless dispatch, training
+    # path only
     shared_expert_d_ff: int = 0  # >0 => beside the routed experts, one
     # expert of this width that every token meets (its activation is
     # ``expert_act``), ungated, whole on every member of the expert
@@ -288,6 +357,8 @@ class TransformerConfig:
     # and the attention output, and recomputes only the cheap
     # elementwise/norm ops — at 16.0 GB of temporaries for the 300M
     # model at 8 x 2,048 (sandbox compile, PR 21) it fits no cell
+    norm_eps: float = 1e-6     # the RMSNorms' epsilon; the training path
+    # reads it (decoding and serving keep 1e-6 and refuse another)
     dtype: str = "bfloat16"    # compute dtype (params stay fp32)
 
     @property
@@ -306,18 +377,31 @@ class TransformerConfig:
         """Query heads of a layer of ``kind`` (None: an untyped layer)."""
         return (kind.n_heads if kind else 0) or self.n_heads
 
+    @staticmethod
+    def mixer_of(kind) -> str:
+        """Mixer of a layer of ``kind`` (None: an untyped layer)."""
+        return kind.mixer if kind else "softmax"
+
     @property
     def leading_sparse(self) -> bool:
         return self.moe and self.leading_mlp == "sparse"
 
     @property
     def blocks_by_position(self) -> bool:
-        """Whether the layers of a period differ in parameter SHAPES (a
-        kind with query heads of its own).  One stacked array a leaf
-        cannot hold them: ``params["blocks"]`` is then a tuple with one
-        stack over the periods for each position of the pattern,
-        ``(pipe, periods/pipe, ...)`` a leaf, each at its own shapes."""
-        return len({self.heads_of(k) for k in self.layer_pattern}) > 1
+        """Whether the layers of a period differ in their parameter
+        TREE (a kind with query heads or a mixer of its own).  One
+        stacked array a leaf cannot hold them: ``params["blocks"]`` is
+        then a tuple with one stack over the periods for each position
+        of the pattern, ``(pipe, periods/pipe, ...)`` a leaf, each with
+        its own leaves at their own shapes."""
+        return len({(self.heads_of(k), k.tree)
+                    for k in self.layer_pattern}) > 1
+
+    @property
+    def mixers(self):
+        """The mixers in use beside softmax attention, by name."""
+        return sorted({k.mixer for k in self.layer_pattern
+                       + self.leading_layers} - {"softmax"})
 
     @property
     def training_only(self):
@@ -328,7 +412,11 @@ class TransformerConfig:
             ("leading_layers", bool(self.leading_layers)),
             ("AttentionKind.n_heads", any(
                 k.n_heads for k in self.layer_pattern + self.leading_layers)),
+            ("AttentionKind.mixer=" + "/".join(self.mixers),
+             bool(self.mixers)),
             ("attn_gate", bool(self.attn_gate)),
+            ("router_bias", bool(self.router_bias)),
+            ("norm_eps", self.norm_eps != 1e-6),
             ("experts_held", bool(self.experts_held)),
             ("moe_dispatch='dropless'",
              self.moe and self.moe_dispatch == "dropless"),
@@ -453,12 +541,20 @@ class TransformerConfig:
             raise ValueError(
                 'expert_act="swiglu" is implemented by the dropless '
                 "expert layer only (moe=True, moe_dispatch='dropless')")
-        if (self.router_score != "softmax" or self.router_scale != 1.0
-                or self.shared_expert_d_ff) and not dropless:
+        if self.router_bias not in ("", "selection"):
             raise ValueError(
-                "router_score, router_scale and shared_expert_d_ff are "
-                "the dropless expert layer's (moe=True, "
-                "moe_dispatch='dropless')")
+                f"router_bias {self.router_bias!r} not in ('', selection)")
+        if (self.router_score != "softmax" or self.router_scale != 1.0
+                or self.shared_expert_d_ff or self.router_bias) \
+                and not dropless:
+            raise ValueError(
+                "router_score, router_scale, router_bias and "
+                "shared_expert_d_ff are the dropless expert layer's "
+                "(moe=True, moe_dispatch='dropless')")
+        if self.mixers and self.attn_gate:
+            raise ValueError(
+                f"attn_gate is softmax attention's; the {self.mixers} "
+                "layers have none (kda's output gate is its own)")
         if self.experts_held:
             if not dropless:
                 raise ValueError(
@@ -509,12 +605,21 @@ class TransformerConfig:
 # --------------------------------------------------------------------- #
 
 
+# what _init_block builds for a mixer beside the norms and ``wo``
+_MIXER_LEAVES = {
+    "mla": ("wq", "wkva", "kv_norm", "wkvb"),
+    "kda": ("wqkv", "conv", "wf_a", "wf_b", "a_log", "dt_bias", "wbeta",
+            "wg_a", "wg_b", "o_norm"),
+}
+
+
 def _init_block(key, cfg: TransformerConfig, kind=None, sparse=None):
     """One layer's parameters at its own shapes: ``kind`` gives the
-    query heads (None: the config's), ``sparse`` the MLP (None: the
-    config's ``moe``)."""
+    mixer and the query heads (None: softmax attention at the config's),
+    ``sparse`` the MLP (None: the config's ``moe``)."""
     D, Dh = cfg.d_model, cfg.d_head
     H = cfg.heads_of(kind)
+    mixer = cfg.mixer_of(kind)
     sparse = cfg.moe if sparse is None else sparse
     F = cfg.d_ff if sparse else cfg.dense_d_ff or cfg.d_ff
     ks = jax.random.split(key, 6)
@@ -522,12 +627,40 @@ def _init_block(key, cfg: TransformerConfig, kind=None, sparse=None):
     def dense_init(k, shape, fan_in):
         return jax.random.normal(k, shape, jnp.float32) * (fan_in ** -0.5)
 
+    Dv = kind.d_value or Dh if mixer == "mla" else Dh
     block = {
         "ln1": jnp.ones((D,), jnp.float32),
         "ln2": jnp.ones((D,), jnp.float32),
-        "wo": dense_init(ks[1], (H, Dh, D), H * Dh),
+        "wo": dense_init(ks[1], (H, Dv, D), H * Dv),
     }
-    if cfg.kv_heads == H:
+    if mixer == "mla":
+        L, Ds = kind.kv_latent, kind.d_shared_key
+        block["wq"] = dense_init(ks[0], (D, H, Dh + Ds), D)
+        block["wkva"] = dense_init(ks[5], (D, L + Ds), D)
+        block["kv_norm"] = jnp.ones((L,), jnp.float32)
+        block["wkvb"] = dense_init(
+            jax.random.fold_in(key, 11), (L, H, Dh + Dv), L)
+    elif mixer == "kda":
+        # the two-matrix projections of the decay and of the output
+        # gate go through a rank of d_head
+        R, taps = Dh, kind.conv_taps
+        kk = iter(jax.random.split(jax.random.fold_in(key, 12), 8))
+        block["wqkv"] = dense_init(ks[0], (D, 3, H, Dh), D)
+        block["conv"] = dense_init(next(kk), (3, H, Dh, taps), taps)
+        block["wf_a"] = dense_init(next(kk), (D, R), D)
+        block["wf_b"] = dense_init(next(kk), (R, H, Dh), R)
+        # the published initialisers: exp(a_log) uniform in [1, 16]; the
+        # step's bias the inverse softplus of a log-uniform [1e-3, 1e-1]
+        block["a_log"] = jnp.log(jax.random.uniform(
+            next(kk), (H,), jnp.float32, 1.0, 16.0))
+        dt = jnp.exp(jax.random.uniform(
+            next(kk), (H, Dh), jnp.float32, math.log(1e-3), math.log(1e-1)))
+        block["dt_bias"] = dt + jnp.log(-jnp.expm1(-dt))
+        block["wbeta"] = dense_init(next(kk), (D, H), D)
+        block["wg_a"] = dense_init(next(kk), (D, R), D)
+        block["wg_b"] = dense_init(next(kk), (R, H, Dh), R)
+        block["o_norm"] = jnp.ones((Dh,), jnp.float32)
+    elif cfg.kv_heads == H:
         block["wqkv"] = dense_init(ks[0], (D, 3, H, Dh), D)
     else:
         # GQA/MQA: Hkv shared K/V heads, each serving H/Hkv query heads
@@ -541,6 +674,8 @@ def _init_block(key, cfg: TransformerConfig, kind=None, sparse=None):
         # the router scores every expert; the weights are of those held
         E, G = cfg.n_experts, cfg.n_experts_held
         block["router"] = dense_init(ks[2], (D, E), D)
+        if cfg.router_bias:
+            block["router_bias"] = jnp.zeros((E,), jnp.float32)
         block["w1"] = dense_init(ks[3], (G, D, F), D)
         block["w2"] = dense_init(ks[4], (G, F, D), F)
         if gated == "swiglu":
@@ -781,7 +916,13 @@ def _block_specs(cfg: TransformerConfig, kind, sparse: bool,
         "wo": P("pipe", None, "model", None, None),
     }
     mha = cfg.kv_heads == cfg.heads_of(kind)
-    if mha:
+    mixer = cfg.mixer_of(kind)
+    if mixer != "softmax":
+        # whole on every member of ``model`` (_check_mesh keeps that
+        # axis at 1 for these mixers): the stack's pipe axis and no other
+        blk.update({name: P("pipe") for name in _MIXER_LEAVES[mixer]},
+                   wo=P("pipe"))
+    elif mha:
         blk["wqkv"] = P("pipe", None, None, None, "model", None)
     else:
         blk["wq"] = P("pipe", None, None, "model", None)
@@ -791,6 +932,8 @@ def _block_specs(cfg: TransformerConfig, kind, sparse: bool,
     gated = (cfg.expert_act if sparse else cfg.dense_act) == "swiglu"
     if sparse:
         blk["router"] = P("pipe")
+        if cfg.router_bias:
+            blk["router_bias"] = P("pipe")
         blk["w1"] = P("pipe", None, "expert", None, "model")
         blk["w2"] = P("pipe", None, "expert", "model", None)
         if gated:
@@ -883,9 +1026,9 @@ def param_specs(cfg: TransformerConfig, quantized: bool = False):
 # --------------------------------------------------------------------- #
 
 
-def _rms_norm(x, scale):
+def _rms_norm(x, scale, eps=1e-6):
     x32 = x.astype(jnp.float32)
-    r = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + 1e-6)
+    r = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
     return (x32 * r * scale).astype(x.dtype)
 
 
@@ -1298,10 +1441,111 @@ def _attention(cfg: TransformerConfig, h, blk, kind=None):
     return _attention_of_kind(cfg, h, blk, None)
 
 
+def _require_flash(T):
+    """``attention="flash"`` as asked or not at all: no silent stand-in,
+    a run that asked for the kernel and got the XLA attention would be
+    measured as the kernel."""
+    if lax.axis_size("seq") != 1:
+        raise ValueError(
+            'attention="flash" covers only the unsharded-sequence '
+            'case (mesh seq axis is '
+            f'{lax.axis_size("seq")}); use attention="ring" to '
+            "shard the sequence")
+    if not flash_attention_supported(T, T):
+        raise ValueError(
+            f'attention="flash" cannot tile a sequence of {T}: '
+            "lengths must be multiples of 8 and either fit one "
+            "block or divide by a power-of-two block >= 128 "
+            '(flash_attention_supported); use attention="local" '
+            "for the XLA path")
+
+
+def _mla_mixer(cfg: TransformerConfig, x, blk, kind):
+    """Latent attention without rotary on the normed input ``x``: the
+    layer's contribution to the residual stream.  The shared key part is
+    copied out to the heads ahead of the kernel (as ``broadcast_kv``
+    does for grouped heads); the kernel takes keys of ``d_head +
+    d_shared_key`` and values of ``d_value`` as they are."""
+    cd = cfg.compute_dtype
+    B, T, D = x.shape
+    H, L, Ds, Dn = (blk["wq"].shape[1], kind.kv_latent, kind.d_shared_key,
+                    cfg.d_head)
+    q = (x @ blk["wq"].reshape(D, -1).astype(cd)).reshape(B, T, H, Dn + Ds)
+    with jax.named_scope("mla/latent"):
+        down = x @ blk["wkva"].astype(cd)
+        latent = _rms_norm(down[..., :L], blk["kv_norm"], cfg.norm_eps)
+        up = (latent @ blk["wkvb"].reshape(L, -1).astype(cd)).reshape(
+            B, T, H, -1)
+    k = jnp.concatenate([up[..., :Dn], jnp.broadcast_to(
+        down[:, :, None, L:], (B, T, H, Ds))], axis=-1)
+    v = up[..., Dn:]
+    if cfg.attention == "flash":
+        _require_flash(T)
+        o = flash_attention(
+            q, k, v, causal=True,
+            bwd_block_q=cfg.flash_bwd_block_q or None,
+            bwd_block_k=cfg.flash_bwd_block_k or None,
+            interpret=interpret_kernels())
+    else:
+        o = local_attention(q, k, v, causal=True)
+    o = checkpoint_name(o, "attn_out")
+    return o.reshape(B, T, -1) @ blk["wo"].reshape(-1, D).astype(cd)
+
+
+def _kda_mixer(cfg: TransformerConfig, x, blk, kind):
+    """Kimi Delta Attention on the normed input ``x``: the layer's
+    contribution to the residual stream.  Projections in the compute
+    dtype with float32 results; convolution, norms, gates and the
+    recurrence (``ops/kda.py``) in float32."""
+    cd, f32 = cfg.compute_dtype, jnp.float32
+    B, T, D = x.shape
+    H, Dh = blk["wqkv"].shape[2:]
+    taps = blk["conv"].shape[-1]
+
+    def project(*ws):
+        y = x
+        for w in ws:
+            y = jnp.dot(y.astype(cd), w.reshape(w.shape[0], -1).astype(cd),
+                        preferred_element_type=f32)
+        return y
+
+    qkv = project(blk["wqkv"]).reshape(B, T, 3, H, Dh)
+    with jax.named_scope("kda/conv"):
+        # y_t = sum_j w_j x_(t - taps + 1 + j): the last tap meets the
+        # token itself, nothing reaches back past the sequence's start
+        padded = jnp.pad(qkv, ((0, 0), (taps - 1, 0)) + ((0, 0),) * 3)
+        qkv = jax.nn.silu(sum(
+            padded[:, j:j + T] * blk["conv"][..., j] for j in range(taps)))
+        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        unit = lambda y: y * lax.rsqrt(
+            jnp.sum(y * y, axis=-1, keepdims=True) + KDA_L2_NORM_EPS)
+        q, k = unit(q) * Dh ** -0.5, unit(k)
+    with jax.named_scope("kda/gate"):
+        # the recurrence's two gates: the log of the decay a channel
+        # (<= 0) and the step size a head
+        g = -jnp.exp(blk["a_log"])[:, None] * jax.nn.softplus(
+            project(blk["wf_a"], blk["wf_b"]).reshape(B, T, H, Dh)
+            + blk["dt_bias"])
+        beta = jax.nn.sigmoid(project(blk["wbeta"]))
+    with jax.named_scope("kda/scan"):
+        o = kda_chunked(q, k, v, g, beta)
+    with jax.named_scope("kda/gate"):
+        # the way out: RMSNorm over each head with one scale for all,
+        # times a sigmoid gate from the input
+        o = _rms_norm(o, blk["o_norm"], cfg.norm_eps) * jax.nn.sigmoid(
+            project(blk["wg_a"], blk["wg_b"]).reshape(B, T, H, Dh))
+    o = checkpoint_name(o.astype(cd), "attn_out")
+    return o.reshape(B, T, -1) @ blk["wo"].reshape(-1, D).astype(cd)
+
+
 def _attention_of_kind(cfg: TransformerConfig, h, blk, kind):
     cd = cfg.compute_dtype
+    if cfg.mixer_of(kind) != "softmax":
+        mix = _mla_mixer if kind.mixer == "mla" else _kda_mixer
+        return h + mix(cfg, _rms_norm(h, blk["ln1"], cfg.norm_eps), blk,
+                       kind)
     win = (kind.window if kind else cfg.attention_window) or None
-    x = _rms_norm(h, blk["ln1"])
+    x = _rms_norm(h, blk["ln1"], cfg.norm_eps)
     B, T, D = x.shape
     if "wqkv" in blk:
         Hl = blk["wqkv"].shape[2]      # local heads = H / model-axis size
@@ -1391,21 +1635,7 @@ def _attention_of_kind(cfg: TransformerConfig, h, blk, kind):
     elif cfg.attention == "flash":
         # Pallas kernel: compiled when the step was built for TPU
         # devices, interpreted otherwise (interpret_kernels)
-        if lax.axis_size("seq") != 1:
-            raise ValueError(
-                'attention="flash" covers only the unsharded-sequence '
-                'case (mesh seq axis is '
-                f'{lax.axis_size("seq")}); use attention="ring" to '
-                "shard the sequence")
-        if not flash_attention_supported(T, T):
-            # no silent stand-in: a run that asked for the kernel and
-            # got the XLA attention would be measured as the kernel
-            raise ValueError(
-                f'attention="flash" cannot tile a sequence of {T}: '
-                "lengths must be multiples of 8 and either fit one "
-                "block or divide by a power-of-two block >= 128 "
-                '(flash_attention_supported); use attention="local" '
-                "for the XLA path")
+        _require_flash(T)
         # kernel wants matching head counts
         k, v = broadcast_kv(k, v, q.shape[2] // k.shape[2])
         o = flash_attention(
@@ -1459,7 +1689,7 @@ def _mlp(cfg: TransformerConfig, h, blk, with_chosen=False, sparse=None,
     cd = cfg.compute_dtype
     sparse = cfg.moe if sparse is None else sparse
     scope = jax.named_scope if scoped else (lambda _: nullcontext())
-    x = _rms_norm(h, blk["ln2"])
+    x = _rms_norm(h, blk["ln2"], cfg.norm_eps)
     if with_chosen and not (sparse and cfg.moe_dispatch == "dropless"):
         raise ValueError("the choices are read from the dropless layer")
     if not sparse:
@@ -1488,6 +1718,10 @@ def _mlp(cfg: TransformerConfig, h, blk, with_chosen=False, sparse=None,
             first_expert=cfg.experts_held[0] if cfg.experts_held else 0,
             score=cfg.router_score,
             scale=cfg.router_scale,
+            # no gradient reaches the selection bias: its update rule is
+            # not the loss's
+            bias=lax.stop_gradient(blk["router_bias"])
+            if "router_bias" in blk else None,
             axis_name="expert",
         )
         out = out.reshape(B, T, D)
@@ -1681,7 +1915,7 @@ def transformer_backbone(cfg: TransformerConfig, params, tokens):
         h = lax.psum(h, "pipe")
         aux = lax.psum(aux, "pipe")
 
-    return _rms_norm(h, params["ln_f"]), aux
+    return _rms_norm(h, params["ln_f"], cfg.norm_eps), aux
 
 
 def _head_matrix(cfg: TransformerConfig, params):
@@ -1822,7 +2056,7 @@ def _make_1f1b_grad(cfg: TransformerConfig):
         h, vjp_embed = jax.vjp(embed_fn, ep)
 
         def loss_fn(lp, y, tgt):
-            hN = _rms_norm(y, lp["ln_f"])
+            hN = _rms_norm(y, lp["ln_f"], cfg.norm_eps)
             return _shard_nll_sum(cfg, hN, lp["embed"], tgt) / tgt.size
 
         lp = {"ln_f": params["ln_f"], "embed": _head_matrix(cfg, params)}
@@ -1884,6 +2118,26 @@ def _check_mesh(mesh_cfg, cfg: TransformerConfig):
             raise ValueError(
                 f"{kind.name}: n_heads={kind.n_heads} must be divisible "
                 f"by the model mesh axis ({mp})")
+    if cfg.mixers:
+        names = "/".join(cfg.mixers)
+        axes = {a: mesh_cfg.mesh.shape.get(a, 1)
+                for a in ("seq", "model", "pipe")}
+        if max(axes.values()) > 1:
+            raise ValueError(
+                f"the {names} layers run whole on a device: their "
+                "recurrence and latent are not split over heads, "
+                "sequence or stages yet, so the seq, model and pipe "
+                f"mesh axes must be 1 (got {axes}); data and expert "
+                "are open")
+        if cfg.attention not in ("flash", "local"):
+            raise ValueError(
+                f"the {names} layers run with attention='flash' or "
+                f"'local' (got {cfg.attention!r}): the ring and Ulysses "
+                "exchanges move softmax attention's q/k/v heads only")
+        if cfg.fsdp:
+            raise ValueError(
+                f"fsdp=True is not implemented for the {names} layers: "
+                "_fsdp_dims has no dims for their leaves")
     if (cfg.leading_layers or cfg.blocks_by_position) and (
             mesh_cfg.mesh.shape.get("pipe", 1) > 1 or cfg.virtual_pipe > 1
             or cfg.num_microbatches > 1
@@ -1958,6 +2212,24 @@ def make_forward_fn(mesh_cfg, cfg: TransformerConfig):
         ))
 
 
+def hold_selection_bias(optimizer):
+    """``optimizer`` with every leaf named ``router_bias`` out of its
+    reach: their updates are zero whatever it computes (no gradient
+    reaches the selection bias, but AdamW's weight decay would move
+    it).  ``init`` and the state's tree are ``optimizer``'s own, so a
+    state made for one fits the other.  :func:`make_train_step` applies
+    it where ``cfg.router_bias`` is set; a step of one's own around
+    ``optimizer.update`` has to."""
+    def update(grads, state, params=None):
+        updates, state = optimizer.update(grads, state, params)
+        return jax.tree_util.tree_map_with_path(
+            lambda path, u: jnp.zeros_like(u) if any(
+                getattr(k, "key", None) == "router_bias"
+                for k in path) else u, updates), state
+
+    return optax.GradientTransformation(optimizer.init, update)
+
+
 def make_train_step(mesh_cfg, cfg: TransformerConfig, optimizer):
     """Full jitted SPMD train step over all five axes.
 
@@ -1983,6 +2255,8 @@ def make_train_step(mesh_cfg, cfg: TransformerConfig, optimizer):
     """
     _check_mesh(mesh_cfg, cfg)
     specs = param_specs(cfg)
+    if cfg.router_bias:
+        optimizer = hold_selection_bias(optimizer)
 
     if cfg.pipeline_schedule in ("1f1b", "interleaved"):
         grad_body = _make_1f1b_grad(cfg)

@@ -1,0 +1,197 @@
+"""Kimi Delta Attention's recurrence, chunked (arXiv:2510.26692, §3).
+
+A head keeps a matrix state ``S`` (``d_k x d_v``, zero at the start of a
+sequence) and every token decays it a channel, applies the delta rule
+and reads it::
+
+    S_t = (I - b_t k_t k_t^T) Diag(a_t) S_{t-1} + b_t k_t v_t^T
+    o_t = S_t^T q_t                      a_t = exp(g_t),  g_t <= 0
+
+:func:`kda_recurrent` is that, a token at a time: the yardstick of the
+tests.  :func:`kda_chunked` is what the model runs: chunks of ``CHUNK``
+tokens, inside a chunk the WY/UT form.  With ``G_t`` the running sum of
+``g`` inside the chunk, ``u_t = b_t (v_t - S_{t-1}^T (a_t . k_t))`` and
+``S_t = Diag(a_t) S_{t-1} + k_t u_t^T``, so for the chunk's ``C`` rows
+
+    (I + Diag(b) A) U = Diag(b) (V - (K . exp(G)) S_0),
+    A_ti = sum_c k_tc k_ic exp(G_tc - G_ic)   (i < t, else 0)
+    O = (Q . exp(G)) S_0 + A' U,   A'_ti = sum_c q_tc k_ic exp(G_tc - G_ic)  (i <= t)
+    S_C = Diag(exp(G_C)) S_0 + (K . exp(G_C - G))^T U
+
+The unit lower triangular system is solved once a chunk for the two
+right-hand sides ``Diag(b) V`` and ``Diag(b) (K . exp(G))``, which no
+state enters; between chunks a ``lax.scan`` carries ``S`` through three
+products of chunk-sized blocks.
+
+**Every exponent is a difference of running sums that is <= 0**, and
+nothing is divided by a decay: with decays drawn as published a chunk's
+running sum passes -200 and ``exp(-G)`` is not a float32.  ``A`` and
+``A'`` are therefore built from sub-blocks of ``SUB`` rows, as the
+published kernel does: a pair of rows of the same sub-block takes its
+own exponent ``G_t - G_i`` (masked to the pairs that are read before
+``exp``); a row of sub-block ``a`` meets the rows of earlier sub-blocks
+through the reference point ``R_a``, the running sum just before ``a``,
+as ``(k_t . exp(G_t - R_a)) . (k_i . exp(R_a - G_i))``, both factors
+<= 1.  A product of two such factors that underflows is a pair whose
+true weight is below float32 too.
+
+Backward is autodiff through the chunked form.  The sequence is cut
+into slabs of ``SLAB`` chunks; the outer scan over the slabs has its
+body under ``jax.checkpoint``, so what is kept for the backward pass is
+the state at each slab's start (``kda/state_bytes_kept``) and a slab's
+own inputs, and a slab's pair-by-pair exponents (``SUB`` times the size
+of its keys) live only while that slab is differentiated.
+
+Trace-time counters (``utils.metrics`` registry, a call): ``kda/chunks``
+(chunks a sequence) and ``kda/state_bytes_kept``.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from chainermn_tpu.utils.metrics import get_registry
+
+__all__ = ["kda_chunked", "kda_recurrent"]
+
+_HI = lax.Precision.HIGHEST
+
+# The three sizes are the op's own and no caller's: a shorter sequence
+# is one shorter chunk, and a test reaches several slabs through T.
+CHUNK = 64   # tokens a chunk (the published kernel's)
+SUB = 16     # rows a sub-block of the pair weights
+SLAB = 4     # chunks differentiated at a time.  Read on the chip: a
+# layer's forward and backward at 16,384 tokens and 32 heads of 128
+# took 157 ms at 4 against 212 at 16 and 193 at 64 (PERF.md, PR 32)
+
+
+def kda_recurrent(q, k, v, g, beta):
+    """The recurrence a token at a time.  ``q``, ``k``, ``g``
+    ``(B, T, H, d_k)``, ``v`` ``(B, T, H, d_v)``, ``beta`` ``(B, T, H)``;
+    ``g <= 0`` is the log of the decay.  Returns ``o`` ``(B, T, H, d_v)``
+    in float32."""
+    f32 = jnp.float32
+    q, k, v, g, beta = (x.astype(f32) for x in (q, k, v, g, beta))
+    B, T, H, dk = k.shape
+
+    def step(S, x):
+        q_t, k_t, v_t, g_t, b_t = x                   # (B, H, ...)
+        S = jnp.exp(g_t)[..., None] * S
+        u = b_t[..., None] * (v_t - jnp.einsum(
+            "bhkv,bhk->bhv", S, k_t, precision=_HI))
+        S = S + k_t[..., :, None] * u[..., None, :]
+        return S, jnp.einsum("bhkv,bhk->bhv", S, q_t, precision=_HI)
+
+    S0 = jnp.zeros((B, H, dk, v.shape[-1]), f32)
+    _, o = lax.scan(step, S0, tuple(
+        jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta)))
+    return jnp.moveaxis(o, 0, 1)
+
+
+def _pair_weights(q, k, G, g, sub):
+    """``(A, A')`` of a chunk (module docstring): ``q``, ``k``, ``G``,
+    ``g`` ``(..., C, d_k)``, results ``(..., C, C)``; ``A`` strictly
+    lower triangular, ``A'`` with its diagonal."""
+    C, dk = k.shape[-2:]
+    n = C // sub
+    lead = k.shape[:-2]
+    by_sub = lambda x: x.reshape(*lead, n, sub, dk)
+    qs, ks, Gs = by_sub(q), by_sub(k), by_sub(G)
+    # R_a: the running sum just before sub-block a
+    ref = Gs[..., 0, :] - by_sub(g)[..., 0, :]                # (..., n, dk)
+    row = jnp.exp(Gs - ref[..., None, :])                     # <= 1
+    before = jnp.arange(C)[None, :] < (jnp.arange(n) * sub)[:, None]
+    col = jnp.exp(jnp.where(
+        before[..., None],
+        ref[..., :, None, :] - G[..., None, :, :], -jnp.inf))  # (..., n, C, dk)
+    kcol = k[..., None, :, :] * col
+    off_kk = jnp.einsum("...atc,...aic->...ati", ks * row, kcol)
+    off_qk = jnp.einsum("...atc,...aic->...ati", qs * row, kcol)
+    # the pairs inside a sub-block, each with its own exponent
+    t, i = jnp.arange(sub)[:, None], jnp.arange(sub)[None, :]
+    pair = jnp.exp(jnp.where(
+        (t >= i)[..., None],
+        Gs[..., :, None, :] - Gs[..., None, :, :], -jnp.inf))
+    in_kk = jnp.where(t > i, jnp.einsum(
+        "...atc,...aic,...atic->...ati", ks, ks, pair), 0.0)
+    in_qk = jnp.einsum("...atc,...aic,...atic->...ati", qs, ks, pair)
+    own = jnp.eye(n, dtype=k.dtype)[:, None, :, None]         # (n, 1, n, 1)
+
+    def whole(off, inside):
+        # sub-block a's rows: the columns before it, and its own
+        placed = inside[..., :, :, None, :] * own             # (n, sub, n, sub)
+        return (off.reshape(*lead, n, sub, n, sub) + placed).reshape(
+            *lead, C, C)
+
+    return whole(off_kk, in_kk), whole(off_qk, in_qk)
+
+
+def _chunk_parts(q, k, v, g, beta, sub):
+    """What a chunk gives the scan over the states, none of it a
+    function of a state: ``(U_v, W, Q_g, A', K_end, decay_C)`` with
+    ``U = U_v - W S_0``."""
+    G = jnp.cumsum(g, axis=-2)
+    A, A_q = _pair_weights(q, k, G, g, sub)
+    C, dv = v.shape[-2:]
+    system = jnp.eye(C, dtype=k.dtype) + beta[..., None] * A
+    rhs = beta[..., None] * jnp.concatenate([v, k * jnp.exp(G)], axis=-1)
+    solved = lax.linalg.triangular_solve(
+        system, rhs, left_side=True, lower=True, unit_diagonal=True)
+    G_end = G[..., -1:, :]
+    return (solved[..., :dv], solved[..., dv:], q * jnp.exp(G), A_q,
+            k * jnp.exp(G_end - G), jnp.exp(G_end[..., 0, :]))
+
+
+def _slab(S, xs, sub):
+    """One slab of chunks from the state ``S`` ``(B, H, d_k, d_v)``:
+    ``xs`` are ``q, k, v, g, beta`` as ``(B, H, N, C, ...)``.  Returns
+    the state after the slab and ``o`` ``(B, H, N, C, d_v)``."""
+    parts = _chunk_parts(*xs, sub)
+
+    def chunk(S, part):
+        u_v, w, q_g, a_q, k_end, decay = part
+        u = u_v - w @ S
+        o = q_g @ S + a_q @ u
+        return decay[..., None] * S + jnp.swapaxes(k_end, -1, -2) @ u, o
+
+    S, o = lax.scan(chunk, S, tuple(jnp.moveaxis(p, 2, 0) for p in parts))
+    return S, jnp.moveaxis(o, 0, 2)
+
+
+def kda_chunked(q, k, v, g, beta):
+    """:func:`kda_recurrent` in chunks (module docstring): the same
+    arguments and result, float32 inside whatever the inputs' dtype.
+    ``T`` divides by ``CHUNK`` (or is one shorter chunk that divides by
+    ``SUB``); the largest divisor of the chunk count that is at most
+    ``SLAB`` is differentiated at a time."""
+    f32 = jnp.float32
+    B, T, H, dk = k.shape
+    dv = v.shape[-1]
+    chunk = min(CHUNK, T)
+    sub = min(SUB, chunk)
+    if T % chunk or chunk % sub:
+        raise ValueError(
+            f"a sequence of {T} is not whole chunks of {chunk} in "
+            f"sub-blocks of {sub}")
+    n_chunks = T // chunk
+    slab = min(SLAB, n_chunks)
+    while n_chunks % slab:
+        slab -= 1
+    n_slabs = n_chunks // slab
+    reg = get_registry()
+    reg.inc("kda/chunks", n_chunks)
+    reg.inc("kda/state_bytes_kept", n_slabs * B * H * dk * dv * 4)
+
+    def slabs(x):
+        # (B, T, H, ...) -> (slabs, B, H, chunks a slab, chunk, ...)
+        x = x.astype(f32).reshape(B, n_slabs, slab, chunk, H, *x.shape[3:])
+        return jnp.moveaxis(x, 4, 2).swapaxes(0, 1)
+
+    xs = tuple(slabs(x) for x in (q, k, v, g, beta))
+    # the carry takes its varying mesh axes from the inputs
+    S0 = jnp.zeros((B, H, dk, dv), f32) + jnp.sum(xs[1][0] * 0)
+    _, o = lax.scan(jax.checkpoint(lambda S, x: _slab(S, x, sub)), S0, xs)
+    # (slabs, B, H, chunks, chunk, dv) -> (B, T, H, dv)
+    return jnp.moveaxis(o.swapaxes(0, 1), 2, 4).reshape(B, T, H, dv)

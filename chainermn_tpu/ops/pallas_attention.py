@@ -29,6 +29,11 @@ Design (flash-attention v2 schedule, TPU-shaped):
   normaliser ``l``, accumulator) — no (T, T) score matrix in HBM;
 - matmuls via ``jnp.dot(..., preferred_element_type=float32)`` so bf16
   inputs hit the MXU at full rate with fp32 accumulation;
+- the value width may differ from the key width (latent attention:
+  keys of 128 + 64 channels, values of 128): q and k are ``(…, D)``,
+  v, o and their cotangents ``(…, Dv)``, the scale comes from ``D``,
+  and every block, accumulator and output takes its own tensor's
+  width — V is not padded to ``D`` and q/k are not split;
 - causal masking in *global* positions: ``q_offset``/``k_offset`` are
   the scalar-prefetch operand (SMEM, read by the index maps and the
   kernels), so they may be **traced values** (ring attention's
@@ -443,7 +448,7 @@ def _call(kernel, plan, BH, in_specs, out_specs, out_shape, scratch_shapes,
 def _fwd(q3, k3, v3, offs, static_offs, scale, causal, window, block_q,
          block_k, interpret):
     BH, Tq, D = q3.shape
-    Tk = k3.shape[1]
+    Tk, Dv = v3.shape[1:]
     plan = _visit_plan(Tq, Tk, block_q, block_k, causal, window,
                        static_offs)
     q_spec, k_spec = _outer_spec(block_q, D), _inner_spec(plan, block_k, D)
@@ -451,14 +456,14 @@ def _fwd(q3, k3, v3, offs, static_offs, scale, causal, window, block_q,
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           window=window),
         plan, BH,
-        in_specs=[q_spec, k_spec, k_spec],
-        out_specs=[q_spec, _outer_spec(block_q, _LANE)],
+        in_specs=[q_spec, k_spec, _inner_spec(plan, block_k, Dv)],
+        out_specs=[_outer_spec(block_q, Dv), _outer_spec(block_q, _LANE)],
         out_shape=[
-            _sds((BH, Tq, D), q3.dtype, q3),
+            _sds((BH, Tq, Dv), q3.dtype, q3),
             _sds((BH, Tq, _LANE), jnp.float32, q3),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
             pltpu.VMEM((block_q, _LANE), jnp.float32),
             pltpu.VMEM((block_q, _LANE), jnp.float32),
         ],
@@ -500,7 +505,7 @@ def _flash_bwd(static_offs, scale, causal, window, fwd_block_q,
     q3, k3, v3, offs, o, lse = res
     do, dlse = cts
     BH, Tq, D = q3.shape
-    Tk = k3.shape[1]
+    Tk, Dv = v3.shape[1:]
     # d s_ij = p_ij (dp_ij − delta_i) from o's cotangent, plus p_ij·dlse_i
     # from lse's — both fold into one "delta_eff = delta − dlse" term.
     delta = jnp.sum(
@@ -516,7 +521,8 @@ def _flash_bwd(static_offs, scale, causal, window, fwd_block_q,
     qvec_spec = _outer_spec(block_q, _LANE)
     dq = _call(
         functools.partial(_dq_kernel, **kernel_args), plan, BH,
-        in_specs=[q_spec, k_spec, k_spec, q_spec, qvec_spec, qvec_spec],
+        in_specs=[q_spec, k_spec, _inner_spec(plan, block_k, Dv),
+                  _outer_spec(block_q, Dv), qvec_spec, qvec_spec],
         out_specs=q_spec,
         out_shape=_sds((BH, Tq, D), q3.dtype, q3),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
@@ -527,18 +533,20 @@ def _flash_bwd(static_offs, scale, causal, window, fwd_block_q,
     plan = _visit_plan(Tq, Tk, block_q, block_k, causal, window,
                        static_offs, outer="k")
     k_spec, q_spec = _outer_spec(block_k, D), _inner_spec(plan, block_q, D)
+    v_spec = _outer_spec(block_k, Dv)
     qvec_spec = _inner_spec(plan, block_q, _LANE)
     dk, dv = _call(
         functools.partial(_dkv_kernel, **kernel_args), plan, BH,
-        in_specs=[q_spec, k_spec, k_spec, q_spec, qvec_spec, qvec_spec],
-        out_specs=[k_spec, k_spec],
+        in_specs=[q_spec, k_spec, v_spec, _inner_spec(plan, block_q, Dv),
+                  qvec_spec, qvec_spec],
+        out_specs=[k_spec, v_spec],
         out_shape=[
             _sds((BH, Tk, D), k3.dtype, k3),
-            _sds((BH, Tk, D), v3.dtype, v3),
+            _sds((BH, Tk, Dv), v3.dtype, v3),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         interpret=interpret,
     )(offs, q3, k3, v3, do, lse3, delta)
@@ -591,7 +599,9 @@ def flash_attention(q, k, v, *, causal: bool = False, window=None,
                     bwd_block_q: Optional[int] = None,
                     bwd_block_k: Optional[int] = None,
                     return_lse: bool = False, interpret: bool = False):
-    """Flash attention over ``(B, T, H, D)`` tensors.
+    """Flash attention over ``(B, T, H, D)`` tensors; ``v`` may be
+    ``(B, T, H, Dv)`` with a width of its own, and the output then has
+    that width (the scale is ``D ** -0.5``, the key width's).
 
     ``q_offset``/``k_offset`` are *global* position offsets of the local
     blocks for sequence-sharded callers — python ints or traced int
@@ -616,7 +626,9 @@ def flash_attention(q, k, v, *, causal: bool = False, window=None,
     valid tiling (the OPT cells' ``flash_roofline`` reads a retune).
     """
     B, Tq, H, D = q.shape
-    Tk = k.shape[1]
+    Tk, Dv = k.shape[1], v.shape[-1]
+    if k.shape[-1] != D:
+        raise ValueError(f"q and k widths differ: {D} and {k.shape[-1]}")
     if window is not None and not causal:
         raise ValueError("window requires causal=True (sliding causal "
                          "window attention)")
@@ -645,11 +657,12 @@ def flash_attention(q, k, v, *, causal: bool = False, window=None,
     static_offs = None
     if all(isinstance(x, (int, np.integer)) for x in (q_offset, k_offset)):
         static_offs = (int(q_offset), int(k_offset))
-    to3 = lambda x: x.transpose(0, 2, 1, 3).reshape(B * H, x.shape[1], D)
+    to3 = lambda x: x.transpose(0, 2, 1, 3).reshape(
+        B * H, x.shape[1], x.shape[3])
     o, lse = _flash(to3(q), to3(k), to3(v), offs, static_offs, D ** -0.5,
                     causal, None if window is None else int(window),
                     block_q, block_k, bwd_bq, bwd_bk, interpret)
-    o = o.reshape(B, H, Tq, D).transpose(0, 2, 1, 3)
+    o = o.reshape(B, H, Tq, Dv).transpose(0, 2, 1, 3)
     if return_lse:
         return o, lse.reshape(B, H, Tq).transpose(0, 2, 1)
     return o

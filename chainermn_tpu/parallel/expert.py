@@ -176,7 +176,7 @@ def expert_parallel_moe(
 
 
 def route_top_k(x, router_w, top_k: int, score: str = "softmax",
-                scale: float = 1.0):
+                scale: float = 1.0, bias=None):
     """``(probs, top_i, gates)`` of a linear router, in float32 whatever
     the compute dtype (input, product and score): a choice that flips
     between two near-equal experts moves a whole expert's output, which
@@ -189,17 +189,22 @@ def route_top_k(x, router_w, top_k: int, score: str = "softmax",
     ``s = sigmoid(logits)``; the k largest win, ``gates`` are their
     scores normalised over the k chosen, and ``probs`` is ``s`` over its
     sum across the experts, the distribution the balancing loss needs.
-    ``scale`` multiplies the gates (a routed scaling factor)."""
+    ``scale`` multiplies the gates (a routed scaling factor).
+
+    ``bias`` ``(E,)``: a selection bias.  The k experts with the largest
+    ``score + bias`` win; ``gates`` are the winners' scores WITHOUT it,
+    and ``probs`` does not see it either: it steers the choice and not
+    the mixture."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
     if score == "sigmoid":
         s = jax.nn.sigmoid(logits)                      # (N, E)
-        top_s, top_i = lax.top_k(s, top_k)              # (N, k)
+        top_s, top_i = _top_k_biased(s, top_k, bias)    # (N, k)
         gates = top_s / jnp.sum(top_s, axis=-1, keepdims=True)
         probs = s / jnp.sum(s, axis=-1, keepdims=True)
     elif score == "softmax":
         probs = jax.nn.softmax(logits, axis=-1)         # (N, E)
-        top_p, top_i = lax.top_k(probs, top_k)          # (N, k)
+        top_p, top_i = _top_k_biased(probs, top_k, bias)  # (N, k)
         gates = top_p if top_k == 1 else \
             top_p / jnp.sum(top_p, axis=-1, keepdims=True)
     else:
@@ -207,6 +212,15 @@ def route_top_k(x, router_w, top_k: int, score: str = "softmax",
     if scale != 1.0:
         gates = gates * scale
     return probs, top_i, gates
+
+
+def _top_k_biased(scores, top_k, bias):
+    """``(values, indices)`` of the k largest ``scores + bias`` a row;
+    the values are the winners' ``scores``."""
+    if bias is None:
+        return lax.top_k(scores, top_k)
+    _, top_i = lax.top_k(scores + bias.astype(scores.dtype), top_k)
+    return jnp.take_along_axis(scores, top_i, axis=-1), top_i
 
 
 def grouped_dense(rows, w, group_sizes):
@@ -340,6 +354,7 @@ def expert_parallel_moe_dropless(
     first_expert: int = 0,
     score: str = "softmax",
     scale: float = 1.0,
+    bias=None,
     axis_name: str = "expert",
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Top-k mixture of experts without capacity: every (token, choice)
@@ -366,8 +381,8 @@ def expert_parallel_moe_dropless(
         experts' network over rows sorted by local expert
         (:func:`grouped_dense` products).
       top_k: experts per token (static; 1 <= k <= E).
-      score, scale: the router's score function and the factor its
-        gates carry (:func:`route_top_k`).
+      score, scale, bias: the router's score function, the factor its
+        gates carry and its selection bias (:func:`route_top_k`).
 
     Returns ``(out, aux, chosen)``: ``out`` ``(N, D)``; ``aux`` the
     global balancing loss ``E * sum_e f_e * P_e`` over all ``E`` columns
@@ -386,7 +401,7 @@ def expert_parallel_moe_dropless(
 
     with jax.named_scope("moe/route"):
         probs, top_i, gates = route_top_k(
-            x, router_w, top_k, score, scale)
+            x, router_w, top_k, score, scale, bias)
         choice = top_i.reshape(-1) - first_expert       # (N*k,)
         held = (choice >= 0) & (choice < G)
         order, inv, sizes = _sort_by_group(jnp.where(held, choice, G), G)

@@ -311,3 +311,49 @@ def test_laguna_cell_step_fits_and_pads_no_heads(topo):
     gib = program_bytes(compiled) / 2 ** 30
     assert 10 <= gib <= 14.5, f"{gib:.2f} GiB"
 
+
+
+def test_kimi_cell_step_fits_with_its_mixers_in_it(topo):
+    """The benchmark's Kimi Linear cell at its real size (one sequence
+    of 16,384 tokens, a leading KDA layer with the dense MLP and a
+    period KDA, KDA, MLA, KDA of sparse ones, 8 of 256 experts held),
+    through the cell's own files and its driver's mapping: the MLA
+    layer's three flash kernels take keys 192 wide and values 128 as
+    they are, the chunked recurrence and every new scope are in the
+    program, and the compiled step needs between 10 and 14.5 GiB of the
+    chip's 16 at the traffic file's ``loss_chunk`` (the sizing rule of
+    ISSUE 32: the first branch, ``loss_chunk`` 0)."""
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.lib import cells, scopes, scopes_hybrid
+    from benchmarks.lib.harness import build_optimizer, program_bytes
+    from chainermn_tpu.parallel import MeshConfig
+
+    cell, cfg, job = cells.load_cell("kimi-linear-l5-ep32-train-tok16384")
+    assert (job["batch"], job["seq"], job["loss_chunk"]) == (1, 16384, 0)
+    pcfg = cells.module("drivers", job["driver"])._program_config(cfg, job)
+    assert pcfg.blocks_by_position and len(pcfg.leading_layers) == 1
+    compiled = _compile_step(
+        MeshConfig(devices=topo.devices[:cell["chips"]], **job["mesh"]),
+        pcfg, build_optimizer(cfg["optimizer"]), job["batch"], job["seq"])
+    text = compiled.as_text()
+    # forward, dq and dkv of the one MLA layer, and no second forward;
+    # the KDA layers run no kernel of ours
+    assert _flash_kernels(text, "attn/mla") == 3
+    assert _flash_kernels(text) == 3
+    kernels = [line for line in text.splitlines()
+               if "pallas_call" in line and "tpu_custom_call" in line]
+    for line in kernels:
+        # 32 heads of one sequence: q and k 192 wide, v and o 128
+        assert "bf16[32,16384,192]" in line and "bf16[32,16384,128]" \
+            in line, line[:300]
+    assert set(scopes_hybrid.instruction_scopes(text).values()) == {
+        "kda/conv", "kda/scan", "kda/gate", "mla/latent"}
+    by_scope = set(scopes.instruction_scopes(text).values())
+    assert {"attn/kda", "attn/mla", "moe/experts"} <= by_scope
+    gib = program_bytes(compiled) / 2 ** 30
+    assert 10 <= gib <= 14.5, f"{gib:.2f} GiB"

@@ -1,0 +1,364 @@
+"""A transformer whose layers differ in their MIXER: Kimi Delta
+Attention (the chunked recurrence of ``ops/kda.py``), latent attention
+without rotary through flash kernels whose values are narrower than
+their keys, and a router whose selection bias steers the choice and not
+the gate.  The op against the recurrence a token at a time, the kernels
+against plain scores, the parameter trees, the counters, the bias held
+fixed, and every refusal by name."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from chainermn_tpu.models import (
+    AttentionKind,
+    TransformerConfig,
+    init_transformer,
+    make_generate_fn,
+    make_train_step,
+    param_specs,
+    shard_params,
+)
+from chainermn_tpu.ops.kda import kda_chunked, kda_recurrent
+from chainermn_tpu.ops.pallas_attention import flash_attention
+from chainermn_tpu.parallel import MeshConfig
+from chainermn_tpu.parallel.expert import route_top_k
+from chainermn_tpu.parallel.ring_attention import local_attention
+from chainermn_tpu.training import shard_opt_state
+from chainermn_tpu.utils.metrics import MetricsRegistry, set_registry
+
+VOCAB, B, T = 64, 2, 128
+KDA = AttentionKind("kda", mixer="kda")
+MLA = AttentionKind("mla", mixer="mla", kv_latent=16, d_shared_key=8,
+                    d_value=16)
+
+
+def hybrid_cfg(**kw):
+    base = dict(
+        vocab_size=VOCAB, d_model=32, n_heads=4, d_head=8, d_ff=16,
+        n_layers=5, max_seq=T, attention="local", dtype="float32",
+        pos_embedding="rope", norm_eps=1e-5, leading_layers=(KDA,),
+        layer_pattern=(KDA, KDA, MLA, KDA), dense_act="swiglu",
+        dense_d_ff=48, moe=True, n_experts=8, router_top_k=2,
+        moe_dispatch="dropless", expert_act="swiglu", experts_held=(2, 4),
+        router_score="sigmoid", router_scale=2.446,
+        router_bias="selection", shared_expert_d_ff=24,
+        tie_embeddings=False)
+    base.update(kw)
+    return TransformerConfig(**base)
+
+
+def one_chip():
+    return MeshConfig(devices=jax.devices()[:1], data=1)
+
+
+@pytest.fixture(scope="module")
+def host():
+    """Seeded weights of ``hybrid_cfg(experts_held=(0, 8))``, made once,
+    as numpy (a donated step cannot delete them)."""
+    return jax.tree.map(np.asarray, jax.jit(lambda: init_transformer(
+        jax.random.PRNGKey(0), hybrid_cfg(experts_held=(0, 8))))())
+
+
+def tokens(b=B):
+    t = jnp.asarray(np.random.RandomState(0).randint(
+        0, VOCAB, (b, T + 1)), jnp.int32)
+    return t[:, :-1], t[:, 1:]
+
+
+# -- the recurrence --------------------------------------------------- #
+
+def _draw(seed, t, decay, b=2, h=3, d=16):
+    """q, k, v, g, beta as the layer hands them over.  ``published``:
+    exp(A) uniform in [1, 16] times a softplus that reaches 8, so a
+    chunk's running sum passes -200 and exp(-G) is no float32;
+    ``mild``: decays near 1, so the state carries across chunks."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    a = jax.random.uniform(ks[3], (h,), minval=1.0, maxval=16.0)
+    soft = jax.nn.softplus(2 * jax.random.normal(ks[4], (b, t, h, d)))
+    g = -a[:, None] * (soft if decay == "published" else soft * 1e-3)
+    return (unit(jax.random.normal(ks[0], (b, t, h, d))) * d ** -0.5,
+            unit(jax.random.normal(ks[1], (b, t, h, d))),
+            jax.random.normal(ks[2], (b, t, h, d)), g,
+            jax.nn.sigmoid(jax.random.normal(ks[5], (b, t, h))))
+
+
+@pytest.mark.parametrize("decay", ["published", "mild"])
+@pytest.mark.parametrize("chunks", [1, 4, 8])
+def test_chunked_recurrence_equals_the_recurrence(chunks, decay):
+    """Values and all five gradients against the recurrence a token at
+    a time, at one chunk, at four (one slab) and at eight (two slabs of
+    four), every value finite in float32 under the published decays."""
+    args = _draw(chunks, 64 * chunks, decay)
+    if decay == "published":
+        assert float(jnp.cumsum(args[3][:, :64], axis=1).min()) < -200
+    weight = jnp.cos(jnp.arange(args[2].size, dtype=jnp.float32)).reshape(
+        args[2].shape)
+
+    def loss(fn):
+        return lambda *a: jnp.sum(fn(*a) * weight)
+
+    want, got = kda_recurrent(*args), jax.jit(kda_chunked)(*args)
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+    if decay == "mild":     # the state is not forgotten within a chunk
+        assert float(jnp.abs(want[:, -1]).mean()) > 0.05
+    grads = jax.jit(jax.grad(loss(kda_chunked), argnums=(0, 1, 2, 3, 4)))(*args)
+    for got, want in zip(grads, jax.grad(
+            loss(kda_recurrent), argnums=(0, 1, 2, 3, 4))(*args)):
+        assert bool(jnp.isfinite(got).all())
+        np.testing.assert_allclose(
+            got, want, rtol=2e-3, atol=2e-4 * float(jnp.abs(want).max()))
+
+
+def test_recurrence_counters_and_shapes_it_refuses():
+    reg = MetricsRegistry(enabled=True)
+    prev = set_registry(reg)
+    try:
+        args = _draw(0, 512, "mild")
+        # a function of its own: a trace cached for these shapes would
+        # count nothing
+        jax.eval_shape(lambda *a: kda_chunked(*a), *args)
+    finally:
+        set_registry(prev)
+    assert reg.counter("kda/chunks").value == 8
+    # the state at the start of each of two slabs: B x H x d x d floats
+    assert reg.counter("kda/state_bytes_kept").value == 2 * 2 * 3 * 16 * 16 * 4
+    with pytest.raises(ValueError, match="whole chunks"):
+        kda_chunked(*_draw(0, 96, "mild"))
+
+
+# -- the flash kernels at a value width of their own ------------------ #
+
+@pytest.mark.parametrize("t,block", [(64, 64), (256, 128)],
+                         ids=["one-block", "four-pairs"])
+def test_flash_kernels_with_keys_wider_than_values(t, block):
+    """Keys 192 wide, values 128: forward and the three gradients
+    against plain scores, interpreted; the scale is the key width's."""
+    ks = jax.random.split(jax.random.PRNGKey(t), 4)
+    q = jax.random.normal(ks[0], (1, t, 2, 192))
+    k = jax.random.normal(ks[1], (1, t, 2, 192))
+    v = jax.random.normal(ks[2], (1, t, 2, 128))
+    w = jax.random.normal(ks[3], (1, t, 2, 128))
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=block, block_k=block, interpret=True)
+    plain = lambda q, k, v: local_attention(q, k, v, causal=True)
+    got = flash(q, k, v)
+    assert got.shape == (1, t, 2, 128)
+    np.testing.assert_allclose(got, plain(q, k, v), rtol=2e-5, atol=2e-5)
+    grad = lambda f: jax.grad(
+        lambda q, k, v: jnp.sum(f(q, k, v) * w), argnums=(0, 1, 2))(q, k, v)
+    for got, want in zip(grad(flash), grad(plain)):
+        assert got.shape == want.shape
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+    with pytest.raises(ValueError, match="q and k widths differ"):
+        flash_attention(q, v, v, causal=True, interpret=True)
+
+
+# -- the parameter trees ---------------------------------------------- #
+
+def test_each_mixer_has_its_own_tree():
+    cfg = hybrid_cfg()
+    assert cfg.blocks_by_position and cfg.mixers == ["kda", "mla"]
+    params = jax.eval_shape(
+        lambda: init_transformer(jax.random.PRNGKey(0), cfg))
+    shape = lambda blk: {k: v.shape[2:] for k, v in blk.items()}
+    kda, mla = shape(params["blocks"][0]), shape(params["blocks"][2])
+    mixer = lambda blk: {k: v for k, v in blk.items() if k not in (
+        "ln1", "ln2", "router", "router_bias", "w1", "w2", "w3", "ws1",
+        "ws2", "ws3")}
+    assert mixer(kda) == {
+        "wqkv": (32, 3, 4, 8), "conv": (3, 4, 8, 4), "wf_a": (32, 8),
+        "wf_b": (8, 4, 8), "a_log": (4,), "dt_bias": (4, 8),
+        "wbeta": (32, 4), "wg_a": (32, 8), "wg_b": (8, 4, 8),
+        "o_norm": (8,), "wo": (4, 8, 32)}
+    assert mixer(mla) == {
+        "wq": (32, 4, 16), "wkva": (32, 24), "kv_norm": (16,),
+        "wkvb": (16, 4, 24), "wo": (4, 16, 32)}
+    assert kda["router_bias"] == (8,)
+    # the leading layer: KDA with the dense MLP, no router
+    lead = params["leading"][0]
+    assert lead["w1"].shape == (32, 48) and "router" not in lead
+    assert jax.tree.structure(params) == jax.tree.structure(
+        param_specs(cfg), is_leaf=lambda s: isinstance(
+            s, jax.sharding.PartitionSpec))
+    # the same mixer at every position keeps the single stack
+    alike = hybrid_cfg(layer_pattern=(KDA,) * 4)
+    assert not alike.blocks_by_position
+
+
+@pytest.mark.parametrize("kw", [
+    dict(mixer="mamba"), dict(mixer="mla"), dict(mixer="kda", conv_taps=0),
+    dict(mixer="kda", window=8), dict(mixer="mla", kv_latent=8,
+                                      yarn_factor=2, yarn_original_max=8)])
+def test_attention_kind_validation(kw):
+    with pytest.raises(ValueError):
+        AttentionKind("x", **kw)
+
+
+def test_the_published_initialisers(host):
+    blk = host["leading"][0]
+    a = np.exp(np.asarray(blk["a_log"]))
+    assert a.min() >= 1 and a.max() <= 16
+    dt = np.asarray(jax.nn.softplus(blk["dt_bias"]))
+    assert dt.min() >= 1e-3 * 0.99 and dt.max() <= 1e-1 * 1.01
+
+
+# -- the selection bias ------------------------------------------------ #
+
+def test_selection_bias_flips_a_choice_and_leaves_the_gates_to_the_scores():
+    x = jax.random.normal(jax.random.PRNGKey(0), (16, 8))
+    w = jax.random.normal(jax.random.PRNGKey(1), (8, 6))
+    probs, top, gates = route_top_k(x, w, 2, "sigmoid", 2.446)
+    s = np.asarray(jax.nn.sigmoid(x @ w))
+    # lift the expert that came third for token 0 past the second
+    order = np.argsort(-s[0])
+    bias = np.zeros(6, np.float32)
+    bias[order[2]] = s[0, order[1]] - s[0, order[2]] + 1e-3
+    probs_b, top_b, gates_b = route_top_k(
+        x, w, 2, "sigmoid", 2.446, jnp.asarray(bias))
+    assert sorted(np.asarray(top_b[0])) == sorted(order[[0, 2]])
+    assert sorted(np.asarray(top[0])) == sorted(order[[0, 1]])
+    # the gates are the winners' scores without the bias
+    chosen = np.take_along_axis(s, np.asarray(top_b), axis=1)
+    np.testing.assert_allclose(
+        gates_b, 2.446 * chosen / chosen.sum(1, keepdims=True), rtol=1e-5)
+    np.testing.assert_allclose(probs_b, probs)
+    # softmax scores take it too
+    _, top_s, gates_s = route_top_k(x, w, 2, "softmax", 1.0,
+                                    jnp.asarray(bias) * 10)
+    assert order[2] in np.asarray(top_s[0])
+    assert float(gates_s.sum(1).max()) <= 1 + 1e-5
+
+
+def test_the_bias_is_held_fixed_by_gradient_and_by_weight_decay(host):
+    """Three AdamW steps with a weight decay that moves every other
+    leaf: the bias stays to the last bit, and it decides choices (the
+    loss differs from the unbiased router's)."""
+    cfg, mc = hybrid_cfg(experts_held=(0, 8)), one_chip()
+    bias = lambda p: [np.asarray(b["router_bias"]) for b in p["blocks"]]
+    host = dict(host, blocks=tuple(dict(
+        b, router_bias=b["router_bias"] + 0.3 * (np.arange(8) % 3).astype(
+            np.float32)) for b in host["blocks"]))
+    before = bias(host)
+    opt = optax.adamw(1e-3, weight_decay=0.1)
+    params = shard_params(mc, cfg, host)
+    state = shard_opt_state(opt, params)
+    step = make_train_step(mc, cfg, opt)
+    losses = []
+    for _ in range(3):
+        params, state, loss = step(params, state, *tokens())
+        losses.append(float(loss))
+    assert all(np.array_equal(a, b) for a, b in zip(bias(params), before))
+    moved = np.abs(np.asarray(params["blocks"][0]["router"])
+                   - np.asarray(host["blocks"][0]["router"])).max()
+    assert moved > 0 and np.isfinite(losses).all()
+    unbiased = jax.tree_util.tree_map_with_path(
+        lambda path, a: a * 0 if "router_bias" in str(path) else a, host)
+    params = shard_params(mc, cfg, unbiased)
+    _, _, loss0 = step(params, shard_opt_state(opt, params), *tokens())
+    assert abs(float(loss0) - losses[0]) > 1e-4
+
+
+def test_hold_selection_bias_serves_a_step_of_ones_own():
+    """Outside ``make_train_step`` (a step around ``optimizer.update``
+    as ``training/updater.py`` has one): the wrapped optimizer leaves
+    every ``router_bias`` leaf to the bit, moves the rest as the plain
+    one does, and its state is the plain one's."""
+    from chainermn_tpu.models.transformer import hold_selection_bias
+
+    params = {"blocks": ({"router": jnp.ones((4, 8)),
+                          "router_bias": jnp.arange(8.0)},),
+              "embed": jnp.ones((8, 4))}
+    grads = jax.tree.map(jnp.zeros_like, params)
+    plain = optax.adamw(1e-2, weight_decay=0.1)
+    held = hold_selection_bias(plain)
+    state = held.init(params)
+    assert jax.tree.structure(state) == jax.tree.structure(
+        plain.init(params))
+    want, _ = plain.update(grads, state, params)
+    got, _ = held.update(grads, state, params)
+    assert float(jnp.abs(want["blocks"][0]["router_bias"]).max()) > 0
+    assert not np.asarray(got["blocks"][0]["router_bias"]).any()
+    for leaf in ("router",):
+        np.testing.assert_array_equal(
+            got["blocks"][0][leaf], want["blocks"][0][leaf])
+    np.testing.assert_array_equal(got["embed"], want["embed"])
+
+
+# -- refusals, by name -------------------------------------------------- #
+
+@pytest.mark.parametrize("mesh,kw,match", [
+    (dict(seq=2), {}, "seq, model and pipe mesh axes must be 1"),
+    (dict(model=2), {}, "seq, model and pipe mesh axes must be 1"),
+    (dict(pipe=2), dict(leading_layers=(), n_layers=8),
+     "seq, model and pipe mesh axes must be 1"),
+    (dict(data=1), dict(attention="ring"), "attention='flash' or 'local'"),
+    (dict(data=1), dict(attention="ulysses"),
+     "attention='flash' or 'local'"),
+    (dict(data=2), dict(fsdp=True), "fsdp=True is not implemented for the "
+     "kda/mla layers"),
+], ids=["seq", "model", "pipe", "ring", "ulysses", "fsdp"])
+def test_meshes_and_paths_the_mixers_cannot_run_are_refused(mesh, kw, match):
+    n = int(np.prod(list(mesh.values())))
+    mc = MeshConfig(devices=jax.devices()[:n], **mesh)
+    with pytest.raises(ValueError, match=match):
+        make_train_step(mc, hybrid_cfg(**kw), optax.sgd(1.0))
+
+
+def test_data_and_expert_axes_stay_open(host):
+    """Two data members, each an expert group of two that shares its
+    experts out, against two data members that hold them whole (the
+    balancing loss is a data member's own): the same loss and the same
+    update."""
+    def one_step(**mesh):
+        n = int(np.prod(list(mesh.values())))
+        mc = MeshConfig(devices=jax.devices()[:n], **mesh)
+        cfg = hybrid_cfg(experts_held=(0, 8))
+        params = shard_params(mc, cfg, host)
+        opt = optax.sgd(1.0)
+        params, _, loss = make_train_step(mc, cfg, opt)(
+            params, shard_opt_state(opt, params), *tokens(4))
+        return float(loss), jax.tree.map(
+            lambda a, b: b - np.asarray(a), params, host)
+
+    loss1, delta1 = one_step(data=2)
+    loss4, delta4 = one_step(data=2, expert=2)
+    assert loss4 == pytest.approx(loss1, rel=2e-5)
+    for a, b in zip(jax.tree.leaves(delta1), jax.tree.leaves(delta4)):
+        np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-5)
+
+
+@pytest.mark.parametrize("kw,named", [
+    ({}, "AttentionKind.mixer=kda/mla"),
+    ({}, "router_bias"),
+    ({}, "norm_eps"),
+], ids=["mixers", "selection-bias", "norm-eps"])
+def test_decoding_and_serving_refuse_the_new_fields(kw, named):
+    from chainermn_tpu.serving.engine import TransformerAdapter
+
+    cfg = hybrid_cfg(**kw)
+    assert named in cfg.training_only
+    with pytest.raises(ValueError, match="decoding does not implement") \
+            as err:
+        make_generate_fn(one_chip(), cfg, max_len=T)
+    assert named in str(err.value)
+    with pytest.raises(ValueError, match="serving engine does not "
+                       "implement") as err:
+        TransformerAdapter(one_chip(), cfg)
+    assert named in str(err.value)
+
+
+@pytest.mark.parametrize("kw,match", [
+    (dict(router_bias="learned"), "router_bias"),
+    (dict(router_bias="selection", moe_dispatch="capacity",
+          expert_act="relu", experts_held=(), router_score="softmax",
+          router_scale=1.0, shared_expert_d_ff=0), "dropless expert layer"),
+    (dict(attn_gate="per_head"), "attn_gate is softmax attention's"),
+])
+def test_config_validation(kw, match):
+    with pytest.raises(ValueError, match=match):
+        hybrid_cfg(**kw)
