@@ -1496,7 +1496,9 @@ def _kda_mixer(cfg: TransformerConfig, x, blk, kind):
     """Kimi Delta Attention on the normed input ``x``: the layer's
     contribution to the residual stream.  Projections in the compute
     dtype with float32 results; convolution, norms, gates and the
-    recurrence (``ops/kda.py``) in float32."""
+    recurrence (``ops/kda.py``) in float32.  ``kda/scan`` holds the
+    whole op, its Pallas kernel for the chunks' unit-triangular systems
+    (interpreted off the TPU, as the flash kernels are) included."""
     cd, f32 = cfg.compute_dtype, jnp.float32
     B, T, D = x.shape
     H, Dh = blk["wqkv"].shape[2:]

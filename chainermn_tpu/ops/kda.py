@@ -20,8 +20,15 @@ tokens, inside a chunk the WY/UT form.  With ``G_t`` the running sum of
 
 The unit lower triangular system is solved once a chunk for the two
 right-hand sides ``Diag(b) V`` and ``Diag(b) (K . exp(G))``, which no
-state enters; between chunks a ``lax.scan`` carries ``S`` through three
-products of chunk-sized blocks.
+state enters (:func:`solve`): ``T = (I + Diag(b) A)^-1`` by float32
+substitution in a Pallas kernel, then one product ``T R`` at
+``Precision.HIGHEST`` for both right-hand sides.  The kernel lays the
+SYSTEMS on the lanes, 128 to a vector (a slab step of 32 heads and 4
+chunks is exactly one): XLA's own kernel for the solve took the 32
+heads for its lanes and a quarter of the vector unit did the work.  A
+count that is no multiple of 128 is padded inside.  Between chunks a
+``lax.scan`` carries ``S`` through three products of chunk-sized
+blocks.
 
 **Every exponent is a difference of running sums that is <= 0**, and
 nothing is divided by a decay: with decays drawn as published a chunk's
@@ -35,15 +42,23 @@ as ``(k_t . exp(G_t - R_a)) . (k_i . exp(R_a - G_i))``, both factors
 <= 1.  A product of two such factors that underflows is a pair whose
 true weight is below float32 too.
 
-Backward is autodiff through the chunked form.  The sequence is cut
-into slabs of ``SLAB`` chunks; the outer scan over the slabs has its
-body under ``jax.checkpoint``, so what is kept for the backward pass is
-the state at each slab's start (``kda/state_bytes_kept``) and a slab's
-own inputs, and a slab's pair-by-pair exponents (``SUB`` times the size
-of its keys) live only while that slab is differentiated.
+Backward is autodiff through the chunked form, except through the
+solve, whose ``jax.custom_vjp`` keeps ``T`` and ``X`` and inverts
+nothing again: ``dR = T^T dX`` (``HIGHEST``) and ``dN`` the strictly
+lower part of ``-dR X^T``, where autodiff through a triangular solve
+would solve a second time.  The sequence is cut into slabs of ``SLAB``
+chunks; the outer scan over the slabs has its body under
+``jax.checkpoint``, so what is kept for the backward pass is the state
+at each slab's start (``kda/state_bytes_kept``) and a slab's own
+inputs; a slab's pair-by-pair exponents (``SUB`` times the size of its
+keys), its ``T`` and its ``X`` live only while that slab is
+differentiated.  A layer inverts its systems once in the forward pass
+and once when the slab is recomputed (and once more where the block's
+own checkpoint reruns the layer).
 
 Trace-time counters (``utils.metrics`` registry, a call): ``kda/chunks``
-(chunks a sequence) and ``kda/state_bytes_kept``.
+(chunks a sequence), ``kda/state_bytes_kept`` and
+``kda/systems_inverted`` (systems a pass: a chunk and head each).
 """
 
 from __future__ import annotations
@@ -51,7 +66,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from chainermn_tpu.ops.pallas_attention import interpret_kernels
 from chainermn_tpu.utils.metrics import get_registry
 
 __all__ = ["kda_chunked", "kda_recurrent"]
@@ -128,17 +146,91 @@ def _pair_weights(q, k, G, g, sub):
     return whole(off_kk, in_kk), whole(off_qk, in_qk)
 
 
+_LANES = 128     # systems a vector: one a lane
+
+
+def _inverse_kernel(n_ref, t_ref):
+    """``(I + N)^-1`` of ``_LANES`` systems side by side: ``n_ref`` and
+    ``t_ref`` ``(C, C, _LANES)``, row, column, system.  Row by row
+    ``T[i] = e_i - sum_{j<i} N[i, j] T[j]``, ``T[j]`` a ``(C, _LANES)``
+    tile and ``N[i, j]`` a lane vector spread over it: float32
+    multiplies and subtractions on the vector unit.  The diagonal of
+    ``N`` and what is above it are never read."""
+    C = n_ref.shape[0]
+    column = lax.broadcasted_iota(jnp.int32, (C, _LANES), 0)
+
+    def row(i, carry):
+        def term(j, acc):
+            return acc - n_ref[i, pl.ds(j, 1), :] * t_ref[j]
+
+        t_ref[i] = lax.fori_loop(
+            0, i, term, (column == i).astype(jnp.float32))
+        return carry
+
+    # a traced bound: with a static one the loop is a scan, whose carry
+    # check the interpreter trips under shard_map's varying axes
+    lax.fori_loop(jnp.int32(0), C, row, 0)
+
+
+def _inverse(N):
+    """``(I + N)^-1`` for ``N`` ``(systems, C, C)`` strictly lower
+    triangular (what is on and above the diagonal is ignored), in
+    float32 on the vector unit, ``_LANES`` systems a kernel step."""
+    S, C, _ = N.shape
+    padded = -(-S // _LANES) * _LANES
+    # the systems go on the lanes; a padded lane inverts the identity
+    by_lane = jnp.pad(jnp.moveaxis(N, 0, -1),
+                      ((0, 0), (0, 0), (0, padded - S)))
+    block = pl.BlockSpec((C, C, _LANES), lambda s: (0, 0, s))
+    T = pl.pallas_call(
+        _inverse_kernel, grid=(padded // _LANES,),
+        in_specs=[block], out_specs=block,
+        out_shape=jax.ShapeDtypeStruct(
+            by_lane.shape, jnp.float32, vma=jax.typeof(N).vma),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret_kernels())(by_lane)
+    return jnp.moveaxis(T[..., :S], -1, 0)
+
+
+@jax.custom_vjp
+def solve(N, R):
+    """``X`` of ``(I + N) X = R`` for ``N`` ``(..., C, C)``, of which
+    only the part strictly below the diagonal is read, and ``R``
+    ``(..., C, n)``: the systems are inverted once, side by side on the
+    lanes in float32, and the inverse is applied at full precision.
+    The backward pass applies the same inverse transposed and inverts
+    nothing."""
+    return _solve_fwd(N, R)[0]
+
+
+def _solve_fwd(N, R):
+    C = N.shape[-1]
+    T = _inverse(N.reshape(-1, C, C)).reshape(N.shape)
+    X = jnp.matmul(T, R, precision=_HI)
+    return X, (T, X)
+
+
+def _solve_bwd(kept, dX):
+    T, X = kept
+    dR = jnp.matmul(jnp.swapaxes(T, -1, -2), dX, precision=_HI)
+    dN = -jnp.tril(jnp.matmul(
+        dR, jnp.swapaxes(X, -1, -2), precision=_HI), -1)
+    return dN, dR
+
+
+solve.defvjp(_solve_fwd, _solve_bwd)
+
+
 def _chunk_parts(q, k, v, g, beta, sub):
     """What a chunk gives the scan over the states, none of it a
     function of a state: ``(U_v, W, Q_g, A', K_end, decay_C)`` with
     ``U = U_v - W S_0``."""
     G = jnp.cumsum(g, axis=-2)
     A, A_q = _pair_weights(q, k, G, g, sub)
-    C, dv = v.shape[-2:]
-    system = jnp.eye(C, dtype=k.dtype) + beta[..., None] * A
+    dv = v.shape[-1]
     rhs = beta[..., None] * jnp.concatenate([v, k * jnp.exp(G)], axis=-1)
-    solved = lax.linalg.triangular_solve(
-        system, rhs, left_side=True, lower=True, unit_diagonal=True)
+    solved = solve(beta[..., None] * A, rhs)
     G_end = G[..., -1:, :]
     return (solved[..., :dv], solved[..., dv:], q * jnp.exp(G), A_q,
             k * jnp.exp(G_end - G), jnp.exp(G_end[..., 0, :]))
@@ -183,6 +275,7 @@ def kda_chunked(q, k, v, g, beta):
     reg = get_registry()
     reg.inc("kda/chunks", n_chunks)
     reg.inc("kda/state_bytes_kept", n_slabs * B * H * dk * dv * 4)
+    reg.inc("kda/systems_inverted", B * H * n_chunks)
 
     def slabs(x):
         # (B, T, H, ...) -> (slabs, B, H, chunks a slab, chunk, ...)

@@ -319,10 +319,14 @@ def test_kimi_cell_step_fits_with_its_mixers_in_it(topo):
     period KDA, KDA, MLA, KDA of sparse ones, 8 of 256 experts held),
     through the cell's own files and its driver's mapping: the MLA
     layer's three flash kernels take keys 192 wide and values 128 as
-    they are, the chunked recurrence and every new scope are in the
-    program, and the compiled step needs between 10 and 14.5 GiB of the
-    chip's 16 at the traffic file's ``loss_chunk`` (the sizing rule of
-    ISSUE 32: the first branch, ``loss_chunk`` 0)."""
+    they are; each KDA position inverts its chunks' systems in three
+    call sites of the op's own kernel (forward, the block's recompute,
+    the slab's; the solve's VJP has none), 128 systems on the lanes,
+    and XLA's triangular solve is gone; the chunked recurrence and
+    every new scope are in the program, and the compiled step needs
+    between 10 and 14.5 GiB of the chip's 16 at the traffic file's
+    ``loss_chunk`` (the sizing rule of ISSUE 32: the first branch,
+    ``loss_chunk`` 0)."""
     import sys
 
     root = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -341,16 +345,23 @@ def test_kimi_cell_step_fits_with_its_mixers_in_it(topo):
         MeshConfig(devices=topo.devices[:cell["chips"]], **job["mesh"]),
         pcfg, build_optimizer(cfg["optimizer"]), job["batch"], job["seq"])
     text = compiled.as_text()
-    # forward, dq and dkv of the one MLA layer, and no second forward;
-    # the KDA layers run no kernel of ours
+    # forward, dq and dkv of the one MLA layer, and no second forward
     assert _flash_kernels(text, "attn/mla") == 3
-    assert _flash_kernels(text) == 3
     kernels = [line for line in text.splitlines()
                if "pallas_call" in line and "tpu_custom_call" in line]
+    inversions = [line for line in kernels if "kda/scan" in line]
+    # the leading layer and three of the period's four: forward, the
+    # block's recompute and the slab's, and no site in the solve's VJP
+    assert len(inversions) == 4 * 3 and len(kernels) == 3 + 4 * 3
+    assert "InvertDiagBlocksLowerTriangular" not in text
+    for line in inversions:
+        # a slab step's 1 x 32 x 4 systems of 64 x 64, a system a lane
+        assert "= f32[64,64,128]{2,1,0" in line, line[:300]
     for line in kernels:
         # 32 heads of one sequence: q and k 192 wide, v and o 128
-        assert "bf16[32,16384,192]" in line and "bf16[32,16384,128]" \
-            in line, line[:300]
+        assert line in inversions or (
+            "bf16[32,16384,192]" in line
+            and "bf16[32,16384,128]" in line), line[:300]
     assert set(scopes_hybrid.instruction_scopes(text).values()) == {
         "kda/conv", "kda/scan", "kda/gate", "mla/latent"}
     by_scope = set(scopes.instruction_scopes(text).values())

@@ -6,6 +6,8 @@ the gate.  The op against the recurrence a token at a time, the kernels
 against plain scores, the parameter trees, the counters, the bias held
 fixed, and every refusal by name."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,6 +23,7 @@ from chainermn_tpu.models import (
     param_specs,
     shard_params,
 )
+from chainermn_tpu.ops import kda
 from chainermn_tpu.ops.kda import kda_chunked, kda_recurrent
 from chainermn_tpu.ops.pallas_attention import flash_attention
 from chainermn_tpu.parallel import MeshConfig
@@ -127,8 +130,89 @@ def test_recurrence_counters_and_shapes_it_refuses():
     assert reg.counter("kda/chunks").value == 8
     # the state at the start of each of two slabs: B x H x d x d floats
     assert reg.counter("kda/state_bytes_kept").value == 2 * 2 * 3 * 16 * 16 * 4
+    # a system a chunk and head, each inverted once a pass
+    assert reg.counter("kda/systems_inverted").value == 2 * 3 * 8
     with pytest.raises(ValueError, match="whole chunks"):
         kda_chunked(*_draw(0, 96, "mild"))
+
+
+# -- the solve inside a chunk ----------------------------------------- #
+
+@functools.cache
+def _layers_systems(c):
+    """``Diag(beta) A`` of one chunk of ``c`` tokens in each of 129
+    heads, as the layer makes it."""
+    @jax.jit
+    def made(q, k, g, beta):
+        return beta[..., None] * kda._pair_weights(
+            q, k, jnp.cumsum(g, axis=-2), g, min(kda.SUB, c))[0]
+
+    q, k, _, g, beta = (jnp.moveaxis(x[0], 1, 0) for x in _draw(
+        c, c, "mild", b=1, h=129))
+    return made(q, k, g, beta)
+
+
+def _systems(seed, systems, c, entries):
+    """``N`` ``(systems, c, c)`` and two right-hand sides side by side.
+    ``layer``: as the layer makes it; ``near_one``: entries of size 0.9
+    to 1 and either sign, where the inverse's entries grow (to 1e3 at
+    16 rows, 1e8 at 64).  On and above the diagonal stands garbage that
+    no result may depend on."""
+    rs = np.random.RandomState(seed)
+    if entries == "layer":
+        below = np.asarray(_layers_systems(c)[:systems])
+    else:
+        below = rs.uniform(0.9, 1.0, (systems, c, c)) * rs.choice(
+            [-1.0, 1.0], (systems, c, c))
+    garbage = 1e6 * rs.standard_normal((systems, c, c))
+    return (jnp.asarray(np.where(np.tri(c, k=-1, dtype=bool), below,
+                                 garbage), jnp.float32),
+            jnp.asarray(rs.standard_normal((systems, c, 2 * 16)),
+                        jnp.float32))
+
+
+@jax.jit
+def _xla_solve(N, R):
+    """What ``solve`` replaced: XLA's triangular solve of ``I + N``."""
+    return jax.lax.linalg.triangular_solve(
+        jnp.tril(N, -1) + jnp.eye(N.shape[-1]), R, left_side=True,
+        lower=True, unit_diagonal=True)
+
+
+# one trace a shape, whatever the entries
+_solve = jax.jit(kda.solve)
+
+
+@pytest.mark.parametrize("entries", ["layer", "near_one"])
+@pytest.mark.parametrize("c", [64, 16])
+@pytest.mark.parametrize("systems", [1, 127, 128, 129])
+def test_solve_equals_xlas_triangular_solve(systems, c, entries):
+    """One lane short of a vector, a whole vector, one lane over (the
+    padding) and a lone system, at the chunk's 64 rows and at 16."""
+    N, R = _systems(systems + c, systems, c, entries)
+    got, want = _solve(N, R), _xla_solve(N, R)
+    assert got.shape == want.shape == R.shape
+    size = jnp.abs(want).max(axis=(1, 2), keepdims=True)
+    if entries == "near_one":
+        assert float(size.max()) > (1e6 if c == 64 else 10)
+    np.testing.assert_array_less(jnp.abs(got - want) / size, 1e-5)
+
+
+@pytest.mark.parametrize("systems,c,entries", [
+    (129, 16, "layer"), (3, 64, "layer"), (5, 16, "near_one")])
+def test_solve_vjp_equals_autodiff_through_xlas(systems, c, entries):
+    """Both arguments' cotangents: the inverse applied transposed, and
+    the strictly lower part of ``-dR X^T``; none where ``N`` is not
+    read."""
+    N, R = _systems(7 * systems + c, systems, c, entries)
+    weight = jnp.cos(jnp.arange(R.size, dtype=jnp.float32)).reshape(R.shape)
+    loss = lambda fn: lambda N, R: jnp.sum(fn(N, R) * weight)
+    got = jax.jit(jax.grad(loss(kda.solve), argnums=(0, 1)))(N, R)
+    want = jax.jit(jax.grad(loss(_xla_solve), argnums=(0, 1)))(N, R)
+    assert not np.triu(got[0]).any()
+    for g, w in zip(got, want):
+        size = jnp.abs(w).max(axis=(1, 2), keepdims=True)
+        np.testing.assert_array_less(jnp.abs(g - w) / size, 2e-5)
 
 
 # -- the flash kernels at a value width of their own ------------------ #
