@@ -24,6 +24,8 @@ from typing import NamedTuple, Optional
 import jax.numpy as jnp
 from jax import lax
 
+from chainermn_tpu.utils.telemetry import device_scope
+
 __all__ = [
     "BatchNormState",
     "init_batch_norm",
@@ -87,30 +89,37 @@ def multi_node_batch_normalization(
     x32 = x.astype(jnp.float32)
 
     if not train:
-        inv = lax.rsqrt(state.var + eps) * gamma
-        return (x32 * inv + (beta - state.mean * inv)).astype(x.dtype), state
+        with device_scope("bn/apply"):
+            inv = lax.rsqrt(state.var + eps) * gamma
+            return (x32 * inv + (beta - state.mean * inv)).astype(
+                x.dtype), state
 
     # Global batch statistics: local moments, then mean over the mesh axis.
     # (Mean-of-means is exact because every device holds the same local
     # batch size — the same assumption the reference's allreduce/size made.)
-    mean = jnp.mean(x32, axis=reduce_axes)
-    sq_mean = jnp.mean(jnp.square(x32), axis=reduce_axes)
-    if axis_name is not None:
-        mean = lax.pmean(mean, axis_name)
-        sq_mean = lax.pmean(sq_mean, axis_name)
-    var = sq_mean - jnp.square(mean)
+    # The two passes over the activations, each under its name: the
+    # reductions (``bn/stats``) and the normalisation (``bn/apply``).
+    with device_scope("bn/stats"):
+        mean = jnp.mean(x32, axis=reduce_axes)
+        sq_mean = jnp.mean(jnp.square(x32), axis=reduce_axes)
+        if axis_name is not None:
+            mean = lax.pmean(mean, axis_name)
+            sq_mean = lax.pmean(sq_mean, axis_name)
+        var = sq_mean - jnp.square(mean)
 
-    inv = lax.rsqrt(var + eps) * gamma
-    y = (x32 * inv + (beta - mean * inv)).astype(x.dtype)
+    with device_scope("bn/apply"):
+        inv = lax.rsqrt(var + eps) * gamma
+        y = (x32 * inv + (beta - mean * inv)).astype(x.dtype)
 
     # Running stats with the reference's unbiased-variance correction.
     m = x.size // x.shape[-1]
     if axis_name is not None:
         m = m * lax.axis_size(axis_name)
     adjust = m / max(m - 1.0, 1.0)
-    new_state = BatchNormState(
-        mean=decay * state.mean + (1.0 - decay) * mean,
-        var=decay * state.var + (1.0 - decay) * var * adjust,
-        n=state.n + 1,
-    )
+    with device_scope("bn/stats"):
+        new_state = BatchNormState(
+            mean=decay * state.mean + (1.0 - decay) * mean,
+            var=decay * state.var + (1.0 - decay) * var * adjust,
+            n=state.n + 1,
+        )
     return y, new_state

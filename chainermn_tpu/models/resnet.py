@@ -30,6 +30,7 @@ from chainermn_tpu.links.batch_normalization import (
     init_batch_norm,
     multi_node_batch_normalization,
 )
+from chainermn_tpu.utils.telemetry import device_scope
 
 __all__ = ["ResNetConfig", "init_resnet", "resnet_apply"]
 
@@ -114,12 +115,13 @@ def init_resnet(key, cfg: ResNetConfig):
 
 
 def _conv(x, w, stride=1):
-    return lax.conv_general_dilated(
-        x, w.astype(x.dtype),
-        window_strides=(stride, stride),
-        padding="SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-    )
+    with device_scope("resnet/conv"):
+        return lax.conv_general_dilated(
+            x, w.astype(x.dtype),
+            window_strides=(stride, stride),
+            padding="SAME",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        )
 
 
 def _bn_relu(p, s, x, axis_name, train, relu=True):

@@ -25,7 +25,6 @@ Design rules (TPU-first):
 from __future__ import annotations
 
 import math
-from contextlib import nullcontext
 from dataclasses import dataclass
 from functools import partial
 
@@ -70,6 +69,7 @@ from chainermn_tpu.parallel.tensor import (
     row_parallel_dense,
 )
 from chainermn_tpu.parallel.ulysses import ulysses_attention
+from chainermn_tpu.utils.telemetry import device_scope
 
 __all__ = [
     "AttentionKind",
@@ -901,8 +901,10 @@ def _fsdp_gather(cfg: TransformerConfig, blk):
     :func:`...parallel.fsdp.fsdp_gather`; this only binds the
     transformer's dim map (norm scales get ``None`` → pass through)."""
     dims = _fsdp_dims("wqkv" in blk, "router" in blk)
-    return fsdp_gather(blk, {k: dims.get(k) for k in blk},
-                       "data", cfg.fsdp_wire_dtype or None)
+    # gather and wire cast; by transposition the reduce-scatter too
+    with device_scope("fsdp/gather"):
+        return fsdp_gather(blk, {k: dims.get(k) for k in blk},
+                           "data", cfg.fsdp_wire_dtype or None)
 
 
 def _block_specs(cfg: TransformerConfig, kind, sparse: bool,
@@ -1433,12 +1435,13 @@ def _attention(cfg: TransformerConfig, h, blk, kind=None):
     seq-parallel core (ring/Ulysses over ``seq``), row-parallel output.
     ``kind`` is the layer's :class:`AttentionKind` under a
     ``layer_pattern``: its window and rotary parameters then stand in
-    for the config's, and the layer's ops carry ``attn/<kind.name>`` in
-    their ``op_name``."""
-    if kind is not None:
-        with jax.named_scope(f"attn/{kind.name}"):
-            return _attention_of_kind(cfg, h, blk, kind)
-    return _attention_of_kind(cfg, h, blk, None)
+    for the config's.  The layer's ops carry ``attn/<kind.name>`` in
+    their ``op_name``; where the layers are all alike, ``attn/sliding``
+    under an ``attention_window`` and ``attn/full`` without one."""
+    name = kind.name if kind is not None else (
+        "sliding" if cfg.attention_window else "full")
+    with device_scope(f"attn/{name}"):
+        return _attention_of_kind(cfg, h, blk, kind)
 
 
 def _require_flash(T):
@@ -1470,15 +1473,18 @@ def _mla_mixer(cfg: TransformerConfig, x, blk, kind):
     B, T, D = x.shape
     H, L, Ds, Dn = (blk["wq"].shape[1], kind.kv_latent, kind.d_shared_key,
                     cfg.d_head)
-    q = (x @ blk["wq"].reshape(D, -1).astype(cd)).reshape(B, T, H, Dn + Ds)
-    with jax.named_scope("mla/latent"):
+    with device_scope("attn.qkv"):
+        q = (x @ blk["wq"].reshape(D, -1).astype(cd)).reshape(
+            B, T, H, Dn + Ds)
+    with device_scope("mla/latent"):
         down = x @ blk["wkva"].astype(cd)
         latent = _rms_norm(down[..., :L], blk["kv_norm"], cfg.norm_eps)
         up = (latent @ blk["wkvb"].reshape(L, -1).astype(cd)).reshape(
             B, T, H, -1)
-    k = jnp.concatenate([up[..., :Dn], jnp.broadcast_to(
-        down[:, :, None, L:], (B, T, H, Ds))], axis=-1)
-    v = up[..., Dn:]
+    with device_scope("attn.kv_repeat"):
+        k = jnp.concatenate([up[..., :Dn], jnp.broadcast_to(
+            down[:, :, None, L:], (B, T, H, Ds))], axis=-1)
+        v = up[..., Dn:]
     if cfg.attention == "flash":
         _require_flash(T)
         o = flash_attention(
@@ -1487,9 +1493,11 @@ def _mla_mixer(cfg: TransformerConfig, x, blk, kind):
             bwd_block_k=cfg.flash_bwd_block_k or None,
             interpret=interpret_kernels())
     else:
-        o = local_attention(q, k, v, causal=True)
+        with device_scope("attn.core"):
+            o = local_attention(q, k, v, causal=True)
     o = checkpoint_name(o, "attn_out")
-    return o.reshape(B, T, -1) @ blk["wo"].reshape(-1, D).astype(cd)
+    with device_scope("attn.out"):
+        return o.reshape(B, T, -1) @ blk["wo"].reshape(-1, D).astype(cd)
 
 
 def _kda_mixer(cfg: TransformerConfig, x, blk, kind):
@@ -1511,8 +1519,9 @@ def _kda_mixer(cfg: TransformerConfig, x, blk, kind):
                         preferred_element_type=f32)
         return y
 
-    qkv = project(blk["wqkv"]).reshape(B, T, 3, H, Dh)
-    with jax.named_scope("kda/conv"):
+    with device_scope("attn.qkv"):
+        qkv = project(blk["wqkv"]).reshape(B, T, 3, H, Dh)
+    with device_scope("kda/conv"):
         # y_t = sum_j w_j x_(t - taps + 1 + j): the last tap meets the
         # token itself, nothing reaches back past the sequence's start
         padded = jnp.pad(qkv, ((0, 0), (taps - 1, 0)) + ((0, 0),) * 3)
@@ -1522,82 +1531,29 @@ def _kda_mixer(cfg: TransformerConfig, x, blk, kind):
         unit = lambda y: y * lax.rsqrt(
             jnp.sum(y * y, axis=-1, keepdims=True) + KDA_L2_NORM_EPS)
         q, k = unit(q) * Dh ** -0.5, unit(k)
-    with jax.named_scope("kda/gate"):
+    with device_scope("kda/gate"):
         # the recurrence's two gates: the log of the decay a channel
         # (<= 0) and the step size a head
         g = -jnp.exp(blk["a_log"])[:, None] * jax.nn.softplus(
             project(blk["wf_a"], blk["wf_b"]).reshape(B, T, H, Dh)
             + blk["dt_bias"])
         beta = jax.nn.sigmoid(project(blk["wbeta"]))
-    with jax.named_scope("kda/scan"):
+    with device_scope("kda/scan"):
         o = kda_chunked(q, k, v, g, beta)
-    with jax.named_scope("kda/gate"):
+    with device_scope("kda/gate"):
         # the way out: RMSNorm over each head with one scale for all,
         # times a sigmoid gate from the input
         o = _rms_norm(o, blk["o_norm"], cfg.norm_eps) * jax.nn.sigmoid(
             project(blk["wg_a"], blk["wg_b"]).reshape(B, T, H, Dh))
     o = checkpoint_name(o.astype(cd), "attn_out")
-    return o.reshape(B, T, -1) @ blk["wo"].reshape(-1, D).astype(cd)
+    with device_scope("attn.out"):
+        return o.reshape(B, T, -1) @ blk["wo"].reshape(-1, D).astype(cd)
 
 
-def _attention_of_kind(cfg: TransformerConfig, h, blk, kind):
-    cd = cfg.compute_dtype
-    if cfg.mixer_of(kind) != "softmax":
-        mix = _mla_mixer if kind.mixer == "mla" else _kda_mixer
-        return h + mix(cfg, _rms_norm(h, blk["ln1"], cfg.norm_eps), blk,
-                       kind)
-    win = (kind.window if kind else cfg.attention_window) or None
-    x = _rms_norm(h, blk["ln1"], cfg.norm_eps)
-    B, T, D = x.shape
-    if "wqkv" in blk:
-        Hl = blk["wqkv"].shape[2]      # local heads = H / model-axis size
-        qkv = column_parallel_dense(
-            x, blk["wqkv"].reshape(D, -1).astype(cd))
-        qkv = qkv.reshape(B, T, 3, Hl, cfg.d_head)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        gate = column_parallel_dense(x, blk["wg"].astype(cd)) \
-            if "wg" in blk else None
-    else:
-        # GQA/MQA: H/Hkv query heads share each K/V head.  K/V stay at
-        # their natural (shared) width all the way through the attention
-        # cores — the ring rotates and Ulysses exchanges Hkv-head blocks
-        # (ICI traffic shrinks by H/Hkv) and the grouped einsums read the
-        # shared heads in place.  Local (per model-rank) grouping equals
-        # global grouping because both H and Hkv shard over the same
-        # axis: global query head r·Hl+i reads kv head r·Hkvl + i//rep
-        # for rep = Hl/Hkvl = H/Hkv (mesh divisibility is validated at
-        # shard/jit build time by _check_mesh).
-        Hl = blk["wq"].shape[1]
-        Hkvl = blk["wkv"].shape[2]
-        # ONE fused projection dot, like the MHA wqkv path: concatenating
-        # the (local-shard) weights along the output dim reads the
-        # activations once instead of twice — the concat costs one
-        # weight-sized copy, far less than the saved (B,T,D) re-read at
-        # training shapes, and removes a dispatch on the decode path.
-        # The at-rest params stay separate (their TP/FSDP specs differ).
-        dq = Hl * cfg.d_head
-        dkv = 2 * Hkvl * cfg.d_head
-        # the per-head gate's projection (Hl more columns) rides it too
-        fused = jnp.concatenate(
-            [blk["wq"].reshape(D, -1), blk["wkv"].reshape(D, -1)]
-            + ([blk["wg"]] if "wg" in blk else []),
-            axis=1).astype(cd)
-        qkv = column_parallel_dense(x, fused)
-        q = qkv[..., :dq].reshape(B, T, Hl, cfg.d_head)
-        kv = qkv[..., dq:dq + dkv].reshape(B, T, 2, Hkvl, cfg.d_head)
-        k, v = kv[:, :, 0], kv[:, :, 1]
-        gate = qkv[..., dq + dkv:] if "wg" in blk else None
-    if cfg.pos_embedding == "rope":
-        # rotate by each local token's GLOBAL position BEFORE any ring
-        # rotation / Ulysses exchange — relative attention then holds
-        # across shard boundaries by construction
-        pos = _block_positions(
-            lax.axis_index("seq"), T, lax.axis_size("seq"),
-            cfg.seq_layout if cfg.attention == "ring" else "contiguous")
-        rope = dict(theta=cfg.rope_theta) if kind is None else dict(
-            inv_freq=kind.inv_freq(cfg.d_head), scale=kind.attention_factor)
-        q = apply_rope(q, pos, **rope)
-        k = apply_rope(k, pos, **rope)
+def _exchanged_or_local_core(cfg: TransformerConfig, q, k, v, win):
+    """The attention core where it is not the flash kernel alone: the
+    ring, Ulysses' exchange, or XLA's own attention."""
+    T = q.shape[1]
     if cfg.attention == "ring":
         # flagship long-context path: ring schedule with the Pallas
         # kernel as the per-pair compute whenever the local block shape
@@ -1607,14 +1563,14 @@ def _attention_of_kind(cfg: TransformerConfig, h, blk, kind):
         if cfg.seq_layout == "zigzag":
             # each zigzag half-run must itself fit the kernel's blocks
             use_flash = flash_attention_supported(T // 2, T // 2)
-        o = ring_attention(q, k, v, axis_name="seq", causal=True,
-                           window=win,
-                           remat=cfg.remat, use_flash=use_flash,
-                           bwd_block_q=cfg.flash_bwd_block_q or None,
-                           bwd_block_k=cfg.flash_bwd_block_k or None,
-                           layout=cfg.seq_layout,
-                           interpret=interpret_kernels())
-    elif cfg.attention == "ulysses":
+        return ring_attention(q, k, v, axis_name="seq", causal=True,
+                              window=win,
+                              remat=cfg.remat, use_flash=use_flash,
+                              bwd_block_q=cfg.flash_bwd_block_q or None,
+                              bwd_block_k=cfg.flash_bwd_block_k or None,
+                              layout=cfg.seq_layout,
+                              interpret=interpret_kernels())
+    if cfg.attention == "ulysses":
         # after the head<->seq exchange each device holds the FULL
         # sequence for its head subset — the flash kernel slots straight
         # in (static zero offsets), falling back to the XLA path when
@@ -1625,21 +1581,87 @@ def _attention_of_kind(cfg: TransformerConfig, h, blk, kind):
                          bwd_block_q=cfg.flash_bwd_block_q or None,
                          bwd_block_k=cfg.flash_bwd_block_k or None,
                          interpret=interpret_kernels())
-            o = ulysses_attention(q, k, v, axis_name="seq", causal=True,
-                                  window=win,
-                                  attn_fn=fa)
+            return ulysses_attention(q, k, v, axis_name="seq", causal=True,
+                                     window=win,
+                                     attn_fn=fa)
+        return ulysses_attention(q, k, v, axis_name="seq", causal=True,
+                                 window=win)
+    if cfg.attention == "local":
+        return local_attention(q, k, v, causal=True,
+                               window=win)
+    raise ValueError(cfg.attention)
+
+
+def _attention_of_kind(cfg: TransformerConfig, h, blk, kind):
+    cd = cfg.compute_dtype
+    if cfg.mixer_of(kind) != "softmax":
+        mix = _mla_mixer if kind.mixer == "mla" else _kda_mixer
+        return h + mix(cfg, _rms_norm(h, blk["ln1"], cfg.norm_eps), blk,
+                       kind)
+    win = (kind.window if kind else cfg.attention_window) or None
+    with device_scope("attn.qkv"):
+        x = _rms_norm(h, blk["ln1"], cfg.norm_eps)
+        B, T, D = x.shape
+        if "wqkv" in blk:
+            Hl = blk["wqkv"].shape[2]      # local heads = H / model-axis size
+            qkv = column_parallel_dense(
+                x, blk["wqkv"].reshape(D, -1).astype(cd))
+            qkv = qkv.reshape(B, T, 3, Hl, cfg.d_head)
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            gate = column_parallel_dense(x, blk["wg"].astype(cd)) \
+                if "wg" in blk else None
         else:
-            o = ulysses_attention(q, k, v, axis_name="seq", causal=True,
-                                  window=win)
-    elif cfg.attention == "local":
-        o = local_attention(q, k, v, causal=True,
-                            window=win)
-    elif cfg.attention == "flash":
+            # GQA/MQA: H/Hkv query heads share each K/V head.  K/V stay at
+            # their natural (shared) width all the way through the attention
+            # cores — the ring rotates and Ulysses exchanges Hkv-head blocks
+            # (ICI traffic shrinks by H/Hkv) and the grouped einsums read the
+            # shared heads in place.  Local (per model-rank) grouping equals
+            # global grouping because both H and Hkv shard over the same
+            # axis: global query head r·Hl+i reads kv head r·Hkvl + i//rep
+            # for rep = Hl/Hkvl = H/Hkv (mesh divisibility is validated at
+            # shard/jit build time by _check_mesh).
+            Hl = blk["wq"].shape[1]
+            Hkvl = blk["wkv"].shape[2]
+            # ONE fused projection dot, like the MHA wqkv path: concatenating
+            # the (local-shard) weights along the output dim reads the
+            # activations once instead of twice — the concat costs one
+            # weight-sized copy, far less than the saved (B,T,D) re-read at
+            # training shapes, and removes a dispatch on the decode path.
+            # The at-rest params stay separate (their TP/FSDP specs differ).
+            dq = Hl * cfg.d_head
+            dkv = 2 * Hkvl * cfg.d_head
+            # the per-head gate's projection (Hl more columns) rides it too
+            fused = jnp.concatenate(
+                [blk["wq"].reshape(D, -1), blk["wkv"].reshape(D, -1)]
+                + ([blk["wg"]] if "wg" in blk else []),
+                axis=1).astype(cd)
+            qkv = column_parallel_dense(x, fused)
+            q = qkv[..., :dq].reshape(B, T, Hl, cfg.d_head)
+            kv = qkv[..., dq:dq + dkv].reshape(B, T, 2, Hkvl, cfg.d_head)
+            k, v = kv[:, :, 0], kv[:, :, 1]
+            gate = qkv[..., dq + dkv:] if "wg" in blk else None
+    if cfg.pos_embedding == "rope":
+        with device_scope("attn.rope"):
+            # rotate by each local token's GLOBAL position BEFORE any ring
+            # rotation / Ulysses exchange — relative attention then holds
+            # across shard boundaries by construction
+            pos = _block_positions(
+                lax.axis_index("seq"), T, lax.axis_size("seq"),
+                cfg.seq_layout if cfg.attention == "ring" else "contiguous")
+            rope = dict(theta=cfg.rope_theta) if kind is None else dict(
+                inv_freq=kind.inv_freq(cfg.d_head),
+                scale=kind.attention_factor)
+            q = apply_rope(q, pos, **rope)
+            k = apply_rope(k, pos, **rope)
+    if cfg.attention == "flash":
         # Pallas kernel: compiled when the step was built for TPU
-        # devices, interpreted otherwise (interpret_kernels)
+        # devices, interpreted otherwise (interpret_kernels).  The
+        # kernels wear ``attn.core`` themselves (forward, dq, dkv); the
+        # relayouts around them stay the layer's own
         _require_flash(T)
-        # kernel wants matching head counts
-        k, v = broadcast_kv(k, v, q.shape[2] // k.shape[2])
+        with device_scope("attn.kv_repeat"):
+            # kernel wants matching head counts
+            k, v = broadcast_kv(k, v, q.shape[2] // k.shape[2])
         o = flash_attention(
             q, k, v, causal=True,
             window=win,
@@ -1647,13 +1669,15 @@ def _attention_of_kind(cfg: TransformerConfig, h, blk, kind):
             bwd_block_k=cfg.flash_bwd_block_k or None,
             interpret=interpret_kernels())
     else:
-        raise ValueError(cfg.attention)
+        with device_scope("attn.core"):
+            o = _exchanged_or_local_core(cfg, q, k, v, win)
     if gate is not None:
-        # o_j <- sigmoid(x W_g)_j o_j, one scalar a local query head.
-        # Its backward reads the core's o, which the block's checkpoint
-        # already keeps where the core is the flash kernel
-        o = o * jax.nn.sigmoid(
-            gate.astype(jnp.float32)).astype(o.dtype)[..., None]
+        with device_scope("attn.gate"):
+            # o_j <- sigmoid(x W_g)_j o_j, one scalar a local query head.
+            # Its backward reads the core's o, which the block's checkpoint
+            # already keeps where the core is the flash kernel
+            o = o * jax.nn.sigmoid(
+                gate.astype(jnp.float32)).astype(o.dtype)[..., None]
     # named for the "dots" remat policy, which saves it as the input of
     # the output projection's backward.  It never kept the flash kernel
     # out of the recompute (the kernel's residuals are its own o and
@@ -1662,8 +1686,9 @@ def _attention_of_kind(cfg: TransformerConfig, h, blk, kind):
     # "local", the exchange back under "ulysses", and a transpose (for a
     # second copy of o) under "flash" and "ring"
     o = checkpoint_name(o, "attn_out")
-    o = row_parallel_dense(
-        o.reshape(B, T, -1), blk["wo"].reshape(-1, D).astype(cd))
+    with device_scope("attn.out"):
+        o = row_parallel_dense(
+            o.reshape(B, T, -1), blk["wo"].reshape(-1, D).astype(cd))
     return h + o
 
 
@@ -1679,23 +1704,21 @@ def _gated(cfg: TransformerConfig, act: str, x, w1, w3, w2):
     return row_parallel_dense(y, w2.astype(cd))
 
 
-def _mlp(cfg: TransformerConfig, h, blk, with_chosen=False, sparse=None,
-         scoped=False):
+def _mlp(cfg: TransformerConfig, h, blk, with_chosen=False, sparse=None):
     """Pre-LN MLP: dense (column→row TP pair, one psum) or Switch-MoE
     (expert all-to-alls; experts' FFNs are themselves TP-split), by
     ``sparse`` (None: the config's ``moe``).  ``with_chosen`` (dropless
     dispatch) also returns the ``(B, T, k)`` experts each token chose,
-    for :func:`expert_choices`.  ``scoped``: a typed layer, whose dense
-    MLP and shared expert carry ``mlp/dense`` and ``moe/shared`` in
-    their ``op_name`` (the dropless layer names its own parts)."""
+    for :func:`expert_choices`.  The dense MLP and the shared expert
+    carry ``mlp/dense`` and ``moe/shared`` in their ``op_name`` (the
+    dropless layer names its own parts)."""
     cd = cfg.compute_dtype
     sparse = cfg.moe if sparse is None else sparse
-    scope = jax.named_scope if scoped else (lambda _: nullcontext())
     x = _rms_norm(h, blk["ln2"], cfg.norm_eps)
     if with_chosen and not (sparse and cfg.moe_dispatch == "dropless"):
         raise ValueError("the choices are read from the dropless layer")
     if not sparse:
-        with scope("mlp/dense"):
+        with device_scope("mlp/dense"):
             out = h + _gated(cfg, cfg.dense_act, x, blk["w1"],
                              blk.get("w3"), blk["w2"])
         return out, jnp.zeros((), jnp.float32)
@@ -1730,7 +1753,7 @@ def _mlp(cfg: TransformerConfig, h, blk, with_chosen=False, sparse=None,
         if "ws1" in blk:
             # the expert every token meets: whole on each member of the
             # expert group, for the member's own tokens; no gate
-            with scope("moe/shared"):
+            with device_scope("moe/shared"):
                 out = out + _gated(cfg, cfg.expert_act, x, blk["ws1"],
                                    blk.get("ws3"), blk["ws2"])
         if with_chosen:
@@ -1758,7 +1781,7 @@ def _block(cfg: TransformerConfig, h, blk, kind=None, with_chosen=False,
     if cfg.fsdp:
         blk = _fsdp_gather(cfg, blk)
     h = _attention(cfg, h, blk, kind)
-    return _mlp(cfg, h, blk, with_chosen, sparse, scoped=kind is not None)
+    return _mlp(cfg, h, blk, with_chosen, sparse)
 
 
 def _scan_layers(cfg: TransformerConfig, layer_fn, carry, blocks):
@@ -1820,24 +1843,26 @@ def _stage(cfg: TransformerConfig, stage_params, h):
 
 
 def _embed(cfg: TransformerConfig, params, tokens):
-    """Token rows (+ the learned positions' rows) in the compute dtype."""
+    """Token rows (+ the learned positions' rows) in the compute dtype;
+    by transposition the scatter-add into the embedding's gradient."""
     cd = cfg.compute_dtype
     B, T = tokens.shape
     r = lax.axis_index("seq")
 
-    if cfg.vocab_parallel:
-        h = _vp_embed_lookup(params["embed"], tokens)  # (B, T, D) fp32
-    else:
-        h = params["embed"][tokens]                    # (B, T, D) fp32
-    if cfg.pos_embedding == "rope":
-        return h.astype(cd)       # rotations happen inside attention
-    if cfg.seq_layout == "zigzag":
-        # position rows follow the zigzag permutation of this shard
-        return (h + params["pos"][
-            _block_positions(r, T, lax.axis_size("seq"), "zigzag")]
-        ).astype(cd)
-    return (h + lax.dynamic_slice_in_dim(
-        params["pos"], r * T, T, axis=0)).astype(cd)
+    with device_scope("step/embed"):
+        if cfg.vocab_parallel:
+            h = _vp_embed_lookup(params["embed"], tokens)  # (B, T, D) fp32
+        else:
+            h = params["embed"][tokens]                    # (B, T, D) fp32
+        if cfg.pos_embedding == "rope":
+            return h.astype(cd)       # rotations happen inside attention
+        if cfg.seq_layout == "zigzag":
+            # position rows follow the zigzag permutation of this shard
+            return (h + params["pos"][
+                _block_positions(r, T, lax.axis_size("seq"), "zigzag")]
+            ).astype(cd)
+        return (h + lax.dynamic_slice_in_dim(
+            params["pos"], r * T, T, axis=0)).astype(cd)
 
 
 def transformer_backbone(cfg: TransformerConfig, params, tokens):
@@ -1859,18 +1884,34 @@ def transformer_backbone(cfg: TransformerConfig, params, tokens):
             f'attention={cfg.attention!r} expects contiguous shards')
     h = _embed(cfg, params, tokens)
     S = lax.axis_size("pipe")
-    if cfg.virtual_pipe > 1:
-        # forward-only traversal of the V chunk rings: chunk c of every
-        # device runs as one GPipe pass; the next chunk's pass consumes
-        # its output (virtual stage order g = c·S + s is preserved).
-        # The interleaved schedule proper only matters when backward
-        # timing is involved — make_train_step uses it.
-        aux = jnp.zeros((), jnp.float32)
-        for c in range(cfg.virtual_pipe):
-            chunk = jax.tree.map(lambda a: a[:, c], params["blocks"])
-            h, a = pipeline_apply(
+    # the stack of blocks under one name: what wears no inner scope there
+    # is the stack's own (the scan's slicing and its saved activations,
+    # the residual adds, the MLP's norm)
+    with device_scope("step/layers"):
+        if cfg.virtual_pipe > 1:
+            # forward-only traversal of the V chunk rings: chunk c of every
+            # device runs as one GPipe pass; the next chunk's pass consumes
+            # its output (virtual stage order g = c·S + s is preserved).
+            # The interleaved schedule proper only matters when backward
+            # timing is involved — make_train_step uses it.
+            aux = jnp.zeros((), jnp.float32)
+            for c in range(cfg.virtual_pipe):
+                chunk = jax.tree.map(lambda a: a[:, c], params["blocks"])
+                h, a = pipeline_apply(
+                    partial(_stage, cfg),
+                    chunk,
+                    h,
+                    axis_name="pipe",
+                    num_microbatches=cfg.num_microbatches,
+                    remat=cfg.remat,
+                    with_aux=True,
+                    checkpoint_fn=cfg.checkpoint_fn,
+                )
+                aux = aux + a
+        elif S > 1 or cfg.num_microbatches > 1:
+            h, aux = pipeline_apply(
                 partial(_stage, cfg),
-                chunk,
+                params["blocks"],
                 h,
                 axis_name="pipe",
                 num_microbatches=cfg.num_microbatches,
@@ -1878,46 +1919,36 @@ def transformer_backbone(cfg: TransformerConfig, params, tokens):
                 with_aux=True,
                 checkpoint_fn=cfg.checkpoint_fn,
             )
-            aux = aux + a
-    elif S > 1 or cfg.num_microbatches > 1:
-        h, aux = pipeline_apply(
-            partial(_stage, cfg),
-            params["blocks"],
-            h,
-            axis_name="pipe",
-            num_microbatches=cfg.num_microbatches,
-            remat=cfg.remat,
-            with_aux=True,
-            checkpoint_fn=cfg.checkpoint_fn,
-        )
-    else:
-        blocks = jax.tree.map(
-            lambda a: jnp.squeeze(a, axis=0), params["blocks"])
+        else:
+            blocks = jax.tree.map(
+                lambda a: jnp.squeeze(a, axis=0), params["blocks"])
 
-        def body(carry, blk, kind):
-            h, aux = carry
-            fn = cfg.checkpoint_fn(partial(_block, cfg, kind=kind))
-            h, a = fn(h, blk)
-            return (h, aux + a), None
+            def body(carry, blk, kind):
+                h, aux = carry
+                fn = cfg.checkpoint_fn(partial(_block, cfg, kind=kind))
+                h, a = fn(h, blk)
+                return (h, aux + a), None
 
-        # block params are pipe-sharded (varying) even at pipe size 1, so
-        # the carry must be marked pipe-varying going in; the closing psum
-        # over the size-1 axis is a free re-replication (vma discipline).
-        # aux derives from h so it inherits the batch axes' variance too.
-        vary = partial(lax.pcast, axis_name=("pipe",), to="varying")
-        aux = jnp.sum(h * 0, dtype=jnp.float32)
-        # the layers that lead run ahead of the scan, each under the
-        # same checkpoint (their blocks are not pipe-sharded)
-        for blk, kind in zip(params.get("leading", ()), cfg.leading_layers):
-            h, a = cfg.checkpoint_fn(partial(
-                _block, cfg, kind=kind, sparse=cfg.leading_sparse))(h, blk)
-            aux = aux + a
-        (h, aux), _ = _scan_layers(
-            cfg, body, (vary(h), vary(aux)), blocks)
-        h = lax.psum(h, "pipe")
-        aux = lax.psum(aux, "pipe")
+            # block params are pipe-sharded (varying) even at pipe size 1, so
+            # the carry must be marked pipe-varying going in; the closing psum
+            # over the size-1 axis is a free re-replication (vma discipline).
+            # aux derives from h so it inherits the batch axes' variance too.
+            vary = partial(lax.pcast, axis_name=("pipe",), to="varying")
+            aux = jnp.sum(h * 0, dtype=jnp.float32)
+            # the layers that lead run ahead of the scan, each under the
+            # same checkpoint (their blocks are not pipe-sharded)
+            for blk, kind in zip(params.get("leading", ()),
+                                 cfg.leading_layers):
+                h, a = cfg.checkpoint_fn(partial(
+                    _block, cfg, kind=kind, sparse=cfg.leading_sparse))(h, blk)
+                aux = aux + a
+            (h, aux), _ = _scan_layers(
+                cfg, body, (vary(h), vary(aux)), blocks)
+            h = lax.psum(h, "pipe")
+            aux = lax.psum(aux, "pipe")
 
-    return _rms_norm(h, params["ln_f"], cfg.norm_eps), aux
+    with device_scope("step/head"):
+        return _rms_norm(h, params["ln_f"], cfg.norm_eps), aux
 
 
 def _head_matrix(cfg: TransformerConfig, params):
@@ -1935,17 +1966,19 @@ def transformer_forward(cfg: TransformerConfig, params, tokens):
     the vocab shards back to full width (training's loss path never
     pays that gather — see :func:`_vp_nll_sum`)."""
     h, aux = transformer_backbone(cfg, params, tokens)
-    if cfg.vocab_parallel:
-        # _vp_head, not _lm_head: the latter's custom VJP psums the
-        # embed cotangent over every varying axis, which would wrongly
-        # sum the DISTINCT vocab shards over model
-        logits = _vp_head(cfg.compute_dtype, "model", h,
-                          _head_matrix(cfg, params))
-        # invariant gather: the full logits are identical on every
-        # model member, and the vma type must say so for out_specs
-        return _all_gather_invariant(
-            logits, "model", axis=2, tiled=True), aux
-    return _lm_head(cfg.compute_dtype, h, _head_matrix(cfg, params)), aux
+    with device_scope("step/head"):
+        if cfg.vocab_parallel:
+            # _vp_head, not _lm_head: the latter's custom VJP psums the
+            # embed cotangent over every varying axis, which would
+            # wrongly sum the DISTINCT vocab shards over model
+            logits = _vp_head(cfg.compute_dtype, "model", h,
+                              _head_matrix(cfg, params))
+            # invariant gather: the full logits are identical on every
+            # model member, and the vma type must say so for out_specs
+            return _all_gather_invariant(
+                logits, "model", axis=2, tiled=True), aux
+        return _lm_head(
+            cfg.compute_dtype, h, _head_matrix(cfg, params)), aux
 
 
 # coefficient of the Switch-MoE balancing loss in the training objective
@@ -1957,8 +1990,11 @@ _AUX_WEIGHT = 0.01
 def lm_loss(cfg: TransformerConfig, params, inputs, targets):
     """Local-shard mean next-token cross-entropy (+0.01·aux)."""
     h, aux = transformer_backbone(cfg, params, inputs)
-    nll_sum = _shard_nll_sum(cfg, h, _head_matrix(cfg, params), targets)
-    return nll_sum / targets.size + _AUX_WEIGHT * aux
+    # logits and loss, with the head's custom VJPs
+    with device_scope("step/head"):
+        nll_sum = _shard_nll_sum(
+            cfg, h, _head_matrix(cfg, params), targets)
+        return nll_sum / targets.size + _AUX_WEIGHT * aux
 
 
 def expert_choices(mesh_cfg, cfg: TransformerConfig, params, tokens):
@@ -2043,14 +2079,16 @@ def _make_1f1b_grad(cfg: TransformerConfig):
         r = lax.axis_index("seq")
 
         def embed_fn(ep):
-            if cfg.vocab_parallel:
-                h = _vp_embed_lookup(ep["embed"], inputs)
-            else:
-                h = ep["embed"][inputs]
-            if cfg.pos_embedding == "rope":
-                return h.astype(cd)
-            pos = lax.dynamic_slice_in_dim(ep["pos"], r * T, T, axis=0)
-            return (h + pos).astype(cd)
+            with device_scope("step/embed"):
+                if cfg.vocab_parallel:
+                    h = _vp_embed_lookup(ep["embed"], inputs)
+                else:
+                    h = ep["embed"][inputs]
+                if cfg.pos_embedding == "rope":
+                    return h.astype(cd)
+                pos = lax.dynamic_slice_in_dim(
+                    ep["pos"], r * T, T, axis=0)
+                return (h + pos).astype(cd)
 
         ep = {"embed": params["embed"]}
         if cfg.pos_embedding == "learned":
@@ -2058,8 +2096,10 @@ def _make_1f1b_grad(cfg: TransformerConfig):
         h, vjp_embed = jax.vjp(embed_fn, ep)
 
         def loss_fn(lp, y, tgt):
-            hN = _rms_norm(y, lp["ln_f"], cfg.norm_eps)
-            return _shard_nll_sum(cfg, hN, lp["embed"], tgt) / tgt.size
+            with device_scope("step/head"):
+                hN = _rms_norm(y, lp["ln_f"], cfg.norm_eps)
+                return _shard_nll_sum(
+                    cfg, hN, lp["embed"], tgt) / tgt.size
 
         lp = {"ln_f": params["ln_f"], "embed": _head_matrix(cfg, params)}
         aux_kw = dict(with_aux=True, aux_weight=_AUX_WEIGHT) \
@@ -2280,8 +2320,9 @@ def make_train_step(mesh_cfg, cfg: TransformerConfig, optimizer):
 
     def step(params, opt_state, inputs, targets):
         loss, grads = grad_fn(params, inputs, targets)
-        updates, new_state = optimizer.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
+        with device_scope("step/optimizer"):
+            updates, new_state = optimizer.update(grads, opt_state, params)
+            new_params = optax.apply_updates(params, updates)
         return new_params, new_state, loss
 
     return jax.jit(step, donate_argnums=(0, 1))

@@ -71,6 +71,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from chainermn_tpu.ops.pallas_attention import interpret_kernels
 from chainermn_tpu.utils.metrics import get_registry
+from chainermn_tpu.utils.telemetry import device_scope
 
 __all__ = ["kda_chunked", "kda_recurrent"]
 
@@ -226,11 +227,14 @@ def _chunk_parts(q, k, v, g, beta, sub):
     """What a chunk gives the scan over the states, none of it a
     function of a state: ``(U_v, W, Q_g, A', K_end, decay_C)`` with
     ``U = U_v - W S_0``."""
-    G = jnp.cumsum(g, axis=-2)
-    A, A_q = _pair_weights(q, k, G, g, sub)
+    with device_scope("kda.pairs"):
+        G = jnp.cumsum(g, axis=-2)
+        A, A_q = _pair_weights(q, k, G, g, sub)
     dv = v.shape[-1]
-    rhs = beta[..., None] * jnp.concatenate([v, k * jnp.exp(G)], axis=-1)
-    solved = solve(beta[..., None] * A, rhs)
+    with device_scope("kda.solve"):
+        rhs = beta[..., None] * jnp.concatenate(
+            [v, k * jnp.exp(G)], axis=-1)
+        solved = solve(beta[..., None] * A, rhs)
     G_end = G[..., -1:, :]
     return (solved[..., :dv], solved[..., dv:], q * jnp.exp(G), A_q,
             k * jnp.exp(G_end - G), jnp.exp(G_end[..., 0, :]))
@@ -244,9 +248,16 @@ def _slab(S, xs, sub):
 
     def chunk(S, part):
         u_v, w, q_g, a_q, k_end, decay = part
-        u = u_v - w @ S
-        o = q_g @ S + a_q @ u
-        return decay[..., None] * S + jnp.swapaxes(k_end, -1, -2) @ u, o
+        # what meets the state (inter-chunk) and the chunk's own pairs
+        # (intra-chunk), each under its name
+        with device_scope("kda.inter"):
+            u = u_v - w @ S
+            o = q_g @ S
+        with device_scope("kda.intra"):
+            o = o + a_q @ u
+        with device_scope("kda.inter"):
+            S = decay[..., None] * S + jnp.swapaxes(k_end, -1, -2) @ u
+        return S, o
 
     S, o = lax.scan(chunk, S, tuple(jnp.moveaxis(p, 2, 0) for p in parts))
     return S, jnp.moveaxis(o, 0, 2)

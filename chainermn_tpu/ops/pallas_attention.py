@@ -63,6 +63,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from chainermn_tpu.utils.metrics import get_registry
+from chainermn_tpu.utils.telemetry import device_scope
 
 __all__ = ["flash_attention", "flash_attention_supported",
            "interpret_kernels", "tracing_for_mesh", "FLASH_RESIDUAL_NAMES"]
@@ -430,9 +431,11 @@ def _sds(shape, dtype, like):
 def _call(kernel, plan, BH, in_specs, out_specs, out_shape, scratch_shapes,
           interpret):
     """One kernel over ``plan``'s grid, the offsets prefetched to SMEM
-    ahead of the index maps."""
+    ahead of the index maps.  The kernel wears ``attn.core`` (forward,
+    dq and dkv alike); the relayouts and the backward's ``delta``
+    around it do not."""
     _count_visits(plan)
-    return pl.pallas_call(
+    kernel = pl.pallas_call(
         functools.partial(kernel, plan=plan),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -443,6 +446,12 @@ def _call(kernel, plan, BH, in_specs, out_specs, out_shape, scratch_shapes,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret)
+
+    def scoped(*operands):
+        with device_scope("attn.core"):
+            return kernel(*operands)
+
+    return scoped
 
 
 def _fwd(q3, k3, v3, offs, static_offs, scale, causal, window, block_q,

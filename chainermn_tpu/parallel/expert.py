@@ -37,6 +37,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from chainermn_tpu.utils.telemetry import device_scope
+
 __all__ = [
     "expert_parallel_moe",
     "expert_parallel_moe_dropless",
@@ -399,7 +401,7 @@ def expert_parallel_moe_dropless(
         raise ValueError(
             f"experts [{first_expert}, {first_expert + G}) held, of {E}")
 
-    with jax.named_scope("moe/route"):
+    with device_scope("moe/route"):
         probs, top_i, gates = route_top_k(
             x, router_w, top_k, score, scale, bias)
         choice = top_i.reshape(-1) - first_expert       # (N*k,)
@@ -407,14 +409,14 @@ def expert_parallel_moe_dropless(
         order, inv, sizes = _sort_by_group(jnp.where(held, choice, G), G)
         rows = _rows_out(x, order, inv, top_k)          # (N*k, D)
 
-    with jax.named_scope("moe/experts"):
+    with device_scope("moe/experts"):
         if S == 1:
             ys = _experts_of_rows(expert_fn, expert_params, rows, sizes)
         else:
             ys = _exchange(rows, sizes, expert_fn, expert_params,
                            axis_name, S, N * min(top_k, G // S))
 
-    with jax.named_scope("moe/combine"):
+    with device_scope("moe/combine"):
         ys = _rows_back(ys, order, inv).reshape(N, top_k, D)
         held = held.reshape(N, top_k, 1)
         # a where, not a product by a zero gate: the rows of choices

@@ -41,7 +41,7 @@ from chainermn_tpu.utils.programs import (
     ledger_jit,
     weakref_root,
 )
-from chainermn_tpu.utils.telemetry import get_recorder
+from chainermn_tpu.utils.telemetry import device_scope, get_recorder
 
 __all__ = ["StandardUpdater", "default_converter", "fuse_steps"]
 
@@ -498,8 +498,10 @@ class StandardUpdater:
                 # wire bytes — the `extra` assert_accum_collectives
                 # allows); sits after the scan, never inside it
                 loss = jax.lax.pmean(jnp.mean(micro_losses), ax)
-            updates, new_state = optimizer.update(grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
+            with device_scope("step/optimizer"):
+                updates, new_state = optimizer.update(
+                    grads, opt_state, params)
+                new_params = optax.apply_updates(params, updates)
             if zero1:
                 new_state = jax.tree.map(lambda s: s[None], new_state)
             # loss is already the global mean (ObservationAggregator

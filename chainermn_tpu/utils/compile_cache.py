@@ -28,6 +28,11 @@ def enable_compile_cache() -> Optional[str]:
     cache goes to the fixed ``<checkout>/.jax_cache`` (git-ignored) —
     never a temp dir, a pid or a timestamp, which would never hit.
 
+    The key of an entry takes in the ops' metadata (their name stacks:
+    file paths and line numbers are switched off, see below), because
+    the per-layer metrics are read off the op names in a profile and a
+    stale name is a wrong number.
+
     Asking which backend this is initialises it: in a multi-host
     program call ``init_distributed`` first.
     """
@@ -48,4 +53,15 @@ def enable_compile_cache() -> Optional[str]:
     # entry's key: with it, a checkout in another directory — or an
     # edit that shifts a line above the kernel — never hits
     jax.config.update("jax_traceback_in_locations_limit", 0)
+    # ...and with the limit at 0 a location holds the op's name stack
+    # and nothing else, so it can go back INTO the key.  JAX leaves it
+    # out by default and says what that costs: "executables loaded from
+    # the cache may have stale metadata, which may show up in profiles".
+    # This repo reads its per-layer metrics off those op names
+    # (``utils.telemetry.classify_op_name``): a step that differs from
+    # a cached one only by a scope's name would otherwise be served the
+    # old executable, whose ops lack the scope: a wrong measurement,
+    # not a cosmetic fault.  Two checkouts in different directories
+    # still hit each other (no path or line is left in a location)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path

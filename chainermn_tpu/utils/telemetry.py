@@ -69,6 +69,7 @@ import collections
 import functools
 import json
 import os
+import re
 import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence
@@ -76,11 +77,14 @@ from typing import Any, Dict, List, Optional, Sequence
 from chainermn_tpu.utils.metrics import Histogram, append_jsonl
 
 __all__ = [
+    "DEVICE_SCOPES",
     "MetricsExport",
     "RequestTraceStore",
     "SpanEvent",
     "StragglerReport",
     "TraceRecorder",
+    "classify_op_name",
+    "device_scope",
     "get_recorder",
     "merge_traces",
     "set_recorder",
@@ -843,6 +847,89 @@ class RequestTraceStore:
         with open(path, "w") as f:
             json.dump(self.to_chrome(trace_id), f, default=str)
         return path
+
+
+# ---------------------------------------------------------------------- #
+# device scopes
+# ---------------------------------------------------------------------- #
+
+# Every name the step programs put on the device's ops, and no other:
+# the one vocabulary ``classify_op_name`` reads back out of a profile's
+# op names (docs/OBSERVABILITY.md has what an operator reads off each).
+# ``attn/<kind>`` stands for the family: ``<kind>`` is the layer's
+# ``AttentionKind.name``, or ``full`` / ``sliding`` by
+# ``attention_window`` where the layers are all alike.  The dotted names
+# are children, worn inside ``attn/<kind>`` and ``kda/scan``: dotted so
+# that a reader which looks for ``attn/...`` or ``kda/...`` still finds
+# the layer and not its part.
+DEVICE_SCOPES = (
+    "step/embed", "step/layers", "step/head", "step/optimizer",
+    "fsdp/gather",
+    "attn/<kind>",
+    "attn.qkv", "attn.rope", "attn.kv_repeat", "attn.core", "attn.gate",
+    "attn.out",
+    "mla/latent",
+    "kda/conv", "kda/gate", "kda/scan",
+    "kda.pairs", "kda.solve", "kda.intra", "kda.inter",
+    "mlp/dense",
+    "moe/route", "moe/experts", "moe/combine", "moe/shared",
+    "resnet/conv", "bn/stats", "bn/apply",
+)
+# any name of the list; a kind is whatever ``AttentionKind`` lets
+# through, the placeholder itself apart
+_ANY_SCOPE = "|".join(
+    r"attn/[^/()<>\s]+" if s == "attn/<kind>" else re.escape(s)
+    for s in DEVICE_SCOPES)
+# a scope stands between the delimiters of a name stack: ``/`` and the
+# brackets of a transformation
+_SCOPE_AT = re.compile(r"(?:^|[/(])(" + _ANY_SCOPE + r")(?=$|[/)])")
+
+
+def device_scope(name: str):
+    """``jax.named_scope(name)`` for a name of ``DEVICE_SCOPES`` (any
+    ``attn/<kind>``), a ``ValueError`` for another: a scope nobody can
+    read back is not added by accident.  Trace-time only: it names the
+    ops traced under it (through differentiation and remat) and costs
+    no host call and no device op when the program runs."""
+    if not re.fullmatch(_ANY_SCOPE, name):
+        raise ValueError(
+            f"{name!r} is not in DEVICE_SCOPES: add it there (and to "
+            "docs/OBSERVABILITY.md) before an op wears it")
+    import jax
+
+    return jax.named_scope(name)
+
+
+def classify_op_name(op_name: str):
+    """``(phase, path)`` of one device op from the name stack JAX wrote
+    into its ``op_name`` (``jit(step)/transpose(jvp(step/layers))/while/
+    body/closed_call/checkpoint/attn/full/attn.qkv/dot_general``).
+
+    ``phase``: ``"recompute"`` under ``rematted_computation`` (what
+    ``jax.checkpoint`` runs again in the backward pass: JAX 0.9 keeps a
+    block's transposed ops beside it, under ``checkpoint/`` alone, so
+    the two are told apart), else ``"backward"`` under ``transpose(``,
+    else ``"forward"`` under ``jvp(``, else ``"update"``: what is not
+    differentiated -- the optimizer, and whatever of the model depends
+    on no parameter (rotary tables, masks).  ``"unnamed"`` where the
+    name holds no ``jit(`` and so no name stack at all: the compiler's
+    own names (``ragged-dot-none``, the grouped-matmul kernels it makes
+    of ``lax.ragged_dot``) and ops it adds of its own.  ``path``: the
+    scopes of ``DEVICE_SCOPES`` in the name, outermost first, each
+    once (a remat region's ops carry the stack they were first traced
+    under inside the transposed one: ``step/layers`` twice); empty where
+    the op wears none."""
+    if "jit(" not in op_name:
+        phase = "unnamed"
+    elif "rematted_computation" in op_name:
+        phase = "recompute"
+    elif "transpose(" in op_name:
+        phase = "backward"
+    elif "jvp(" in op_name:
+        phase = "forward"
+    else:
+        phase = "update"
+    return phase, tuple(dict.fromkeys(_SCOPE_AT.findall(op_name)))
 
 
 # ---------------------------------------------------------------------- #

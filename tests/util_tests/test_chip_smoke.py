@@ -58,7 +58,8 @@ def on_a_chip(monkeypatch):
     names = ("jax_compilation_cache_dir",
              "jax_persistent_cache_min_compile_time_secs",
              "jax_persistent_cache_min_entry_size_bytes",
-             "jax_traceback_in_locations_limit")
+             "jax_traceback_in_locations_limit",
+             "jax_compilation_cache_include_metadata_in_key")
     before = {n: getattr(jax.config, n) for n in names}
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     yield
@@ -88,6 +89,20 @@ def test_cache_dir_defaults_to_a_fixed_path_in_the_checkout(
     # and git ignores it
     with open(os.path.join(_ROOT, ".gitignore")) as f:
         assert ".jax_cache/" in f.read().split()
+
+
+def test_cache_key_takes_in_the_name_stacks_and_nothing_of_the_path(
+        on_a_chip, monkeypatch, tmp_path):
+    """The per-layer metrics are read off op names in a profile, so an
+    executable served from the cache must carry this program's names:
+    the metadata goes into the key, and with no traceback in a location
+    the metadata holds name stacks alone (two checkouts still hit each
+    other)."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert not jax.config.jax_compilation_cache_include_metadata_in_key
+    enable_compile_cache()
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
+    assert jax.config.jax_traceback_in_locations_limit == 0
 
 
 def test_cache_stays_off_on_the_cpu(monkeypatch):

@@ -139,3 +139,24 @@ def test_every_recorder_span_name_is_documented():
     assert not missing, (
         "flight-recorder span names recorded at runtime but absent "
         f"from docs/OBSERVABILITY.md: {missing}")
+
+
+def test_every_device_scope_is_documented_and_worn_through_the_list():
+    """PR 34: the names the step programs put on the device's ops are
+    operator surface as well (XProf folds a step by them), and they are
+    one closed list: each is in the doc's table, and no module names a
+    scope past ``device_scope``."""
+    from chainermn_tpu.utils.telemetry import DEVICE_SCOPES
+
+    doc = open(_DOC).read()
+    missing = [s for s in DEVICE_SCOPES if f"`{s}`" not in doc]
+    assert not missing, (
+        f"device scopes absent from docs/OBSERVABILITY.md: {missing}")
+    worn = set()
+    for src in _walk_sources():
+        # the one call allowed is device_scope's own
+        assert len(re.findall(r"(?<!`)jax\.named_scope\(", src)) == (
+            1 if "def device_scope(" in src else 0)
+        worn.update(re.findall(r"device_scope\(\s*f?\"([^\"]+)\"", src))
+    worn = {"attn/<kind>" if s.startswith("attn/") else s for s in worn}
+    assert worn == set(DEVICE_SCOPES)
