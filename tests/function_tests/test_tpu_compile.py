@@ -142,6 +142,29 @@ def test_ring_pair_compiles_on_four_chips(topo, layout):
     assert "collective-permute" in text
 
 
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "backward"])
+def test_kda_pair_kernels_compile(topo, one_chip, backward):
+    """The two kernels that make a chunk's pair weights in VMEM
+    (``ops/kda.py``), at the Kimi cell's slab step: 32 heads x 4 chunks
+    of 64 tokens, keys 128 wide, float32."""
+    from chainermn_tpu.ops import kda
+    from chainermn_tpu.ops.pallas_attention import tracing_for_mesh
+
+    x = jax.ShapeDtypeStruct((1, 32, 4, 64, 128), jnp.float32,
+                             sharding=one_chip)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    weights = tracing_for_mesh(mesh, kda._pair_weights)
+
+    def loss(q, k, G):
+        return sum(jnp.sum(jnp.sin(a)) for a in weights(q, k, G))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else weights
+    text = _compile(fn, x, x, x).as_text()
+    assert sum('custom_call_target="tpu_custom_call"' in line
+               for line in text.splitlines()) == 1 + backward
+
+
 def _compile_step(mc, cfg, opt, batch, seq):
     """``make_train_step`` compiled for the described devices of ``mc``
     (shapes only: there is no device to hold an array)."""
@@ -322,7 +345,10 @@ def test_kimi_cell_step_fits_with_its_mixers_in_it(topo):
     they are; each KDA position inverts its chunks' systems in three
     call sites of the op's own kernel (forward, the block's recompute,
     the slab's; the solve's VJP has none), 128 systems on the lanes,
-    and XLA's triangular solve is gone; the chunked recurrence and
+    and XLA's triangular solve is gone; the same three sites make the
+    chunks' pair weights in a kernel and a fourth, backward, makes them
+    again from q, k and G, so no slab step's pair-by-pair tensor is in
+    the program; the chunked recurrence and
     every new scope are in the program, and the compiled step needs
     between 10 and 14.5 GiB of the chip's 16 at the traffic file's
     ``loss_chunk`` (the sizing rule of ISSUE 32: the first branch,
@@ -349,19 +375,29 @@ def test_kimi_cell_step_fits_with_its_mixers_in_it(topo):
     assert _flash_kernels(text, "attn/mla") == 3
     kernels = [line for line in text.splitlines()
                if "pallas_call" in line and "tpu_custom_call" in line]
-    inversions = [line for line in kernels if "kda/scan" in line]
+    inversions = [line for line in kernels if "kda.solve" in line]
+    pairs = [line for line in kernels if "kda.pairs" in line]
     # the leading layer and three of the period's four: forward, the
-    # block's recompute and the slab's, and no site in the solve's VJP
-    assert len(inversions) == 4 * 3 and len(kernels) == 3 + 4 * 3
+    # block's recompute and the slab's, and no site in the solve's VJP;
+    # the pair weights' kernel at the same three and its VJP's once
+    assert len(inversions) == 4 * 3 and len(pairs) == 4 * (3 + 1)
+    assert len(kernels) == 3 + 4 * 3 + 4 * 4
+    assert all("kda/scan" in line for line in inversions + pairs)
     assert "InvertDiagBlocksLowerTriangular" not in text
     for line in inversions:
         # a slab step's 1 x 32 x 4 systems of 64 x 64, a system a lane
         assert "= f32[64,64,128]{2,1,0" in line, line[:300]
+    # a slab step's 128 blocks: A and A' out, or dq, dk and dG
+    assert sum(" = (f32[128,64,64]{" in line for line in pairs) == 4 * 3
+    assert sum(" = (f32[128,4,16,128]{" in line for line in pairs) == 4
     for line in kernels:
         # 32 heads of one sequence: q and k 192 wide, v and o 128
-        assert line in inversions or (
+        assert line in inversions or line in pairs or (
             "bf16[32,16384,192]" in line
             and "bf16[32,16384,128]" in line), line[:300]
+    # what the jnp form stored a slab step: col and kcol, pair
+    assert "f32[32,4,4,64,128]" not in text
+    assert "4,4,16,16,128]" not in text
     assert set(scopes_hybrid.instruction_scopes(text).values()) == {
         "kda/conv", "kda/scan", "kda/gate", "mla/latent"}
     by_scope = set(scopes.instruction_scopes(text).values())

@@ -132,8 +132,168 @@ def test_recurrence_counters_and_shapes_it_refuses():
     assert reg.counter("kda/state_bytes_kept").value == 2 * 2 * 3 * 16 * 16 * 4
     # a system a chunk and head, each inverted once a pass
     assert reg.counter("kda/systems_inverted").value == 2 * 3 * 8
+    # and every one of them takes its pair weights from the kernel
+    assert reg.counter("kda/pair_blocks_in_vmem").value == 2 * 3 * 8
     with pytest.raises(ValueError, match="whole chunks"):
         kda_chunked(*_draw(0, 96, "mild"))
+
+
+# -- the pair weights of a chunk --------------------------------------- #
+
+def _pair_weights_jnp(q, k, G, g, sub):
+    """What ``ops/kda.py`` ran until its kernel pair (PR 37), and the
+    kernels' yardstick since: ``(A, A')`` of a chunk from tensors that
+    hold every pair's exponent, ``col`` and ``kcol`` ``(..., n, C,
+    d_k)`` and ``pair`` ``(..., n, sub, sub, d_k)``.  ``q``, ``k``,
+    ``G``, ``g`` ``(..., C, d_k)``, results ``(..., C, C)``."""
+    C, dk = k.shape[-2:]
+    n = C // sub
+    lead = k.shape[:-2]
+    by_sub = lambda x: x.reshape(*lead, n, sub, dk)
+    qs, ks, Gs = by_sub(q), by_sub(k), by_sub(G)
+    # R_a: the running sum just before sub-block a
+    ref = Gs[..., 0, :] - by_sub(g)[..., 0, :]                # (..., n, dk)
+    row = jnp.exp(Gs - ref[..., None, :])                     # <= 1
+    before = jnp.arange(C)[None, :] < (jnp.arange(n) * sub)[:, None]
+    col = jnp.exp(jnp.where(
+        before[..., None],
+        ref[..., :, None, :] - G[..., None, :, :], -jnp.inf))  # (..., n, C, dk)
+    kcol = k[..., None, :, :] * col
+    off_kk = jnp.einsum("...atc,...aic->...ati", ks * row, kcol)
+    off_qk = jnp.einsum("...atc,...aic->...ati", qs * row, kcol)
+    # the pairs inside a sub-block, each with its own exponent
+    t, i = jnp.arange(sub)[:, None], jnp.arange(sub)[None, :]
+    pair = jnp.exp(jnp.where(
+        (t >= i)[..., None],
+        Gs[..., :, None, :] - Gs[..., None, :, :], -jnp.inf))
+    in_kk = jnp.where(t > i, jnp.einsum(
+        "...atc,...aic,...atic->...ati", ks, ks, pair), 0.0)
+    in_qk = jnp.einsum("...atc,...aic,...atic->...ati", qs, ks, pair)
+    own = jnp.eye(n, dtype=k.dtype)[:, None, :, None]         # (n, 1, n, 1)
+
+    def whole(off, inside):
+        # sub-block a's rows: the columns before it, and its own
+        placed = inside[..., :, :, None, :] * own             # (n, sub, n, sub)
+        return (off.reshape(*lead, n, sub, n, sub) + placed).reshape(
+            *lead, C, C)
+
+    return whole(off_kk, in_kk), whole(off_qk, in_qk)
+
+
+def _one_chunk(c, decay, h, d=16):
+    """q, k, g of one chunk of ``c`` tokens in each of ``h`` heads, as
+    ``_chunk_parts`` sees them: ``(h, c, d)``."""
+    q, k, _, g, _ = (jnp.moveaxis(x[0], 1, 0) for x in _draw(
+        c + h, c, decay, b=1, h=h, d=d))
+    return q, k, g
+
+
+def _both_forms(c):
+    sub = min(kda.SUB, c)
+    return (lambda q, k, g: kda._pair_weights(q, k, jnp.cumsum(g, axis=-2)),
+            lambda q, k, g: _pair_weights_jnp(
+                q, k, jnp.cumsum(g, axis=-2), g, sub))
+
+
+# a full chunk, a short one (padded inside), a count of blocks that is
+# no whole kernel step, and keys as wide as the lanes
+PAIR_SHAPES = pytest.mark.parametrize("c,h,d", [
+    (64, 3, 16), (32, 5, 16), (16, 129, 16), (64, 2, 128)],
+    ids=["full", "short", "h129", "lanes128"])
+
+
+@pytest.mark.parametrize("decay", ["published", "mild"])
+@PAIR_SHAPES
+def test_pair_kernel_equals_the_jnp_form(c, h, d, decay):
+    """``A`` and ``A'`` from the kernel, float32, against the form that
+    stores every pair's exponent."""
+    q, k, g = _one_chunk(c, decay, h, d)
+    if decay == "published" and c == 64:
+        assert float(jnp.cumsum(g, axis=1).min()) < -200
+    kernel, plain = _both_forms(c)
+    got, want = jax.jit(kernel)(q, k, g), jax.jit(plain)(q, k, g)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape == (h, c, c)
+        np.testing.assert_allclose(
+            a, b, rtol=1e-5, atol=1e-6 * float(jnp.abs(b).max()))
+    assert not np.triu(got[0]).any() and not np.triu(got[1], 1).any()
+    assert np.diagonal(got[1], axis1=1, axis2=2).any()
+
+
+@pytest.mark.parametrize("decay", ["published", "mild"])
+@PAIR_SHAPES
+def test_pair_kernel_vjp_equals_autodiff_through_the_jnp_form(c, h, d, decay):
+    """``dq``, ``dk`` and ``dg`` from the backward kernel, which makes
+    the pair weights again from q, k and ``G``, against autodiff
+    through the stored form; cotangents stand above the diagonal too,
+    where neither may read them."""
+    q, k, g = _one_chunk(c, decay, h, d)
+    weights = [f(jnp.arange(h * c * c, dtype=jnp.float32)).reshape(h, c, c)
+               for f in (jnp.cos, jnp.sin)]
+
+    def loss(fn):
+        return lambda *a: sum(jnp.sum(x * w) for x, w in zip(fn(*a), weights))
+
+    kernel, plain = _both_forms(c)
+    got = jax.jit(jax.grad(loss(kernel), argnums=(0, 1, 2)))(q, k, g)
+    want = jax.jit(jax.grad(loss(plain), argnums=(0, 1, 2)))(q, k, g)
+    for a, b in zip(got, want):
+        assert bool(jnp.isfinite(a).all())
+        np.testing.assert_allclose(
+            a, b, rtol=1e-4, atol=2e-6 * float(jnp.abs(b).max()))
+
+
+def test_pair_weights_stay_lower_and_finite_when_exponents_underflow():
+    """Decays a thousand times the published ones: every weight but a
+    row's own and its neighbours' is below float32.  ``A`` stays
+    strictly lower, ``A'`` lower, every entry and every cotangent
+    finite, and the diagonal of ``A'`` is q . k."""
+    q, k, g = _one_chunk(64, "published", 3)
+    g = 1e3 * g
+    assert float(jnp.cumsum(g, axis=1).min()) < -1e5
+    kernel, _ = _both_forms(64)
+    A, A_q = jax.jit(kernel)(q, k, g)
+    assert bool(jnp.isfinite(A).all()) and bool(jnp.isfinite(A_q).all())
+    assert not np.triu(A).any() and not np.triu(A_q, 1).any()
+    np.testing.assert_allclose(
+        np.diagonal(A_q, axis1=1, axis2=2), jnp.sum(q * k, -1), rtol=1e-5,
+        atol=1e-7)
+    grads = jax.jit(jax.grad(
+        lambda *a: sum(jnp.sum(x) for x in kernel(*a)),
+        argnums=(0, 1, 2)))(q, k, g)
+    assert all(bool(jnp.isfinite(x).all()) for x in grads)
+
+
+def _equations(jaxpr):
+    """Equations of a jaxpr and of every jaxpr inside it."""
+    count = 0
+    for eqn in jaxpr.eqns:
+        count += 1
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (tuple, list)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    count += _equations(inner)
+    return count
+
+
+def test_pair_kernel_bodies_stay_small_to_lower():
+    """A Pallas kernel is traced and lowered to Mosaic once a call site
+    at every process start, whatever the compile cache holds, and the
+    Kimi step has 12 + 4 sites of this pair: the seconds a site follow
+    the body's size.  The bodies hold eight columns a loop turn and
+    the three earlier sub-blocks' products once each (531 and 868
+    equations: 0.05 to 0.11 s a site in the sandbox); with every
+    sub-block and row written out they would be several thousand."""
+    x = jax.ShapeDtypeStruct((kda._BLOCKS, 4, 16, 128), jnp.float32)
+    text = jax.make_jaxpr(jax.grad(
+        lambda q, k, G: sum(jnp.sum(a) for a in kda._pairs(q, k, G, True)),
+        argnums=(0, 1, 2)))(x, x, x)
+    bodies = [eqn.params["jaxpr"] for eqn in text.jaxpr.eqns
+              if eqn.primitive.name == "pallas_call"]
+    assert len(bodies) == 2
+    forward, backward = (_equations(body) for body in bodies)
+    assert forward <= 600 and backward <= 1000, (forward, backward)
 
 
 # -- the solve inside a chunk ----------------------------------------- #
@@ -145,7 +305,7 @@ def _layers_systems(c):
     @jax.jit
     def made(q, k, g, beta):
         return beta[..., None] * kda._pair_weights(
-            q, k, jnp.cumsum(g, axis=-2), g, min(kda.SUB, c))[0]
+            q, k, jnp.cumsum(g, axis=-2))[0]
 
     q, k, _, g, beta = (jnp.moveaxis(x[0], 1, 0) for x in _draw(
         c, c, "mild", b=1, h=129))
@@ -345,6 +505,25 @@ def test_the_bias_is_held_fixed_by_gradient_and_by_weight_decay(host):
     params = shard_params(mc, cfg, unbiased)
     _, _, loss0 = step(params, shard_opt_state(opt, params), *tokens())
     assert abs(float(loss0) - losses[0]) > 1e-4
+
+
+def test_toy_step_makes_every_chunks_pair_weights_in_the_kernel():
+    """The toy step as it is traced: four KDA layers (the leading one
+    and the period's three) of 2 sequences x 4 heads x 2 chunks, each
+    block's pair weights from the kernel and each block a system."""
+    cfg, mc = hybrid_cfg(), one_chip()
+    opt = optax.sgd(0.1)
+    shapes = jax.eval_shape(
+        lambda: init_transformer(jax.random.PRNGKey(0), cfg))
+    reg = MetricsRegistry(enabled=True)
+    prev = set_registry(reg)
+    try:
+        make_train_step(mc, cfg, opt).trace(
+            shapes, jax.eval_shape(opt.init, shapes), *tokens())
+    finally:
+        set_registry(prev)
+    assert reg.counter("kda/pair_blocks_in_vmem").value == 4 * 2 * 4 * 2
+    assert reg.counter("kda/systems_inverted").value == 4 * 2 * 4 * 2
 
 
 def test_hold_selection_bias_serves_a_step_of_ones_own():
