@@ -5,6 +5,8 @@ recurrence against the program's chunked op, the configuration file
 against the published numbers, the required counts against hand counts,
 the new scopes' readers and the control."""
 
+import copy
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,6 +20,7 @@ from benchmarks.reference import kimi_linear
 from benchmarks.reference.common import delta_norms
 
 CELL = "kimi-linear-l5-ep32-train-tok16384"
+CONFIG = "kimi-linear-l5-ep32"
 DRIVER = cells.module("drivers", "train_step_hybrid")
 _SAME = lambda x: x     # noqa: E731  (the reference proper rounds nothing)
 
@@ -224,33 +227,77 @@ def test_config_keeps_every_published_number():
     assert cfg["num_experts"] == 8 and cfg["vocabulary"] * 8 == 163840
 
 
-def test_manifest_entries_of_the_cell_fit_the_form():
-    """What this cell wrote into ``BENCHMARK.json``: each sentence is 1 to
-    200 printable characters on one line (the first check refused a
-    configuration's ``why`` of 206), each entry has just its keys, and
-    the new entries stand last in their lists."""
-    import json
-    import pathlib
+# what PR 32 wrote into ``BENCHMARK.json`` for this cell, by name
+SIX = ("kda.ms_per_step", "kda.scan_ms_per_step", "kda.scan_roofline",
+       "mla.ms_per_step", "flash.mla_ms_per_step", "flash.mla_roofline")
+SHARED = ("tokens_per_s", "step_ms.p90", "step.mfu_pct.lm",
+          "device.idle_pct.lm", "device.hbm_gib.lm", "moe.ms_per_step",
+          "moe.experts_roofline", "moe.load_imbalance",
+          "moe.shared_ms_per_step", "moe.moves_ms_per_step",
+          "mlp.dense_ms_per_step")
 
-    bench = json.loads(
-        (pathlib.Path(__file__).parents[2] / "BENCHMARK.json").read_text())
-    config, cell = bench["configs"][-1], bench["workloads"][-1]
-    assert (config["name"], cell["name"]) == (cell["config"], CELL)
+
+def _by_name(entries):
+    return {e["name"]: e for e in entries}
+
+
+def _entries_of_the_cell_fit_the_form(bench):
+    """Each entry is found by its NAME, wherever it stands: a later PR
+    appends configurations, cells and metrics after these, and its
+    cell's name after this one's in the lists they share."""
+    cell = _by_name(bench["workloads"])[CELL]
+    config = _by_name(bench["configs"])[cell["config"]]
+    assert config["name"] == CONFIG
     assert set(config) == {"name", "source", "file", "reduced", "why"}
     assert set(cell) == {"name", "config", "traffic", "chips", "why"}
-    new = bench["per_layer"][-6:]
-    assert [m["name"] for m in new] == [
-        "kda.ms_per_step", "kda.scan_ms_per_step", "kda.scan_roofline",
-        "mla.ms_per_step", "flash.mla_ms_per_step", "flash.mla_roofline"]
-    for metric in new:
+    metrics = _by_name(bench["end_to_end"] + bench["per_layer"])
+    mine = [metrics[name] for name in SIX]
+    for metric in mine:
         assert set(metric) == {"name", "unit", "better", "source", "layer",
                                "moves", "workloads"}
         assert metric["workloads"] == [CELL]
-    for metric in bench["end_to_end"] + bench["per_layer"][:-6]:
-        assert CELL not in metric.get("workloads", [])[:-1]
+    for name in SHARED:
+        assert CELL in metrics[name]["workloads"], name
     for text in ([config["why"], config["source"], cell["why"]]
-                 + [m["layer"] for m in new]):
+                 + [m["layer"] for m in mine]):
         assert 1 <= len(text) <= 200 and text.isascii() and text.isprintable()
+
+
+def _with_a_cell_appended(bench):
+    """A copy of the manifest as the next ``model_config`` PR leaves it:
+    a seventh configuration and its cell after the last, the cell's name
+    after the last in every list this cell shares with another, and one
+    per-layer metric of its own after the last."""
+    bench = copy.deepcopy(bench)
+    bench["configs"].append(dict(
+        _by_name(bench["configs"])[CONFIG], name="appended-l1"))
+    bench["workloads"].append(dict(
+        _by_name(bench["workloads"])[CELL],
+        name="appended-l1-train", config="appended-l1"))
+    for metric in bench["end_to_end"] + bench["per_layer"]:
+        listed = metric.get("workloads", [])
+        if CELL in listed and listed != [CELL]:
+            listed.append("appended-l1-train")
+    bench["per_layer"].append(dict(
+        _by_name(bench["per_layer"])[SIX[0]],
+        name="appended.ms_per_step", workloads=["appended-l1-train"]))
+    return bench
+
+
+@pytest.mark.parametrize("appended", [False, True],
+                         ids=["as-it-is", "a-seventh-cell-appended"])
+def test_manifest_entries_of_the_cell_fit_the_form(appended):
+    """What this cell wrote into ``BENCHMARK.json``: each sentence is 1 to
+    200 printable characters on one line (the first check refused a
+    configuration's ``why`` of 206), each entry has just its keys, its
+    six metrics are this cell's alone and the cell is in the lists it
+    shares.  The manifest is append-only by name, not by position: the
+    same holds of a copy to which a later PR's configuration, cell and
+    metric are appended, so a check that pins an index fails here
+    before it stops that PR (``[-1]`` and ``[-6:]`` did, PRs 33-38)."""
+    bench = cells.manifest()
+    _entries_of_the_cell_fit_the_form(
+        _with_a_cell_appended(bench) if appended else bench)
 
 
 # -- required counts by hand ----------------------------------------- #
@@ -398,10 +445,7 @@ def test_new_scopes_and_their_readers():
 
     ctx = {"facts": facts, "trace": trace, "window": window,
            "peaks": {"flops_per_s": 1e12, "hbm_bytes_per_s": 1e9}}
-    names = ("kda.ms_per_step", "kda.scan_ms_per_step", "kda.scan_roofline",
-             "mla.ms_per_step", "flash.mla_ms_per_step",
-             "flash.mla_roofline")
-    read = {m: cells.module("layer_metrics", m).read for m in names}
+    read = {m: cells.module("layer_metrics", m).read for m in SIX}
     assert read["kda.ms_per_step"](ctx) == pytest.approx(15.0)
     assert read["kda.scan_ms_per_step"](ctx) == pytest.approx(10.0)
     assert read["mla.ms_per_step"](ctx) == pytest.approx(5.0)
@@ -415,11 +459,12 @@ def test_new_scopes_and_their_readers():
     for bare in (dict(ctx, facts={}), dict(ctx, trace=None)):
         assert all(r(bare) is None for r in read.values())
     assert read["kda.scan_roofline"](dict(ctx, peaks=None)) is None
-    # the manifest names exactly these six for this cell alone
-    mine = [m for m in cells.manifest()["per_layer"]
-            if m.get("workloads") == [CELL]]
-    assert sorted(m["name"] for m in mine) == sorted(names)
-    assert all(m["moves"] == "tokens_per_s" for m in mine)
+    # these six are among the manifest's metrics of this cell alone
+    # (a later PR may append more), and each moves the rate
+    mine = {m["name"]: m for m in cells.manifest()["per_layer"]
+            if m.get("workloads") == [CELL]}
+    assert set(SIX) <= set(mine)
+    assert all(mine[n]["moves"] == "tokens_per_s" for n in SIX)
 
 
 def test_every_scope_is_in_the_compiled_toy_step():

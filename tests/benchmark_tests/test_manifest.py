@@ -43,6 +43,28 @@ def test_names_are_unique():
     assert len(pairs) == len(set(pairs))
 
 
+# a manifest list, or a metric's ``workloads``, indexed or sliced
+_BY_POSITION = re.compile(
+    r"""\[["'](?:configs|workloads|per_layer|end_to_end)["']\]\s*\[\s*[-\d:]"""
+    r"""|get\(["']workloads["'][^)\n]*\)\s*\[\s*[-\d:]""")
+
+
+def test_no_test_indexes_a_manifest_list_by_position():
+    """Entries are appended and found by name: a test that says where
+    one stands (``[-1]``, ``[-6:]``, ``[:-1]``) stops the next PR that
+    appends, and only a ``benchmark`` PR may then edit it (PRs 33-38)."""
+    found = []
+    for base, dirs, files in os.walk(os.path.join(cells.ROOT, "tests")):
+        dirs[:] = [d for d in dirs if d != "__pycache__"]
+        for name in files:
+            if name.endswith(".py"):
+                with open(os.path.join(base, name)) as f:
+                    found += [(name, n, line.strip())
+                              for n, line in enumerate(f, 1)
+                              if _BY_POSITION.search(line)]
+    assert not found, found
+
+
 def test_top_level_keys_and_limits():
     assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
