@@ -22,6 +22,7 @@ from .transformer import (
     AttentionKind,
     TransformerConfig,
     apply_rope,
+    expert_buffer_rows,
     expert_choices,
     expert_load,
     init_transformer,
@@ -44,6 +45,7 @@ __all__ = [
     "Seq2seqConfig",
     "TransformerConfig",
     "apply_rope",
+    "expert_buffer_rows",
     "expert_choices",
     "expert_load",
     "init_seq2seq",

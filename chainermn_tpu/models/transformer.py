@@ -47,6 +47,7 @@ from chainermn_tpu.ops.pallas_attention import (
     tracing_for_mesh,
 )
 from chainermn_tpu.parallel.expert import (
+    buffer_rows,
     expert_parallel_moe,
     expert_parallel_moe_dropless,
     grouped_dense,
@@ -77,6 +78,7 @@ __all__ = [
     "AttentionKind",
     "TransformerConfig",
     "apply_rope",
+    "expert_buffer_rows",
     "expert_choices",
     "expert_load",
     "init_transformer",
@@ -2255,6 +2257,27 @@ def expert_load(mesh_cfg, cfg: TransformerConfig, params, tokens):
     chosen = expert_choices(mesh_cfg, cfg, params, tokens)
     return jax.vmap(lambda c: jnp.zeros((cfg.n_experts,), jnp.int32).at[
         c.reshape(-1)].add(1))(chosen)
+
+
+def expert_buffer_rows(mesh_cfg, cfg: TransformerConfig, params, tokens):
+    """``(sparse layers, 2)`` int32: of these tokens' (token, choice)
+    rows, those that fall to the experts held here, and the rows of the
+    sorted buffer the dropless layer then works on (the rung of
+    ``parallel.expert``'s ladder it takes, by the function the layer
+    itself calls), a layer.  Where the tokens are split over several
+    members of the mesh, the member that holds most."""
+    chosen = expert_choices(mesh_cfg, cfg, params, tokens)
+    shape = mesh_cfg.mesh.shape
+    members, seqs = shape["data"] * shape["expert"], shape["seq"]
+    layers, B, T, k = chosen.shape
+    first, held = cfg.experts_held or (0, cfg.n_experts)
+    here = (chosen >= first) & (chosen < first + held)
+    here = here.reshape(layers, members, B // members, seqs, T // seqs, k)
+    count = here.sum(axis=(2, 4, 5), dtype=jnp.int32).max(axis=(1, 2))
+    # one expert group sorts a member's tokens for all its experts
+    return jnp.stack([count, buffer_rows(
+        count, (B // members) * (T // seqs) * k, held, cfg.n_experts)],
+        axis=1)
 
 
 # --------------------------------------------------------------------- #

@@ -24,7 +24,8 @@ expert, the experts held here run as grouped products over exactly the
 rows routed to them (``lax.ragged_dot``), and no token is dropped
 whatever the imbalance.  It can be told that it holds only a contiguous
 share of the router's experts: it then routes over all of them and
-returns its own experts' part of the result.
+returns its own experts' part of the result, through a sorted buffer
+no larger than the rows it holds need (:func:`_buffer_rungs`).
 """
 
 from __future__ import annotations
@@ -37,9 +38,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from chainermn_tpu.utils.metrics import get_registry
 from chainermn_tpu.utils.telemetry import device_scope
 
 __all__ = [
+    "buffer_rows",
     "expert_parallel_moe",
     "expert_parallel_moe_dropless",
     "grouped_dense",
@@ -231,7 +234,11 @@ def grouped_dense(rows, w, group_sizes):
     ``group_sizes`` ``(G,)`` int32.  On TPU ``lax.ragged_dot`` compiles
     to a grouped-matmul kernel whose grid follows ``group_sizes``, so
     the work is that of the rows really there; rows past the last group
-    come back undefined (see :func:`_experts_of_rows`)."""
+    come back undefined (see :func:`_experts_of_rows`).  What is done
+    to ``rows`` and to the result around it (a cast, an activation, the
+    cotangent's mask) passes over all ``R`` rows, held or not: the
+    dropless layer keeps ``R`` near the rows held
+    (:func:`_buffer_rungs`)."""
     return lax.ragged_dot(rows, w, group_sizes,
                           preferred_element_type=rows.dtype)
 
@@ -274,15 +281,59 @@ def _rows_back_bwd(order, g):
 _rows_back.defvjp(_rows_back_fwd, _rows_back_bwd)
 
 
+# The compact buffer's two moves, each the other's transpose: row r of
+# the buffer stands for token tok[r], and a token has as many rows
+# there as it chose experts held here (none, often).
+
+
+def _sum_into(rows, tok, n_tokens: int):
+    """``out[t] = sum of rows[r] over the r with tok[r] == t``, ``(n_tokens,
+    D)``: a scatter-add of the buffer's rows and no ``(N*k, D)``
+    tensor.  Summed in float32 as the full buffer's sum over a token's
+    k choices is; AD's transpose is the gather ``g[tok]``."""
+    out = jnp.zeros((n_tokens, rows.shape[1]), jnp.float32).at[tok].add(
+        rows.astype(jnp.float32))
+    return out.astype(rows.dtype)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _take(x, tok, n_tokens: int):
+    """``x[tok]``, ``x`` ``(n_tokens, D)``: the tokens of the buffer's
+    rows, a gather of as many rows as the buffer has.  The transpose is
+    :func:`_sum_into` where AD's own would add the cotangent's rows up
+    in their own dtype."""
+    return x[tok]
+
+
+def _take_fwd(x, tok, n_tokens):
+    return x[tok], tok
+
+
+def _take_bwd(n_tokens, tok, g):
+    return _sum_into(g, tok, n_tokens), None
+
+
+_take.defvjp(_take_fwd, _take_bwd)
+
+
 def _sort_by_group(key, n_groups: int):
-    """``(order, inv, sizes)``: the stable sort of ``key`` (values in
+    """``(order, sizes)``: the stable sort of ``key`` (values in
     ``[0, n_groups]``; ``n_groups`` marks a row of no group, sorted
-    last), its inverse, and each group's row count."""
+    last) and each group's row count."""
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    inv = jnp.zeros_like(order).at[order].set(
+    # a compare and a sum: a scatter-add of one a row costs six times
+    # as much on the chip (1.18 against 0.21 ms at 131,072 rows and 8
+    # groups, PERF.md section 6, PR 41)
+    sizes = jnp.sum(
+        key[:, None] == jnp.arange(n_groups, dtype=key.dtype)[None, :],
+        axis=0, dtype=jnp.int32)
+    return order, sizes
+
+
+def _inverse(order):
+    """The inverse of a permutation."""
+    return jnp.zeros_like(order).at[order].set(
         jnp.arange(order.shape[0], dtype=jnp.int32))
-    sizes = jnp.zeros((n_groups + 1,), jnp.int32).at[key].add(1)
-    return order, inv, sizes[:n_groups]
 
 
 @jax.custom_vjp
@@ -333,7 +384,8 @@ def _exchange(rows, sizes, expert_fn, expert_params, axis_name, S, cap):
     # e-th run; past p's rows it holds nothing
     ends = jnp.cumsum(recv_sizes, axis=1)               # (S, e_local)
     key = jnp.sum(j[None, :, None] >= ends[:, None, :], axis=-1)
-    order, inv, mine = _sort_by_group(key.reshape(-1), e_local)
+    order, mine = _sort_by_group(key.reshape(-1), e_local)
+    inv = _inverse(order)
     ys = _experts_of_rows(
         expert_fn, expert_params, recv.reshape(S * cap, D)[order], mine)
     home = lax.all_to_all(ys[inv].reshape(S, cap, D), axis_name, 0, 0,
@@ -344,6 +396,120 @@ def _exchange(rows, sizes, expert_fn, expert_params, axis_name, S, cap):
     s_r = jnp.minimum(s_r, S - 1)
     slot = jnp.clip(r - peer_start[s_r], 0, cap - 1)
     return home.reshape(S * cap, D)[s_r * cap + slot]
+
+
+# rows of one tile of the grouped kernels: a rung is whole tiles
+_ROW_TILE = 128
+
+
+def _buffer_rungs(n_rows: int, held: int, of: int) -> Tuple[int, ...]:
+    """The static sizes the sorted buffer may take, ascending, for
+    ``n_rows`` (token, choice) rows of which the ``held`` experts of
+    the router's ``of`` get ``n_rows * held / of`` if the router is
+    even: twice that, four times that, and ``n_rows`` itself, which
+    holds whatever the router does.  Whole tiles, none above ``n_rows``,
+    none twice: a member that holds every expert has the one rung.
+
+    Four times is not decoration: the rows held grow 1.6-fold inside a
+    run of the Kimi cell and grew 2.4-fold at a learning rate the
+    Nemotron cell tried (PERF.md section 6, PRs 32 and 40).  The rule
+    is read off the layer's own shapes; if a chip says other multiples
+    are better, change it here."""
+    rungs = {n_rows}
+    for times in (2, 4):
+        rows = -(-times * n_rows * held // of)
+        rungs.add(min(-(-rows // _ROW_TILE) * _ROW_TILE, n_rows))
+    return tuple(sorted(rungs))
+
+
+def buffer_rows(rows_held, n_rows: int, held: int, of: int):
+    """The rows of the smallest rung of :func:`_buffer_rungs` that
+    holds ``rows_held`` (an int32 array of any shape, on the device or
+    not): the buffer the layer works on at that count."""
+    rungs = _buffer_rungs(n_rows, held, of)
+    return jnp.asarray(rungs, jnp.int32)[_rung_of(rows_held, rungs)]
+
+
+def _rung_of(rows_held, rungs):
+    return sum(((rows_held > r).astype(jnp.int32) for r in rungs[:-1]),
+               jnp.zeros_like(rows_held, jnp.int32))
+
+
+def _held_part(experts, k: int, C: int, x, expert_params, gates, order,
+               sizes, held):
+    """The held experts' part of the layer's result ``(N, D)`` through
+    a sorted buffer of ``C`` rows, ``C >= sum(sizes)``: the held rows
+    are the first of the sorted order, so ``order[:C]`` names them all
+    and the rest of the buffer is rows of choices not held, which the
+    grouped kernels leave undefined and the sum home leaves out.
+    ``C == N*k`` is the whole order: two gathers, each the other's
+    inverse (the only rung that needs the order's).  A smaller ``C``
+    takes ``C`` rows and adds ``C`` rows home, and no tensor in it has
+    ``N*k`` rows."""
+    N, D = x.shape
+    n = jnp.sum(sizes)
+    whole = C == N * k
+    with device_scope("moe/route"):
+        if whole:
+            inv = _inverse(order)
+            rows = _rows_out(x, order, inv, k)          # (N*k, D)
+        else:
+            first = order[:C]
+            tok = first // k
+            rows = _take(x, tok, N)                     # (C, D)
+    with device_scope("moe/experts"):
+        ys = experts(expert_params, rows, sizes)
+    with device_scope("moe/combine"):
+        # a where, not a product by a zero gate: the rows of choices
+        # not held here are whatever the grouped kernels left there
+        if whole:
+            ys = _rows_back(ys, order, inv).reshape(N, k, D)
+            return jnp.sum(jnp.where(held.reshape(N, k, 1), ys, 0)
+                           * gates[..., None].astype(ys.dtype), axis=1)
+        here = jnp.arange(C, dtype=jnp.int32) < n
+        gate = jnp.where(here, gates.reshape(-1)[first], 0)
+        return _sum_into(jnp.where(here[:, None], ys, 0)
+                         * gate[:, None].astype(ys.dtype), tok, N)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _laddered(part, rungs, rung, x, expert_params, gates, *counts):
+    """``part(rungs[rung], x, expert_params, gates, *counts)``, the rung
+    chosen on the device.  One ``custom_vjp`` around the switch: AD's
+    own partial evaluation of a conditional has EVERY branch return
+    every branch's residuals, zero-filled where not taken, so a small
+    rung would write and hold the full rung's.  Here the residuals are
+    the arguments, the same at every rung, and the backward pass
+    switches on the saved rung and differentiates that rung's function
+    inside its branch."""
+    return lax.switch(rung, [partial(part, C) for C in rungs],
+                      x, expert_params, gates, *counts)
+
+
+def _laddered_fwd(part, rungs, rung, x, expert_params, gates, *counts):
+    out = _laddered(part, rungs, rung, x, expert_params, gates, *counts)
+    return out, (rung, x, expert_params, gates, counts)
+
+
+def _laddered_bwd(part, rungs, res, g):
+    rung, x, expert_params, gates, counts = res
+
+    def pull(C, x, expert_params, gates, g):
+        return jax.vjp(lambda *diff: part(C, *diff, *counts),
+                       x, expert_params, gates)[1](g)
+
+    grads = lax.switch(rung, [partial(pull, C) for C in rungs],
+                       x, expert_params, gates, g)
+    # the compiler otherwise moves what consumes the branches' results
+    # into them: each layer's conditional then returns the gradient of
+    # the whole STACK of layers, all but its own slice zeros, and a
+    # step holds as many stacks as it has layers (read in the Mellum
+    # cell's compiled step: 13.28 -> 16.01 GiB without this line)
+    grads = jax.tree.map(lax.optimization_barrier, grads)
+    return (None, *grads, *(None for _ in counts))
+
+
+_laddered.defvjp(_laddered_fwd, _laddered_bwd)
 
 
 def expert_parallel_moe_dropless(
@@ -369,9 +535,13 @@ def expert_parallel_moe_dropless(
     leading axis of ``expert_params`` times the axis size); with
     ``G < E`` the result is the held experts' part of the layer's
     output, and what the absent experts would have added is left out.
-    Shapes are static (the sorted buffer has all ``N*k`` rows, the most
-    that can be routed here); the grouped products' work follows the
-    rows really routed here.  With an axis of size ``S > 1`` member
+    Shapes are static: the sorted buffer has the rows of one rung of a
+    short ladder (:func:`_buffer_rungs`: twice and four times the rows
+    an even router would send here, and all ``N*k``, the most that can
+    be routed here), the smallest that holds the rows this step routed
+    here, chosen on the device; moves, grouped products and the sum
+    home work on that many rows.  A group that holds every expert has
+    the one rung and no conditional.  With an axis of size ``S > 1`` member
     ``r`` holds experts ``first_expert + [r*G/S, (r+1)*G/S)`` and rows
     travel by all-to-all.
 
@@ -406,23 +576,26 @@ def expert_parallel_moe_dropless(
             x, router_w, top_k, score, scale, bias)
         choice = top_i.reshape(-1) - first_expert       # (N*k,)
         held = (choice >= 0) & (choice < G)
-        order, inv, sizes = _sort_by_group(jnp.where(held, choice, G), G)
-        rows = _rows_out(x, order, inv, top_k)          # (N*k, D)
+        order, sizes = _sort_by_group(jnp.where(held, choice, G), G)
 
-    with device_scope("moe/experts"):
-        if S == 1:
-            ys = _experts_of_rows(expert_fn, expert_params, rows, sizes)
-        else:
-            ys = _exchange(rows, sizes, expert_fn, expert_params,
-                           axis_name, S, N * min(top_k, G // S))
-
-    with device_scope("moe/combine"):
-        ys = _rows_back(ys, order, inv).reshape(N, top_k, D)
-        held = held.reshape(N, top_k, 1)
-        # a where, not a product by a zero gate: the rows of choices
-        # not held here are whatever the grouped kernels left there
-        out = jnp.sum(jnp.where(held, ys, 0) * gates[..., None].astype(
-            ys.dtype), axis=1)
+    if S == 1:
+        experts = partial(_experts_of_rows, expert_fn)
+    else:
+        def experts(expert_params, rows, sizes):
+            return _exchange(rows, sizes, expert_fn, expert_params,
+                             axis_name, S, N * min(top_k, G // S))
+    part = partial(_held_part, experts, top_k)
+    rungs = _buffer_rungs(N * top_k, G, E)
+    get_registry().inc("moe/buffer_rungs", len(rungs))
+    if len(rungs) == 1:
+        out = part(rungs[0], x, expert_params, gates, order, sizes, held)
+    else:
+        rung = _rung_of(jnp.sum(sizes), rungs)
+        if S > 1:
+            # the branches exchange rows: one rung for the whole group
+            rung = lax.pmax(rung, axis_name)
+        out = _laddered(part, rungs, rung, x, expert_params, gates, order,
+                        sizes, held)
 
     frac_tokens = jax.nn.one_hot(top_i[:, 0], E, dtype=jnp.float32).mean(0)
     frac_probs = probs.mean(axis=0)

@@ -246,6 +246,58 @@ def test_300m_train_step_four_chips(topo, axes, cfg_kw, kernels):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
+def _branch_bodies(text):
+    """For every ``conditional`` of a compiled program's text, its
+    branches' bodies: each the lines of the branch's computation and of
+    every computation it calls, as one string."""
+    import re
+
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+
+    def called(line):
+        names = re.findall(
+            r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", line)
+        for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+            names += [n.strip().lstrip("%") for n in group.split(",")]
+        return names
+
+    def body(name, seen):
+        if name in seen or name not in comps:
+            return []
+        seen.add(name)
+        return comps[name] + [l for line in comps[name]
+                              for c in called(line) for l in body(c, seen)]
+
+    return [["\n".join(body(b.strip().lstrip("%"), set()))
+             for b in group.split(",")]
+            for lines in comps.values() for line in lines
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", line)]
+
+
+def _assert_compact_rungs(text, n_rows, rungs, layers):
+    """The dropless layers' ladder in a compiled step: a conditional
+    forward and one backward a layer (none for the block's remat: the
+    laddered section's residuals are its arguments), a branch a rung,
+    and a tensor with all ``n_rows`` (token, choice) rows, whatever its
+    width, in the last rung's branch alone."""
+    import re
+
+    ladders = _branch_bodies(text)
+    assert len(ladders) == 2 * layers
+    for branches in ladders:
+        assert len(branches) == rungs
+        full = [len(re.findall(rf"\[{n_rows},\d+\]", b)) for b in branches]
+        assert full[-1] > 0 and not any(full[:-1]), full
+        # each rung runs the grouped products itself
+        assert all("ragged-dot-none" in b for b in branches)
+
+
 def test_mellum_cell_step_fits_and_holds_no_capacity_tensor(topo):
     """The benchmark's Mellum cell at its real size (2 x 8,192 tokens,
     four typed layers, 16 of 64 experts held), through the cell's own
@@ -278,11 +330,13 @@ def test_mellum_cell_step_fits_and_holds_no_capacity_tensor(topo):
     assert _flash_kernels(text, "attn/sliding") == 3 * 3
     assert _flash_kernels(text, "attn/full") == 3
     # three grouped products forward, three recomputed, six backward,
-    # in each of four layers
+    # in each of four layers, at each of the buffer's two sizes (a
+    # quarter of the experts held: half the rows, and all of them)
     grouped = [n for n in by_scope["moe/experts"]
                if n.startswith("ragged-dot-none")]
-    assert len(grouped) == 4 * 12
+    assert len(grouped) == 4 * 12 * 2
     tokens, experts = job["batch"] * job["seq"], cfg["router_experts"]
+    _assert_compact_rungs(text, tokens * pcfg.router_top_k, 2, layers=4)
     for dims in set(re.findall(r"\[([\d,]+)\]", text)):
         dims = [int(d) for d in dims.split(",")]
         assert not (len(dims) == 3 and dims[0] == tokens
@@ -323,7 +377,10 @@ def test_laguna_cell_step_fits_and_pads_no_heads(topo):
     assert _flash_kernels(text, "attn/full") == 2 * 3
     grouped = [n for n, scope in scopes.instruction_scopes(text).items()
                if scope == "moe/experts" and n.startswith("ragged-dot-none")]
-    assert len(grouped) == 4 * 12
+    # an eighth of the experts held: a quarter of the rows, half, all
+    assert len(grouped) == 4 * 12 * 3
+    _assert_compact_rungs(text, job["batch"] * job["seq"]
+                          * pcfg.router_top_k, 3, layers=4)
     assert set(scopes_mixed.instruction_scopes(text).values()) == {
         "moe/shared", "mlp/dense"}
     # the kernels' operands: (batch x heads, 8192, 128) at 64 and at 48

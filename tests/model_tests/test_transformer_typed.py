@@ -17,6 +17,7 @@ import pytest
 from chainermn_tpu.models import (
     AttentionKind,
     TransformerConfig,
+    expert_buffer_rows,
     expert_choices,
     expert_load,
     init_transformer,
@@ -255,6 +256,34 @@ def test_expert_load_sums_to_k_times_tokens(mesh):
     # a token's k choices are k different experts
     assert (np.diff(np.sort(chosen, axis=-1), axis=-1) > 0).all()
     assert (np.bincount(chosen[0].ravel(), minlength=8) == load[0]).all()
+
+
+@pytest.mark.parametrize("mesh", [dict(data=1), dict(data=1, expert=2)],
+                         ids=["one", "expert2"])
+def test_expert_buffer_rows_names_the_rung_each_layer_takes(mesh):
+    """2 of 8 experts held: the sorted buffer has half the (token,
+    choice) rows or all of them, by the rows held, a layer; counted by
+    the function the layer itself calls, for the member that holds
+    most."""
+    from chainermn_tpu.parallel.expert import _buffer_rungs
+
+    cfg = typed_cfg(experts_held=(2, 2))
+    n = int(np.prod(list(mesh.values())))
+    mc = MeshConfig(devices=jax.devices()[:n], **mesh)
+    params = shard_params(
+        mc, cfg, init_transformer(jax.random.PRNGKey(0), cfg))
+    x, _ = tokens()
+    rows = np.asarray(expert_buffer_rows(mc, cfg, params, x))
+    assert rows.shape == (cfg.n_layers, 2)
+    held = np.asarray(expert_load(mc, cfg, params, x))[:, 2:4].sum(axis=1)
+    rungs = _buffer_rungs(cfg.router_top_k * B * T // n, 2, cfg.n_experts)
+    assert len(rungs) == 2
+    if n == 1:
+        assert (rows[:, 0] == held).all()
+    else:   # the fullest member holds at least its share of them
+        assert (rows[:, 0] <= held).all() and (rows[:, 0] * n >= held).all()
+    assert (rows[:, 1] == np.where(
+        rows[:, 0] <= rungs[0], rungs[0], rungs[1])).all()
 
 
 def test_expert_load_needs_the_dropless_layer():
