@@ -36,6 +36,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from chainermn_tpu.ops.gdn import gdn_chunked
 from chainermn_tpu.ops.kda import kda_chunked
 from chainermn_tpu.ops.recurrent import causal_conv_silu
 from chainermn_tpu.ops.ssd import ssd_chunked
@@ -96,7 +97,7 @@ KDA_L2_NORM_EPS = 1e-6
 
 # the token mixers an :class:`AttentionKind` may name, and the parts of
 # a layer it may have: the checks and their messages read these
-MIXERS = ("softmax", "mla", "kda", "mamba2")
+MIXERS = ("softmax", "mla", "kda", "mamba2", "gdn")
 PARTS = ("both", "mixer", "mlp")
 
 
@@ -106,8 +107,10 @@ class AttentionKind:
     has, its mixer, and for the mixer what only it has -- softmax
     attention its window, rotary parameters and query heads; latent
     attention its latent rank and the widths of its shared key part and
-    its values; the delta-rule layer its convolution; the state-space
-    layer its heads' width, state size, groups and convolution.
+    its values; the delta-rule layers their convolution (the
+    scalar-decay one also its key heads and the two head widths); the
+    state-space layer its heads' width, state size, groups and
+    convolution.
     ``TransformerConfig.layer_pattern`` is a tuple of these, one per
     layer of a period (``leading_layers`` one per layer before them).
     Every field is read by the training path alone
@@ -140,13 +143,30 @@ class AttentionKind:
     # ``x``, ``B``, ``C`` through a causal depthwise convolution of
     # ``conv_taps`` with bias and SiLU; a scalar decay a head over a
     # ``ssm_head_dim x ssm_state`` state a head, a skip ``D x``, the
-    # gate ``SiLU(z)``, then an RMSNorm over each group's channels.
-    # None of the three takes positions: window, rotary and YaRN fields
+    # gate ``SiLU(z)``, then an RMSNorm over each group's channels |
+    # "gdn": Gated DeltaNet (``ops/gdn.py``): one projection to q and k
+    # (``key_heads`` heads of ``d_key``), v and an output gate ``z``
+    # (``n_heads`` value heads of ``d_value``), one to a step and a
+    # decay's input a value head; q, k, v through a causal depthwise
+    # convolution of ``conv_taps`` and SiLU, q and k L2-normed a head;
+    # the delta rule with ONE scalar decay a value head over a ``d_key
+    # x d_value`` state, value head j reading key head j // (n_heads /
+    # key_heads); a per-head RMSNorm with one plain scale for all
+    # heads, THEN the gate ``SiLU(z)`` (norm first, gate after).
+    # None of the four takes positions: window, rotary and YaRN fields
     # are the softmax mixer's
     kv_latent: int = 0         # mla: rank of the key-value latent
     d_shared_key: int = 0      # mla: key channels shared by the heads
-    d_value: int = 0           # mla: value head width; 0 => d_head
-    conv_taps: int = 4         # kda, mamba2: taps of the short convolution
+    d_value: int = 0           # mla: value head width; 0 => d_head.
+    # gdn: a value head's width (its ``n_heads`` count the value heads)
+    key_heads: int = 0         # gdn: heads of q and k, each serving
+    # n_heads / key_heads value heads
+    d_key: int = 0             # gdn: a key head's width
+    conv_taps: int = 4         # kda, mamba2, gdn: taps of the short
+    # convolution
+    qk_norm: bool = False      # softmax: an RMSNorm with a learned scale
+    # over each head of q and of k (``q_norm``, ``k_norm``, one scale of
+    # d_head each for all heads), before any rotation
     ssm_head_dim: int = 0      # mamba2: channels a head (its ``n_heads``
     # are this kind's own: the config's are softmax attention's)
     ssm_state: int = 0         # mamba2: the state's size N a channel
@@ -191,7 +211,7 @@ class AttentionKind:
             raise ValueError(
                 f"{self.name}: mla needs kv_latent >= 1 and widths >= 0, "
                 f"got {self.kv_latent}, {self.d_shared_key}, {self.d_value}")
-        if self.mixer in ("kda", "mamba2") and self.conv_taps < 1:
+        if self.mixer in ("kda", "mamba2", "gdn") and self.conv_taps < 1:
             raise ValueError(
                 f"{self.name}: {self.mixer} needs conv_taps >= 1, got "
                 f"{self.conv_taps}")
@@ -203,10 +223,19 @@ class AttentionKind:
                 "ssm_state and ssm_groups >= 1 and whole groups of heads, "
                 f"got {self.n_heads}, {self.ssm_head_dim}, "
                 f"{self.ssm_state}, {self.ssm_groups}")
-        if self.mixer != "softmax" and (self.window or self.yarn_factor):
+        if self.mixer == "gdn" and (
+                min(self.n_heads, self.key_heads, self.d_key,
+                    self.d_value) < 1 or self.n_heads % self.key_heads):
             raise ValueError(
-                f"{self.name}: window and rotary fields are the softmax "
-                f"mixer's; mixer={self.mixer!r} takes no positions")
+                f"{self.name}: gdn needs its own n_heads (value heads), "
+                "key_heads, d_key and d_value >= 1 and whole groups of "
+                f"value heads a key head, got {self.n_heads}, "
+                f"{self.key_heads}, {self.d_key}, {self.d_value}")
+        if self.mixer != "softmax" and (
+                self.window or self.yarn_factor or self.qk_norm):
+            raise ValueError(
+                f"{self.name}: window, rotary fields and qk_norm are the "
+                f"softmax mixer's; mixer={self.mixer!r} takes no positions")
         if self.window < 0:
             raise ValueError(f"{self.name}: window {self.window} < 0")
         if self.rope_theta <= 1:
@@ -239,7 +268,10 @@ class AttentionKind:
             return ("mla", self.kv_latent, self.d_shared_key, self.d_value)
         if self.mixer == "kda":
             return ("kda", self.conv_taps)
-        return ("softmax",)
+        if self.mixer == "gdn":
+            return ("gdn", self.key_heads, self.d_key, self.d_value,
+                    self.conv_taps)
+        return ("softmax", self.qk_norm)
 
     def rotary_dim(self, d_head: int) -> int:
         """How many leading dimensions of a head are rotated."""
@@ -350,15 +382,20 @@ class TransformerConfig:
     # expert of this width that every token meets (its activation is
     # ``expert_act``), ungated, whole on every member of the expert
     # group for the member's own tokens (dropless dispatch only)
+    shared_expert_gate: bool = False  # True => the shared expert's
+    # result is multiplied by sigmoid(u . w_s), one scalar a token from
+    # the MLP's normed input (``wsg``, (d_model, 1)); training path only
     dense_act: str = "relu"    # the dense MLP: "relu": w2(relu(w1 x)) |
     # "relu2": w2(relu(w1 x)^2) | "swiglu": w2(silu(w1 x) * w3 x); the
     # last two training path only
     dense_d_ff: int = 0        # 0 => d_ff.  Width of the dense MLP where
     # d_ff is the experts' (a sparse model's leading dense layers)
-    attn_gate: str = ""        # "" | "per_head": o_j <- sigmoid(x W_g)_j
-    # o_j between the attention core and the output projection, one
-    # scalar a query head from the layer's normed input (``wg``, riding
-    # the fused q/k/v product); training path only
+    attn_gate: str = ""        # "" | "per_head" | "per_element":
+    # o_j <- sigmoid(x W_g)_j o_j between the attention core and the
+    # output projection of every SOFTMAX layer, from the layer's normed
+    # input (``wg``, riding the fused q/k/v product): one scalar a query
+    # head (``wg`` has H columns) or one an element of the head (H x
+    # d_head columns); training path only
     tie_embeddings: bool = True  # False => a separate output matrix
     # ``head`` (vocab, d_model) beside ``embed``; training path only
     num_microbatches: int = 1  # GPipe M (>1 only useful when pipe > 1)
@@ -414,6 +451,12 @@ class TransformerConfig:
     # model at 8 x 2,048 (sandbox compile, PR 21) it fits no cell
     norm_eps: float = 1e-6     # the RMSNorms' epsilon; the training path
     # reads it (decoding and serving keep 1e-6 and refuse another)
+    norm_scale: str = "plain"  # "plain": y = x / rms(x) * w, w seeded 1 |
+    # "zero_centred": y = x / rms(x) * (1 + w), w seeded 0 and STORED as
+    # w, so that weight decay pulls the scale to 1 and not to 0: a
+    # parameterisation, not a constant.  Every norm with a learned scale
+    # (a layer's, the last, q/k, the latent's) but the recurrent
+    # mixers' output norm, which stays plain; training path only
     dtype: str = "bfloat16"    # compute dtype (params stay fp32)
 
     @property
@@ -490,6 +533,10 @@ class TransformerConfig:
                 not k.rotary_share
                 for k in self.layer_pattern + self.leading_layers)),
             ("attn_gate", bool(self.attn_gate)),
+            ("AttentionKind.qk_norm", any(
+                k.qk_norm for k in self.layer_pattern + self.leading_layers)),
+            ("norm_scale='zero_centred'", self.norm_scale != "plain"),
+            ("shared_expert_gate", self.shared_expert_gate),
             ("router_bias", bool(self.router_bias)),
             ("norm_eps", self.norm_eps != 1e-6),
             ("experts_held", bool(self.experts_held)),
@@ -576,7 +623,7 @@ class TransformerConfig:
                        for k in self.leading_layers):
                 raise ValueError("leading_layers holds AttentionKind values")
             for k in self._mixing(self.layer_pattern + self.leading_layers):
-                if k.mixer != "mamba2" \
+                if k.mixer not in ("mamba2", "gdn") \
                         and self.heads_of(k) % self.kv_heads:
                     raise ValueError(
                         f"{k.name}: n_heads={k.n_heads} must be a multiple "
@@ -598,9 +645,18 @@ class TransformerConfig:
         if self.dense_act not in ("relu", "relu2", "swiglu"):
             raise ValueError(
                 f"dense_act {self.dense_act!r} not in (relu, relu2, swiglu)")
-        if self.attn_gate not in ("", "per_head"):
+        if self.attn_gate not in ("", "per_head", "per_element"):
             raise ValueError(
-                f"attn_gate {self.attn_gate!r} not in ('', per_head)")
+                f"attn_gate {self.attn_gate!r} not in "
+                "('', per_head, per_element)")
+        if self.norm_scale not in ("plain", "zero_centred"):
+            raise ValueError(
+                f"norm_scale {self.norm_scale!r} not in "
+                "(plain, zero_centred)")
+        if self.shared_expert_gate and not self.shared_expert_d_ff:
+            raise ValueError(
+                "shared_expert_gate gates the shared expert, which "
+                "shared_expert_d_ff=0 leaves out")
         if self.router_score not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"router_score {self.router_score!r} not in "
@@ -631,11 +687,13 @@ class TransformerConfig:
                 "router_score, router_scale, router_bias and "
                 "shared_expert_d_ff are the dropless expert layer's "
                 "(moe=True, moe_dispatch='dropless')")
-        if self.mixers and self.attn_gate:
+        if self.attn_gate and self.layer_pattern and not any(
+                k.mixer == "softmax" for k in self._mixing(
+                    self.layer_pattern + self.leading_layers)):
             raise ValueError(
                 f"attn_gate is softmax attention's; the {self.mixers} "
-                "layers have none (kda's and mamba2's output gates are "
-                "their own)")
+                "layers have none (the recurrent mixers' output gates "
+                "are their own)")
         if self.experts_held:
             if not dropless:
                 raise ValueError(
@@ -693,7 +751,15 @@ _MIXER_LEAVES = {
             "wg_a", "wg_b", "o_norm"),
     "mamba2": ("w_in", "conv", "conv_b", "a_log", "dt_bias", "d_skip",
                "o_norm"),
+    "gdn": ("w_in", "w_ba", "conv", "a_log", "dt_bias", "o_norm"),
 }
+
+
+def _norm_init(cfg: "TransformerConfig", shape):
+    """A learned norm scale at its seed: 1, or 0 where the config's
+    norms add 1 to what they store (``norm_scale``)."""
+    fill = jnp.zeros if cfg.norm_scale == "zero_centred" else jnp.ones
+    return fill(shape, jnp.float32)
 
 
 def _init_block(key, cfg: TransformerConfig, kind=None, sparse=None):
@@ -726,8 +792,10 @@ def _init_mixer(key, ks, cfg: TransformerConfig, kind):
         Dv = kind.d_value or Dh
     elif mixer == "mamba2":
         Dv = kind.ssm_head_dim
+    elif mixer == "gdn":
+        Dv = kind.d_value
     block = {
-        "ln1": jnp.ones((D,), jnp.float32),
+        "ln1": _norm_init(cfg, (D,)),
         "wo": _dense_init(ks[1], (H, Dv, D), H * Dv),
     }
     if mixer == "mamba2":
@@ -752,7 +820,7 @@ def _init_mixer(key, ks, cfg: TransformerConfig, kind):
         L, Ds = kind.kv_latent, kind.d_shared_key
         block["wq"] = _dense_init(ks[0], (D, H, Dh + Ds), D)
         block["wkva"] = _dense_init(ks[5], (D, L + Ds), D)
-        block["kv_norm"] = jnp.ones((L,), jnp.float32)
+        block["kv_norm"] = _norm_init(cfg, (L,))
         block["wkvb"] = _dense_init(
             jax.random.fold_in(key, 11), (L, H, Dh + Dv), L)
     elif mixer == "kda":
@@ -775,6 +843,21 @@ def _init_mixer(key, ks, cfg: TransformerConfig, kind):
         block["wg_a"] = _dense_init(next(kk), (D, R), D)
         block["wg_b"] = _dense_init(next(kk), (R, H, Dh), R)
         block["o_norm"] = jnp.ones((Dh,), jnp.float32)
+    elif mixer == "gdn":
+        # one projection to [q | k | v | z], one to [b | a]; A_log and
+        # dt_bias a value head, seeded as KDA's and Mamba-2's are
+        keys, taps = kind.key_heads * kind.d_key, kind.conv_taps
+        kk = iter(jax.random.split(jax.random.fold_in(key, 14), 4))
+        block["w_in"] = _dense_init(ks[0], (D, 2 * keys + 2 * H * Dv), D)
+        block["w_ba"] = _dense_init(next(kk), (D, 2 * H), D)
+        block["conv"] = _dense_init(
+            next(kk), (2 * keys + H * Dv, taps), taps)
+        block["a_log"] = jnp.log(jax.random.uniform(
+            next(kk), (H,), jnp.float32, 1.0, 16.0))
+        dt = jnp.exp(jax.random.uniform(
+            next(kk), (H,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+        block["dt_bias"] = dt + jnp.log(-jnp.expm1(-dt))
+        block["o_norm"] = jnp.ones((Dv,), jnp.float32)
     elif cfg.kv_heads == H:
         block["wqkv"] = _dense_init(ks[0], (D, 3, H, Dh), D)
     else:
@@ -782,8 +865,14 @@ def _init_mixer(key, ks, cfg: TransformerConfig, kind):
         # (consecutive grouping: query head h reads kv head h//(H/Hkv))
         block["wq"] = _dense_init(ks[0], (D, H, Dh), D)
         block["wkv"] = _dense_init(ks[5], (D, 2, cfg.kv_heads, Dh), D)
-    if cfg.attn_gate:
-        block["wg"] = _dense_init(jax.random.fold_in(key, 7), (D, H), D)
+    if mixer == "softmax":
+        if cfg.attn_gate:
+            block["wg"] = _dense_init(
+                jax.random.fold_in(key, 7),
+                (D, H) + (Dh,) * (cfg.attn_gate == "per_element"), D)
+        if kind is not None and kind.qk_norm:
+            block["q_norm"] = _norm_init(cfg, (Dh,))
+            block["k_norm"] = _norm_init(cfg, (Dh,))
     return block
 
 
@@ -791,7 +880,7 @@ def _init_mlp(key, ks, cfg: TransformerConfig, sparse: bool):
     """The MLP's norm and leaves (``ks``: the layer's six keys)."""
     D = cfg.d_model
     F = cfg.d_ff if sparse else cfg.dense_d_ff or cfg.d_ff
-    block = {"ln2": jnp.ones((D,), jnp.float32)}
+    block = {"ln2": _norm_init(cfg, (D,))}
     gated = cfg.expert_act if sparse else cfg.dense_act
     if sparse:
         # the router scores every expert; the weights are of those held
@@ -812,6 +901,9 @@ def _init_mlp(key, ks, cfg: TransformerConfig, sparse: bool):
             if gated == "swiglu":
                 block["ws3"] = _dense_init(
                     jax.random.fold_in(key, 10), (D, Fs), D)
+            if cfg.shared_expert_gate:
+                block["wsg"] = _dense_init(
+                    jax.random.fold_in(key, 15), (D, 1), D)
     else:
         block["w1"] = _dense_init(ks[3], (D, F), D)
         block["w2"] = _dense_init(ks[4], (F, D), F)
@@ -873,7 +965,7 @@ def init_transformer(key, cfg: TransformerConfig, pipe_size: int = 1):
         "embed": jax.random.normal(
             k_emb, (cfg.vocab_size, D), jnp.float32) * 0.02,
         "blocks": stacked,
-        "ln_f": jnp.ones((D,), jnp.float32),
+        "ln_f": _norm_init(cfg, (D,)),
     }
     if n_lead:
         params["leading"] = tuple(
@@ -1010,7 +1102,7 @@ def _fsdp_dims(mha: bool, sparse: bool):
     dims.update({"wqkv": 0} if mha else {"wq": 0, "wkv": 0})
     if sparse:
         dims.update({"router": 0, "w1": 1, "w2": 2, "w3": 1,
-                     "ws1": 0, "ws2": 1, "ws3": 0})
+                     "ws1": 0, "ws2": 1, "ws3": 0, "wsg": 0})
     else:
         dims.update({"w1": 0, "w2": 1, "w3": 0})
     return dims
@@ -1047,8 +1139,12 @@ def _mixer_specs(cfg: TransformerConfig, kind, mha: bool):
     else:
         blk["wq"] = P("pipe", None, None, "model", None)
         blk["wkv"] = P("pipe", None, None, None, "model", None)
-    if cfg.attn_gate:
-        blk["wg"] = P("pipe", None, None, "model")
+    if mixer == "softmax":
+        if cfg.attn_gate:
+            # (D, H) a head, (D, H, d_head) an element
+            blk["wg"] = P("pipe", None, None, "model")
+        if kind is not None and kind.qk_norm:
+            blk["q_norm"] = blk["k_norm"] = P("pipe")
     return blk
 
 
@@ -1069,6 +1165,8 @@ def _mlp_specs(cfg: TransformerConfig, sparse: bool):
             blk["ws2"] = P("pipe", None, "model", None)
             if gated:
                 blk["ws3"] = blk["ws1"]
+            if cfg.shared_expert_gate:
+                blk["wsg"] = P("pipe")
     else:
         blk["w1"] = P("pipe", None, None, "model")
         blk["w2"] = P("pipe", None, "model", None)
@@ -1171,6 +1269,13 @@ def _rms_norm(x, scale, eps=1e-6):
     x32 = x.astype(jnp.float32)
     r = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
     return (x32 * r * scale).astype(x.dtype)
+
+
+def _norm(cfg: TransformerConfig, x, w):
+    """The RMSNorm of a learned scale stored as ``w``, as the config's
+    ``norm_scale`` reads it: ``w`` itself, or ``1 + w``."""
+    return _rms_norm(x, 1.0 + w if cfg.norm_scale == "zero_centred" else w,
+                     cfg.norm_eps)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -1617,7 +1722,7 @@ def _mla_mixer(cfg: TransformerConfig, x, blk, kind):
             B, T, H, Dn + Ds)
     with device_scope("mla/latent"):
         down = x @ blk["wkva"].astype(cd)
-        latent = _rms_norm(down[..., :L], blk["kv_norm"], cfg.norm_eps)
+        latent = _norm(cfg, down[..., :L], blk["kv_norm"])
         up = (latent @ blk["wkvb"].reshape(L, -1).astype(cd)).reshape(
             B, T, H, -1)
     with device_scope("attn.kv_repeat"):
@@ -1637,6 +1742,13 @@ def _mla_mixer(cfg: TransformerConfig, x, blk, kind):
     o = checkpoint_name(o, "attn_out")
     with device_scope("attn.out"):
         return o.reshape(B, T, -1) @ blk["wo"].reshape(-1, D).astype(cd)
+
+
+def _l2_unit(y):
+    """``y`` over its L2 norm along the last axis (a head's channels):
+    the delta-rule mixers' norm of q and k."""
+    return y * lax.rsqrt(
+        jnp.sum(y * y, axis=-1, keepdims=True) + KDA_L2_NORM_EPS)
 
 
 def _kda_mixer(cfg: TransformerConfig, x, blk, kind):
@@ -1662,9 +1774,7 @@ def _kda_mixer(cfg: TransformerConfig, x, blk, kind):
     with device_scope("kda/conv"):
         qkv = causal_conv_silu(qkv, blk["conv"])
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        unit = lambda y: y * lax.rsqrt(
-            jnp.sum(y * y, axis=-1, keepdims=True) + KDA_L2_NORM_EPS)
-        q, k = unit(q) * Dh ** -0.5, unit(k)
+        q, k = _l2_unit(q) * Dh ** -0.5, _l2_unit(k)
     with device_scope("kda/gate"):
         # the recurrence's two gates: the log of the decay a channel
         # (<= 0) and the step size a head
@@ -1724,7 +1834,51 @@ def _mamba2_mixer(cfg: TransformerConfig, x, blk, kind):
         return o @ blk["wo"].reshape(-1, D).astype(cd)
 
 
-_MIXER_FNS = {"mla": _mla_mixer, "kda": _kda_mixer, "mamba2": _mamba2_mixer}
+def _gdn_mixer(cfg: TransformerConfig, x, blk, kind):
+    """Gated DeltaNet on the normed input ``x``: the layer's
+    contribution to the residual stream.  Projections in the compute
+    dtype with float32 results; convolution, L2 norms, gates and the
+    recurrence (``ops/gdn.py``) in float32.  ``gdn/scan`` holds the
+    whole op, ``ops/kda.py``'s Pallas kernel for the chunks'
+    unit-triangular systems (interpreted off the TPU) included."""
+    cd, f32 = cfg.compute_dtype, jnp.float32
+    B, T, D = x.shape
+    Hv, Dv = blk["wo"].shape[:2]
+    Hk, Dk = kind.key_heads, kind.d_key
+    keys, values = Hk * Dk, Hv * Dv
+    with device_scope("attn.qkv"):
+        # two products: to [q | k | v | z] and to [b | a]
+        proj = jnp.dot(x.astype(cd), blk["w_in"].astype(cd),
+                       preferred_element_type=f32)
+        ba = jnp.dot(x.astype(cd), blk["w_ba"].astype(cd),
+                     preferred_element_type=f32)
+        qkv, z = proj[..., :2 * keys + values], proj[..., 2 * keys + values:]
+    with device_scope("gdn/conv"):
+        qkv = causal_conv_silu(qkv, blk["conv"])
+        q = qkv[..., :keys].reshape(B, T, Hk, Dk)
+        k = qkv[..., keys:2 * keys].reshape(B, T, Hk, Dk)
+        v = qkv[..., 2 * keys:].reshape(B, T, Hv, Dv)
+        q, k = _l2_unit(q) * Dk ** -0.5, _l2_unit(k)
+    with device_scope("gdn/gate"):
+        # the recurrence's two gates, a scalar a value head each: the
+        # step size and the log of the decay (<= 0)
+        beta = jax.nn.sigmoid(ba[..., :Hv])
+        g = -jnp.exp(blk["a_log"]) * jax.nn.softplus(
+            ba[..., Hv:] + blk["dt_bias"])
+    with device_scope("gdn/scan"):
+        o = gdn_chunked(q, k, v, g, beta)
+    with device_scope("gdn/gate"):
+        # the way out: RMSNorm over each head with one plain scale for
+        # all, THEN the gate SiLU(z) (norm first, gate after)
+        o = _rms_norm(o, blk["o_norm"], cfg.norm_eps) \
+            * jax.nn.silu(z.reshape(B, T, Hv, Dv))
+    o = checkpoint_name(o.astype(cd), "attn_out")
+    with device_scope("attn.out"):
+        return o.reshape(B, T, -1) @ blk["wo"].reshape(-1, D).astype(cd)
+
+
+_MIXER_FNS = {"mla": _mla_mixer, "kda": _kda_mixer, "mamba2": _mamba2_mixer,
+              "gdn": _gdn_mixer}
 
 
 def _exchanged_or_local_core(cfg: TransformerConfig, q, k, v, win):
@@ -1773,10 +1927,10 @@ def _attention_of_kind(cfg: TransformerConfig, h, blk, kind):
     cd = cfg.compute_dtype
     if cfg.mixer_of(kind) != "softmax":
         return h + _MIXER_FNS[kind.mixer](
-            cfg, _rms_norm(h, blk["ln1"], cfg.norm_eps), blk, kind)
+            cfg, _norm(cfg, h, blk["ln1"]), blk, kind)
     win = (kind.window if kind else cfg.attention_window) or None
     with device_scope("attn.qkv"):
-        x = _rms_norm(h, blk["ln1"], cfg.norm_eps)
+        x = _norm(cfg, h, blk["ln1"])
         B, T, D = x.shape
         if "wqkv" in blk:
             Hl = blk["wqkv"].shape[2]      # local heads = H / model-axis size
@@ -1784,7 +1938,8 @@ def _attention_of_kind(cfg: TransformerConfig, h, blk, kind):
                 x, blk["wqkv"].reshape(D, -1).astype(cd))
             qkv = qkv.reshape(B, T, 3, Hl, cfg.d_head)
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            gate = column_parallel_dense(x, blk["wg"].astype(cd)) \
+            gate = column_parallel_dense(
+                x, blk["wg"].reshape(D, -1).astype(cd)) \
                 if "wg" in blk else None
         else:
             # GQA/MQA: H/Hkv query heads share each K/V head.  K/V stay at
@@ -1806,16 +1961,22 @@ def _attention_of_kind(cfg: TransformerConfig, h, blk, kind):
             # The at-rest params stay separate (their TP/FSDP specs differ).
             dq = Hl * cfg.d_head
             dkv = 2 * Hkvl * cfg.d_head
-            # the per-head gate's projection (Hl more columns) rides it too
+            # the gate's projection (Hl more columns a head, or Hl x
+            # d_head an element) rides it too
             fused = jnp.concatenate(
                 [blk["wq"].reshape(D, -1), blk["wkv"].reshape(D, -1)]
-                + ([blk["wg"]] if "wg" in blk else []),
+                + ([blk["wg"].reshape(D, -1)] if "wg" in blk else []),
                 axis=1).astype(cd)
             qkv = column_parallel_dense(x, fused)
             q = qkv[..., :dq].reshape(B, T, Hl, cfg.d_head)
             kv = qkv[..., dq:dq + dkv].reshape(B, T, 2, Hkvl, cfg.d_head)
             k, v = kv[:, :, 0], kv[:, :, 1]
             gate = qkv[..., dq + dkv:] if "wg" in blk else None
+    if "q_norm" in blk:
+        with device_scope("attn.qk_norm"):
+            # over each head's d_head, one scale for all heads
+            q = _norm(cfg, q, blk["q_norm"])
+            k = _norm(cfg, k, blk["k_norm"])
     if cfg.pos_embedding == "rope" and (kind is None or kind.rotary_share):
         with device_scope("attn.rope"):
             # rotate by each local token's GLOBAL position BEFORE any ring
@@ -1849,11 +2010,13 @@ def _attention_of_kind(cfg: TransformerConfig, h, blk, kind):
             o = _exchanged_or_local_core(cfg, q, k, v, win)
     if gate is not None:
         with device_scope("attn.gate"):
-            # o_j <- sigmoid(x W_g)_j o_j, one scalar a local query head.
-            # Its backward reads the core's o, which the block's checkpoint
-            # already keeps where the core is the flash kernel
-            o = o * jax.nn.sigmoid(
-                gate.astype(jnp.float32)).astype(o.dtype)[..., None]
+            # o_j <- sigmoid(x W_g)_j o_j, one scalar a local query head
+            # or one an element of it.  Its backward reads the core's o,
+            # which the block's checkpoint already keeps where the core
+            # is the flash kernel
+            gate = jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
+            o = o * (gate.reshape(o.shape) if blk["wg"].ndim == 3
+                     else gate[..., None])
     # named for the "dots" remat policy, which saves it as the input of
     # the output projection's backward.  It never kept the flash kernel
     # out of the recompute (the kernel's residuals are its own o and
@@ -1919,7 +2082,7 @@ def _mlp(cfg: TransformerConfig, h, blk, with_chosen=False, sparse=None):
     dropless layer names its own parts)."""
     cd = cfg.compute_dtype
     sparse = cfg.moe if sparse is None else sparse
-    x = _rms_norm(h, blk["ln2"], cfg.norm_eps)
+    x = _norm(cfg, h, blk["ln2"])
     if with_chosen and not (sparse and cfg.moe_dispatch == "dropless"):
         raise ValueError("the choices are read from the dropless layer")
     if not sparse:
@@ -1958,10 +2121,16 @@ def _mlp(cfg: TransformerConfig, h, blk, with_chosen=False, sparse=None):
         out = out.reshape(B, T, D)
         if "ws1" in blk:
             # the expert every token meets: whole on each member of the
-            # expert group, for the member's own tokens; no gate
+            # expert group, for the member's own tokens; gated by one
+            # sigmoid scalar a token where the block has ``wsg``
             with device_scope("moe/shared"):
-                out = out + _gated(cfg, cfg.expert_act, x, blk["ws1"],
-                                   blk.get("ws3"), blk["ws2"])
+                shared = _gated(cfg, cfg.expert_act, x, blk["ws1"],
+                                blk.get("ws3"), blk["ws2"])
+                if "wsg" in blk:
+                    shared = shared * jax.nn.sigmoid(jnp.dot(
+                        x, blk["wsg"].astype(cd),
+                        preferred_element_type=jnp.float32)).astype(cd)
+                out = out + shared
         if with_chosen:
             return h + out, aux, chosen.reshape(B, T, -1)
         return h + out, aux
@@ -2162,7 +2331,7 @@ def transformer_backbone(cfg: TransformerConfig, params, tokens):
             aux = lax.psum(aux, "pipe")
 
     with device_scope("step/head"):
-        return _rms_norm(h, params["ln_f"], cfg.norm_eps), aux
+        return _norm(cfg, h, params["ln_f"]), aux
 
 
 def _head_matrix(cfg: TransformerConfig, params):
@@ -2332,7 +2501,7 @@ def _make_1f1b_grad(cfg: TransformerConfig):
 
         def loss_fn(lp, y, tgt):
             with device_scope("step/head"):
-                hN = _rms_norm(y, lp["ln_f"], cfg.norm_eps)
+                hN = _norm(cfg, y, lp["ln_f"])
                 return _shard_nll_sum(
                     cfg, hN, lp["embed"], tgt) / tgt.size
 

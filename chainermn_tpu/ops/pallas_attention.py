@@ -566,6 +566,17 @@ def _flash_bwd(static_offs, scale, causal, window, fwd_block_q,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+# The backward kernels' tiles grow with the heads' width.  At blocks of
+# 1,024 x 1,024 the dkv kernel's take 17.04 MB at key and value widths
+# of 256 + 256, over the 16 MB the v5e's compiler allows one kernel (a
+# compile for a described v5e, PR 42); the widest that has run there is
+# latent attention's 192 + 128.  Past that the backward's query block
+# is halved unless the caller chose a tiling; gradients are exact for
+# any.  (512 x 1,024 and 1,024 x 512 both compile at 256 + 256.)
+_WIDEST_AT_FULL_BLOCKS = 192 + 128
+_WIDE_BWD_BLOCK_Q = 512
+
+
 def _fit_block(T: int, want: int) -> Optional[int]:
     """Pick the block size for a length-``T`` axis given requested size
     ``want``; ``None`` means "not worth the kernel — fall back to XLA".
@@ -651,6 +662,8 @@ def flash_attention(q, k, v, *, causal: bool = False, window=None,
             "by a power-of-two block >= 128 — gate on "
             "flash_attention_supported() and fall back to "
             "local_attention")
+    if not (bwd_block_q or bwd_block_k) and D + Dv > _WIDEST_AT_FULL_BLOCKS:
+        bwd_block_q = _WIDE_BWD_BLOCK_Q
     # a bwd override that doesn't tile THIS shape falls back to the
     # forward blocks rather than erroring: the knob is a perf hint
     # (often adopted from a sweep at another sequence length) and must

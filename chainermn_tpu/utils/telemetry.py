@@ -79,6 +79,7 @@ from chainermn_tpu.utils.metrics import Histogram, append_jsonl
 __all__ = [
     "DEVICE_SCOPES",
     "DEVICE_SCOPES_SSM",
+    "DEVICE_SCOPES_GDN",
     "MetricsExport",
     "RequestTraceStore",
     "SpanEvent",
@@ -888,20 +889,30 @@ DEVICE_SCOPES_SSM = (
     "ssm/conv", "ssm/gate", "ssm/scan",
     "ssm.intra", "ssm.states", "ssm.carry", "ssm.inter",
 )
-# any name of the two lists; a kind is whatever ``AttentionKind`` lets
+# The scalar-decay delta rule's scopes (PR 42: ``_gdn_mixer``,
+# ``ops/gdn.py``; its systems wear ``kda.solve``, whose op it is) and
+# the q/k norm of a softmax layer, beside the tuple above for the same
+# reason: the ``benchmark`` PR that lists the seventh and eighth cells
+# in ``WORN`` folds both into ``DEVICE_SCOPES``.
+DEVICE_SCOPES_GDN = (
+    "gdn/conv", "gdn/gate", "gdn/scan",
+    "gdn.pairs", "gdn.intra", "gdn.inter",
+    "attn.qk_norm",
+)
+# any name of the three lists; a kind is whatever ``AttentionKind`` lets
 # through, the placeholder itself apart
 _ANY_SCOPE = "|".join(
     r"attn/[^/()<>\s]+" if s == "attn/<kind>" else re.escape(s)
-    for s in DEVICE_SCOPES + DEVICE_SCOPES_SSM)
+    for s in DEVICE_SCOPES + DEVICE_SCOPES_SSM + DEVICE_SCOPES_GDN)
 # a scope stands between the delimiters of a name stack: ``/`` and the
 # brackets of a transformation
 _SCOPE_AT = re.compile(r"(?:^|[/(])(" + _ANY_SCOPE + r")(?=$|[/)])")
 
 
 def device_scope(name: str):
-    """``jax.named_scope(name)`` for a name of ``DEVICE_SCOPES`` or
-    ``DEVICE_SCOPES_SSM`` (any ``attn/<kind>``), a ``ValueError`` for
-    another: a scope nobody can
+    """``jax.named_scope(name)`` for a name of ``DEVICE_SCOPES``,
+    ``DEVICE_SCOPES_SSM`` or ``DEVICE_SCOPES_GDN`` (any
+    ``attn/<kind>``), a ``ValueError`` for another: a scope nobody can
     read back is not added by accident.  Trace-time only: it names the
     ops traced under it (through differentiation and remat) and costs
     no host call and no device op when the program runs."""

@@ -319,7 +319,7 @@ def test_decoding_and_serving_refuse_the_new_fields(kw, named):
 
 @pytest.mark.parametrize("kw,match", [
     (dict(name="m", mixer="mamba"),
-     r"not in \(softmax, mla, kda, mamba2\)"),
+     r"not in \(softmax, mla, kda, mamba2, gdn\)"),
     (dict(name="m", part="ffn"), r"not in \(both, mixer, mlp\)"),
     (dict(name="m", mixer="mamba2"), "mamba2 needs its own n_heads"),
     (dict(name="m", mixer="mamba2", n_heads=4, ssm_head_dim=8, ssm_state=16,
@@ -334,7 +334,7 @@ def test_attention_kind_names_what_it_refuses(kw, match):
     the message reads the tuple the check uses."""
     with pytest.raises(ValueError, match=match):
         AttentionKind(**kw)
-    assert tr.MIXERS == ("softmax", "mla", "kda", "mamba2")
+    assert tr.MIXERS[:4] == ("softmax", "mla", "kda", "mamba2")
 
 
 @pytest.mark.parametrize("kw,match", [
@@ -343,7 +343,10 @@ def test_attention_kind_names_what_it_refuses(kw, match):
     (dict(expert_act="relu2", moe_dispatch="capacity", experts_held=(),
           router_score="softmax", router_scale=1.0, router_bias="",
           shared_expert_d_ff=0), 'expert_act="relu2" is implemented by'),
-    (dict(attn_gate="per_head"), "attn_gate is softmax attention's"),
+    # the gate is the softmax layers' (since PR 42 also beside other
+    # mixers); a pattern with no softmax layer has nothing to gate
+    (dict(attn_gate="per_head", layer_pattern=(M, E), n_layers=2),
+     "attn_gate is softmax attention's"),
 ])
 def test_config_validation(kw, match):
     with pytest.raises(ValueError, match=match):

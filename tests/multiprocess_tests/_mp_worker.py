@@ -1557,8 +1557,12 @@ def main():
     chainermn_tpu.init_distributed(
         coordinator_address=addr, num_processes=n, process_id=i)
     comm = chainermn_tpu.create_communicator("tpu_xla")
-    SCENARIOS[scenario](comm)
-    print(f"WORKER_OK {i} {scenario}", flush=True)
+    # "a+b+c": several scenarios in one launch of the processes, each
+    # with its own marker (the light ones share a launch: a process's
+    # start and its join cost more than their work)
+    for name in scenario.split("+"):
+        SCENARIOS[name](comm)
+        print(f"WORKER_OK {i} {name}", flush=True)
 
 
 if __name__ == "__main__":

@@ -80,7 +80,9 @@ def mp_run():
                 for i in range(nprocs))
             pytest.fail(f"scenario {scenario!r} failed:\n{report}")
         for i, out in enumerate(outputs):
-            assert f"WORKER_OK {i} {scenario}" in out, (
-                f"worker {i} exited 0 without the OK marker:\n{out}")
+            for name in scenario.split("+"):
+                assert f"WORKER_OK {i} {name}" in out, (
+                    f"worker {i} exited 0 without {name}'s OK marker:\n"
+                    f"{out}")
 
     return run

@@ -5,22 +5,49 @@ reference's ``mpiexec -n 2 pytest``; SURVEY.md §4)."""
 import pytest
 
 
+# the scenarios whose own work is a second or two, of which a launch of
+# two processes (start, join the cluster, exit) was four fifths: they
+# share ONE launch, each with its own OK marker from each worker (PR 42:
+# 11 launches of 5-9 s in the tier-1 run became one)
+_LIGHT = ("topology",
+          "obj_collectives",
+          "p2p_obj",
+          "array_collectives",
+          "scatter_dataset",
+          "evaluator",
+          "broadcast_iterator",
+          "observation_aggregator",
+          "snapshot",
+          "allreduce_persistent",
+          "dp_train")
+
+
+@pytest.fixture(scope="module")
+def light(mp_run):
+    """The light scenarios, run one after the other by the same two
+    processes; what each of their tests asserts is that its own ran to
+    its marker on both (``mp_run`` fails the set-up otherwise, with the
+    workers' output)."""
+    mp_run("+".join(_LIGHT), timeout=600)
+    return _LIGHT
+
+
 @pytest.mark.multiprocess
 class TestTwoProcess:
-    def test_topology_contract(self, mp_run):
-        mp_run("topology")
+    def test_topology_contract(self, light):
+        assert "topology" in light
 
-    def test_obj_collectives(self, mp_run):
-        mp_run("obj_collectives")
+    def test_obj_collectives(self, light):
+        assert "obj_collectives" in light
 
-    def test_p2p_obj_channel(self, mp_run):
-        mp_run("p2p_obj")
+    def test_p2p_obj_channel(self, light):
+        assert "p2p_obj" in light
 
-    def test_array_collectives(self, mp_run):
-        mp_run("array_collectives")
+    def test_array_collectives(self, light):
+        assert "array_collectives" in light
 
-    def test_scatter_dataset(self, mp_run):
-        mp_run("scatter_dataset")
+    def test_scatter_dataset(self, light):
+        assert "scatter_dataset" in light
 
     def test_checkpoint_agreement_resume(self, mp_run):
         mp_run("checkpoint")
@@ -39,14 +66,14 @@ class TestTwoProcess:
         # detection through the cross-process KV heartbeats
         mp_run("watchdog_stall", timeout=240)
 
-    def test_evaluator_averaging(self, mp_run):
-        mp_run("evaluator")
+    def test_evaluator_averaging(self, light):
+        assert "evaluator" in light
 
-    def test_broadcast_iterator(self, mp_run):
-        mp_run("broadcast_iterator")
+    def test_broadcast_iterator(self, light):
+        assert "broadcast_iterator" in light
 
-    def test_observation_aggregator(self, mp_run):
-        mp_run("observation_aggregator")
+    def test_observation_aggregator(self, light):
+        assert "observation_aggregator" in light
 
     def test_split(self, mp_run):
         # 4 processes: each even/odd subgroup spans 2 processes, forcing
@@ -63,14 +90,14 @@ class TestTwoProcess:
         # sizes below, at, and above the round count
         mp_run("alltoall_window", nprocs=8, timeout=300)
 
-    def test_snapshot(self, mp_run):
-        mp_run("snapshot")
+    def test_snapshot(self, light):
+        assert "snapshot" in light
 
-    def test_allreduce_persistent(self, mp_run):
-        mp_run("allreduce_persistent")
+    def test_allreduce_persistent(self, light):
+        assert "allreduce_persistent" in light
 
-    def test_dp_train_step(self, mp_run):
-        mp_run("dp_train")
+    def test_dp_train_step(self, light):
+        assert "dp_train" in light
 
     def test_preemption_collective_flag(self, mp_run):
         mp_run("preemption")
