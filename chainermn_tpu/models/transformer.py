@@ -1755,9 +1755,13 @@ def _kda_mixer(cfg: TransformerConfig, x, blk, kind):
     """Kimi Delta Attention on the normed input ``x``: the layer's
     contribution to the residual stream.  Projections in the compute
     dtype with float32 results; convolution, norms, gates and the
-    recurrence (``ops/kda.py``) in float32.  ``kda/scan`` holds the
-    whole op, its Pallas kernel for the chunks' unit-triangular systems
-    (interpreted off the TPU, as the flash kernels are) included."""
+    recurrence (``ops/kda.py``) in float32.  ``kda/conv`` holds the
+    convolution (``ops/recurrent.py``: at whole lane tiles and token
+    blocks one Pallas kernel forward that hands back q, k and v, one
+    backward; the plain sum over taps otherwise) and the L2 norms after
+    it; ``kda/scan`` holds the whole op, its Pallas kernel for the
+    chunks' unit-triangular systems included.  Off the TPU the kernels
+    are interpreted, as the flash kernels are."""
     cd, f32 = cfg.compute_dtype, jnp.float32
     B, T, D = x.shape
     H, Dh = blk["wqkv"].shape[2:]
@@ -1772,8 +1776,7 @@ def _kda_mixer(cfg: TransformerConfig, x, blk, kind):
     with device_scope("attn.qkv"):
         qkv = project(blk["wqkv"]).reshape(B, T, 3, H, Dh)
     with device_scope("kda/conv"):
-        qkv = causal_conv_silu(qkv, blk["conv"])
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        q, k, v = causal_conv_silu(qkv, blk["conv"], split=((H, Dh),) * 3)
         q, k = _l2_unit(q) * Dh ** -0.5, _l2_unit(k)
     with device_scope("kda/gate"):
         # the recurrence's two gates: the log of the decay a channel
@@ -1798,7 +1801,10 @@ def _mamba2_mixer(cfg: TransformerConfig, x, blk, kind):
     """Mamba-2 on the normed input ``x``: the layer's contribution to
     the residual stream.  Projections in the compute dtype with float32
     results; the convolution, the step, the decay, the recurrence's
-    arrays (``ops/ssd.py``) and the gated norm in float32."""
+    arrays (``ops/ssd.py``) and the gated norm in float32.  The
+    convolution (``ops/recurrent.py``) hands back x, B and C apart: at
+    whole lane tiles and token blocks it is a Pallas kernel with a
+    backward kernel of its own, the layer's only ones."""
     cd, f32 = cfg.compute_dtype, jnp.float32
     B, T, D = x.shape
     H, P = blk["wo"].shape[:2]
@@ -1811,10 +1817,12 @@ def _mamba2_mixer(cfg: TransformerConfig, x, blk, kind):
         z, xbc, dt = (proj[..., :inner], proj[..., inner:-H],
                       proj[..., -H:])
     with device_scope("ssm/conv"):
-        xbc = causal_conv_silu(xbc, blk["conv"], blk["conv_b"])
-        xs = xbc[..., :inner].reshape(B, T, H, P)
-        b_in = xbc[..., inner:inner + grouped].reshape(B, T, G, N)
-        c_out = xbc[..., inner + grouped:].reshape(B, T, G, N)
+        # flat parts: x's heads are half a lane tile wide, so the
+        # kernel has no head-by-head form for them
+        xs, b_in, c_out = causal_conv_silu(
+            xbc, blk["conv"], blk["conv_b"], split=(inner, grouped, grouped))
+        xs = xs.reshape(B, T, H, P)
+        b_in, c_out = b_in.reshape(B, T, G, N), c_out.reshape(B, T, G, N)
     with device_scope("ssm/gate"):
         # the step a head (no clamp: time_step_limit (0, inf)) and the
         # decay's rate a head (< 0)
@@ -1838,9 +1846,12 @@ def _gdn_mixer(cfg: TransformerConfig, x, blk, kind):
     """Gated DeltaNet on the normed input ``x``: the layer's
     contribution to the residual stream.  Projections in the compute
     dtype with float32 results; convolution, L2 norms, gates and the
-    recurrence (``ops/gdn.py``) in float32.  ``gdn/scan`` holds the
+    recurrence (``ops/gdn.py``) in float32.  ``gdn/conv`` holds the
+    convolution (``ops/recurrent.py``, which hands back q, k and v
+    apart; a Pallas kernel forward and one backward at whole lane tiles
+    and token blocks) and the L2 norms after it; ``gdn/scan`` holds the
     whole op, ``ops/kda.py``'s Pallas kernel for the chunks'
-    unit-triangular systems (interpreted off the TPU) included."""
+    unit-triangular systems included (all interpreted off the TPU)."""
     cd, f32 = cfg.compute_dtype, jnp.float32
     B, T, D = x.shape
     Hv, Dv = blk["wo"].shape[:2]
@@ -1854,10 +1865,8 @@ def _gdn_mixer(cfg: TransformerConfig, x, blk, kind):
                      preferred_element_type=f32)
         qkv, z = proj[..., :2 * keys + values], proj[..., 2 * keys + values:]
     with device_scope("gdn/conv"):
-        qkv = causal_conv_silu(qkv, blk["conv"])
-        q = qkv[..., :keys].reshape(B, T, Hk, Dk)
-        k = qkv[..., keys:2 * keys].reshape(B, T, Hk, Dk)
-        v = qkv[..., 2 * keys:].reshape(B, T, Hv, Dv)
+        q, k, v = causal_conv_silu(
+            qkv, blk["conv"], split=((Hk, Dk), (Hk, Dk), (Hv, Dv)))
         q, k = _l2_unit(q) * Dk ** -0.5, _l2_unit(k)
     with device_scope("gdn/gate"):
         # the recurrence's two gates, a scalar a value head each: the

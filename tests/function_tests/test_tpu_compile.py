@@ -165,6 +165,61 @@ def test_kda_pair_kernels_compile(topo, one_chip, backward):
                for line in text.splitlines()) == 1 + backward
 
 
+# the three cells' convolutions (``ops/recurrent.py``): the tensor's
+# shape (Kimi's q, k, v of 32 heads side by side, as the projection
+# leaves them), the parts it leaves in (the delta rules' by head),
+# whether it has a bias
+CONV_CELLS = {
+    "kimi": ((1, 16384, 12288), ((32, 128),) * 3, False),
+    "qwen3-next": ((1, 16384, 8192), ((16, 128), (16, 128), (32, 128)),
+                   False),
+    "nemotron": ((2, 8192, 6144), (4096, 1024, 1024), True),
+}
+
+
+def _conv_sites(text, scope):
+    """The convolution kernels' call sites under ``scope`` in a
+    compiled program's text, after checking that nothing under it pads
+    a tensor (the plain form's copy of the whole projection, and
+    autodiff's of its cotangent)."""
+    under = [line for line in text.splitlines() if scope in line]
+    assert not any(" pad(" in line for line in under), \
+        [line[:200] for line in under if " pad(" in line]
+    return [line for line in under
+            if "pallas_call" in line and "tpu_custom_call" in line]
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "backward"])
+@pytest.mark.parametrize("cell", list(CONV_CELLS))
+def test_conv_kernels_compile(topo, one_chip, cell, backward):
+    """``causal_conv_silu``'s two kernels alone at the three cells' real
+    shapes, float32: Mosaic lowers the rotations along the sublanes and
+    the blocks fit VMEM; one kernel forward, a second backward, no pad,
+    and, where the parts leave as the kernel wrote them (by head they
+    are swapped into this test's row-major results), no tensor beside
+    the arguments and the results forward."""
+    from chainermn_tpu.ops.pallas_attention import tracing_for_mesh
+    from chainermn_tpu.ops.recurrent import causal_conv_silu
+
+    shape, split, biased = CONV_CELLS[cell]
+    like = lambda s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    conv = tracing_for_mesh(mesh, lambda y, w, b: causal_conv_silu(
+        y, w, b, split))
+
+    def loss(y, w, b):
+        return sum(jnp.sum(jnp.sin(part)) for part in conv(y, w, b))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2) if biased else (0, 1)) \
+        if backward else conv
+    compiled = _compile(fn, like(shape), like(shape[2:] + (4,)),
+                        like(shape[2:]) if biased else None)
+    assert len(_conv_sites(compiled.as_text(), "")) == 1 + backward
+    if not backward and not any(isinstance(p, tuple) for p in split):
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
 def _compile_step(mc, cfg, opt, batch, seq):
     """``make_train_step`` compiled for the described devices of ``mc``
     (shapes only: there is no device to hold an array)."""
@@ -415,7 +470,9 @@ def test_kimi_cell_step_fits_with_its_mixers_in_it(topo):
     and XLA's triangular solve is gone; the same three sites make the
     chunks' pair weights in a kernel and a fourth, backward, makes them
     again from q, k and G, so no slab step's pair-by-pair tensor is in
-    the program; the chunked recurrence and
+    the program; each KDA position's convolution is a kernel under
+    ``kda/conv`` at three sites (forward, the block's recompute,
+    backward) and pads nothing; the chunked recurrence and
     every new scope are in the program, and the compiled step needs
     between 10 and 14.5 GiB of the chip's 16 at the traffic file's
     ``loss_chunk`` (the sizing rule of ISSUE 32: the first branch,
@@ -448,7 +505,9 @@ def test_kimi_cell_step_fits_with_its_mixers_in_it(topo):
     # block's recompute and the slab's, and no site in the solve's VJP;
     # the pair weights' kernel at the same three and its VJP's once
     assert len(inversions) == 4 * 3 and len(pairs) == 4 * (3 + 1)
-    assert len(kernels) == 3 + 4 * 3 + 4 * 4
+    convs = _conv_sites(text, "kda/conv")
+    assert len(convs) == 4 * 3
+    assert len(kernels) == 3 + 4 * 3 + 4 * 4 + 4 * 3
     assert all("kda/scan" in line for line in inversions + pairs)
     assert "InvertDiagBlocksLowerTriangular" not in text
     for line in inversions:
@@ -459,7 +518,7 @@ def test_kimi_cell_step_fits_with_its_mixers_in_it(topo):
     assert sum(" = (f32[128,4,16,128]{" in line for line in pairs) == 4
     for line in kernels:
         # 32 heads of one sequence: q and k 192 wide, v and o 128
-        assert line in inversions or line in pairs or (
+        assert line in inversions or line in pairs or line in convs or (
             "bf16[32,16384,192]" in line
             and "bf16[32,16384,128]" in line), line[:300]
     # what the jnp form stored a slab step: col and kcol, pair
@@ -478,14 +537,16 @@ def test_nemotron_cell_step_fits_with_its_scan_in_xla(topo):
     """The benchmark's Nemotron-H cell at its real size (two sequences
     of 8,192 tokens, nine one-part layers MEMEM*EME, 8 of 128 ReLU^2
     experts held), through the cell's own files and its driver's
-    mapping: the one attention layer's three flash kernels are the
-    step's only custom calls (the state-space scan is XLA's batched
-    products: importing ``ops/ssd.py`` brings no kernel), K and V reach
-    them copied out to the 32 query heads, the held experts' width of
-    1,856 is padded to 2,048 for XLA's grouped kernel, every new scope
-    is in the program, and the compiled step needs between 12 and 14.5
-    GiB of the
-    chip's 16 at the traffic file's ``loss_chunk`` (the sizing rule of
+    mapping: the one attention layer's three flash kernels and, a
+    Mamba-2 position, the convolution's kernel under ``ssm/conv`` at
+    three sites (forward, the block's recompute, backward; nothing
+    padded) are the step's only custom calls (the state-space scan is
+    XLA's batched products: importing ``ops/ssd.py`` brings no kernel),
+    K and V reach the flash kernels copied out to the 32 query heads,
+    the held experts' width of 1,856 is padded to 2,048 for XLA's
+    grouped kernel, every new scope is in the program, and the compiled
+    step needs between 12 and 14.5 GiB of the chip's 16 at the traffic
+    file's ``loss_chunk`` (the sizing rule of
     ISSUE 40: the first branch, ``loss_chunk`` 0)."""
     import sys
 
@@ -506,14 +567,17 @@ def test_nemotron_cell_step_fits_with_its_scan_in_xla(topo):
         MeshConfig(devices=topo.devices[:cell["chips"]], **job["mesh"]),
         pcfg, build_optimizer(cfg["optimizer"]), job["batch"], job["seq"])
     text = compiled.as_text()
-    # forward, dq and dkv of the one attention layer; no other kernel
+    # forward, dq and dkv of the one attention layer; the convolution
+    # of each of the four Mamba-2 positions; no other kernel
     assert _flash_kernels(text, "attn/full") == 3
     kernels = [line for line in text.splitlines()
                if "pallas_call" in line and "tpu_custom_call" in line]
-    assert len(kernels) == 3 and "ssm" not in "".join(kernels)
+    convs = _conv_sites(text, "ssm/conv")
+    assert len(convs) == 4 * 3 and len(kernels) == 3 + 4 * 3
+    assert "ssm/scan" not in "".join(kernels)
     for line in kernels:
         # 32 query heads of two sequences, keys and values copied out
-        assert "bf16[64,8192,128]" in line, line[:300]
+        assert line in convs or "bf16[64,8192,128]" in line, line[:300]
     for scope in ("attn/mamba2", "ssm/conv", "ssm/gate", "ssm/scan",
                   "ssm.intra", "ssm.states", "ssm.carry", "ssm.inter",
                   "moe/shared", "moe/route"):
@@ -534,9 +598,11 @@ def test_qwen3_next_cell_step_fits_with_one_kernel_in_its_delta_rule(topo):
     kernels (the backward's query block halved for the width: 1,024 x
     1,024 does not fit the compiler's 16 MB at 256 + 256) and, a linear
     layer, the inversion kernel of ``ops/kda.py`` three times (forward,
-    the slab's recompute, the block's recompute) are the step's only
-    custom calls -- no pair kernel: the scalar decay's pair weights are
-    XLA's -- every new scope is in the program, and the compiled step
+    the slab's recompute, the block's recompute) and the convolution's
+    kernel under ``gdn/conv`` three times (forward, the block's
+    recompute, backward; nothing padded) are the step's only custom
+    calls -- no pair kernel: the scalar decay's pair weights are XLA's
+    -- every new scope is in the program, and the compiled step
     needs between 12 and 14.5 GiB of the chip's 16 at the traffic
     file's ``loss_chunk`` (the sizing rule of ISSUE 42: the first
     branch).  Slow from the start: the benchmark's run repeats it."""
@@ -561,7 +627,8 @@ def test_qwen3_next_cell_step_fits_with_one_kernel_in_its_delta_rule(topo):
     assert _flash_kernels(text, "attn/full") == 3
     kernels = [line for line in text.splitlines()
                if "pallas_call" in line and "tpu_custom_call" in line]
-    assert len(kernels) == 3 + 3 * 3
+    assert len(_conv_sites(text, "gdn/conv")) == 3 * 3
+    assert len(kernels) == 3 + 3 * 3 + 3 * 3
     assert sum("kda.solve" in line for line in kernels) == 9
     assert "kda.pairs" not in text
     for scope in ("attn/gdn", "gdn/conv", "gdn/gate", "gdn/scan",
