@@ -308,7 +308,7 @@ class TransformerConfig:
     max_seq: int = 2048
     attention: str = "ring"    # "ring" | "ulysses" | "local" | "flash"
     flash_bwd_block_q: int = 0  # 0 = kernel default; >0 retunes the
-    # flash BACKWARD kernels' tiling independently of the forward
+    # flash BACKWARD kernel's tiling independently of the forward
     # (gradients are tiling-exact; flash.ms_per_step in the OPT cells
     # of benchmarks/ reads a pair on the chip, this knob adopts it)
     flash_bwd_block_k: int = 0
@@ -554,8 +554,8 @@ class TransformerConfig:
         ``remat=False``).  Under either policy it keeps the flash
         kernel's two residual outputs (``FLASH_RESIDUAL_NAMES``: ``o``
         as the kernel wrote it and the ``(B·H, T)`` log-sum-exp), so the
-        backward pass launches dq and dkv and does not run the forward
-        kernel again: a custom call is invisible to the dots policy, and
+        backward pass launches the one backward kernel and does not run
+        the forward kernel again: a custom call is invisible to the dots policy, and
         plain ``jax.checkpoint`` rebuilds every residual.  A block
         without the kernel has no such names and remats as before.
 
@@ -2002,7 +2002,7 @@ def _attention_of_kind(cfg: TransformerConfig, h, blk, kind):
     if cfg.attention == "flash":
         # Pallas kernel: compiled when the step was built for TPU
         # devices, interpreted otherwise (interpret_kernels).  The
-        # kernels wear ``attn.core`` themselves (forward, dq, dkv); the
+        # kernels wear ``attn.core`` themselves (forward, backward); the
         # relayouts around them stay the layer's own
         _require_flash(T)
         with device_scope("attn.kv_repeat"):

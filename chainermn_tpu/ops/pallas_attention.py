@@ -16,9 +16,10 @@ Design (flash-attention v2 schedule, TPU-shaped):
   block's math, and only ``block_k`` tokens of K/V ever sit in VMEM (so
   context length is bounded by HBM, not the 16 MB of VMEM);
 - **the grid visits the block pairs the mask needs**
-  (:func:`_visit_plan`, one function for all three kernels): for a
-  query block the needed key blocks are one contiguous run.  With a
-  ``window`` the innermost extent ``width`` is the band's width in
+  (:func:`_visit_plan`, one function for the forward kernel and the
+  backward's): for a query block the needed key blocks are one
+  contiguous run.  With a ``window`` the innermost extent ``width`` is
+  the band's width in
   blocks (2 of 8 at 8,192 tokens, 1,024-wide blocks and a window of
   512 or 1,024) and step ``s`` reads block ``first + s``; without one
   it stays ``T_k/block_k`` and the K/V index map holds at the run's
@@ -43,8 +44,14 @@ Design (flash-attention v2 schedule, TPU-shaped):
 - optionally returns the softmax log-sum-exp, with its own VJP path, so
   sequence-sharded callers can combine per-shard partial attentions
   exactly (``o = Σ o_i·exp(lse_i − lse)``);
-- backward = two recompute kernels (dq; dk/dv) off the saved lse —
-  flash's O(T) memory in the backward too;
+- backward = ONE recompute kernel off the saved lse (:func:`_bwd_kernel`:
+  key blocks outer, the needed query blocks inner): P, dP and dS once a
+  block pair and five products, dk and dv accumulated over a key block's
+  steps and dq in a float32 VMEM accumulator that spans the query length
+  for the whole ``B·H`` row, so the backward's HBM stays O(T) and its
+  query length is bounded by VMEM (:func:`_bwd_vmem_bytes` derives the
+  limit the kernel asks the compiler for, and refuses a length past the
+  budget by name);
 - ``interpret=True`` runs the identical kernels on CPU (how the test
   suite exercises them on the virtual pod).
 """
@@ -149,7 +156,7 @@ class _VisitPlan(NamedTuple):
     whose in-kernel predicate holds) and ``pairs_needed`` (block pairs
     with an allowed position) are per head, and ``None`` where the
     offsets are traced."""
-    outer: str            # "q": key blocks innermost; "k": dkv's grid
+    outer: str            # "q": key blocks innermost; "k": the backward's
     n_outer: int
     n_inner: int
     width: int
@@ -176,11 +183,12 @@ class _VisitPlan(NamedTuple):
 
 def _visit_plan(T_q, T_k, block_q, block_k, causal, window,
                 offsets=None, outer="q") -> _VisitPlan:
-    """The plan of the forward and dq grids (``outer="q"``: key blocks
-    innermost) or of dkv's (``outer="k"``).  ``offsets`` is ``(q_offset,
-    k_offset)`` as Python ints, or ``None`` where they are traced: the
-    band's width does not depend on them, only its place, so the extent
-    is then the most blocks a band can touch wherever it sits."""
+    """The plan of the forward grid (``outer="q"``: key blocks
+    innermost) or of the backward's (``outer="k"``).  ``offsets`` is
+    ``(q_offset, k_offset)`` as Python ints, or ``None`` where they are
+    traced: the band's width does not depend on them, only its place,
+    so the extent is then the most blocks a band can touch wherever it
+    sits."""
     nq, nk = T_q // block_q, T_k // block_k
     n_outer, n_inner = (nq, nk) if outer == "q" else (nk, nq)
     b_outer, b_inner = ((block_q, block_k) if outer == "q"
@@ -332,47 +340,23 @@ def _recompute_p(q, kb, scale, lse, causal, window, q_off, k_off, i, j,
     return jnp.exp(s - lse[:, None])
 
 
-def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, dq_acc, *, scale, causal, window, plan):
-    Bq, D = q_ref.shape[1:]
+def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, scale,
+                causal, window, plan):
+    """dq, dk and dv from one walk of the block pairs (k outer, q
+    inner): P, dP and dS once a pair, five products.  ``dk_acc`` and
+    ``dv_acc`` live for one key block; ``dq_acc`` holds the WHOLE query
+    length in float32, ``(T_q / Bq, Bq, D)``, across the key blocks of
+    one ``B·H`` row: zeroed at the row's first step, cast and written
+    at its last (the two inner grid axes are ``arbitrary``)."""
     Bk = k_ref.shape[1]
-    first, last, i, j, q_off, k_off, needed = _step(
-        plan, offs_ref, Bq, Bk, causal, window)
-
-    @pl.when(first)
-    def _():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
-
-    @pl.when(needed)
-    def _():
-        # native-dtype (bf16) dot operands, fp32 accumulation — see the
-        # forward kernel's note; ds is cast back to the wire dtype for
-        # the MXU (the standard flash-v2 backward numerics)
-        q = q_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, 0]
-        delta = delta_ref[0][:, 0]
-        kb = k_ref[0]
-        vb = v_ref[0]
-        p = _recompute_p(q, kb, scale, lse, causal, window, q_off, k_off,
-                         i, j, Bq, Bk)
-        dp = jnp.dot(do, vb.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
-        dq_acc[...] += jnp.dot(ds.astype(kb.dtype), kb,
-                               preferred_element_type=jnp.float32)
-
-    @pl.when(last)
-    def _():
-        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
-
-
-def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal, window,
-                plan):
-    Bk, D = k_ref.shape[1:]
     Bq = q_ref.shape[1]
     first, last, i, j, q_off, k_off, needed = _step(   # k outer, q inner
         plan, offs_ref, Bq, Bk, causal, window)
+
+    @pl.when(first & (j == 0))
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
     @pl.when(first)
     def _():
@@ -383,6 +367,7 @@ def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _():
         # native-dtype (bf16) dot operands, fp32 accumulation — see the
         # forward kernel's note; p/ds cast to the wire dtype for the MXU
+        # (the standard flash-v2 backward numerics)
         kb = k_ref[0]
         vb = v_ref[0]
         q = q_ref[0]
@@ -394,14 +379,18 @@ def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_acc[...] += jnp.dot(p.astype(do.dtype).T, do,
                                preferred_element_type=jnp.float32)
         dp = jnp.dot(do, vb.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
-        dk_acc[...] += jnp.dot(ds.astype(q.dtype).T, q,
-                               preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta[:, None]) * scale).astype(q.dtype)
+        dk_acc[...] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+        dq_acc[i] += jnp.dot(ds, kb, preferred_element_type=jnp.float32)
 
     @pl.when(last)
     def _():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when(last & (j == plan.n_outer - 1))
+    def _():
+        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
 # --------------------------------------------------------------------- #
@@ -429,10 +418,10 @@ def _sds(shape, dtype, like):
 
 
 def _call(kernel, plan, BH, in_specs, out_specs, out_shape, scratch_shapes,
-          interpret):
+          interpret, outer_semantics="parallel", vmem_limit_bytes=None):
     """One kernel over ``plan``'s grid, the offsets prefetched to SMEM
-    ahead of the index maps.  The kernel wears ``attn.core`` (forward,
-    dq and dkv alike); the relayouts and the backward's ``delta``
+    ahead of the index maps.  The kernel wears ``attn.core`` (forward
+    and backward alike); the relayouts and the backward's ``delta``
     around it do not."""
     _count_visits(plan)
     kernel = pl.pallas_call(
@@ -444,7 +433,8 @@ def _call(kernel, plan, BH, in_specs, out_specs, out_shape, scratch_shapes,
             scratch_shapes=scratch_shapes),
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", outer_semantics, "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret)
 
     def scoped(*operands):
@@ -496,21 +486,48 @@ def _flash_fwd(q3, k3, v3, offs, static_offs, scale, causal, window,
     # named so that an enclosing jax.checkpoint can keep them: they are
     # the only residuals the forward kernel produces, and a policy that
     # saves both (TransformerConfig.checkpoint_fn) leaves the backward
-    # pass with dq and dkv alone.  The name sits on the (BH, Tq) slice
-    # of lse, not on the kernel's 128-lane copy.  Inert under any other
+    # pass with its one kernel alone.  The name sits on the (BH, Tq)
+    # slice of lse, not on the kernel's 128-lane copy.  Inert under any other
     # policy, plain jax.checkpoint and no checkpoint at all.
     o = checkpoint_name(o, FLASH_RESIDUAL_NAMES[0])
     lse = checkpoint_name(lse, FLASH_RESIDUAL_NAMES[1])
     return (o, lse), (q3, k3, v3, offs, o, lse)
 
 
+# What one kernel may ask of the v5e's 128 MiB of VMEM
+# (``vmem_limit_bytes``; without it the compiler's scoped default is 16).
+_VMEM_BUDGET = 100 * 2 ** 20
+
+
+def _bwd_vmem_bytes(Tq, D, Dv, block_q, block_k, dtype):
+    """The VMEM the backward kernel needs, from its shapes alone, and so
+    the limit it asks the compiler for (``vmem_limit_bytes``)."""
+    lanes = lambda width: -(-width // _LANE) * _LANE
+    d, dv, wire = lanes(D), lanes(Dv), jnp.dtype(dtype).itemsize
+    # dq over the whole query length: the accumulator, the out block twice
+    held = Tq * d * (4 + 2 * wire)
+    # q, do in; k, v in; dk, dv out: double-buffered.  lse and delta at
+    # 128 lanes of float32.  dk's and dv's accumulators.
+    tiles = 2 * wire * (block_q + 2 * block_k) * (d + dv)
+    tiles += 2 * 2 * block_q * _LANE * 4 + block_k * (d + dv) * 4
+    # s / P, dP, dS in float32; P and dS again on the wire
+    temps = block_q * block_k * (3 * 4 + 2 * wire)
+    need = held + tiles + temps
+    if need > _VMEM_BUDGET:
+        raise ValueError(
+            f"flash backward: dq's accumulator over {Tq} queries of width "
+            f"{D} and the kernel's tiles need {need / 2**20:.0f} MiB of "
+            f"VMEM, over the {_VMEM_BUDGET // 2**20} a kernel may ask for; "
+            "shard the sequence (attention=\"ring\") or use "
+            "local_attention")
+    return need
+
+
 def _flash_bwd(static_offs, scale, causal, window, fwd_block_q,
                fwd_block_k, block_q, block_k, interpret, res, cts):
-    # the backward kernels tile on their OWN block sizes: dq's q-outer
-    # grid and dkv's k-outer revisit pattern have different optimal
-    # shapes than the forward (a retune is read in the OPT cells'
-    # flash.ms_per_step); the fwd blocks arrive first in the nondiff
-    # tuple and are unused here
+    # the backward kernel tiles on its OWN block sizes (a retune is
+    # read in the OPT cells' flash.ms_per_step); the fwd blocks arrive
+    # first in the nondiff tuple and are unused here
     q3, k3, v3, offs, o, lse = res
     do, dlse = cts
     BH, Tq, D = q3.shape
@@ -522,59 +539,56 @@ def _flash_bwd(static_offs, scale, causal, window, fwd_block_q,
     delta = delta - dlse.astype(jnp.float32)
     delta = jnp.broadcast_to(delta[..., None], delta.shape + (_LANE,))
     lse3 = jnp.broadcast_to(lse[..., None], lse.shape + (_LANE,))
-    kernel_args = dict(scale=scale, causal=causal, window=window)
+    get_registry().inc("flash/backward_fused_sites")
 
-    plan = _visit_plan(Tq, Tk, block_q, block_k, causal, window,
-                       static_offs)
-    q_spec, k_spec = _outer_spec(block_q, D), _inner_spec(plan, block_k, D)
-    qvec_spec = _outer_spec(block_q, _LANE)
-    dq = _call(
-        functools.partial(_dq_kernel, **kernel_args), plan, BH,
-        in_specs=[q_spec, k_spec, _inner_spec(plan, block_k, Dv),
-                  _outer_spec(block_q, Dv), qvec_spec, qvec_spec],
-        out_specs=q_spec,
-        out_shape=_sds((BH, Tq, D), q3.dtype, q3),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=interpret,
-    )(offs, q3, k3, v3, do, lse3, delta)
-
-    # k outer / q inner grid: the two sides swap roles
+    # k outer / q inner grid; dq's accumulator spans the query length
     plan = _visit_plan(Tq, Tk, block_q, block_k, causal, window,
                        static_offs, outer="k")
+    nq = Tq // block_q
     k_spec, q_spec = _outer_spec(block_k, D), _inner_spec(plan, block_q, D)
     v_spec = _outer_spec(block_k, Dv)
     qvec_spec = _inner_spec(plan, block_q, _LANE)
-    dk, dv = _call(
-        functools.partial(_dkv_kernel, **kernel_args), plan, BH,
+    dq, dk, dv = _call(
+        functools.partial(_bwd_kernel, scale=scale, causal=causal,
+                          window=window),
+        plan, BH,
         in_specs=[q_spec, k_spec, v_spec, _inner_spec(plan, block_q, Dv),
                   qvec_spec, qvec_spec],
-        out_specs=[k_spec, v_spec],
+        out_specs=[
+            pl.BlockSpec((1, nq, block_q, D),
+                         lambda b, o, s, offs: (b, 0, 0, 0)),
+            k_spec, v_spec],
         out_shape=[
+            _sds((BH, nq, block_q, D), q3.dtype, q3),
             _sds((BH, Tk, D), k3.dtype, k3),
             _sds((BH, Tk, Dv), v3.dtype, v3),
         ],
         scratch_shapes=[
+            pltpu.VMEM((nq, block_q, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, outer_semantics="arbitrary",
+        vmem_limit_bytes=_bwd_vmem_bytes(Tq, D, Dv, block_q, block_k,
+                                         q3.dtype),
     )(offs, q3, k3, v3, do, lse3, delta)
     d_offs = jnp.zeros(offs.shape, jax.dtypes.float0)
-    return dq, dk, dv, d_offs
+    return dq.reshape(BH, Tq, D), dk, dv, d_offs
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-# The backward kernels' tiles grow with the heads' width.  At blocks of
-# 1,024 x 1,024 the dkv kernel's take 17.04 MB at key and value widths
-# of 256 + 256, over the 16 MB the v5e's compiler allows one kernel (a
-# compile for a described v5e, PR 42); the widest that has run there is
-# latent attention's 192 + 128.  Past that the backward's query block
-# is halved unless the caller chose a tiling; gradients are exact for
-# any.  (512 x 1,024 and 1,024 x 512 both compile at 256 + 256.)
-_WIDEST_AT_FULL_BLOCKS = 192 + 128
-_WIDE_BWD_BLOCK_Q = 512
+# The backward kernel's blocks under a window.  Without one the forward's
+# 1,024 x 1,024 is the fastest tiling the chip has shown at every head
+# width the cells run, 256 + 256 included; a band no wider than 1,024
+# crosses a key block's 1,024 queries in two half-masked blocks, and at
+# 512 x 512 the kernel computes half (a window of 512) or three quarters
+# (1,024) of that.  Read on the chip, the backward of one layer at 2 x
+# 8,192 tokens and 128-wide heads (PR 44): window 512 and 64 heads 21.1
+# -> 15.3 ms, window 1,024 and 32 heads 10.6 -> 10.0; blocks of 2,048
+# or 256 lose everywhere.  A caller's own tiling stands.
+_BAND_BWD_BLOCK = 512
 
 
 def _fit_block(T: int, want: int) -> Optional[int]:
@@ -639,11 +653,11 @@ def flash_attention(q, k, v, *, causal: bool = False, window=None,
     With ``return_lse=True`` returns ``(out, lse)`` where ``lse`` is
     ``(B, T, H)`` fp32 — both outputs are differentiable.
 
-    ``bwd_block_q``/``bwd_block_k`` tile the two backward kernels
-    independently of the forward (default: the forward blocks) — the
-    dq kernel's q-outer grid and the dkv kernel's k-outer revisit
-    pattern peak at different shapes, and gradients are exact for any
-    valid tiling (the OPT cells' ``flash_roofline`` reads a retune).
+    ``bwd_block_q``/``bwd_block_k`` tile the backward kernel (its query
+    and key blocks) independently of the forward (default: the forward
+    blocks, and at most ``_BAND_BWD_BLOCK`` under a window no wider
+    than two of those); gradients are exact for any valid tiling (the
+    OPT cells' ``flash_roofline`` reads a retune).
     """
     B, Tq, H, D = q.shape
     Tk, Dv = k.shape[1], v.shape[-1]
@@ -662,8 +676,10 @@ def flash_attention(q, k, v, *, causal: bool = False, window=None,
             "by a power-of-two block >= 128 — gate on "
             "flash_attention_supported() and fall back to "
             "local_attention")
-    if not (bwd_block_q or bwd_block_k) and D + Dv > _WIDEST_AT_FULL_BLOCKS:
-        bwd_block_q = _WIDE_BWD_BLOCK_Q
+    if not (bwd_block_q or bwd_block_k) and window is not None \
+            and window <= 2 * _BAND_BWD_BLOCK:
+        bwd_block_q = min(block_q, _BAND_BWD_BLOCK)
+        bwd_block_k = min(block_k, _BAND_BWD_BLOCK)
     # a bwd override that doesn't tile THIS shape falls back to the
     # forward blocks rather than erroring: the knob is a perf hint
     # (often adopted from a sweep at another sequence length) and must

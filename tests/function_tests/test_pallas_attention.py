@@ -1,6 +1,8 @@
 """Pallas flash attention vs the XLA oracle (interpret mode on CPU —
 the same kernel code path that compiles on TPU)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,20 +10,24 @@ import pytest
 
 from chainermn_tpu.ops.pallas_attention import (
     _block_needed,
+    _bwd_vmem_bytes,
     _visit_plan,
     flash_attention,
     flash_attention_supported,
 )
-from chainermn_tpu.parallel.ring_attention import local_attention
+from chainermn_tpu.parallel.ring_attention import (
+    _lse_attention_pair,
+    local_attention,
+)
 
 B, T, H, D = 2, 64, 2, 16
 
 
-def qkv(seed=0, t=T):
+def qkv(seed=0, t=T, d=D, dv=None):
     rng = np.random.RandomState(seed)
-    mk = lambda: jnp.asarray(
-        rng.randn(B, t, H, D).astype(np.float32) * 0.5)
-    return mk(), mk(), mk()
+    mk = lambda width: jnp.asarray(
+        rng.randn(B, t, H, width).astype(np.float32) * 0.5)
+    return mk(d), mk(d), mk(dv or d)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -34,9 +40,14 @@ def test_forward_matches_oracle(causal):
         np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("d,dv", [(D, D), (24, 16)],
+                         ids=["one-width", "values-narrower"])
 @pytest.mark.parametrize("causal", [False, True])
-def test_grads_match_oracle(causal):
-    q, k, v = qkv(1)
+def test_grads_match_oracle(causal, d, dv):
+    """dq, dk and dv of the one backward kernel; the second case has
+    keys and values of different widths (latent attention's 192 + 128:
+    dq and dk take the keys' width, dv the values')."""
+    q, k, v = qkv(1, d=d, dv=dv)
 
     def loss_flash(q, k, v):
         o = flash_attention(
@@ -56,11 +67,13 @@ def test_grads_match_oracle(causal):
 
 @pytest.mark.parametrize("bwd_q,bwd_k,window", [
     (16, 32, None), (32, 16, None), (64, 64, None),
-    # with a window the two backward grids are bands of their own
-    # widths (dq walks key blocks of bwd_k, dkv query blocks of bwd_q)
-    (16, 32, 24), (32, 16, 24), (16, 32, 48), (32, 16, 5)])
+    # with a window the backward grid is a band of its own width (key
+    # blocks of bwd_k outer, the needed query blocks of bwd_q inner)
+    (16, 32, 24), (32, 16, 24), (16, 32, 48), (32, 16, 5),
+    # one key block for the whole length, and one query block
+    (8, 64, None), (64, 8, 24), (16, 16, 48)])
 def test_bwd_block_retune_grads_exact(bwd_q, bwd_k, window):
-    """Backward kernels tiled independently of the forward must give
+    """The backward kernel tiled independently of the forward must give
     the same gradients for ANY valid tiling — the correctness side of
     the bwd block retune lever (the OPT cells' flash.ms_per_step
     reads the perf side)."""
@@ -108,6 +121,59 @@ def test_bf16_inputs():
     np.testing.assert_allclose(
         np.asarray(out, dtype=np.float32), np.asarray(ref),
         rtol=5e-2, atol=5e-2)
+
+
+@pytest.mark.parametrize("window", [None, 24])
+def test_bf16_grads(window):
+    """bf16 on the wire, float32 accumulators (dq's over the whole
+    query length): gradients against the float32 oracle."""
+    q, k, v = (x.astype(jnp.bfloat16) for x in qkv(5))
+
+    def loss(f):
+        return lambda q, k, v: jnp.sum(
+            f(q, k, v, causal=True, window=window).astype(jnp.float32) ** 2)
+
+    g_flash = jax.grad(loss(functools.partial(
+        flash_attention, block_q=16, block_k=32, interpret=True)),
+        argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(loss(local_attention), argnums=(0, 1, 2))(
+        *(x.astype(jnp.float32) for x in (q, k, v)))
+    for a, b in zip(g_flash, g_ref):
+        assert a.dtype == jnp.bfloat16
+        np.testing.assert_allclose(
+            np.asarray(a, dtype=np.float32), np.asarray(b),
+            rtol=5e-2, atol=5e-2)
+
+
+@pytest.mark.parametrize("window,q_off,k_off", [
+    (None, 0, 0), (24, 0, 0), (None, 40, 16), (None, 0, 64), (8, 200, 0)],
+    ids=["causal", "window", "offsets", "no-key-allowed",
+         "band-before-the-keys"])
+def test_lse_cotangent_grads_match_oracle(window, q_off, k_off):
+    """The ring's call form: ``lse`` returned and differentiated, its
+    cotangent folded into ``delta``.  In the last two cases no query
+    may meet any key: the pair still hands back gradients, all zero."""
+    q, k, v = qkv(6)
+
+    def loss(f):
+        def inner(q, k, v):
+            o, lse = f(q, k, v)
+            return jnp.sum(o * jnp.cos(o)) + jnp.sum(jnp.sin(lse * 1e-3))
+        return inner
+
+    g_flash = jax.grad(loss(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, q_offset=q_off,
+        k_offset=k_off, block_q=16, block_k=32, return_lse=True,
+        interpret=True)), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(loss(lambda q, k, v: _lse_attention_pair(
+        q, k, v, causal=True, window=window, q_offset=q_off,
+        k_offset=k_off)), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g_flash, g_ref):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-5)
+    if not _rows_with_a_key(T, T, q_off, k_off, window).any():
+        for a in g_flash:
+            np.testing.assert_array_equal(np.asarray(a), 0.0)
 
 
 def test_supported_predicate():
@@ -358,12 +424,16 @@ def test_visit_plan_walks_exactly_the_needed_pairs():
     assert n == 4 * 4 * 8 * 5 * 4
 
 
-@pytest.mark.parametrize("window,steps,pairs", [
-    (512, 16, 15), (1024, 16, 15), (None, 64, 36)])
-def test_grid_counters_after_a_traced_call(window, steps, pairs):
+@pytest.mark.parametrize("window,steps,pairs,bwd_steps,bwd_pairs", [
+    (512, 16, 15, 32, 31), (1024, 16, 15, 48, 45), (None, 64, 36, 64, 36)])
+def test_grid_counters_after_a_traced_call(window, steps, pairs, bwd_steps,
+                                           bwd_pairs):
     """``flash/grid_steps`` and ``flash/pairs_computed`` (a head, one
     addition for each kernel call site as it is traced) at the typed
-    cells' shapes: forward alone, then forward, dq and dkv."""
+    cells' shapes: forward alone, then forward (traced twice) and the
+    one backward kernel, which ``flash/backward_fused_sites`` counts
+    and which walks a band in 512-wide blocks of its own (16 key blocks
+    of 2 or 3 query blocks each)."""
     from chainermn_tpu.utils.metrics import MetricsRegistry, set_registry
 
     s = jax.ShapeDtypeStruct((1, 8192, 2, 128), jnp.bfloat16)
@@ -378,10 +448,43 @@ def test_grid_counters_after_a_traced_call(window, steps, pairs):
         jax.eval_shape(attn, s, s, s)
         assert reg.counter("flash/grid_steps").value == steps
         assert reg.counter("flash/pairs_computed").value == pairs
+        assert reg.counter("flash/backward_fused_sites").value == 0
         jax.eval_shape(jax.grad(
             lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(),
             argnums=(0, 1, 2)), s, s, s)
-        assert reg.counter("flash/grid_steps").value == 4 * steps
-        assert reg.counter("flash/pairs_computed").value == 4 * pairs
+        assert reg.counter("flash/grid_steps").value \
+            == 2 * steps + bwd_steps
+        assert reg.counter("flash/pairs_computed").value \
+            == 2 * pairs + bwd_pairs
+        assert reg.counter("flash/backward_fused_sites").value == 1
     finally:
         set_registry(prev)
+
+
+# the cells' backward shapes (T_q, D, D_v, block_q, block_k) with the
+# VMEM the chip's compiler needed for each, MiB (bisection of
+# vmem_limit_bytes in compiles for a described v5e, PR 44)
+@pytest.mark.parametrize("shape,compiler_needs", [
+    ((2048, 64, 64, 1024, 1024), 13.3),       # OPT, one chip and four
+    ((8192, 128, 128, 1024, 1024), 20.2),     # Mellum, Laguna, Nemotron
+    ((8192, 128, 128, 512, 1024), 15.1),
+    ((16384, 192, 128, 1024, 1024), 46.6),    # Kimi's latent layer
+    ((16384, 256, 256, 512, 1024), 44.0),     # Qwen3-Next
+    ((16384, 256, 256, 1024, 1024), 49.5)])
+def test_backward_vmem_limit_follows_the_shapes(shape, compiler_needs):
+    """The limit the backward kernel asks for is derived from its
+    shapes: above what the compiler needed, within a quarter over."""
+    asked = _bwd_vmem_bytes(*shape, jnp.bfloat16) / 2 ** 20
+    assert compiler_needs < asked < 1.25 * compiler_needs + 8
+
+
+def test_backward_refuses_a_length_its_accumulator_cannot_hold():
+    """dq's float32 accumulator spans the query length in VMEM: a length
+    past the budget is refused by name as the backward is traced, not
+    by the chip's compiler."""
+    s = jax.ShapeDtypeStruct((1, 131072, 1, 128), jnp.bfloat16)
+    attn = lambda q, k, v: flash_attention(
+        q, k, v, causal=True, interpret=True).astype(jnp.float32).sum()
+    jax.eval_shape(attn, s, s, s)
+    with pytest.raises(ValueError, match="dq's accumulator over 131072"):
+        jax.eval_shape(jax.grad(attn, argnums=(0, 1, 2)), s, s, s)

@@ -124,11 +124,11 @@ def _same(a, b):
         np.asarray(u), np.asarray(v)), a, b)
 
 
-@pytest.mark.parametrize("attention,kernels", [("local", 0), ("flash", 3)])
+@pytest.mark.parametrize("attention,kernels", [("local", 0), ("flash", 2)])
 def test_remat_policy_grad_equivalence(attention, kernels):
     """remat_policy is a pure scheduling choice: grads equal the
     remat=False path (fp32), and under every setting the traced gradient
-    holds the kernels of remat=False (forward, dq, dkv) and no more."""
+    holds the kernels of remat=False (forward, backward) and no more."""
     toks = tokens()
     x, y = toks[:, :T], toks[:, 1:]
     grads = {}
@@ -174,9 +174,9 @@ def _gated_kw():
                                     "leading+gated+heads"])
 def test_flash_forward_runs_once_under_remat(monkeypatch, layers, policy):
     """The block's checkpoint keeps the kernel's own ``o`` and ``lse``:
-    the traced gradient of the scanned blocks holds three kernels a
-    layer kind (forward, dq, dkv), where plain ``jax.checkpoint`` holds
-    four (the forward again), and the gradients are those of
+    the traced gradient of the scanned blocks holds two kernels a
+    layer kind (forward, backward), where plain ``jax.checkpoint`` holds
+    three (the forward again), and the gradients are those of
     ``remat=False`` and of plain ``jax.checkpoint`` to the last bit."""
     kw = dict(attention="flash", **(
         _gated_kw() if "gated" in layers
@@ -193,7 +193,7 @@ def test_flash_forward_runs_once_under_remat(monkeypatch, layers, policy):
                         property(lambda self: jax.checkpoint))
     plain, n_plain = _lm_grad(cfg, x, y)
     assert [n["pallas_call"] for n in (n_none, n_kept, n_plain)] == [
-        3 * kinds, 3 * kinds, 4 * kinds]
+        2 * kinds, 2 * kinds, 3 * kinds]
     _same(kept, none)
     _same(kept, plain)
 
@@ -396,7 +396,7 @@ def test_flash_unsupported_length_is_an_error():
 
 
 def test_flash_bwd_block_override_train_step_exact():
-    """flash_bwd_block_q/k retune the backward kernels' tiling only:
+    """flash_bwd_block_q/k retune the backward kernel's tiling only:
     a train step (loss AND updated params) must be bit-comparable to
     the default tiling — adoption of a sweep winner is purely a perf
     decision."""
