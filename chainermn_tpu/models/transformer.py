@@ -24,27 +24,18 @@ Design rules (TPU-first):
 
 from __future__ import annotations
 
-import math
+from contextlib import nullcontext
 from dataclasses import dataclass
 from functools import partial
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import optax
 from jax import lax
-from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from chainermn_tpu.ops.gdn import gdn_chunked
-from chainermn_tpu.ops.kda import kda_chunked
-from chainermn_tpu.ops.recurrent import causal_conv_silu
-from chainermn_tpu.ops.ssd import ssd_chunked
 from chainermn_tpu.ops.pallas_attention import (
     FLASH_RESIDUAL_NAMES,
-    flash_attention,
-    flash_attention_supported,
-    interpret_kernels,
     tracing_for_mesh,
 )
 from chainermn_tpu.parallel.expert import (
@@ -59,12 +50,7 @@ from chainermn_tpu.parallel.pipeline import (
     pipeline_train_1f1b,
     pipeline_train_interleaved,
 )
-from chainermn_tpu.parallel.ring_attention import (
-    _block_positions,
-    broadcast_kv,
-    local_attention,
-    ring_attention,
-)
+from chainermn_tpu.parallel.ring_attention import _block_positions
 from chainermn_tpu.parallel._compat import (
     all_gather_invariant as _all_gather_invariant,
 )
@@ -72,8 +58,20 @@ from chainermn_tpu.parallel.tensor import (
     column_parallel_dense,
     row_parallel_dense,
 )
-from chainermn_tpu.parallel.ulysses import ulysses_attention
 from chainermn_tpu.utils.telemetry import device_scope
+
+from . import mixers
+# defined in ``models/mixers.py``; what imports them from here finds
+# them here (decoding, the benchmark's drivers, the tests)
+from .mixers import (  # noqa: F401
+    KDA_L2_NORM_EPS,
+    AttentionKind,
+    _dense_init,
+    _norm,
+    _norm_init,
+    _rms_norm,
+    apply_rope,
+)
 
 __all__ = [
     "AttentionKind",
@@ -90,210 +88,14 @@ __all__ = [
     "hold_selection_bias",
 ]
 
-# KDA's L2 norm of q and k: y * rsqrt(sum(y^2) + eps).  The published
-# config file carries no key for it; a benchmark driver holds its
-# reference's value to this one
-KDA_L2_NORM_EPS = 1e-6
-
-# the token mixers an :class:`AttentionKind` may name, and the parts of
-# a layer it may have: the checks and their messages read these
-MIXERS = ("softmax", "mla", "kda", "mamba2", "gdn")
-PARTS = ("both", "mixer", "mlp")
+# the mixers an :class:`AttentionKind` may name, in the order of the
+# table (``mixers.MIXERS``, which the code here reads)
+MIXERS = tuple(mixers.MIXERS)
 
 
-@dataclass(frozen=True)
-class AttentionKind:
-    """One kind of layer of a model whose layers differ: which parts it
-    has, its mixer, and for the mixer what only it has -- softmax
-    attention its window, rotary parameters and query heads; latent
-    attention its latent rank and the widths of its shared key part and
-    its values; the delta-rule layers their convolution (the
-    scalar-decay one also its key heads and the two head widths); the
-    state-space layer its heads' width, state size, groups and
-    convolution.
-    ``TransformerConfig.layer_pattern`` is a tuple of these, one per
-    layer of a period (``leading_layers`` one per layer before them).
-    Every field is read by the training path alone
-    (``_init_block``, ``_block_specs``, ``_block``,
-    ``_attention_of_kind``)."""
-    name: str                  # names the layer's scope: ``attn/<name>``
-    part: str = "both"         # "both": a mixer, then an MLP, each
-    # behind its own norm and residual add | "mixer": the mixer alone |
-    # "mlp": the feed-forward part alone (the config's: sparse where
-    # ``moe``), and no mixer field is read.  A layer of one part holds
-    # that part's leaves and ONE norm (``ln1`` a mixer's, ``ln2`` an
-    # MLP's) and adds to the residual stream once
-    mixer: str = "softmax"     # "softmax": the config's attention core
-    # over q/k/v heads of d_head, rotated as below | "mla": multi-head
-    # latent attention without rotary (``mla_use_nope``): queries of
-    # d_head + d_shared_key straight from the input, keys and values
-    # from one normed latent of rank ``kv_latent``, the last
-    # ``d_shared_key`` key channels one vector shared by every head,
-    # values ``d_value`` wide; causal softmax with the scale of the
-    # whole key width, through the flash kernels (``attention="flash"``)
-    # or XLA (``"local"``) | "kda": Kimi Delta Attention
-    # (``ops/kda.py``): q, k, v through a causal depthwise convolution
-    # of ``conv_taps`` and SiLU, q and k L2-normed a head, a decay a
-    # channel and a step size a head from the input, the delta rule
-    # over a ``d_head x d_head`` state a head, a per-head RMSNorm and
-    # a sigmoid gate on the way out | "mamba2": the Mamba-2 state-space
-    # layer (``ops/ssd.py``): one projection to a gate ``z``, to ``x``
-    # (``n_heads`` heads of ``ssm_head_dim``), ``B`` and ``C``
-    # (``ssm_groups`` groups of ``ssm_state``) and to a step a head;
-    # ``x``, ``B``, ``C`` through a causal depthwise convolution of
-    # ``conv_taps`` with bias and SiLU; a scalar decay a head over a
-    # ``ssm_head_dim x ssm_state`` state a head, a skip ``D x``, the
-    # gate ``SiLU(z)``, then an RMSNorm over each group's channels |
-    # "gdn": Gated DeltaNet (``ops/gdn.py``): one projection to q and k
-    # (``key_heads`` heads of ``d_key``), v and an output gate ``z``
-    # (``n_heads`` value heads of ``d_value``), one to a step and a
-    # decay's input a value head; q, k, v through a causal depthwise
-    # convolution of ``conv_taps`` and SiLU, q and k L2-normed a head;
-    # the delta rule with ONE scalar decay a value head over a ``d_key
-    # x d_value`` state, value head j reading key head j // (n_heads /
-    # key_heads); a per-head RMSNorm with one plain scale for all
-    # heads, THEN the gate ``SiLU(z)`` (norm first, gate after).
-    # None of the four takes positions: window, rotary and YaRN fields
-    # are the softmax mixer's
-    kv_latent: int = 0         # mla: rank of the key-value latent
-    d_shared_key: int = 0      # mla: key channels shared by the heads
-    d_value: int = 0           # mla: value head width; 0 => d_head.
-    # gdn: a value head's width (its ``n_heads`` count the value heads)
-    key_heads: int = 0         # gdn: heads of q and k, each serving
-    # n_heads / key_heads value heads
-    d_key: int = 0             # gdn: a key head's width
-    conv_taps: int = 4         # kda, mamba2, gdn: taps of the short
-    # convolution
-    qk_norm: bool = False      # softmax: an RMSNorm with a learned scale
-    # over each head of q and of k (``q_norm``, ``k_norm``, one scale of
-    # d_head each for all heads), before any rotation
-    ssm_head_dim: int = 0      # mamba2: channels a head (its ``n_heads``
-    # are this kind's own: the config's are softmax attention's)
-    ssm_state: int = 0         # mamba2: the state's size N a channel
-    ssm_groups: int = 0        # mamba2: groups of B and C; head j reads
-    # group j // (n_heads / ssm_groups)
-    window: int = 0            # 0 => full causal; W>0 => (t-W, t]
-    rope_theta: float = 10000.0
-    n_heads: int = 0           # 0 => the config's n_heads.  Else this
-    # kind's own query heads over the config's key-value heads: its
-    # layers' wq, wo and gate have that many and no more
-    rotary_share: float = 1.0  # the leading part of each head that is
-    # rotated (a partial rotary factor); the rest passes through.  The
-    # frequencies, YaRN's ramp included, are those of a head of that
-    # many dimensions.  0 => nothing is rotated: a softmax layer that
-    # takes no positions at all
-    # YaRN (Peng et al., arXiv:2309.00071), as published configs state
-    # it: frequencies whose wavelength exceeds the original context are
-    # divided by ``yarn_factor``, those that turn often within it are
-    # kept, with a linear blend between ``yarn_beta_fast`` and
-    # ``yarn_beta_slow`` turns; cos and sin are multiplied by
-    # ``attention_factor``.  ``yarn_factor == 0`` => plain rope.
-    yarn_factor: float = 0.0
-    yarn_original_max: int = 0
-    yarn_beta_fast: float = 32.0
-    yarn_beta_slow: float = 1.0
-    attention_factor: float = 1.0
-
-    def __post_init__(self):
-        if not self.name or "/" in self.name:
-            raise ValueError(f"attention kind name {self.name!r}")
-        if self.mixer not in MIXERS:
-            raise ValueError(
-                f"{self.name}: mixer {self.mixer!r} not in "
-                f"({', '.join(MIXERS)})")
-        if self.part not in PARTS:
-            raise ValueError(
-                f"{self.name}: part {self.part!r} not in "
-                f"({', '.join(PARTS)})")
-        if self.mixer == "mla" and (self.kv_latent < 1
-                                    or self.d_shared_key < 0
-                                    or self.d_value < 0):
-            raise ValueError(
-                f"{self.name}: mla needs kv_latent >= 1 and widths >= 0, "
-                f"got {self.kv_latent}, {self.d_shared_key}, {self.d_value}")
-        if self.mixer in ("kda", "mamba2", "gdn") and self.conv_taps < 1:
-            raise ValueError(
-                f"{self.name}: {self.mixer} needs conv_taps >= 1, got "
-                f"{self.conv_taps}")
-        if self.mixer == "mamba2" and (
-                min(self.n_heads, self.ssm_head_dim, self.ssm_state,
-                    self.ssm_groups) < 1 or self.n_heads % self.ssm_groups):
-            raise ValueError(
-                f"{self.name}: mamba2 needs its own n_heads, ssm_head_dim, "
-                "ssm_state and ssm_groups >= 1 and whole groups of heads, "
-                f"got {self.n_heads}, {self.ssm_head_dim}, "
-                f"{self.ssm_state}, {self.ssm_groups}")
-        if self.mixer == "gdn" and (
-                min(self.n_heads, self.key_heads, self.d_key,
-                    self.d_value) < 1 or self.n_heads % self.key_heads):
-            raise ValueError(
-                f"{self.name}: gdn needs its own n_heads (value heads), "
-                "key_heads, d_key and d_value >= 1 and whole groups of "
-                f"value heads a key head, got {self.n_heads}, "
-                f"{self.key_heads}, {self.d_key}, {self.d_value}")
-        if self.mixer != "softmax" and (
-                self.window or self.yarn_factor or self.qk_norm):
-            raise ValueError(
-                f"{self.name}: window, rotary fields and qk_norm are the "
-                f"softmax mixer's; mixer={self.mixer!r} takes no positions")
-        if self.window < 0:
-            raise ValueError(f"{self.name}: window {self.window} < 0")
-        if self.rope_theta <= 1:
-            raise ValueError(f"{self.name}: rope_theta {self.rope_theta}")
-        if self.yarn_factor and (self.yarn_factor < 1
-                                 or self.yarn_original_max < 1):
-            raise ValueError(
-                f"{self.name}: yarn needs factor >= 1 and the original "
-                f"context, got {self.yarn_factor}, {self.yarn_original_max}")
-        if self.n_heads < 0:
-            raise ValueError(f"{self.name}: n_heads {self.n_heads} < 0")
-        if not 0 <= self.rotary_share <= 1:
-            raise ValueError(
-                f"{self.name}: rotary_share {self.rotary_share} not in [0, 1]")
-
-    @property
-    def tree(self):
-        """What of this kind decides its layer's parameter tree, beside
-        the query heads."""
-        if self.part == "mlp":
-            return ("mlp",)
-        return (self.part,) + self._mixer_tree
-
-    @property
-    def _mixer_tree(self):
-        if self.mixer == "mamba2":
-            return ("mamba2", self.ssm_head_dim, self.ssm_state,
-                    self.ssm_groups, self.conv_taps)
-        if self.mixer == "mla":
-            return ("mla", self.kv_latent, self.d_shared_key, self.d_value)
-        if self.mixer == "kda":
-            return ("kda", self.conv_taps)
-        if self.mixer == "gdn":
-            return ("gdn", self.key_heads, self.d_key, self.d_value,
-                    self.conv_taps)
-        return ("softmax", self.qk_norm)
-
-    def rotary_dim(self, d_head: int) -> int:
-        """How many leading dimensions of a head are rotated."""
-        return int(d_head * self.rotary_share)
-
-    def inv_freq(self, d_head: int):
-        """The ``rotary_dim / 2`` rotary frequencies, as float64 numpy
-        (constants of the compiled step)."""
-        d_head = self.rotary_dim(d_head)
-        half = d_head // 2
-        base = self.rope_theta ** (-np.arange(half, dtype=np.float64) / half)
-        if not self.yarn_factor:
-            return base
-
-        def turns_at(n):   # the dimension that turns n times in the context
-            return d_head * math.log(self.yarn_original_max / (
-                2 * math.pi * n)) / (2 * math.log(self.rope_theta))
-
-        lo = max(math.floor(turns_at(self.yarn_beta_fast)), 0)
-        hi = min(math.ceil(turns_at(self.yarn_beta_slow)), d_head - 1)
-        ramp = np.clip((np.arange(half) - lo) / max(hi - lo, 1e-3), 0, 1)
-        return ramp * base / self.yarn_factor + (1 - ramp) * base
+def _mixer(cfg, kind):
+    """The table's record of the mixer of a layer of ``kind``."""
+    return mixers.MIXERS[cfg.mixer_of(kind)]
 
 
 @dataclass(frozen=True)
@@ -623,7 +425,7 @@ class TransformerConfig:
                        for k in self.leading_layers):
                 raise ValueError("leading_layers holds AttentionKind values")
             for k in self._mixing(self.layer_pattern + self.leading_layers):
-                if k.mixer not in ("mamba2", "gdn") \
+                if mixers.MIXERS[k.mixer].groups_kv_heads \
                         and self.heads_of(k) % self.kv_heads:
                     raise ValueError(
                         f"{k.name}: n_heads={k.n_heads} must be a multiple "
@@ -688,7 +490,7 @@ class TransformerConfig:
                 "shared_expert_d_ff are the dropless expert layer's "
                 "(moe=True, moe_dispatch='dropless')")
         if self.attn_gate and self.layer_pattern and not any(
-                k.mixer == "softmax" for k in self._mixing(
+                mixers.MIXERS[k.mixer].takes_attn_gate for k in self._mixing(
                     self.layer_pattern + self.leading_layers)):
             raise ValueError(
                 f"attn_gate is softmax attention's; the {self.mixers} "
@@ -744,24 +546,6 @@ class TransformerConfig:
 # --------------------------------------------------------------------- #
 
 
-# what _init_block builds for a mixer beside the norms and ``wo``
-_MIXER_LEAVES = {
-    "mla": ("wq", "wkva", "kv_norm", "wkvb"),
-    "kda": ("wqkv", "conv", "wf_a", "wf_b", "a_log", "dt_bias", "wbeta",
-            "wg_a", "wg_b", "o_norm"),
-    "mamba2": ("w_in", "conv", "conv_b", "a_log", "dt_bias", "d_skip",
-               "o_norm"),
-    "gdn": ("w_in", "w_ba", "conv", "a_log", "dt_bias", "o_norm"),
-}
-
-
-def _norm_init(cfg: "TransformerConfig", shape):
-    """A learned norm scale at its seed: 1, or 0 where the config's
-    norms add 1 to what they store (``norm_scale``)."""
-    fill = jnp.zeros if cfg.norm_scale == "zero_centred" else jnp.ones
-    return fill(shape, jnp.float32)
-
-
 def _init_block(key, cfg: TransformerConfig, kind=None, sparse=None):
     """One layer's parameters at its own shapes: ``kind`` gives the
     parts it has, the mixer and the query heads (None: both parts,
@@ -778,102 +562,17 @@ def _init_block(key, cfg: TransformerConfig, kind=None, sparse=None):
     return block
 
 
-def _dense_init(k, shape, fan_in):
-    return jax.random.normal(k, shape, jnp.float32) * (fan_in ** -0.5)
-
-
 def _init_mixer(key, ks, cfg: TransformerConfig, kind):
-    """The mixer's norm and leaves (``ks``: the layer's six keys)."""
-    D, Dh = cfg.d_model, cfg.d_head
-    H = cfg.heads_of(kind)
-    mixer = cfg.mixer_of(kind)
-    Dv = Dh   # the width of a head on its way out
-    if mixer == "mla":
-        Dv = kind.d_value or Dh
-    elif mixer == "mamba2":
-        Dv = kind.ssm_head_dim
-    elif mixer == "gdn":
-        Dv = kind.d_value
-    block = {
+    """The mixer's norm, its leaves and the projection back from its
+    heads (``ks``: the layer's six keys)."""
+    D, H = cfg.d_model, cfg.heads_of(kind)
+    mixer = _mixer(cfg, kind)
+    Dv = mixer.out_width(cfg, kind)  # the width of a head on its way out
+    return {
         "ln1": _norm_init(cfg, (D,)),
         "wo": _dense_init(ks[1], (H, Dv, D), H * Dv),
+        **mixer.init(key, ks, cfg, kind),
     }
-    if mixer == "mamba2":
-        # one projection to [z | x B C | dt]; the published initialisers:
-        # exp(a_log) uniform in [1, 16], the step's bias the inverse
-        # softplus of a log-uniform [1e-3, 1e-1] floored at 1e-4, D = 1
-        inner, taps = H * Dv, kind.conv_taps
-        conv = inner + 2 * kind.ssm_groups * kind.ssm_state
-        kk = iter(jax.random.split(jax.random.fold_in(key, 13), 3))
-        block["w_in"] = _dense_init(ks[0], (D, inner + conv + H), D)
-        block["conv"] = _dense_init(next(kk), (conv, taps), taps)
-        block["conv_b"] = jnp.zeros((conv,), jnp.float32)
-        block["a_log"] = jnp.log(jax.random.uniform(
-            next(kk), (H,), jnp.float32, 1.0, 16.0))
-        dt = jnp.maximum(jnp.exp(jax.random.uniform(
-            next(kk), (H,), jnp.float32, math.log(1e-3), math.log(1e-1))),
-            1e-4)
-        block["dt_bias"] = dt + jnp.log(-jnp.expm1(-dt))
-        block["d_skip"] = jnp.ones((H,), jnp.float32)
-        block["o_norm"] = jnp.ones((inner,), jnp.float32)
-    elif mixer == "mla":
-        L, Ds = kind.kv_latent, kind.d_shared_key
-        block["wq"] = _dense_init(ks[0], (D, H, Dh + Ds), D)
-        block["wkva"] = _dense_init(ks[5], (D, L + Ds), D)
-        block["kv_norm"] = _norm_init(cfg, (L,))
-        block["wkvb"] = _dense_init(
-            jax.random.fold_in(key, 11), (L, H, Dh + Dv), L)
-    elif mixer == "kda":
-        # the two-matrix projections of the decay and of the output
-        # gate go through a rank of d_head
-        R, taps = Dh, kind.conv_taps
-        kk = iter(jax.random.split(jax.random.fold_in(key, 12), 8))
-        block["wqkv"] = _dense_init(ks[0], (D, 3, H, Dh), D)
-        block["conv"] = _dense_init(next(kk), (3, H, Dh, taps), taps)
-        block["wf_a"] = _dense_init(next(kk), (D, R), D)
-        block["wf_b"] = _dense_init(next(kk), (R, H, Dh), R)
-        # the published initialisers: exp(a_log) uniform in [1, 16]; the
-        # step's bias the inverse softplus of a log-uniform [1e-3, 1e-1]
-        block["a_log"] = jnp.log(jax.random.uniform(
-            next(kk), (H,), jnp.float32, 1.0, 16.0))
-        dt = jnp.exp(jax.random.uniform(
-            next(kk), (H, Dh), jnp.float32, math.log(1e-3), math.log(1e-1)))
-        block["dt_bias"] = dt + jnp.log(-jnp.expm1(-dt))
-        block["wbeta"] = _dense_init(next(kk), (D, H), D)
-        block["wg_a"] = _dense_init(next(kk), (D, R), D)
-        block["wg_b"] = _dense_init(next(kk), (R, H, Dh), R)
-        block["o_norm"] = jnp.ones((Dh,), jnp.float32)
-    elif mixer == "gdn":
-        # one projection to [q | k | v | z], one to [b | a]; A_log and
-        # dt_bias a value head, seeded as KDA's and Mamba-2's are
-        keys, taps = kind.key_heads * kind.d_key, kind.conv_taps
-        kk = iter(jax.random.split(jax.random.fold_in(key, 14), 4))
-        block["w_in"] = _dense_init(ks[0], (D, 2 * keys + 2 * H * Dv), D)
-        block["w_ba"] = _dense_init(next(kk), (D, 2 * H), D)
-        block["conv"] = _dense_init(
-            next(kk), (2 * keys + H * Dv, taps), taps)
-        block["a_log"] = jnp.log(jax.random.uniform(
-            next(kk), (H,), jnp.float32, 1.0, 16.0))
-        dt = jnp.exp(jax.random.uniform(
-            next(kk), (H,), jnp.float32, math.log(1e-3), math.log(1e-1)))
-        block["dt_bias"] = dt + jnp.log(-jnp.expm1(-dt))
-        block["o_norm"] = jnp.ones((Dv,), jnp.float32)
-    elif cfg.kv_heads == H:
-        block["wqkv"] = _dense_init(ks[0], (D, 3, H, Dh), D)
-    else:
-        # GQA/MQA: Hkv shared K/V heads, each serving H/Hkv query heads
-        # (consecutive grouping: query head h reads kv head h//(H/Hkv))
-        block["wq"] = _dense_init(ks[0], (D, H, Dh), D)
-        block["wkv"] = _dense_init(ks[5], (D, 2, cfg.kv_heads, Dh), D)
-    if mixer == "softmax":
-        if cfg.attn_gate:
-            block["wg"] = _dense_init(
-                jax.random.fold_in(key, 7),
-                (D, H) + (Dh,) * (cfg.attn_gate == "per_element"), D)
-        if kind is not None and kind.qk_norm:
-            block["q_norm"] = _norm_init(cfg, (Dh,))
-            block["k_norm"] = _norm_init(cfg, (Dh,))
-    return block
 
 
 def _init_mlp(key, ks, cfg: TransformerConfig, sparse: bool):
@@ -1124,28 +823,14 @@ def _fsdp_gather(cfg: TransformerConfig, blk):
 
 def _mixer_specs(cfg: TransformerConfig, kind, mha: bool):
     """A mixer's norm and leaves in a stack of blocks."""
-    blk = {
+    mixer = _mixer(cfg, kind)
+    # ``wo`` splits with the heads, or is whole like the mixer's leaves
+    return {
         "ln1": P("pipe"),
-        "wo": P("pipe", None, "model", None, None),
+        "wo": P("pipe", None, "model", None, None) if mixer.splits_heads
+        else P("pipe"),
+        **mixer.specs(cfg, kind, mha),
     }
-    mixer = cfg.mixer_of(kind)
-    if mixer != "softmax":
-        # whole on every member of ``model`` (_check_mesh keeps that
-        # axis at 1 for these mixers): the stack's pipe axis and no other
-        blk.update({name: P("pipe") for name in _MIXER_LEAVES[mixer]},
-                   wo=P("pipe"))
-    elif mha:
-        blk["wqkv"] = P("pipe", None, None, None, "model", None)
-    else:
-        blk["wq"] = P("pipe", None, None, "model", None)
-        blk["wkv"] = P("pipe", None, None, None, "model", None)
-    if mixer == "softmax":
-        if cfg.attn_gate:
-            # (D, H) a head, (D, H, d_head) an element
-            blk["wg"] = P("pipe", None, None, "model")
-        if kind is not None and kind.qk_norm:
-            blk["q_norm"] = blk["k_norm"] = P("pipe")
-    return blk
 
 
 def _mlp_specs(cfg: TransformerConfig, sparse: bool):
@@ -1263,19 +948,6 @@ def param_specs(cfg: TransformerConfig, quantized: bool = False):
 # --------------------------------------------------------------------- #
 # forward (call INSIDE shard_map over the 5-axis mesh)
 # --------------------------------------------------------------------- #
-
-
-def _rms_norm(x, scale, eps=1e-6):
-    x32 = x.astype(jnp.float32)
-    r = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (x32 * r * scale).astype(x.dtype)
-
-
-def _norm(cfg: TransformerConfig, x, w):
-    """The RMSNorm of a learned scale stored as ``w``, as the config's
-    ``norm_scale`` reads it: ``w`` itself, or ``1 + w``."""
-    return _rms_norm(x, 1.0 + w if cfg.norm_scale == "zero_centred" else w,
-                     cfg.norm_eps)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -1631,413 +1303,23 @@ def _shard_nll_sum(cfg, h_normed, embed, targets):
         logp, targets[..., None], axis=-1).sum(dtype=jnp.float32)
 
 
-def apply_rope(x, positions, theta: float = 10000.0, inv_freq=None,
-               scale: float = 1.0):
-    """Rotary embedding (rotate-half convention) on ``x`` (..., T, H, D)
-    at absolute ``positions`` — ``(T,)`` shared across the batch, or
-    ``(B, T)`` per-row (left-padded decoding gives each row its own
-    position origin).  Rotations are absolute per token but the QK dot
-    depends only on position DIFFERENCES — so sharded callers (ring
-    shards, zigzag layouts, KV caches) just pass each token's own
-    global position and relative attention falls out, with no position
-    parameters to learn or extend.
-
-    ``inv_freq`` replaces ``theta``'s frequencies and ``scale``
-    multiplies cos and sin.  Fewer than ``d_head/2`` of them rotate the
-    leading ``2·len(inv_freq)`` dimensions of each head (rotate-half
-    within that part) and pass the rest through: a partial rotary.
-
-    The trig tables are (T, d_head/2) — negligible next to the T² score
-    matrix, so they are recomputed per call (the layer-invariant parts
-    are XLA CSE-hoistable) instead of threading a cache through every
-    stage signature."""
-    half = x.shape[-1] // 2
-    if inv_freq is None:
-        freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    else:
-        # a kind's own frequencies (AttentionKind.inv_freq) and the
-        # factor its cos and sin carry (YaRN's attention factor)
-        freqs = jnp.asarray(inv_freq, jnp.float32)
-        if freqs.shape[0] < half:
-            half = freqs.shape[0]
-            return jnp.concatenate([
-                apply_rope(x[..., :2 * half], positions, inv_freq=inv_freq,
-                           scale=scale), x[..., 2 * half:]], axis=-1)
-    ang = positions.astype(jnp.float32)[..., None] * freqs  # (..., T, half)
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    if scale != 1.0:
-        cos, sin = cos * scale, sin * scale
-    cos = cos[..., None, :].astype(x.dtype)           # (..., T, 1, half)
-    sin = sin[..., None, :].astype(x.dtype)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-
-
 def _attention(cfg: TransformerConfig, h, blk, kind=None):
-    """Pre-LN attention: column-parallel QKV (heads sharded over ``model``),
-    seq-parallel core (ring/Ulysses over ``seq``), row-parallel output.
-    ``kind`` is the layer's :class:`AttentionKind` under a
-    ``layer_pattern``: its window and rotary parameters then stand in
-    for the config's.  The layer's ops carry ``attn/<kind.name>`` in
-    their ``op_name``; where the layers are all alike, ``attn/sliding``
-    under an ``attention_window`` and ``attn/full`` without one."""
+    """Pre-LN token mixing: ``h + mixer(norm(h))``, the mixer the record
+    of ``MIXERS`` that ``kind`` names (``models/mixers.py``; softmax
+    attention for an untyped layer, ``kind`` None, by the config's
+    window and rotary parameters).  The layer's ops carry
+    ``attn/<kind.name>`` in their ``op_name``; where the layers are all
+    alike, ``attn/sliding`` under an ``attention_window`` and
+    ``attn/full`` without one.  The mixer names its inner scopes."""
     name = kind.name if kind is not None else (
         "sliding" if cfg.attention_window else "full")
+    mixer = _mixer(cfg, kind)
+    norm_scope = device_scope(mixer.norm_scope) if mixer.norm_scope \
+        else nullcontext()
     with device_scope(f"attn/{name}"):
-        return _attention_of_kind(cfg, h, blk, kind)
-
-
-def _require_flash(T):
-    """``attention="flash"`` as asked or not at all: no silent stand-in,
-    a run that asked for the kernel and got the XLA attention would be
-    measured as the kernel."""
-    if lax.axis_size("seq") != 1:
-        raise ValueError(
-            'attention="flash" covers only the unsharded-sequence '
-            'case (mesh seq axis is '
-            f'{lax.axis_size("seq")}); use attention="ring" to '
-            "shard the sequence")
-    if not flash_attention_supported(T, T):
-        raise ValueError(
-            f'attention="flash" cannot tile a sequence of {T}: '
-            "lengths must be multiples of 8 and either fit one "
-            "block or divide by a power-of-two block >= 128 "
-            '(flash_attention_supported); use attention="local" '
-            "for the XLA path")
-
-
-def _mla_mixer(cfg: TransformerConfig, x, blk, kind):
-    """Latent attention without rotary on the normed input ``x``: the
-    layer's contribution to the residual stream.  The shared key part is
-    copied out to the heads ahead of the kernel (as ``broadcast_kv``
-    does for grouped heads); the kernel takes keys of ``d_head +
-    d_shared_key`` and values of ``d_value`` as they are."""
-    cd = cfg.compute_dtype
-    B, T, D = x.shape
-    H, L, Ds, Dn = (blk["wq"].shape[1], kind.kv_latent, kind.d_shared_key,
-                    cfg.d_head)
-    with device_scope("attn.qkv"):
-        q = (x @ blk["wq"].reshape(D, -1).astype(cd)).reshape(
-            B, T, H, Dn + Ds)
-    with device_scope("mla/latent"):
-        down = x @ blk["wkva"].astype(cd)
-        latent = _norm(cfg, down[..., :L], blk["kv_norm"])
-        up = (latent @ blk["wkvb"].reshape(L, -1).astype(cd)).reshape(
-            B, T, H, -1)
-    with device_scope("attn.kv_repeat"):
-        k = jnp.concatenate([up[..., :Dn], jnp.broadcast_to(
-            down[:, :, None, L:], (B, T, H, Ds))], axis=-1)
-        v = up[..., Dn:]
-    if cfg.attention == "flash":
-        _require_flash(T)
-        o = flash_attention(
-            q, k, v, causal=True,
-            bwd_block_q=cfg.flash_bwd_block_q or None,
-            bwd_block_k=cfg.flash_bwd_block_k or None,
-            interpret=interpret_kernels())
-    else:
-        with device_scope("attn.core"):
-            o = local_attention(q, k, v, causal=True)
-    o = checkpoint_name(o, "attn_out")
-    with device_scope("attn.out"):
-        return o.reshape(B, T, -1) @ blk["wo"].reshape(-1, D).astype(cd)
-
-
-def _l2_unit(y):
-    """``y`` over its L2 norm along the last axis (a head's channels):
-    the delta-rule mixers' norm of q and k."""
-    return y * lax.rsqrt(
-        jnp.sum(y * y, axis=-1, keepdims=True) + KDA_L2_NORM_EPS)
-
-
-def _kda_mixer(cfg: TransformerConfig, x, blk, kind):
-    """Kimi Delta Attention on the normed input ``x``: the layer's
-    contribution to the residual stream.  Projections in the compute
-    dtype with float32 results; convolution, norms, gates and the
-    recurrence (``ops/kda.py``) in float32.  ``kda/conv`` holds the
-    convolution (``ops/recurrent.py``: at whole lane tiles and token
-    blocks one Pallas kernel forward that hands back q, k and v, one
-    backward; the plain sum over taps otherwise) and the L2 norms after
-    it; ``kda/scan`` holds the whole op, its Pallas kernel for the
-    chunks' unit-triangular systems included.  Off the TPU the kernels
-    are interpreted, as the flash kernels are."""
-    cd, f32 = cfg.compute_dtype, jnp.float32
-    B, T, D = x.shape
-    H, Dh = blk["wqkv"].shape[2:]
-
-    def project(*ws):
-        y = x
-        for w in ws:
-            y = jnp.dot(y.astype(cd), w.reshape(w.shape[0], -1).astype(cd),
-                        preferred_element_type=f32)
-        return y
-
-    with device_scope("attn.qkv"):
-        qkv = project(blk["wqkv"]).reshape(B, T, 3, H, Dh)
-    with device_scope("kda/conv"):
-        q, k, v = causal_conv_silu(qkv, blk["conv"], split=((H, Dh),) * 3)
-        q, k = _l2_unit(q) * Dh ** -0.5, _l2_unit(k)
-    with device_scope("kda/gate"):
-        # the recurrence's two gates: the log of the decay a channel
-        # (<= 0) and the step size a head
-        g = -jnp.exp(blk["a_log"])[:, None] * jax.nn.softplus(
-            project(blk["wf_a"], blk["wf_b"]).reshape(B, T, H, Dh)
-            + blk["dt_bias"])
-        beta = jax.nn.sigmoid(project(blk["wbeta"]))
-    with device_scope("kda/scan"):
-        o = kda_chunked(q, k, v, g, beta)
-    with device_scope("kda/gate"):
-        # the way out: RMSNorm over each head with one scale for all,
-        # times a sigmoid gate from the input
-        o = _rms_norm(o, blk["o_norm"], cfg.norm_eps) * jax.nn.sigmoid(
-            project(blk["wg_a"], blk["wg_b"]).reshape(B, T, H, Dh))
-    o = checkpoint_name(o.astype(cd), "attn_out")
-    with device_scope("attn.out"):
-        return o.reshape(B, T, -1) @ blk["wo"].reshape(-1, D).astype(cd)
-
-
-def _mamba2_mixer(cfg: TransformerConfig, x, blk, kind):
-    """Mamba-2 on the normed input ``x``: the layer's contribution to
-    the residual stream.  Projections in the compute dtype with float32
-    results; the convolution, the step, the decay, the recurrence's
-    arrays (``ops/ssd.py``) and the gated norm in float32.  The
-    convolution (``ops/recurrent.py``) hands back x, B and C apart: at
-    whole lane tiles and token blocks it is a Pallas kernel with a
-    backward kernel of its own, the layer's only ones."""
-    cd, f32 = cfg.compute_dtype, jnp.float32
-    B, T, D = x.shape
-    H, P = blk["wo"].shape[:2]
-    G, N = kind.ssm_groups, kind.ssm_state
-    inner, grouped = H * P, G * N
-    with device_scope("attn.qkv"):
-        # one product to [z | x B C | dt]
-        proj = jnp.dot(x.astype(cd), blk["w_in"].astype(cd),
-                       preferred_element_type=f32)
-        z, xbc, dt = (proj[..., :inner], proj[..., inner:-H],
-                      proj[..., -H:])
-    with device_scope("ssm/conv"):
-        # flat parts: x's heads are half a lane tile wide, so the
-        # kernel has no head-by-head form for them
-        xs, b_in, c_out = causal_conv_silu(
-            xbc, blk["conv"], blk["conv_b"], split=(inner, grouped, grouped))
-        xs = xs.reshape(B, T, H, P)
-        b_in, c_out = b_in.reshape(B, T, G, N), c_out.reshape(B, T, G, N)
-    with device_scope("ssm/gate"):
-        # the step a head (no clamp: time_step_limit (0, inf)) and the
-        # decay's rate a head (< 0)
-        dt = jax.nn.softplus(dt + blk["dt_bias"])
-        a = -jnp.exp(blk["a_log"])
-    with device_scope("ssm/scan"):
-        y = ssd_chunked(xs, dt, a, b_in, c_out)
-    with device_scope("ssm/gate"):
-        # the skip, the gate, then an RMSNorm over each group's channels
-        # with a scale a channel (gate first, norm after)
-        y = (y + blk["d_skip"][:, None] * xs).reshape(B, T, inner) \
-            * jax.nn.silu(z)
-        y = _rms_norm(y.reshape(B, T, G, inner // G),
-                      blk["o_norm"].reshape(G, -1), cfg.norm_eps)
-    o = checkpoint_name(y.reshape(B, T, inner).astype(cd), "attn_out")
-    with device_scope("attn.out"):
-        return o @ blk["wo"].reshape(-1, D).astype(cd)
-
-
-def _gdn_mixer(cfg: TransformerConfig, x, blk, kind):
-    """Gated DeltaNet on the normed input ``x``: the layer's
-    contribution to the residual stream.  Projections in the compute
-    dtype with float32 results; convolution, L2 norms, gates and the
-    recurrence (``ops/gdn.py``) in float32.  ``gdn/conv`` holds the
-    convolution (``ops/recurrent.py``, which hands back q, k and v
-    apart; a Pallas kernel forward and one backward at whole lane tiles
-    and token blocks) and the L2 norms after it; ``gdn/scan`` holds the
-    whole op, ``ops/kda.py``'s Pallas kernel for the chunks'
-    unit-triangular systems included (all interpreted off the TPU)."""
-    cd, f32 = cfg.compute_dtype, jnp.float32
-    B, T, D = x.shape
-    Hv, Dv = blk["wo"].shape[:2]
-    Hk, Dk = kind.key_heads, kind.d_key
-    keys, values = Hk * Dk, Hv * Dv
-    with device_scope("attn.qkv"):
-        # two products: to [q | k | v | z] and to [b | a]
-        proj = jnp.dot(x.astype(cd), blk["w_in"].astype(cd),
-                       preferred_element_type=f32)
-        ba = jnp.dot(x.astype(cd), blk["w_ba"].astype(cd),
-                     preferred_element_type=f32)
-        qkv, z = proj[..., :2 * keys + values], proj[..., 2 * keys + values:]
-    with device_scope("gdn/conv"):
-        q, k, v = causal_conv_silu(
-            qkv, blk["conv"], split=((Hk, Dk), (Hk, Dk), (Hv, Dv)))
-        q, k = _l2_unit(q) * Dk ** -0.5, _l2_unit(k)
-    with device_scope("gdn/gate"):
-        # the recurrence's two gates, a scalar a value head each: the
-        # step size and the log of the decay (<= 0)
-        beta = jax.nn.sigmoid(ba[..., :Hv])
-        g = -jnp.exp(blk["a_log"]) * jax.nn.softplus(
-            ba[..., Hv:] + blk["dt_bias"])
-    with device_scope("gdn/scan"):
-        o = gdn_chunked(q, k, v, g, beta)
-    with device_scope("gdn/gate"):
-        # the way out: RMSNorm over each head with one plain scale for
-        # all, THEN the gate SiLU(z) (norm first, gate after)
-        o = _rms_norm(o, blk["o_norm"], cfg.norm_eps) \
-            * jax.nn.silu(z.reshape(B, T, Hv, Dv))
-    o = checkpoint_name(o.astype(cd), "attn_out")
-    with device_scope("attn.out"):
-        return o.reshape(B, T, -1) @ blk["wo"].reshape(-1, D).astype(cd)
-
-
-_MIXER_FNS = {"mla": _mla_mixer, "kda": _kda_mixer, "mamba2": _mamba2_mixer,
-              "gdn": _gdn_mixer}
-
-
-def _exchanged_or_local_core(cfg: TransformerConfig, q, k, v, win):
-    """The attention core where it is not the flash kernel alone: the
-    ring, Ulysses' exchange, or XLA's own attention."""
-    T = q.shape[1]
-    if cfg.attention == "ring":
-        # flagship long-context path: ring schedule with the Pallas
-        # kernel as the per-pair compute whenever the local block shape
-        # fits the kernel (interpret mode keeps one config working on
-        # non-TPU backends); XLA einsum blocks otherwise
-        use_flash = flash_attention_supported(T, T)
-        if cfg.seq_layout == "zigzag":
-            # each zigzag half-run must itself fit the kernel's blocks
-            use_flash = flash_attention_supported(T // 2, T // 2)
-        return ring_attention(q, k, v, axis_name="seq", causal=True,
-                              window=win,
-                              remat=cfg.remat, use_flash=use_flash,
-                              bwd_block_q=cfg.flash_bwd_block_q or None,
-                              bwd_block_k=cfg.flash_bwd_block_k or None,
-                              layout=cfg.seq_layout,
-                              interpret=interpret_kernels())
-    if cfg.attention == "ulysses":
-        # after the head<->seq exchange each device holds the FULL
-        # sequence for its head subset — the flash kernel slots straight
-        # in (static zero offsets), falling back to the XLA path when
-        # the full length doesn't fit the kernel's block contract
-        T_full = T * lax.axis_size("seq")
-        if flash_attention_supported(T_full, T_full):
-            fa = partial(flash_attention,
-                         bwd_block_q=cfg.flash_bwd_block_q or None,
-                         bwd_block_k=cfg.flash_bwd_block_k or None,
-                         interpret=interpret_kernels())
-            return ulysses_attention(q, k, v, axis_name="seq", causal=True,
-                                     window=win,
-                                     attn_fn=fa)
-        return ulysses_attention(q, k, v, axis_name="seq", causal=True,
-                                 window=win)
-    if cfg.attention == "local":
-        return local_attention(q, k, v, causal=True,
-                               window=win)
-    raise ValueError(cfg.attention)
-
-
-def _attention_of_kind(cfg: TransformerConfig, h, blk, kind):
-    cd = cfg.compute_dtype
-    if cfg.mixer_of(kind) != "softmax":
-        return h + _MIXER_FNS[kind.mixer](
-            cfg, _norm(cfg, h, blk["ln1"]), blk, kind)
-    win = (kind.window if kind else cfg.attention_window) or None
-    with device_scope("attn.qkv"):
-        x = _norm(cfg, h, blk["ln1"])
-        B, T, D = x.shape
-        if "wqkv" in blk:
-            Hl = blk["wqkv"].shape[2]      # local heads = H / model-axis size
-            qkv = column_parallel_dense(
-                x, blk["wqkv"].reshape(D, -1).astype(cd))
-            qkv = qkv.reshape(B, T, 3, Hl, cfg.d_head)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            gate = column_parallel_dense(
-                x, blk["wg"].reshape(D, -1).astype(cd)) \
-                if "wg" in blk else None
-        else:
-            # GQA/MQA: H/Hkv query heads share each K/V head.  K/V stay at
-            # their natural (shared) width all the way through the attention
-            # cores — the ring rotates and Ulysses exchanges Hkv-head blocks
-            # (ICI traffic shrinks by H/Hkv) and the grouped einsums read the
-            # shared heads in place.  Local (per model-rank) grouping equals
-            # global grouping because both H and Hkv shard over the same
-            # axis: global query head r·Hl+i reads kv head r·Hkvl + i//rep
-            # for rep = Hl/Hkvl = H/Hkv (mesh divisibility is validated at
-            # shard/jit build time by _check_mesh).
-            Hl = blk["wq"].shape[1]
-            Hkvl = blk["wkv"].shape[2]
-            # ONE fused projection dot, like the MHA wqkv path: concatenating
-            # the (local-shard) weights along the output dim reads the
-            # activations once instead of twice — the concat costs one
-            # weight-sized copy, far less than the saved (B,T,D) re-read at
-            # training shapes, and removes a dispatch on the decode path.
-            # The at-rest params stay separate (their TP/FSDP specs differ).
-            dq = Hl * cfg.d_head
-            dkv = 2 * Hkvl * cfg.d_head
-            # the gate's projection (Hl more columns a head, or Hl x
-            # d_head an element) rides it too
-            fused = jnp.concatenate(
-                [blk["wq"].reshape(D, -1), blk["wkv"].reshape(D, -1)]
-                + ([blk["wg"].reshape(D, -1)] if "wg" in blk else []),
-                axis=1).astype(cd)
-            qkv = column_parallel_dense(x, fused)
-            q = qkv[..., :dq].reshape(B, T, Hl, cfg.d_head)
-            kv = qkv[..., dq:dq + dkv].reshape(B, T, 2, Hkvl, cfg.d_head)
-            k, v = kv[:, :, 0], kv[:, :, 1]
-            gate = qkv[..., dq + dkv:] if "wg" in blk else None
-    if "q_norm" in blk:
-        with device_scope("attn.qk_norm"):
-            # over each head's d_head, one scale for all heads
-            q = _norm(cfg, q, blk["q_norm"])
-            k = _norm(cfg, k, blk["k_norm"])
-    if cfg.pos_embedding == "rope" and (kind is None or kind.rotary_share):
-        with device_scope("attn.rope"):
-            # rotate by each local token's GLOBAL position BEFORE any ring
-            # rotation / Ulysses exchange — relative attention then holds
-            # across shard boundaries by construction
-            pos = _block_positions(
-                lax.axis_index("seq"), T, lax.axis_size("seq"),
-                cfg.seq_layout if cfg.attention == "ring" else "contiguous")
-            rope = dict(theta=cfg.rope_theta) if kind is None else dict(
-                inv_freq=kind.inv_freq(cfg.d_head),
-                scale=kind.attention_factor)
-            q = apply_rope(q, pos, **rope)
-            k = apply_rope(k, pos, **rope)
-    if cfg.attention == "flash":
-        # Pallas kernel: compiled when the step was built for TPU
-        # devices, interpreted otherwise (interpret_kernels).  The
-        # kernels wear ``attn.core`` themselves (forward, backward); the
-        # relayouts around them stay the layer's own
-        _require_flash(T)
-        with device_scope("attn.kv_repeat"):
-            # kernel wants matching head counts
-            k, v = broadcast_kv(k, v, q.shape[2] // k.shape[2])
-        o = flash_attention(
-            q, k, v, causal=True,
-            window=win,
-            bwd_block_q=cfg.flash_bwd_block_q or None,
-            bwd_block_k=cfg.flash_bwd_block_k or None,
-            interpret=interpret_kernels())
-    else:
-        with device_scope("attn.core"):
-            o = _exchanged_or_local_core(cfg, q, k, v, win)
-    if gate is not None:
-        with device_scope("attn.gate"):
-            # o_j <- sigmoid(x W_g)_j o_j, one scalar a local query head
-            # or one an element of it.  Its backward reads the core's o,
-            # which the block's checkpoint already keeps where the core
-            # is the flash kernel
-            gate = jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
-            o = o * (gate.reshape(o.shape) if blk["wg"].ndim == 3
-                     else gate[..., None])
-    # named for the "dots" remat policy, which saves it as the input of
-    # the output projection's backward.  It never kept the flash kernel
-    # out of the recompute (the kernel's residuals are its own o and
-    # lse: FLASH_RESIDUAL_NAMES, saved by checkpoint_fn); counted in the
-    # traced gradient, what it spares a layer is the p·v product under
-    # "local", the exchange back under "ulysses", and a transpose (for a
-    # second copy of o) under "flash" and "ring"
-    o = checkpoint_name(o, "attn_out")
-    with device_scope("attn.out"):
-        o = row_parallel_dense(
-            o.reshape(B, T, -1), blk["wo"].reshape(-1, D).astype(cd))
-    return h + o
+        with norm_scope:
+            x = _norm(cfg, h, blk["ln1"])
+        return h + mixer.apply(cfg, x, blk, kind)
 
 
 def _gated(cfg: TransformerConfig, act: str, x, w1, w3, w2):

@@ -18,11 +18,19 @@ from chainermn_tpu.parallel import MeshConfig
 B, HW, C = 8, 32, 8
 
 
+def seeds(cfg):
+    """The parameters at their seeds, made by ONE compiled program: op
+    by op a 22-layer net's init compiles every draw at every shape, most
+    of the 41 s the GoogLeNet forward case took of a 1,319 s tier-1 run
+    (PR 45)."""
+    return jax.jit(lambda: init_convnet(jax.random.PRNGKey(0), cfg))()
+
+
 @pytest.mark.parametrize("arch", ["alex", "nin", "vgg16", "googlenet"])
 def test_forward_shape(arch):
     cfg = ConvNetConfig(arch=arch, num_classes=C, dtype="float32",
                         head="gap")
-    params = init_convnet(jax.random.PRNGKey(0), cfg)
+    params = seeds(cfg)
     x = jnp.asarray(np.random.RandomState(0).randn(B, HW, HW, 3),
                     jnp.float32)
     # one compiled program: op by op the 22-layer net's eager dispatch
@@ -43,7 +51,7 @@ def test_dp_step_reduces_loss():
 
     cfg = ConvNetConfig(arch="nin", num_classes=4, dtype="float32",
                         head="gap")
-    params = init_convnet(jax.random.PRNGKey(0), cfg)
+    params = seeds(cfg)
     rng = np.random.RandomState(1)
     x = jnp.asarray(rng.randn(16, HW, HW, 3), jnp.float32)
     y = jnp.asarray(rng.randint(0, 4, 16))
@@ -78,7 +86,8 @@ def test_reference_flatten_head_parity(arch, fin):
     fan-ins (alex 256*6*6=9216 @227, vgg16 512*7*7=25088 @224) and a
     consistent end-to-end shape (checked via eval_shape, no FLOPs)."""
     cfg = ConvNetConfig(arch=arch, num_classes=C, dtype="float32")
-    params = init_convnet(jax.random.PRNGKey(0), cfg)
+    params = jax.eval_shape(
+        lambda: init_convnet(jax.random.PRNGKey(0), cfg))
     fc = [p for p in params if p and p["w"].ndim == 2][0]
     assert fc["w"].shape == (fin, 4096)
     out = jax.eval_shape(
@@ -92,7 +101,8 @@ def test_googlenet_aux_heads():
     three logit sets have class shape (checked via eval_shape); with_aux
     on other archs raises."""
     cfg = ConvNetConfig(arch="googlenet", num_classes=C, dtype="float32")
-    params = init_convnet(jax.random.PRNGKey(0), cfg)
+    params = jax.eval_shape(
+        lambda: init_convnet(jax.random.PRNGKey(0), cfg))
     assert params["aux_4a"]["fc1"]["w"].shape == (2048, 1024)
     assert params["fc"]["w"].shape == (1024, C)
     outs = jax.eval_shape(
@@ -109,11 +119,12 @@ def test_googlenet_gap_aux_small_input():
     32px with finite values."""
     cfg = ConvNetConfig(arch="googlenet", num_classes=C, dtype="float32",
                         head="gap")
-    params = init_convnet(jax.random.PRNGKey(0), cfg)
+    params = seeds(cfg)
     assert params["aux_4a"]["fc1"]["w"].shape == (128, 1024)
     x = jnp.asarray(np.random.RandomState(0).randn(2, HW, HW, 3),
                     jnp.float32)
-    logits, a1, a2 = convnet_apply(cfg, params, x, with_aux=True)
+    logits, a1, a2 = jax.jit(lambda p, x: convnet_apply(
+        cfg, p, x, with_aux=True))(params, x)
     for o in (logits, a1, a2):
         assert o.shape == (2, C)
         assert np.isfinite(np.asarray(o)).all()

@@ -69,7 +69,7 @@ def test_the_tree_has_each_kinds_leaves_at_their_shapes():
     gdn, full = shapes["blocks"][0], shapes["blocks"][3]
     lead = (1, 1)
     assert {k: v.shape[2:] for k, v in gdn.items() if k in
-            tr._MIXER_LEAVES["gdn"] + ("wo", "ln1")} == {
+            tr.mixers.MIXERS["gdn"].leaves + ("wo", "ln1")} == {
         # [q | k | v | z] and [b | a]; the convolution over q, k and v
         "w_in": (32, 2 * 2 * 32 + 2 * 4 * 8), "w_ba": (32, 8),
         "conv": (2 * 2 * 32 + 4 * 8, 4), "a_log": (4,), "dt_bias": (4,),

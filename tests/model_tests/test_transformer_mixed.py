@@ -408,15 +408,18 @@ def test_reshard_train_state_moves_a_tree_of_several_stacks():
     opt = optax.adamw(1e-3)
     x, y = tokens()
 
-    def after_one_step(mc):
-        params = shard_params(mc, cfg, host_params(cfg))
-        return make_train_step(mc, cfg, opt)(
-            params, shard_opt_state(opt, params), x, y)
-
     one = MeshConfig(devices=jax.devices()[:1], data=1)
-    params, state, _ = after_one_step(one)
-    want = float(make_train_step(one, cfg, opt)(params, state, x, y)[2])
-    params, state, _ = after_one_step(one)
+    # the one-device step and the seeds built once: each
+    # ``make_train_step`` is a program of its own to compile
+    step, host = make_train_step(one, cfg, opt), host_params(cfg)
+
+    def after_one_step():
+        params = shard_params(one, cfg, host)
+        return step(params, shard_opt_state(opt, params), x, y)
+
+    params, state, _ = after_one_step()
+    want = float(step(params, state, x, y)[2])
+    params, state, _ = after_one_step()
     four = MeshConfig(devices=jax.devices()[:4], expert=2, model=2)
     params, state = tr.reshard_train_state(four, cfg, opt, params, state)
     assert params["blocks"][3]["wq"].shape == (1, 2, 32, 4, 8)

@@ -218,7 +218,7 @@ def test_a_kind_that_rotates_nothing_is_rope_left_out(host, monkeypatch):
         lambda h, blk: tr._block(cfg, h, blk, kind)[0], h, blk)
     still, turned = run(A), run(rotating)
     assert float(jnp.abs(still - turned).max()) > 1e-3
-    monkeypatch.setattr(tr, "apply_rope", lambda x, *a, **k: x)
+    monkeypatch.setattr(tr.mixers, "apply_rope", lambda x, *a, **k: x)
     np.testing.assert_array_equal(still, run(rotating))
     assert A.rotary_dim(8) == 0
 
@@ -246,16 +246,20 @@ def test_step_trains_and_only_router_layers_have_rows(host):
                                   host["blocks"][1]["router_bias"])
 
 
-def test_data_and_expert_axes_stay_open(host):
+def test_data_and_expert_axes_stay_open():
     """Two data members, each an expert group of two that shares its
     experts out, against two data members that hold them whole: the same
     loss and the same update."""
+    # one layer of each part and mixer, not nine: what the open axes
+    # are asked does not depend on the depth; seeded once for both meshes
+    cfg = parts_cfg(experts_held=(0, 8), layer_pattern=(M, E, A, E),
+                    n_layers=4)
+    whole = jax.tree.map(np.asarray, jax.jit(lambda: init_transformer(
+        jax.random.PRNGKey(0), cfg))())
+
     def one_step(**mesh):
         n = int(np.prod(list(mesh.values())))
         mc = MeshConfig(devices=jax.devices()[:n], **mesh)
-        cfg = parts_cfg(experts_held=(0, 8))
-        whole = jax.tree.map(np.asarray, init_transformer(
-            jax.random.PRNGKey(0), cfg))
         params = shard_params(mc, cfg, whole)
         opt = optax.sgd(1.0)
         params, _, loss = make_train_step(mc, cfg, opt)(

@@ -89,7 +89,11 @@ def oracle(mini_adapter, mini_params):
             return cache[key]
         prompt = np.asarray(prompt, np.int32)
         p = prompt.shape[0]
-        caches = ad.make_cache(1, p + max_new)
+        # a cache of whole 64s, not of p + max_new: the loop below runs
+        # op by op, and every new cache length compiled each of its ops
+        # again (40 s for twenty requests, 5 s at one length; positions
+        # past the clock are masked, the tokens are the same)
+        caches = ad.make_cache(1, -(-(p + max_new) // 64) * 64)
         offs = jnp.zeros((1,), jnp.int32)
         if p > 1:
             caches = ad.prefill(
