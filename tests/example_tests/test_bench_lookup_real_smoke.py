@@ -1,9 +1,9 @@
 """bench_lookup_real.py must work end-to-end before its first live
 TPU window (the round-4 lesson from bench_quality: a bench's first
 execution must never be a rare live window).  Drives the real flow at
-reduced steps: docs corpus -> BPE + LM training -> three generate.py
---lookup-k measurements (trained quote + two held-out) -> acceptance
-record."""
+reduced steps (20: what is pinned is the harness, not the number): docs
+corpus -> BPE + LM training -> three generate.py --lookup-k
+measurements (trained quote + two held-out) -> acceptance record."""
 
 import json
 import os
@@ -25,8 +25,8 @@ def test_bench_lookup_real_smoke_end_to_end():
     env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, os.path.join(_ROOT, "bench_lookup_real.py"),
-         "--platform", "cpu", "--steps", "60", "--timeouts", "1500"],
-        capture_output=True, text=True, timeout=1600, cwd=_ROOT, env=env)
+         "--platform", "cpu", "--steps", "20", "--timeouts", "240"],
+        capture_output=True, text=True, timeout=280, cwd=_ROOT, env=env)
     assert proc.returncode == 0, proc.stderr[-2000:]
     line = [l for l in proc.stdout.splitlines() if l.startswith("{")][-1]
     rec = json.loads(line)

@@ -25,8 +25,8 @@ def test_bench_quality_smoke_end_to_end():
     env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, os.path.join(_ROOT, "bench_quality.py"),
-         "--platform", "cpu", "--timeouts", "2400"],
-        capture_output=True, text=True, timeout=2500, cwd=_ROOT, env=env)
+         "--platform", "cpu", "--timeouts", "240"],
+        capture_output=True, text=True, timeout=280, cwd=_ROOT, env=env)
     assert proc.returncode == 0, proc.stderr[-2000:]
     line = [l for l in proc.stdout.splitlines() if l.startswith("{")][-1]
     rec = json.loads(line)

@@ -4,6 +4,7 @@ the reference's examples were its de-facto integration suite (run under
 user would launch them (fresh interpreter, CLI flags, tiny settings)."""
 
 import os
+import shutil
 import subprocess
 import sys
 
@@ -24,7 +25,7 @@ def _example_env():
     return env
 
 
-def _run_example(relpath, args, timeout=420, check=True):
+def _run_example(relpath, args, timeout=280, check=True):
     proc = subprocess.run(
         [sys.executable, os.path.join(_ROOT, relpath), "--platform", "cpu",
          *args],
@@ -45,12 +46,19 @@ def _run_example(relpath, args, timeout=420, check=True):
      ["--epoch", "1", "--batchsize", "64"]),
     ("examples/seq2seq/seq2seq.py",
      ["--epoch", "1", "--batchsize", "32", "--unit", "32"]),
-    ("examples/imagenet/train_imagenet.py",
-     ["--tiny", "--epoch", "1", "--batchsize", "64"]),
-    # tier-1 budget (ISSUE 15): googlenet (~28s) and the lars
-    # large-batch variant (~90s) are slow-marked — the resnet arch and
-    # the plain large-batch recipe keep the example paths gated in
-    # tier-1, and `-m slow` (or `-m ''`) still runs the full matrix
+    # tier-1 budget: googlenet (~28 s) and the lars variant (~90 s)
+    # are slow-marked since ISSUE 15, and since ISSUE 46 the plain
+    # ResNet-50 launch (49 s alone, 60 under six workers, no argument
+    # that makes it lighter): `test_imagenet_real_npz_path[npz-native]`
+    # launches this script with the same --tiny ResNet-50 through the
+    # same Trainer, evaluator and report end to end in tier-1, and the
+    # `resnet50-trainer-b256` cell runs the stack on the chip for every
+    # PR.  The plain large-batch recipe stays tier-1's one launch of
+    # its script.  `-m slow` (or `-m ''`) runs the full matrix
+    pytest.param(
+        "examples/imagenet/train_imagenet.py",
+        ["--tiny", "--epoch", "1", "--batchsize", "64"],
+        marks=pytest.mark.slow),
     pytest.param(
         "examples/imagenet/train_imagenet.py",
         ["--tiny", "--epoch", "1", "--batchsize", "64",
@@ -126,11 +134,20 @@ def test_generate_text_prompt_without_tokenizer_is_clean_error(tmp_path):
     assert "Traceback" not in proc.stderr, proc.stderr[-2000:]
 
 
-def test_train_then_generate_roundtrip(tmp_path):
-    ck = str(tmp_path / "ck")
+@pytest.fixture(scope="module")
+def dp_checkpoint(tmp_path_factory):
+    """``train_lm.py --mesh data=8 --steps 10 --checkpoint``, launched
+    once: the round trip through ``generate.py`` and the resume both
+    begin with this very launch, and go on from a copy of their own."""
+    ck = tmp_path_factory.mktemp("dp") / "ck"
     _run_example("examples/transformer/train_lm.py",
                  ["--mesh", "data=8", "--steps", "10",
-                  "--checkpoint", ck])
+                  "--checkpoint", str(ck)])
+    return ck
+
+
+def test_train_then_generate_roundtrip(tmp_path, dp_checkpoint):
+    ck = str(shutil.copytree(dp_checkpoint, tmp_path / "ck"))
     out = _run_example("examples/transformer/generate.py",
                        ["--checkpoint", ck, "--vocab", "128",
                         "--max-len", "16"])
@@ -361,11 +378,9 @@ def test_imagenet_real_npz_path(tmp_path, loader):
          "--out", str(tmp_path / "out")])
 
 
-def test_train_lm_checkpoint_resume(tmp_path):
+def test_train_lm_checkpoint_resume(tmp_path, dp_checkpoint):
     """--checkpoint writes a resumable state; a second run restores it."""
-    args = ["--mesh", "data=8", "--steps", "10",
-            "--checkpoint", str(tmp_path / "ck")]
-    _run_example("examples/transformer/train_lm.py", args)
+    shutil.copytree(dp_checkpoint, tmp_path / "ck")
     out = _run_example("examples/transformer/train_lm.py",
                        ["--mesh", "data=8", "--steps", "14",
                         "--checkpoint", str(tmp_path / "ck")])

@@ -30,34 +30,35 @@ def _inputs(key, t):
     return q, k, v, g, beta
 
 
-def _grads(fn, args):
-    return jax.grad(lambda *a: jnp.sum(jnp.sin(fn(*a))),
-                    argnums=(0, 1, 2, 3, 4))(*args)
+def _grads(jitted, fn, args):
+    return jitted(jax.grad(lambda *a: jnp.sum(jnp.sin(fn(*a))),
+                           argnums=(0, 1, 2, 3, 4)))(*args)
 
 
 # one short chunk; two chunks in one slab; six chunks in slabs of three
 # (four do not divide six); four slabs of two
 @pytest.mark.parametrize("t,chunk,slab,slabs", [
     (8, 16, 4, 1), (32, 16, 4, 1), (96, 16, 4, 2), (128, 16, 2, 4)])
-def test_chunked_is_the_recurrence(monkeypatch, t, chunk, slab, slabs):
+def test_chunked_is_the_recurrence(monkeypatch, jitted, t, chunk, slab,
+                                   slabs):
     monkeypatch.setattr(gdn, "CHUNK", chunk)
     monkeypatch.setattr(gdn, "SLAB", slab)
     args = _inputs(jax.random.PRNGKey(t), t)
     if t > chunk:
         sums = np.asarray(args[3]).reshape(B, t // chunk, chunk, HV).sum(2)
         assert sums.min() < -50
-    want = gdn.gdn_recurrent(*args)
+    want = jitted(gdn.gdn_recurrent)(*args)
     reg = MetricsRegistry(enabled=True)
     prev = set_registry(reg)
     try:
-        got = gdn.gdn_chunked(*args)
+        got = jitted(gdn.gdn_chunked)(*args)
     finally:
         set_registry(prev)
     assert got.dtype == jnp.float32 and got.shape == (B, t, HV, DV)
     np.testing.assert_allclose(
         got, want, atol=2e-5 * float(jnp.abs(want).max()))
-    for g, w in zip(_grads(gdn.gdn_chunked, args),
-                    _grads(gdn.gdn_recurrent, args)):
+    for g, w in zip(_grads(jitted, gdn.gdn_chunked, args),
+                    _grads(jitted, gdn.gdn_recurrent, args)):
         np.testing.assert_allclose(
             g, w, atol=5e-5 * float(jnp.abs(w).max()))
     n_chunks = -(-t // chunk)
@@ -67,7 +68,7 @@ def test_chunked_is_the_recurrence(monkeypatch, t, chunk, slab, slabs):
         == slabs * B * HV * DK * DV * 4
 
 
-def test_chunked_is_kda_under_a_channel_constant_decay():
+def test_chunked_is_kda_under_a_channel_constant_decay(jitted):
     """The yardstick the issue names: ``kda_chunked`` fed the scalar
     decay on all key channels and each key head copied out to its value
     heads computes the same thing (and pays for ``d_k`` exponentials a
@@ -82,12 +83,12 @@ def test_chunked_is_kda_under_a_channel_constant_decay():
             jnp.repeat(q, rep, axis=2), jnp.repeat(k, rep, axis=2), v,
             jnp.broadcast_to(g[..., None], (B, t, HV, DK)), beta)
 
-    want = through_kda(q, k, v, g, beta)
-    got = gdn.gdn_chunked(q, k, v, g, beta)
+    want = jitted(through_kda)(q, k, v, g, beta)
+    got = jitted(gdn.gdn_chunked)(q, k, v, g, beta)
     np.testing.assert_allclose(
         got, want, atol=2e-5 * float(jnp.abs(want).max()))
-    for a, w in zip(_grads(gdn.gdn_chunked, (q, k, v, g, beta)),
-                    _grads(through_kda, (q, k, v, g, beta))):
+    for a, w in zip(_grads(jitted, gdn.gdn_chunked, (q, k, v, g, beta)),
+                    _grads(jitted, through_kda, (q, k, v, g, beta))):
         np.testing.assert_allclose(
             a, w, atol=5e-5 * float(jnp.abs(w).max()))
 

@@ -77,22 +77,23 @@ def test_bwd_block_retune_grads_exact(bwd_q, bwd_k, window):
     the same gradients for ANY valid tiling — the correctness side of
     the bwd block retune lever (the OPT cells' flash.ms_per_step
     reads the perf side)."""
-    q, k, v = qkv(3)
-
-    def loss(bq, bk):
-        def f(q, k, v):
-            o = flash_attention(
-                q, k, v, causal=True, window=window, block_q=32,
-                block_k=32,
-                bwd_block_q=bq, bwd_block_k=bk, interpret=True)
-            return jnp.sum(o * jnp.cos(o))
-        return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-
-    g_default = loss(None, None)
-    g_retuned = loss(bwd_q, bwd_k)
+    g_default = _retune_grads(window, None, None)
+    g_retuned = _retune_grads(window, bwd_q, bwd_k)
     for a, b in zip(g_retuned, g_default):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=2e-5, atol=2e-6)
+
+
+@functools.cache
+def _retune_grads(window, bq, bk):
+    """The gradients at one backward tiling; the default tiling's are
+    every case's yardstick, made once a window."""
+    def f(q, k, v):
+        o = flash_attention(
+            q, k, v, causal=True, window=window, block_q=32, block_k=32,
+            bwd_block_q=bq, bwd_block_k=bk, interpret=True)
+        return jnp.sum(o * jnp.cos(o))
+    return jax.grad(f, argnums=(0, 1, 2))(*qkv(3))
 
 
 def test_global_offsets_match_sliced_oracle():

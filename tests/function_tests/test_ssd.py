@@ -34,27 +34,28 @@ def _loss(fn):
 # divide six) and four slabs of two
 @pytest.mark.parametrize("t,chunk,slab,slabs", [
     (16, 16, 4, 1), (32, 16, 4, 1), (96, 16, 4, 2), (128, 16, 2, 4)])
-def test_chunked_is_the_recurrence_in_float64(monkeypatch, t, chunk, slab,
-                                              slabs):
+def test_chunked_is_the_recurrence_in_float64(monkeypatch, jitted, t, chunk,
+                                              slab, slabs):
     monkeypatch.setattr(ssd, "CHUNK", chunk)
     monkeypatch.setattr(ssd, "SLAB", slab)
     args = _inputs(jax.random.PRNGKey(t), t)
     with jax.enable_x64(True):
         exact = tuple(jnp.asarray(np.asarray(v), jnp.float64) for v in args)
-        want = ssd.ssd_recurrent(*exact)
+        want = jitted(ssd.ssd_recurrent)(*exact)
         assert want.dtype == jnp.float64
-        want_grads = jax.grad(_loss(ssd.ssd_recurrent),
-                              argnums=(0, 1, 2, 3, 4))(*exact)
+        want_grads = jitted(jax.grad(_loss(ssd.ssd_recurrent),
+                                     argnums=(0, 1, 2, 3, 4)))(*exact)
     reg = MetricsRegistry(enabled=True)
     prev = set_registry(reg)
     try:
-        got = ssd.ssd_chunked(*args)
+        got = jitted(ssd.ssd_chunked)(*args)
     finally:
         set_registry(prev)
     assert got.dtype == jnp.float32 and got.shape == (B, t, H, P)
     scale = float(np.abs(want).max())
     np.testing.assert_allclose(got, np.asarray(want), atol=2e-5 * scale)
-    grads = jax.grad(_loss(ssd.ssd_chunked), argnums=(0, 1, 2, 3, 4))(*args)
+    grads = jitted(jax.grad(_loss(ssd.ssd_chunked),
+                            argnums=(0, 1, 2, 3, 4)))(*args)
     for g, w in zip(grads, want_grads):
         np.testing.assert_allclose(
             g, np.asarray(w), atol=5e-5 * float(np.abs(w).max()))

@@ -1,5 +1,7 @@
 """Convnet zoo: shapes, finiteness, DP-train smoke for each arch."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,11 +20,13 @@ from chainermn_tpu.parallel import MeshConfig
 B, HW, C = 8, 32, 8
 
 
+@functools.cache
 def seeds(cfg):
     """The parameters at their seeds, made by ONE compiled program: op
     by op a 22-layer net's init compiles every draw at every shape, most
     of the 41 s the GoogLeNet forward case took of a 1,319 s tier-1 run
-    (PR 45)."""
+    (PR 45).  Once a config: GoogLeNet's program alone compiles for
+    13 s, and two cases seed the same one."""
     return jax.jit(lambda: init_convnet(jax.random.PRNGKey(0), cfg))()
 
 

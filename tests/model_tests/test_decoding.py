@@ -4,20 +4,55 @@ must be self-consistent, and the cache must carry GQA's shared-head
 width.  Covers single-device, DP+TP meshes, GQA, and virtual-pipe
 packed params."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import optax
 import pytest
 
+from chainermn_tpu import models
 from chainermn_tpu.models import (
     TransformerConfig,
     init_transformer,
-    make_forward_fn,
-    make_generate_fn,
+    quantize_params_int8,
+    regroup_blocks,
     shard_params,
 )
 from chainermn_tpu.models.decoding import _decode_step
 from chainermn_tpu.parallel import MeshConfig
+
+
+def _built_once(make):
+    """Every call of a ``make_*_fn`` is a jitted program of its own,
+    compiled again whatever an earlier case compiled: the cases here ask
+    for the same few (one-device greedy oracle, speculative k=3, the
+    train step of ``_trained_host``) dozens of times, so keep one a
+    (mesh, configs, options) for the module (by hand: a ``MeshConfig``
+    does not hash, so ``functools.cache`` cannot key on it).  A refusal
+    is raised before anything is kept."""
+    built = {}
+
+    @functools.wraps(make)
+    def cached(mc, *cfgs, **kw):
+        key = (mc.pipe, mc.data, mc.expert, mc.seq, mc.model,
+               tuple(d.id for d in mc.mesh.devices.flat), cfgs,
+               tuple(sorted(kw.items())))
+        if key not in built:
+            built[key] = make(mc, *cfgs, **kw)
+        return built[key]
+
+    return cached
+
+
+make_forward_fn = _built_once(models.make_forward_fn)
+make_generate_fn = _built_once(models.make_generate_fn)
+make_beam_search_fn = _built_once(models.make_beam_search_fn)
+make_speculative_generate_fn = _built_once(
+    models.make_speculative_generate_fn)
+make_lookup_generate_fn = _built_once(models.make_lookup_generate_fn)
+make_train_step = _built_once(models.make_train_step)
 
 
 VOCAB, B, T = 64, 4, 16
@@ -37,6 +72,27 @@ def prompt(seed=0, length=T):
     return jnp.asarray(
         np.random.RandomState(seed).randint(0, VOCAB, (B, length)),
         jnp.int32)
+
+
+_ADAM = optax.adam(1e-2)
+
+
+@functools.cache
+def _trained_host(cfg, seed):
+    """``cfg`` from ``seed`` after 30 Adam steps, as numpy: the same
+    dozen (config, seed) pairs are asked for by forty cases, and the
+    steps are deterministic."""
+    one = MeshConfig(data=1, devices=jax.devices()[:1])
+    params = shard_params(
+        one, cfg, init_transformer(jax.random.PRNGKey(seed), cfg))
+    st = jax.jit(_ADAM.init)(params)
+    step = make_train_step(one, cfg, _ADAM)
+    x = jnp.asarray(
+        (np.arange(B * (T + 1)).reshape(B, T + 1) * 7 + 3) % VOCAB,
+        jnp.int32)
+    for _ in range(30):
+        params, st, _ = step(params, st, x[:, :T], x[:, 1:])
+    return jax.tree.map(np.asarray, params)
 
 
 def _cached_logits_all_positions(cfg, params, toks, mc):
@@ -303,8 +359,6 @@ def test_seq_kv_beam_matches_single_device():
     """Beam search with the length-blocked cache: token- and
     score-identical to the seq=1 oracle (the beam path reorders caches
     per step — the reorder must commute with the seq blocking)."""
-    from chainermn_tpu.models import make_beam_search_fn
-
     cfg = tiny_cfg()
     host = init_transformer(jax.random.PRNGKey(5), cfg)
     p = prompt(seed=10, length=4)
@@ -475,8 +529,6 @@ class TestPaddedPrompts:
 
     def _beam_padded_vs_solo(self, cfg, score_rtol=1e-5,
                              score_atol=1e-5):
-        from chainermn_tpu.models import make_beam_search_fn
-
         host = init_transformer(jax.random.PRNGKey(7), cfg)
         P_len, G, K = 6, 6, 2
         rng = np.random.RandomState(32)
@@ -548,25 +600,6 @@ class TestSpeculative:
     steps make the argmax decisive (the realistic regime; near-tie
     flips are an fp artifact, not a speculative-logic property)."""
 
-    def _trained_host(self, cfg, seed):
-        import optax
-
-        from chainermn_tpu.models import make_train_step
-
-        one = MeshConfig(data=1, devices=jax.devices()[:1])
-        params = shard_params(
-            one, cfg, init_transformer(jax.random.PRNGKey(seed), cfg))
-        opt = optax.adam(1e-2)
-        st = jax.jit(opt.init)(params)
-        step = make_train_step(one, cfg, opt)
-        rng = np.random.RandomState(seed)
-        x = jnp.asarray(
-            (np.arange(B * (T + 1)).reshape(B, T + 1) * 7 + 3) % VOCAB,
-            jnp.int32)
-        for _ in range(30):
-            params, st, _ = step(params, st, x[:, :T], x[:, 1:])
-        return jax.tree.map(np.asarray, params)
-
     def _target_greedy(self, cfg, host, p, max_len):
         one = MeshConfig(data=1, devices=jax.devices()[:1])
         return np.asarray(
@@ -577,10 +610,8 @@ class TestSpeculative:
     def test_perfect_draft_matches_greedy(self, k):
         """Draft == target: every proposal verifies, rounds stride k+1
         — and the tokens are exactly the greedy sequence."""
-        from chainermn_tpu.models import make_speculative_generate_fn
-
         cfg = tiny_cfg()
-        host = self._trained_host(cfg, 0)
+        host = _trained_host(cfg, 0)
         p = prompt(seed=12, length=4)
         ref = self._target_greedy(cfg, host, p, T)
 
@@ -597,12 +628,10 @@ class TestSpeculative:
         """A DIFFERENT (shallower, differently-initialised) draft:
         acceptance is partial and the corrective path runs — output
         still exactly the target's greedy tokens."""
-        from chainermn_tpu.models import make_speculative_generate_fn
-
         cfg = tiny_cfg(n_layers=4)
         d_cfg = tiny_cfg(n_layers=2)
-        host = self._trained_host(cfg, 0)
-        d_host = self._trained_host(d_cfg, 9)
+        host = _trained_host(cfg, 0)
+        d_host = _trained_host(d_cfg, 9)
         p = prompt(seed=13, length=4)
         ref = self._target_greedy(cfg, host, p, T)
 
@@ -615,12 +644,10 @@ class TestSpeculative:
         assert 0.0 <= float(mean_acc) <= 3.0
 
     def test_tp_mesh_matches_greedy(self):
-        from chainermn_tpu.models import make_speculative_generate_fn
-
         cfg = tiny_cfg(n_layers=4)
         d_cfg = tiny_cfg(n_layers=2)
-        host = self._trained_host(cfg, 1)
-        d_host = self._trained_host(d_cfg, 8)
+        host = _trained_host(cfg, 1)
+        d_host = _trained_host(d_cfg, 8)
         p = prompt(seed=14, length=4)
         ref = self._target_greedy(cfg, host, p, T)
 
@@ -638,12 +665,10 @@ class TestSpeculative:
         greedy oracle exactly."""
         import dataclasses
 
-        from chainermn_tpu.models import make_speculative_generate_fn
-
         cfg = tiny_cfg(n_layers=4)
         d_cfg = tiny_cfg(n_layers=2)
-        host = self._trained_host(cfg, 1)
-        d_host = self._trained_host(d_cfg, 8)
+        host = _trained_host(cfg, 1)
+        d_host = _trained_host(d_cfg, 8)
         p = prompt(seed=14, length=4)
         ref = self._target_greedy(cfg, host, p, T)
 
@@ -659,16 +684,12 @@ class TestSpeculative:
     def test_pipe_mesh_matches_greedy(self):
         """PP-decode composes: the verify chunk rides the S-phase
         ppermute hand-off with stage-masked cache writes."""
-        from chainermn_tpu.models import make_speculative_generate_fn
-
         cfg = tiny_cfg(n_layers=4)
         d_cfg = tiny_cfg(n_layers=2)
-        host = self._trained_host(cfg, 2)
-        d_host = self._trained_host(d_cfg, 7)
+        host = _trained_host(cfg, 2)
+        d_host = _trained_host(d_cfg, 7)
         p = prompt(seed=15, length=4)
         ref = self._target_greedy(cfg, host, p, T)
-
-        from chainermn_tpu.models import regroup_blocks
 
         mc = MeshConfig(pipe=2, data=2, devices=jax.devices()[:4])
         spec = make_speculative_generate_fn(mc, cfg, d_cfg, k=3,
@@ -684,13 +705,10 @@ class TestSpeculative:
         """Weight-only int8 target + draft: tokens equal the int8
         target's own greedy decode (int8 changes the logits, so the
         oracle is the QUANTIZED greedy run)."""
-        from chainermn_tpu.models import (
-            make_speculative_generate_fn, quantize_params_int8)
-
         cfg = tiny_cfg(n_layers=4)
         d_cfg = tiny_cfg(n_layers=2)
-        host = quantize_params_int8(cfg, self._trained_host(cfg, 3))
-        d_host = quantize_params_int8(d_cfg, self._trained_host(d_cfg, 6))
+        host = quantize_params_int8(cfg, _trained_host(cfg, 3))
+        d_host = quantize_params_int8(d_cfg, _trained_host(d_cfg, 6))
         p = prompt(seed=16, length=4)
 
         one = MeshConfig(data=1, devices=jax.devices()[:1])
@@ -712,13 +730,11 @@ class TestSpeculative:
         oracle; int8-KV changes the logits)."""
         import dataclasses
 
-        from chainermn_tpu.models import make_speculative_generate_fn
-
         cfg = tiny_cfg(n_layers=4, kv_cache_dtype="int8")
         d_cfg = tiny_cfg(n_layers=2, kv_cache_dtype="int8")
-        host = self._trained_host(
+        host = _trained_host(
             dataclasses.replace(cfg, kv_cache_dtype=""), 3)
-        d_host = self._trained_host(
+        d_host = _trained_host(
             dataclasses.replace(d_cfg, kv_cache_dtype=""), 6)
         p = prompt(seed=19, length=4)
         one = MeshConfig(data=1, devices=jax.devices()[:1])
@@ -740,11 +756,9 @@ class TestSpeculative:
         writing this test), so this pins the two properties the bench
         row rests on: acceptance well above the random floor, and
         token-exact greedy output regardless."""
-        from chainermn_tpu.models import make_speculative_generate_fn
-
         cfg = tiny_cfg(n_layers=4)
         d_cfg = tiny_cfg(n_layers=2)
-        host = self._trained_host(cfg, 0)
+        host = _trained_host(cfg, 0)
 
         def damp(name, a):
             if name not in ("wo", "w2"):
@@ -778,12 +792,10 @@ class TestSpeculative:
         the accepted proposal at an early cut measured TV 0.156 here;
         the exact scheme measures ~0.077 against ~0.085 expected
         noise)."""
-        from chainermn_tpu.models import make_speculative_generate_fn
-
         cfg = tiny_cfg(n_layers=2)
         d_cfg = tiny_cfg(n_layers=1)
-        host = self._trained_host(cfg, 0)
-        d_host = self._trained_host(d_cfg, 9)
+        host = _trained_host(cfg, 0)
+        d_host = _trained_host(d_cfg, 9)
         one = MeshConfig(data=1, devices=jax.devices()[:1])
         params = shard_params(one, cfg, host)
         d_params = shard_params(one, d_cfg, d_host)
@@ -812,12 +824,10 @@ class TestSpeculative:
         assert tv < 1.6 * noise + 0.02, (tv, noise)
 
     def test_sampling_runs_sharded_and_needs_key(self):
-        from chainermn_tpu.models import make_speculative_generate_fn
-
         cfg = tiny_cfg(n_layers=4)
         d_cfg = tiny_cfg(n_layers=2)
-        host = self._trained_host(cfg, 1)
-        d_host = self._trained_host(d_cfg, 8)
+        host = _trained_host(cfg, 1)
+        d_host = _trained_host(d_cfg, 8)
         mc = MeshConfig(data=2, model=2, devices=jax.devices()[:4])
         spec = make_speculative_generate_fn(
             mc, cfg, d_cfg, k=3, max_len=T, temperature=0.8,
@@ -836,8 +846,6 @@ class TestSpeculative:
         assert not np.array_equal(np.asarray(a), np.asarray(b))
 
     def test_validation(self):
-        from chainermn_tpu.models import make_speculative_generate_fn
-
         cfg = tiny_cfg()
         one = MeshConfig(data=1, devices=jax.devices()[:1])
         with pytest.raises(ValueError, match="k="):
@@ -862,12 +870,10 @@ class TestSpeculative:
         token-identical to make_generate_fn's eos run (first eos kept,
         tail padded), with a draft bad enough that the corrective path
         runs across the freeze boundary."""
-        from chainermn_tpu.models import make_speculative_generate_fn
-
         cfg = tiny_cfg(n_layers=4)
         d_cfg = tiny_cfg(n_layers=2)
-        host = self._trained_host(cfg, 0)
-        d_host = self._trained_host(d_cfg, 9)
+        host = _trained_host(cfg, 0)
+        d_host = _trained_host(d_cfg, 9)
         p = prompt(seed=18, length=4)
         one = MeshConfig(data=1, devices=jax.devices()[:1])
         params = shard_params(one, cfg, host)
@@ -886,12 +892,10 @@ class TestSpeculative:
         """Rows freeze at different times across data shards: the
         pmax'd stop flag and the frozen rows' forced-k acceptance must
         keep every shard in lockstep to the global last row."""
-        from chainermn_tpu.models import make_speculative_generate_fn
-
         cfg = tiny_cfg(n_layers=4)
         d_cfg = tiny_cfg(n_layers=2)
-        host = self._trained_host(cfg, 0)
-        d_host = self._trained_host(d_cfg, 9)
+        host = _trained_host(cfg, 0)
+        d_host = _trained_host(d_cfg, 9)
         p = prompt(seed=18, length=4)
         one = MeshConfig(data=1, devices=jax.devices()[:1])
         plain = self._target_greedy(cfg, host, p, T)
@@ -910,12 +914,10 @@ class TestSpeculative:
         """Variable-length prompts ride through the draft steps and
         verify chunks: token-identical to make_generate_fn's padded
         greedy run on the same rows."""
-        from chainermn_tpu.models import make_speculative_generate_fn
-
         cfg = tiny_cfg(n_layers=4, pos_embedding="rope")
         d_cfg = tiny_cfg(n_layers=2, pos_embedding="rope")
-        host = self._trained_host(cfg, 0)
-        d_host = self._trained_host(d_cfg, 9)
+        host = _trained_host(cfg, 0)
+        d_host = _trained_host(d_cfg, 9)
         P_len = 4
         lens = np.asarray([4, 3, 2, 4])
         rng = np.random.RandomState(33)
@@ -936,12 +938,10 @@ class TestSpeculative:
     def test_eos_and_padded_compose(self):
         """The full serving shape at once: ragged prompts AND eos early
         stop, still token-identical to the plain generator."""
-        from chainermn_tpu.models import make_speculative_generate_fn
-
         cfg = tiny_cfg(n_layers=4, pos_embedding="rope")
         d_cfg = tiny_cfg(n_layers=2, pos_embedding="rope")
-        host = self._trained_host(cfg, 0)
-        d_host = self._trained_host(d_cfg, 9)
+        host = _trained_host(cfg, 0)
+        d_host = _trained_host(d_cfg, 9)
         P_len = 4
         lens = np.asarray([4, 3, 2, 4])
         rng = np.random.RandomState(34)
@@ -969,12 +969,10 @@ class TestSpeculative:
         a row's first generated eos is pad (the distribution identity
         itself is pinned by the statistical tests; this pins the
         composition's bookkeeping)."""
-        from chainermn_tpu.models import make_speculative_generate_fn
-
         cfg = tiny_cfg(n_layers=2, pos_embedding="rope")
         d_cfg = tiny_cfg(n_layers=1, pos_embedding="rope")
-        host = self._trained_host(cfg, 0)
-        d_host = self._trained_host(d_cfg, 9)
+        host = _trained_host(cfg, 0)
+        d_host = _trained_host(d_cfg, 9)
         one = MeshConfig(data=1, devices=jax.devices()[:1])
         params = shard_params(one, cfg, host)
         d_params = shard_params(one, d_cfg, d_host)
@@ -1009,13 +1007,12 @@ class TestSpeculative:
         the target directly WITH the same filters (truncate both
         p_draft and p_target, renormalize, exact residual) — same
         statistical design as the unfiltered test."""
-        from chainermn_tpu.models import make_speculative_generate_fn
         from chainermn_tpu.models.decoding import _filter_logits
 
         cfg = tiny_cfg(n_layers=2)
         d_cfg = tiny_cfg(n_layers=1)
-        host = self._trained_host(cfg, 0)
-        d_host = self._trained_host(d_cfg, 9)
+        host = _trained_host(cfg, 0)
+        d_host = _trained_host(d_cfg, 9)
         one = MeshConfig(data=1, devices=jax.devices()[:1])
         params = shard_params(one, cfg, host)
         d_params = shard_params(one, d_cfg, d_host)
@@ -1050,16 +1047,10 @@ class TestLookupDecoding:
     n-gram matcher proposes, and real acceptance on the workloads it
     exists for (repetitive/copying text)."""
 
-    def _trained(self, cfg, seed=0):
-        return TestSpeculative._trained_host(
-            TestSpeculative(), cfg, seed)
-
     @pytest.mark.parametrize("k,ngram", [(2, 1), (4, 2), (3, 3)])
     def test_matches_greedy(self, k, ngram):
-        from chainermn_tpu.models import make_lookup_generate_fn
-
         cfg = tiny_cfg()
-        host = self._trained(cfg)
+        host = _trained_host(cfg, 0)
         p = prompt(seed=40, length=4)
         one = MeshConfig(data=1, devices=jax.devices()[:1])
         params = shard_params(one, cfg, host)
@@ -1076,10 +1067,8 @@ class TestLookupDecoding:
         with IDENTICAL rows (acceptance is batch-min — mixed batches
         clamp to the worst row) lookup proposals must land at least
         once, proving the matcher finds real earlier occurrences."""
-        from chainermn_tpu.models import make_lookup_generate_fn
-
         cfg = tiny_cfg()
-        host = self._trained(cfg)
+        host = _trained_host(cfg, 0)
         one = MeshConfig(data=1, devices=jax.devices()[:1])
         params = shard_params(one, cfg, host)
         row = np.random.RandomState(40).randint(0, VOCAB, 4)
@@ -1093,10 +1082,8 @@ class TestLookupDecoding:
         assert float(acc) > 0.05, float(acc)
 
     def test_tp_mesh_matches_greedy(self):
-        from chainermn_tpu.models import make_lookup_generate_fn
-
         cfg = tiny_cfg(n_layers=4)
-        host = self._trained(cfg, 1)
+        host = _trained_host(cfg, 1)
         p = prompt(seed=42, length=4)
         one = MeshConfig(data=1, devices=jax.devices()[:1])
         ref = np.asarray(
@@ -1114,10 +1101,8 @@ class TestLookupDecoding:
         logits all-gather before the argmax compare)."""
         import dataclasses
 
-        from chainermn_tpu.models import make_lookup_generate_fn
-
         cfg = tiny_cfg(n_layers=4)
-        host = self._trained(cfg, 1)
+        host = _trained_host(cfg, 1)
         p = prompt(seed=42, length=4)
         one = MeshConfig(data=1, devices=jax.devices()[:1])
         ref = np.asarray(
@@ -1134,11 +1119,8 @@ class TestLookupDecoding:
         """Lookup decoding over pipe-parallel decode: the verify chunk
         rides the S-phase ppermute hand-off with stage-masked cache
         writes, the matcher stays host-side row-local."""
-        from chainermn_tpu.models import (
-            make_lookup_generate_fn, regroup_blocks)
-
         cfg = tiny_cfg(n_layers=4)
-        host = self._trained(cfg, 2)
+        host = _trained_host(cfg, 2)
         p = prompt(seed=45, length=4)
         one = MeshConfig(data=1, devices=jax.devices()[:1])
         ref = np.asarray(
@@ -1155,11 +1137,8 @@ class TestLookupDecoding:
         """Lookup decoding over weight-only int8: exact vs the int8
         greedy oracle (int8 changes the logits, so the quantized run
         is the right reference)."""
-        from chainermn_tpu.models import (
-            make_lookup_generate_fn, quantize_params_int8)
-
         cfg = tiny_cfg(n_layers=4)
-        host = quantize_params_int8(cfg, self._trained(cfg, 2))
+        host = quantize_params_int8(cfg, _trained_host(cfg, 2))
         p = prompt(seed=43, length=4)
         one = MeshConfig(data=1, devices=jax.devices()[:1])
         params = shard_params(one, cfg, host)
@@ -1172,8 +1151,6 @@ class TestLookupDecoding:
         np.testing.assert_array_equal(got, ref)
 
     def test_validation(self):
-        from chainermn_tpu.models import make_lookup_generate_fn
-
         cfg = tiny_cfg()
         one = MeshConfig(data=1, devices=jax.devices()[:1])
         with pytest.raises(ValueError, match="k="):
@@ -1192,10 +1169,8 @@ class TestLookupDecoding:
     def test_eos_matches_generate_eos(self):
         """eos early stop composes with lookup decoding: output
         token-identical to make_generate_fn's eos run."""
-        from chainermn_tpu.models import make_lookup_generate_fn
-
         cfg = tiny_cfg(n_layers=4)
-        host = self._trained(cfg, 1)
+        host = _trained_host(cfg, 1)
         p = prompt(seed=44, length=4)
         one = MeshConfig(data=1, devices=jax.devices()[:1])
         params = shard_params(one, cfg, host)
@@ -1214,10 +1189,8 @@ class TestLookupDecoding:
         """Ragged prompts through the lookup matcher: windows touching
         pad slots propose garbage, verification keeps the output
         token-identical to the plain padded generator."""
-        from chainermn_tpu.models import make_lookup_generate_fn
-
         cfg = tiny_cfg(n_layers=4, pos_embedding="rope")
-        host = self._trained(cfg, 1)
+        host = _trained_host(cfg, 1)
         P_len = 4
         lens = np.asarray([4, 3, 2, 4])
         rng = np.random.RandomState(35)
@@ -1235,10 +1208,8 @@ class TestLookupDecoding:
         np.testing.assert_array_equal(got, ref)
 
     def test_eos_and_padded_compose(self):
-        from chainermn_tpu.models import make_lookup_generate_fn
-
         cfg = tiny_cfg(n_layers=4, pos_embedding="rope")
-        host = self._trained(cfg, 1)
+        host = _trained_host(cfg, 1)
         P_len = 4
         lens = np.asarray([4, 3, 2, 4])
         rng = np.random.RandomState(36)
@@ -1280,8 +1251,6 @@ def test_virtual_pipe_packed_params_decode():
 
 class TestBeamSearch:
     def test_beam1_equals_greedy(self):
-        from chainermn_tpu.models import make_beam_search_fn
-
         cfg = tiny_cfg()
         mc = MeshConfig(data=1, devices=jax.devices()[:1])
         params = shard_params(
@@ -1298,8 +1267,6 @@ class TestBeamSearch:
         """Small vocab, short horizon: a wide beam must recover the true
         argmax sequence found by brute-force enumeration."""
         from itertools import product
-
-        from chainermn_tpu.models import make_beam_search_fn
 
         V, Plen, G = 6, 2, 3          # 6^3 = 216 continuations
         cfg = tiny_cfg(vocab_size=V, max_seq=Plen + G)
@@ -1336,8 +1303,6 @@ class TestBeamSearch:
             np.asarray(scores[:, 0]), best_score, rtol=1e-4, atol=1e-4)
 
     def test_eos_freezes_hypotheses(self):
-        from chainermn_tpu.models import make_beam_search_fn
-
         cfg = tiny_cfg()
         mc = MeshConfig(data=1, devices=jax.devices()[:1])
         params = shard_params(
@@ -1354,8 +1319,6 @@ class TestBeamSearch:
         assert (np.diff(s, axis=1) <= 1e-6).all(), s
 
     def test_dp_tp_mesh(self):
-        from chainermn_tpu.models import make_beam_search_fn
-
         cfg = tiny_cfg(n_kv_heads=2)
         mc = MeshConfig(data=4, model=2)
         params = shard_params(
@@ -1377,9 +1340,6 @@ class TestBeamSearch:
         tokens+scores equal the single-device int8 beam run (int8
         changes the logits, so the quantized single-device run is the
         right oracle)."""
-        from chainermn_tpu.models import (
-            make_beam_search_fn, quantize_params_int8)
-
         cfg = tiny_cfg()
         host = quantize_params_int8(
             cfg, init_transformer(jax.random.PRNGKey(0), cfg))
@@ -1419,8 +1379,6 @@ def test_pp_decode_matches_single_device():
 def test_pp_decode_beam_and_guards():
     """Beam search rides the same pipe-parallel step; virtual-pipe and
     seq meshes stay clearly rejected."""
-    from chainermn_tpu.models import make_beam_search_fn
-
     cfg = tiny_cfg(n_layers=4)
     toks = prompt(length=6)
     one = MeshConfig(data=1, devices=jax.devices()[:1])

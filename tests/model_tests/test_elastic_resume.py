@@ -4,6 +4,8 @@ pipe grouping (blocks regrouped), a different at-rest layout (fsdp) —
 with the same loss trajectory.  Beyond the reference: ChainerMN's
 checkpointer required restart at the identical world size."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -87,6 +89,30 @@ def _run_steps(step, params, opt_state, toks, n):
     return params, opt_state, losses
 
 
+@functools.cache
+def _snapshot_and_uninterrupted():
+    """The data=4 run every target resumes from: two steps, the host
+    snapshot, then the three uninterrupted steps whose losses a resumed
+    run must match.  The same for all six targets, so made once."""
+    toks = tokens(7)
+    opt = optax.adam(1e-2)
+    cfg_a = tiny_cfg()
+    mc_a = MeshConfig(data=4, devices=jax.devices()[:4])
+    params = shard_params(
+        mc_a, cfg_a, init_transformer(jax.random.PRNGKey(0), cfg_a))
+    opt_state = jax.jit(opt.init)(params)
+    step_a = make_train_step(mc_a, cfg_a, opt)
+    params, opt_state, _ = _run_steps(step_a, params, opt_state, toks, 2)
+
+    # host snapshot, BEFORE the donated buffers are consumed further
+    host_p = jax.tree.map(np.asarray, params)
+    host_o = jax.tree.map(np.asarray, opt_state)
+
+    # uninterrupted continuation on mesh A
+    _, _, ref = _run_steps(step_a, params, opt_state, toks, 3)
+    return host_p, host_o, ref
+
+
 RESUME_TARGETS = [
     ("data8", dict(), dict(data=8)),
     ("pipe2_gpipe", dict(num_microbatches=2), dict(pipe=2, data=2)),
@@ -112,21 +138,7 @@ def test_elastic_resume_matches_uninterrupted(name, cfg_kw, axes):
     details of the same math)."""
     toks = tokens(7)
     opt = optax.adam(1e-2)
-
-    cfg_a = tiny_cfg()
-    mc_a = MeshConfig(data=4, devices=jax.devices()[:4])
-    params = shard_params(
-        mc_a, cfg_a, init_transformer(jax.random.PRNGKey(0), cfg_a))
-    opt_state = jax.jit(opt.init)(params)
-    step_a = make_train_step(mc_a, cfg_a, opt)
-    params, opt_state, pre = _run_steps(step_a, params, opt_state, toks, 2)
-
-    # host snapshot, BEFORE the donated buffers are consumed further
-    host_p = jax.tree.map(np.asarray, params)
-    host_o = jax.tree.map(np.asarray, opt_state)
-
-    # uninterrupted continuation on mesh A
-    _, _, ref = _run_steps(step_a, params, opt_state, toks, 3)
+    host_p, host_o, ref = _snapshot_and_uninterrupted()
 
     # resharded continuation on mesh B
     cfg_b = tiny_cfg(**cfg_kw)

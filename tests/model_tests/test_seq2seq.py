@@ -38,14 +38,17 @@ def ragged_batch(n, max_len=8, seed=0):
 def test_loss_finite_and_padding_invariant():
     params = init_seq2seq(jax.random.PRNGKey(0), CFG)
     src, tgt = ragged_batch(8)
-    loss = seq2seq_loss(CFG, params, src, tgt)
+    # one compiled program a shape: eagerly the two-layer recurrence's
+    # every op is a program of its own, at both widths
+    loss_fn = jax.jit(lambda p, s, t: seq2seq_loss(CFG, p, s, t))
+    loss = loss_fn(params, src, tgt)
     assert np.isfinite(float(loss))
 
     # extra all-PAD columns must not change the loss (mask semantics)
     pad_s = jnp.full((8, 4), PAD, jnp.int32)
     pad_t = jnp.full((8, 4), PAD, jnp.int32)
-    loss2 = seq2seq_loss(
-        CFG, params,
+    loss2 = loss_fn(
+        params,
         jnp.concatenate([src, pad_s], axis=1),
         jnp.concatenate([tgt, pad_t], axis=1))
     np.testing.assert_allclose(float(loss), float(loss2), rtol=1e-5)
@@ -111,8 +114,8 @@ def test_dp_grads_match_single_device_on_ragged_batch():
         out_specs=(P(), P())))
     loss_dp, g_dp = f(params, src, tgt)
 
-    loss_1, g_1 = jax.value_and_grad(
-        lambda q: seq2seq_loss(CFG, q, src, tgt))(params)
+    loss_1, g_1 = jax.jit(jax.value_and_grad(
+        lambda q: seq2seq_loss(CFG, q, src, tgt)))(params)
     np.testing.assert_allclose(float(loss_dp), float(loss_1), rtol=1e-5)
     jax.tree.map(
         lambda a, b: np.testing.assert_allclose(

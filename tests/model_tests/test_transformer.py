@@ -3,6 +3,7 @@ single-device oracle (the multi-axis run must be numerically identical —
 SPMD sharding is an implementation detail, not a semantics change)."""
 
 import collections
+import functools
 from functools import partial
 
 import jax
@@ -40,9 +41,16 @@ def tokens(seed=0):
         jnp.int32)
 
 
+@functools.cache
+def _one_device_forward(cfg):
+    """The oracle's program, built once a config: eight cases ask for
+    ``tiny_cfg()``'s, and each ``make_forward_fn`` compiles its own."""
+    return make_forward_fn(
+        MeshConfig(data=1, devices=jax.devices()[:1]), cfg)
+
+
 def oracle_logits(cfg, params, toks):
-    one = MeshConfig(data=1, devices=jax.devices()[:1])
-    return make_forward_fn(one, cfg)(params, toks)
+    return _one_device_forward(cfg)(params, toks)
 
 
 MESHES = [
@@ -95,10 +103,25 @@ def _mesh(**axes):
     return MeshConfig(devices=jax.devices()[:n], **(axes or dict(data=1)))
 
 
+_LM_GRADS = {}
+
+
 def _lm_grad(cfg, x, y, **axes):
     """``(gradient of lm_loss on the mesh, the census of its traced
     program)``; the blocks run under whatever ``cfg.checkpoint_fn`` is
-    when called."""
+    when called.  Kept a (config, mesh, ``checkpoint_fn`` in force):
+    every case feeds ``tokens()``, and the remat cases ask for the same
+    ``remat=False`` and ``remat=True`` gradients once a policy."""
+    key = (cfg, tuple(sorted(axes.items())),
+           TransformerConfig.__dict__["checkpoint_fn"])
+    if key not in _LM_GRADS:
+        traced, params = _lm_grad_traced(cfg, x, y, **axes)
+        _LM_GRADS[key] = (traced.lower().compile()(params, x, y),
+                          _census(traced.jaxpr.jaxpr))
+    return _LM_GRADS[key]
+
+
+def _lm_grad_traced(cfg, x, y, **axes):
     from jax.sharding import PartitionSpec as P
 
     from chainermn_tpu.models.transformer import lm_loss, param_specs
@@ -114,9 +137,7 @@ def _lm_grad(cfg, x, y, **axes):
             lambda q: lm_loss(cfg, q, xx, yy))(p)),
         mesh=mc.mesh, in_specs=(specs, batch_spec, batch_spec),
         out_specs=specs)
-    traced = jax.jit(grad_fn).trace(params, x, y)
-    return traced.lower().compile()(params, x, y), _census(
-        traced.jaxpr.jaxpr)
+    return jax.jit(grad_fn).trace(params, x, y), params
 
 
 def _same(a, b):
@@ -169,6 +190,9 @@ def _gated_kw():
                     "sliding", window=8, rope_theta=5e5, n_heads=2), full))
 
 
+_PLAIN = property(lambda self: jax.checkpoint)
+
+
 @pytest.mark.parametrize("policy", ["full", "dots"])
 @pytest.mark.parametrize("layers", ["uniform", "window+full",
                                     "leading+gated+heads"])
@@ -189,9 +213,16 @@ def test_flash_forward_runs_once_under_remat(monkeypatch, layers, policy):
     cfg = tiny_cfg(remat=True, remat_policy=policy, **kw)
     kept, n_kept = _lm_grad(cfg, x, y)
     none, n_none = _lm_grad(tiny_cfg(**kw), x, y)
-    monkeypatch.setattr(TransformerConfig, "checkpoint_fn",
-                        property(lambda self: jax.checkpoint))
-    plain, n_plain = _lm_grad(cfg, x, y)
+    # plain ``jax.checkpoint`` reads no policy (``checkpoint_fn`` is the
+    # field's one reader), so both policies are held to one gradient
+    # under it, made once a layer set: the ``"full"`` config's, which
+    # under it is this config's program, equation for equation
+    monkeypatch.setattr(TransformerConfig, "checkpoint_fn", _PLAIN)
+    full = tiny_cfg(remat=True, remat_policy="full", **kw)
+    plain, n_plain = _lm_grad(full, x, y)
+    if cfg != full:
+        assert str(_lm_grad_traced(cfg, x, y)[0].jaxpr) \
+            == str(_lm_grad_traced(full, x, y)[0].jaxpr)
     assert [n["pallas_call"] for n in (n_none, n_kept, n_plain)] == [
         2 * kinds, 2 * kinds, 3 * kinds]
     _same(kept, none)

@@ -98,6 +98,23 @@ def _draw(seed, t, decay, b=2, h=3, d=16):
             jax.nn.sigmoid(jax.random.normal(ks[5], (b, t, h))))
 
 
+def _weighted(fn):
+    return lambda weight, *a: jnp.sum(fn(*a) * weight)
+
+
+def _with_grads(fn):
+    """``fn``'s values and its five gradients under a weight, as one
+    program."""
+    return jax.jit(lambda weight, *a: (fn(*a), jax.grad(
+        _weighted(fn), argnums=(1, 2, 3, 4, 5))(weight, *a)))
+
+
+# compiled once a length: the two decays of a chunk count run the same
+# two programs, and the token-by-token form run eagerly dispatches
+# every token's ops, its gradient every token's again
+_CHUNKED, _RECURRENT = _with_grads(kda_chunked), _with_grads(kda_recurrent)
+
+
 @pytest.mark.parametrize("decay", ["published", "mild"])
 @pytest.mark.parametrize("chunks", [1, 4, 8])
 def test_chunked_recurrence_equals_the_recurrence(chunks, decay):
@@ -110,17 +127,13 @@ def test_chunked_recurrence_equals_the_recurrence(chunks, decay):
     weight = jnp.cos(jnp.arange(args[2].size, dtype=jnp.float32)).reshape(
         args[2].shape)
 
-    def loss(fn):
-        return lambda *a: jnp.sum(fn(*a) * weight)
-
-    want, got = kda_recurrent(*args), jax.jit(kda_chunked)(*args)
+    (want, want_grads), (got, got_grads) = (
+        _RECURRENT(weight, *args), _CHUNKED(weight, *args))
     assert bool(jnp.isfinite(got).all())
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
     if decay == "mild":     # the state is not forgotten within a chunk
         assert float(jnp.abs(want[:, -1]).mean()) > 0.05
-    grads = jax.jit(jax.grad(loss(kda_chunked), argnums=(0, 1, 2, 3, 4)))(*args)
-    for got, want in zip(grads, jax.grad(
-            loss(kda_recurrent), argnums=(0, 1, 2, 3, 4))(*args)):
+    for got, want in zip(got_grads, want_grads):
         assert bool(jnp.isfinite(got).all())
         np.testing.assert_allclose(
             got, want, rtol=2e-3, atol=2e-4 * float(jnp.abs(want).max()))
@@ -197,11 +210,28 @@ def _one_chunk(c, decay, h, d=16):
     return q, k, g
 
 
+@functools.cache
 def _both_forms(c):
+    """The kernel's form and the stored one, each jitted; once a chunk
+    length, so that the two decays of a shape (and the underflow case)
+    run one compiled program and not one each."""
     sub = min(kda.SUB, c)
-    return (lambda q, k, g: kda._pair_weights(q, k, jnp.cumsum(g, axis=-2)),
-            lambda q, k, g: _pair_weights_jnp(
-                q, k, jnp.cumsum(g, axis=-2), g, sub))
+    return (jax.jit(lambda q, k, g: kda._pair_weights(
+                q, k, jnp.cumsum(g, axis=-2))),
+            jax.jit(lambda q, k, g: _pair_weights_jnp(
+                q, k, jnp.cumsum(g, axis=-2), g, sub)))
+
+
+@functools.cache
+def _both_forms_grads(c):
+    """``dq``, ``dk``, ``dg`` of both forms under two weights handed in
+    as arguments, jitted once a chunk length as well."""
+    def loss(fn):
+        return lambda weights, *a: sum(
+            jnp.sum(x * w) for x, w in zip(fn(*a), weights))
+
+    return tuple(jax.jit(jax.grad(loss(fn), argnums=(1, 2, 3)))
+                 for fn in _both_forms(c))
 
 
 # a full chunk, a short one (padded inside), a count of blocks that is
@@ -220,7 +250,7 @@ def test_pair_kernel_equals_the_jnp_form(c, h, d, decay):
     if decay == "published" and c == 64:
         assert float(jnp.cumsum(g, axis=1).min()) < -200
     kernel, plain = _both_forms(c)
-    got, want = jax.jit(kernel)(q, k, g), jax.jit(plain)(q, k, g)
+    got, want = kernel(q, k, g), plain(q, k, g)
     for a, b in zip(got, want):
         assert a.shape == b.shape == (h, c, c)
         np.testing.assert_allclose(
@@ -239,13 +269,8 @@ def test_pair_kernel_vjp_equals_autodiff_through_the_jnp_form(c, h, d, decay):
     q, k, g = _one_chunk(c, decay, h, d)
     weights = [f(jnp.arange(h * c * c, dtype=jnp.float32)).reshape(h, c, c)
                for f in (jnp.cos, jnp.sin)]
-
-    def loss(fn):
-        return lambda *a: sum(jnp.sum(x * w) for x, w in zip(fn(*a), weights))
-
-    kernel, plain = _both_forms(c)
-    got = jax.jit(jax.grad(loss(kernel), argnums=(0, 1, 2)))(q, k, g)
-    want = jax.jit(jax.grad(loss(plain), argnums=(0, 1, 2)))(q, k, g)
+    kernel, plain = _both_forms_grads(c)
+    got, want = kernel(weights, q, k, g), plain(weights, q, k, g)
     for a, b in zip(got, want):
         assert bool(jnp.isfinite(a).all())
         np.testing.assert_allclose(
@@ -261,7 +286,7 @@ def test_pair_weights_stay_lower_and_finite_when_exponents_underflow():
     g = 1e3 * g
     assert float(jnp.cumsum(g, axis=1).min()) < -1e5
     kernel, _ = _both_forms(64)
-    A, A_q = jax.jit(kernel)(q, k, g)
+    A, A_q = kernel(q, k, g)
     assert bool(jnp.isfinite(A).all()) and bool(jnp.isfinite(A_q).all())
     assert not np.triu(A).any() and not np.triu(A_q, 1).any()
     np.testing.assert_allclose(

@@ -9,6 +9,7 @@ counters, and the refusal of the paths that do not implement these
 fields."""
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -67,7 +68,11 @@ def host_params(cfg, seed=0):
         jax.random.PRNGKey(seed), cfg))
 
 
+@functools.cache
 def one_step(cfg, **mesh):
+    """Loss and update of one SGD step; kept a (config, mesh), because
+    the parity cases each ask for the same one-device step again and
+    every asking compiles it."""
     n = int(np.prod(list(mesh.values())))
     mc = MeshConfig(devices=jax.devices()[:n], **mesh)
     before = host_params(cfg)
@@ -117,7 +122,9 @@ def test_no_leaf_for_heads_a_layer_does_not_have():
     pattern, and the layers that lead as single blocks."""
     cfg = mixed_cfg(n_layers=9)       # one leading + two periods
     assert cfg.blocks_by_position
-    params = init_transformer(jax.random.PRNGKey(0), cfg)
+    # shapes and structure are all that is read: no value is drawn
+    params = jax.eval_shape(
+        lambda: init_transformer(jax.random.PRNGKey(0), cfg))
     shapes = jax.tree.map(lambda a: a.shape, params)
     assert len(shapes["blocks"]) == 4 and len(shapes["leading"]) == 1
     for j in range(3):
@@ -138,7 +145,7 @@ def test_no_leaf_for_heads_a_layer_does_not_have():
     assert jax.tree.structure(tr.param_specs(cfg)) \
         == jax.tree.structure(params)
     # the optimizer's state follows: nothing is padded to 8 heads
-    moments = optax.adamw(1e-3).init(params)[0].mu
+    moments = jax.eval_shape(optax.adamw(1e-3).init, params)[0].mu
     assert jax.tree.map(lambda a: a.shape, moments) == shapes
 
 
@@ -151,13 +158,15 @@ def test_kinds_of_one_shape_keep_the_single_stack():
     cfg = mixed_cfg(layer_pattern=same, leading_layers=(), n_layers=8,
                     attn_gate="", shared_expert_d_ff=0)
     assert not cfg.blocks_by_position
-    blocks = init_transformer(jax.random.PRNGKey(0), cfg)["blocks"]
+    blocks = jax.eval_shape(
+        lambda: init_transformer(jax.random.PRNGKey(0), cfg))["blocks"]
     assert blocks["wq"].shape == (1, 8, 32, 4, 8)
     assert sorted(blocks) == ["ln1", "ln2", "router", "w1", "w2", "w3",
                               "wkv", "wo", "wq"]
     opt = TransformerConfig(vocab_size=VOCAB, d_model=32, n_heads=4,
                             d_head=8, d_ff=64, n_layers=2, max_seq=T)
-    assert sorted(init_transformer(jax.random.PRNGKey(0), opt)["blocks"]) \
+    assert sorted(jax.eval_shape(lambda: init_transformer(
+        jax.random.PRNGKey(0), opt))["blocks"]) \
         == ["ln1", "ln2", "w1", "w2", "wo", "wqkv"]
 
 

@@ -6,6 +6,7 @@ against one device, the counters, and the refusal of the paths that do
 not implement these fields."""
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -53,7 +54,11 @@ def tokens(seed=0):
     return t[:, :-1], t[:, 1:]
 
 
+@functools.cache
 def one_step(cfg, **mesh):
+    """Loss and update of one SGD step; kept a (config, mesh), because
+    the parity cases each ask for the same one-device step again and
+    every asking compiles it."""
     n = int(np.prod(list(mesh.values())))
     mc = MeshConfig(devices=jax.devices()[:n], **mesh)
     params = shard_params(mc, cfg, init_transformer(
@@ -99,14 +104,17 @@ def test_an_opt_shaped_config_builds_the_tree_it_did():
     cfg = TransformerConfig(vocab_size=VOCAB, d_model=32, n_heads=4,
                             d_head=8, d_ff=64, n_layers=2, max_seq=T)
     assert cfg.training_only == []
-    params = init_transformer(jax.random.PRNGKey(0), cfg)
+    # names and shapes are all these two read: no value is drawn
+    params = jax.eval_shape(
+        lambda: init_transformer(jax.random.PRNGKey(0), cfg))
     assert sorted(params) == ["blocks", "embed", "ln_f", "pos"]
     assert sorted(params["blocks"]) == ["ln1", "ln2", "w1", "w2", "wo", "wqkv"]
 
 
 def test_parameter_tree_of_the_share():
     cfg = typed_cfg()
-    params = init_transformer(jax.random.PRNGKey(0), cfg)
+    params = jax.eval_shape(
+        lambda: init_transformer(jax.random.PRNGKey(0), cfg))
     blocks = jax.tree.map(lambda a: a.shape, params["blocks"])
     assert blocks["router"] == (1, 4, 32, 8)          # all 8 columns
     assert blocks["w1"] == blocks["w3"] == (1, 4, 4, 32, 16)   # 4 held

@@ -16,6 +16,7 @@ A scenario is a function ``scenario_<name>(comm)`` below; workers exit 0
 on success and print tracebacks to stderr on failure.
 """
 
+import functools
 import os
 import sys
 import tempfile
@@ -804,6 +805,28 @@ def _tiny_transformer_losses(mc, cfg, steps=2):
     return out
 
 
+@functools.cache
+def _local_oracle_losses(cfg):
+    """``cfg``'s losses on this process's own device, no axis sharded:
+    the yardstick of the train scenarios, compiled once a launch (the
+    scenarios that share a launch ask for the same one)."""
+    from chainermn_tpu.parallel import MeshConfig
+
+    return _tiny_transformer_losses(
+        MeshConfig(data=1, devices=[jax.local_devices()[0]]), cfg)
+
+
+@functools.cache
+def _local_generate_fn(cfg, **kw):
+    """``make_generate_fn`` on this process's own device: the greedy
+    yardstick of the decode scenarios, built once a launch."""
+    from chainermn_tpu.models import make_generate_fn
+    from chainermn_tpu.parallel import MeshConfig
+
+    return make_generate_fn(
+        MeshConfig(data=1, devices=[jax.local_devices()[0]]), cfg, **kw)
+
+
 def scenario_tp_train(comm):
     """Tensor parallelism ACROSS the process boundary: 2 processes × 1
     device, ``model=2`` — every layer's column→row psum is a real
@@ -817,8 +840,7 @@ def scenario_tp_train(comm):
     tp_losses = _tiny_transformer_losses(
         MeshConfig(model=2, data=1, devices=jax.devices()), cfg)
     # local oracle: this process's own device, no sharded axes
-    oracle = _tiny_transformer_losses(
-        MeshConfig(data=1, devices=[jax.local_devices()[0]]), cfg)
+    oracle = _local_oracle_losses(cfg)
     np.testing.assert_allclose(tp_losses, oracle, rtol=1e-5, atol=1e-5)
     all_losses = comm.allgather_obj(tp_losses)
     for other in all_losses[1:]:
@@ -840,8 +862,7 @@ def scenario_pp_train(comm):
 
     assert jax.process_count() == 2 and len(jax.local_devices()) == 2
     base = _tiny_cfg()
-    oracle = _tiny_transformer_losses(
-        MeshConfig(data=1, devices=[jax.local_devices()[0]]), base)
+    oracle = _local_oracle_losses(base)
 
     for axes, cfg in (
         (dict(pipe=2, model=2, data=1),
@@ -889,7 +910,7 @@ def scenario_decode(comm):
 
     one = MeshConfig(data=1, devices=[jax.local_devices()[0]])
     ref = np.asarray(
-        make_generate_fn(one, base, max_len=8)(
+        _local_generate_fn(base, max_len=8)(
             shard_params(one, base, host), prompt))
 
     for name, axes, cfg in (
@@ -918,7 +939,7 @@ def scenario_decode(comm):
     pl = jnp.asarray(padded)
     kw = dict(max_len=8, eos_id=5, pad_id=0)
     ref2 = np.asarray(
-        make_generate_fn(one, base, **kw)(
+        _local_generate_fn(base, **kw)(
             shard_params(one, base, host), pl, prompt_lens=lens))
     mc = MeshConfig(data=2, devices=jax.devices())
     sh = mc.sharding(("data", "expert"))
@@ -1111,8 +1132,7 @@ def scenario_lookup_decode(comm):
     the acceptance pmin and verify-chunk collectives span processes.
     Tokens must equal the process-local greedy oracle."""
     from chainermn_tpu.models import (
-        init_transformer, make_generate_fn, make_lookup_generate_fn,
-        shard_params,
+        init_transformer, make_lookup_generate_fn, shard_params,
     )
     from chainermn_tpu.parallel import MeshConfig
 
@@ -1126,7 +1146,7 @@ def scenario_lookup_decode(comm):
         jnp.int32)
     one = MeshConfig(data=1, devices=[jax.local_devices()[0]])
     ref = np.asarray(
-        make_generate_fn(one, cfg, max_len=8)(
+        _local_generate_fn(cfg, max_len=8)(
             shard_params(one, cfg, host), prompt))
 
     mc = MeshConfig(data=2, devices=jax.devices())
@@ -1150,7 +1170,7 @@ def scenario_lookup_decode(comm):
     pl = jnp.asarray(padded)
     kw = dict(max_len=8, eos_id=5, pad_id=0)
     ref_pe = np.asarray(
-        make_generate_fn(one, cfg, **kw)(
+        _local_generate_fn(cfg, **kw)(
             shard_params(one, cfg, host), pl, prompt_lens=lens))
     got_pe = make_lookup_generate_fn(mc, cfg, k=2, ngram=2, **kw)(
         params, jax.device_put(pl, sh),
@@ -1217,8 +1237,7 @@ def scenario_sp_ep_train(comm):
 
     assert jax.process_count() == 2 and len(jax.local_devices()) == 1
     base = _tiny_cfg()
-    oracle = _tiny_transformer_losses(
-        MeshConfig(data=1, devices=[jax.local_devices()[0]]), base)
+    oracle = _local_oracle_losses(base)
 
     ring = dataclasses.replace(base, attention="ring")
     ring_losses = _tiny_transformer_losses(
@@ -1231,8 +1250,7 @@ def scenario_sp_ep_train(comm):
                                    rtol=1e-6, atol=1e-6)
 
     moe = dataclasses.replace(base, moe=True, n_experts=2)
-    moe_oracle = _tiny_transformer_losses(
-        MeshConfig(data=1, devices=[jax.local_devices()[0]]), moe)
+    moe_oracle = _local_oracle_losses(moe)
     losses = _tiny_transformer_losses(
         MeshConfig(expert=2, data=1, devices=jax.devices()), moe)
     # step 1 is reduction-order-exact; later steps tolerate top-1
@@ -1263,9 +1281,7 @@ def scenario_vocab_tp_loss_chunk_train(comm):
     from chainermn_tpu.parallel import MeshConfig
 
     assert jax.process_count() == 2 and len(jax.local_devices()) == 1
-    oracle = _tiny_transformer_losses(
-        MeshConfig(data=1, devices=[jax.local_devices()[0]]),
-        _tiny_cfg())
+    oracle = _local_oracle_losses(_tiny_cfg())
     losses = _tiny_transformer_losses(
         MeshConfig(model=2, data=1, devices=jax.devices()),
         _tiny_cfg(loss_chunk=8, vocab_parallel=True))

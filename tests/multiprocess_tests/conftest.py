@@ -41,6 +41,49 @@ def _worker_env(devices_per_proc: int = 1) -> dict:
     return env
 
 
+def _launch(scenario, nprocs, timeout, devices_per_proc):
+    """Run ``scenario`` (``"a+b"``: several, one after the other) across
+    ``nprocs`` real processes.  Returns each worker's output and what
+    went wrong with the launch, ``None`` if every worker exited 0."""
+    addr = f"localhost:{_free_port()}"
+    env = _worker_env(devices_per_proc)
+    procs = [
+        subprocess.Popen(
+            [sys.executable, _WORKER, addr, str(nprocs), str(i),
+             scenario],
+            stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
+            text=True, env=env, cwd=_REPO_ROOT)
+        for i in range(nprocs)
+    ]
+    outputs, codes = [], []
+    try:
+        for p in procs:
+            out, _ = p.communicate(timeout=timeout)
+            outputs.append(out)
+            codes.append(p.returncode)
+    except subprocess.TimeoutExpired:
+        for p in procs:
+            p.kill()
+        outputs = [p.communicate()[0] for p in procs]
+        return outputs, (
+            f"scenario {scenario!r} timed out after {timeout}s "
+            "(likely a cross-process collective deadlock)\n"
+            + "\n---\n".join(outputs))
+    if any(codes):
+        report = "\n".join(
+            f"--- worker {i} rc={codes[i]} ---\n{outputs[i]}"
+            for i in range(nprocs))
+        return outputs, f"scenario {scenario!r} failed:\n{report}"
+    return outputs, None
+
+
+def _assert_ran(name, outputs, wrong):
+    for i, out in enumerate(outputs):
+        assert f"WORKER_OK {i} {name}" in out, (
+            wrong or f"worker {i} exited 0 without {name}'s OK marker:"
+            f"\n{out}")
+
+
 @pytest.fixture(scope="session")
 def mp_run():
     """Run ``scenario`` across ``nprocs`` real processes; fail the test on
@@ -48,41 +91,26 @@ def mp_run():
 
     def run(scenario: str, nprocs: int = 2, timeout: int = 180,
             devices_per_proc: int = 1):
-        addr = f"localhost:{_free_port()}"
-        env = _worker_env(devices_per_proc)
-        procs = [
-            subprocess.Popen(
-                [sys.executable, _WORKER, addr, str(nprocs), str(i),
-                 scenario],
-                stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-                text=True, env=env, cwd=_REPO_ROOT)
-            for i in range(nprocs)
-        ]
-        outputs, codes = [], []
-        try:
-            for p in procs:
-                out, _ = p.communicate(timeout=timeout)
-                outputs.append(out)
-                codes.append(p.returncode)
-        except subprocess.TimeoutExpired:
-            for p in procs:
-                p.kill()
-            for p in procs:
-                out, _ = p.communicate()
-                outputs.append(out)
-            pytest.fail(
-                f"scenario {scenario!r} timed out after {timeout}s "
-                "(likely a cross-process collective deadlock)\n"
-                + "\n---\n".join(outputs))
-        if any(codes):
-            report = "\n".join(
-                f"--- worker {i} rc={codes[i]} ---\n{outputs[i]}"
-                for i in range(nprocs))
-            pytest.fail(f"scenario {scenario!r} failed:\n{report}")
-        for i, out in enumerate(outputs):
-            for name in scenario.split("+"):
-                assert f"WORKER_OK {i} {name}" in out, (
-                    f"worker {i} exited 0 without {name}'s OK marker:\n"
-                    f"{out}")
+        outputs, wrong = _launch(scenario, nprocs, timeout,
+                                 devices_per_proc)
+        if wrong:
+            pytest.fail(wrong)
+        _assert_ran(scenario, outputs, None)
+
+    return run
+
+
+@pytest.fixture(scope="session")
+def mp_run_shared():
+    """One launch of two processes with a device each for several
+    scenarios, each with a test of its own: returns ``ran(name)``, which fails the test that calls it unless
+    ``name`` ran to its marker on every worker.  The workers stop at the
+    first scenario that fails (a rank that went on alone would wait for
+    its peer), so the tests of those before it pass, its own fails with
+    the workers' output, and those after it fail as not reached."""
+
+    def run(names):
+        outputs, wrong = _launch("+".join(names), 2, 280, 1)
+        return lambda name: _assert_ran(name, outputs, wrong)
 
     return run

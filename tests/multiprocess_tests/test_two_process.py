@@ -23,31 +23,50 @@ _LIGHT = ("topology",
 
 
 @pytest.fixture(scope="module")
-def light(mp_run):
+def light(mp_run_shared):
     """The light scenarios, run one after the other by the same two
     processes; what each of their tests asserts is that its own ran to
-    its marker on both (``mp_run`` fails the set-up otherwise, with the
-    workers' output)."""
-    mp_run("+".join(_LIGHT), timeout=600)
-    return _LIGHT
+    its marker on both."""
+    return mp_run_shared(_LIGHT)
+
+
+@pytest.fixture(scope="module")
+def trains(mp_run_shared):
+    """The train scenarios of two processes with a device each, one
+    launch: three of the four hold their losses to the same
+    process-local oracle, which the workers compile once
+    (``_local_oracle_losses``)."""
+    return mp_run_shared(("tp_train", "vocab_tp_loss_chunk_train",
+                          "sp_ep_train", "fsdp_train"))
+
+
+@pytest.fixture(scope="module")
+def decodes(mp_run_shared):
+    """The decode scenarios of two processes with a device each, one
+    launch; ``decode`` and ``lookup_decode`` are held to
+    ``make_generate_fn``'s greedy tokens of the same tiny model, plain
+    and padded with an eos: those two oracle programs are built once
+    (``_local_generate_fn``)."""
+    return mp_run_shared(("decode", "lookup_decode", "speculative_decode",
+                          "speculative_sampling", "beam_search"))
 
 
 @pytest.mark.multiprocess
 class TestTwoProcess:
     def test_topology_contract(self, light):
-        assert "topology" in light
+        light("topology")
 
     def test_obj_collectives(self, light):
-        assert "obj_collectives" in light
+        light("obj_collectives")
 
     def test_p2p_obj_channel(self, light):
-        assert "p2p_obj" in light
+        light("p2p_obj")
 
     def test_array_collectives(self, light):
-        assert "array_collectives" in light
+        light("array_collectives")
 
     def test_scatter_dataset(self, light):
-        assert "scatter_dataset" in light
+        light("scatter_dataset")
 
     def test_checkpoint_agreement_resume(self, mp_run):
         mp_run("checkpoint")
@@ -67,37 +86,37 @@ class TestTwoProcess:
         mp_run("watchdog_stall", timeout=240)
 
     def test_evaluator_averaging(self, light):
-        assert "evaluator" in light
+        light("evaluator")
 
     def test_broadcast_iterator(self, light):
-        assert "broadcast_iterator" in light
+        light("broadcast_iterator")
 
     def test_observation_aggregator(self, light):
-        assert "observation_aggregator" in light
+        light("observation_aggregator")
 
     def test_split(self, mp_run):
         # 4 processes: each even/odd subgroup spans 2 processes, forcing
         # the KV group collectives (whole-world ones would deadlock)
         mp_run("split", nprocs=4)
 
-    def test_vocab_tp_loss_chunk_train(self, mp_run):
+    def test_vocab_tp_loss_chunk_train(self, trains):
         # chunked-vocab CE + vocab-parallel embedding over model=2
         # spanning processes, loss-equal to the process-local oracle
-        mp_run("vocab_tp_loss_chunk_train", timeout=300)
+        trains("vocab_tp_loss_chunk_train")
 
     def test_alltoall_window(self, mp_run):
         # 8 processes: the windowed pairwise-lane alltoall at window
         # sizes below, at, and above the round count
-        mp_run("alltoall_window", nprocs=8, timeout=300)
+        mp_run("alltoall_window", nprocs=8, timeout=280)
 
     def test_snapshot(self, light):
-        assert "snapshot" in light
+        light("snapshot")
 
     def test_allreduce_persistent(self, light):
-        assert "allreduce_persistent" in light
+        light("allreduce_persistent")
 
     def test_dp_train_step(self, light):
-        assert "dp_train" in light
+        light("dp_train")
 
     def test_preemption_collective_flag(self, mp_run):
         mp_run("preemption")
@@ -112,7 +131,7 @@ class TestTwoProcess:
     def test_preemption_sigterm_drill(self, mp_run):
         # real SIGTERM on one process -> OR-reduced collective save ->
         # both ranks stop clean -> resume bitwise-matches uninterrupted
-        mp_run("preemption_sigterm", timeout=300)
+        mp_run("preemption_sigterm", timeout=280)
 
     @pytest.mark.drill
     def test_resize_live_control_plane(self, mp_run):
@@ -124,50 +143,50 @@ class TestTwoProcess:
     def test_zero1_checkpoint(self, mp_run):
         mp_run("zero1_checkpoint")
 
-    def test_fsdp_train(self, mp_run):
-        mp_run("fsdp_train")
+    def test_fsdp_train(self, trains):
+        trains("fsdp_train")
 
-    def test_tp_train(self, mp_run):
+    def test_tp_train(self, trains):
         # per-layer TP psum crosses the process boundary (model=2 over
         # 2 single-device processes)
-        mp_run("tp_train")
+        trains("tp_train")
 
     def test_pp_train(self, mp_run):
         # 2 procs x 2 devices: pipe (mesh-major) ppermute crosses the
         # process boundary; model stays local; + the model=2,data=2 shape
-        mp_run("pp_train", devices_per_proc=2, timeout=300)
+        mp_run("pp_train", devices_per_proc=2, timeout=280)
 
-    def test_sp_ep_train(self, mp_run):
+    def test_sp_ep_train(self, trains):
         # ring-attention ppermute chain and MoE all-to-alls cross the
         # process boundary (seq=2 / expert=2 over 2 processes)
-        mp_run("sp_ep_train", timeout=300)
+        trains("sp_ep_train")
 
-    def test_decode(self, mp_run):
+    def test_decode(self, decodes):
         # per-token seq-KV softmax merges and vocab-parallel lookup/
         # gather collectives cross the process boundary; tokens equal
         # the process-local oracle exactly
-        mp_run("decode", timeout=300)
+        decodes("decode")
 
-    def test_speculative_decode(self, mp_run):
+    def test_speculative_decode(self, decodes):
         # the acceptance pmin + verify-chunk collectives run inside a
         # cross-process while_loop; tokens equal the local oracle
-        mp_run("speculative_decode", timeout=300)
+        decodes("speculative_decode")
 
-    def test_speculative_sampling(self, mp_run):
+    def test_speculative_sampling(self, decodes):
         # acceptance pmin + shard-decorrelated keys + while-loop key
         # carry across the boundary; same-key determinism
-        mp_run("speculative_sampling", timeout=300)
+        decodes("speculative_sampling")
 
-    def test_lookup_decode(self, mp_run):
+    def test_lookup_decode(self, decodes):
         # the draft-free proposer: row-local n-gram matching, shared
         # acceptance pmin and verify chunk across the boundary; plus
         # the padded+eos composition phase
-        mp_run("lookup_decode", timeout=300)
+        decodes("lookup_decode")
 
-    def test_beam_search(self, mp_run):
+    def test_beam_search(self, decodes):
         # the per-step cache-reorder gather over batch-sharded ragged
         # rows; tokens AND scores equal the local oracle
-        mp_run("beam_search", timeout=300)
+        decodes("beam_search")
 
     def test_shuffle_datablock(self, mp_run):
         mp_run("shuffle_datablock")

@@ -76,17 +76,19 @@ def mini_adapter(mini_cfg):
 
 
 @pytest.fixture(scope="session")
-def oracle(mini_adapter, mini_params):
-    """``oracle(prompt, max_new, eos=-1) -> (n,) generated tokens`` —
-    the solo static greedy decode every engine request must match."""
+def solo_decode(mini_adapter, mini_params):
+    """``solo_decode(prompt, max_new, pick, eos=-1) -> (n,) tokens``:
+    one request decoded alone, ``pick(logits, t)`` choosing the token
+    produced at clock ``t`` -- the loop under the greedy oracle here and
+    ``test_sampling.py``'s sampled one."""
     ad, params = mini_adapter, mini_params
-    cache = {}
+    # the adapter's two pure functions, each ONE compiled program a
+    # shape: called eagerly, every op of the prefill was a program of
+    # its own again at every new prompt length (493 compiles, 14 of the
+    # 21 s of the first parity test alone)
+    prefill, step = jax.jit(ad.prefill), jax.jit(ad.step)
 
-    def run(prompt, max_new, eos=-1):
-        key = (bytes(np.asarray(prompt, np.int32)), int(max_new),
-               int(eos))
-        if key in cache:
-            return cache[key]
+    def run(prompt, max_new, pick, eos=-1):
         prompt = np.asarray(prompt, np.int32)
         p = prompt.shape[0]
         # a cache of whole 64s, not of p + max_new: the loop below runs
@@ -96,18 +98,36 @@ def oracle(mini_adapter, mini_params):
         caches = ad.make_cache(1, -(-(p + max_new) // 64) * 64)
         offs = jnp.zeros((1,), jnp.int32)
         if p > 1:
-            caches = ad.prefill(
+            caches = prefill(
                 params, caches, jnp.asarray(prompt[None, :p - 1]), offs)
         tok = jnp.asarray(prompt[-1:], jnp.int32)
         out = []
         for t in range(p - 1, p - 1 + max_new):
-            logits, caches = ad.step(params, caches, tok, jnp.int32(t),
-                                     offs)
-            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            logits, caches = step(params, caches, tok, jnp.int32(t),
+                                  offs)
+            tok = pick(logits, t)
             out.append(int(tok[0]))
             if eos >= 0 and out[-1] == eos:
                 break
-        cache[key] = np.asarray(out, np.int32)
+        return np.asarray(out, np.int32)
+
+    return run
+
+
+@pytest.fixture(scope="session")
+def oracle(solo_decode):
+    """``oracle(prompt, max_new, eos=-1) -> (n,) generated tokens`` —
+    the solo static greedy decode every engine request must match."""
+    cache = {}
+
+    def greedy(logits, t):
+        return jnp.argmax(logits, -1).astype(jnp.int32)
+
+    def run(prompt, max_new, eos=-1):
+        key = (bytes(np.asarray(prompt, np.int32)), int(max_new),
+               int(eos))
+        if key not in cache:
+            cache[key] = solo_decode(prompt, max_new, greedy, eos)
         return cache[key]
 
     return run

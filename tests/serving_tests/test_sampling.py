@@ -89,35 +89,22 @@ class TestFilters:
             SamplingParams(top_p=0.0)
 
 
-def _sampled_oracle(adapter, params, prompt, max_new, sp, eos=-1):
+def _sampled_oracle(solo_decode, prompt, max_new, sp, eos=-1):
     """Engine-independent replay: solo decode with the same key
     stream and the same sampling functions the round program uses."""
-    prompt = np.asarray(prompt, np.int32)
-    p = prompt.shape[0]
-    caches = adapter.make_cache(1, p + max_new)
-    offs = jnp.zeros((1,), jnp.int32)
-    if p > 1:
-        caches = adapter.prefill(params, caches,
-                                 jnp.asarray(prompt[None, :p - 1]),
-                                 offs)
-    tok = jnp.asarray(prompt[-1:], jnp.int32)
     root = jnp.asarray(sp.key())[None]
-    out = []
-    for t in range(p - 1, p - 1 + max_new):
-        logits, caches = adapter.step(params, caches, tok,
-                                      jnp.int32(t), offs)
+
+    def pick(logits, t):
         # token index of the PRODUCED token: t + 1 - offset (= i+1
         # counting the prompt's last token as index p-1... the engine
         # folds by t + 1 - offset with offset = position of token 0)
         keys = fold_keys(root, jnp.asarray([t + 1], jnp.int32))
-        tok = sample_tokens(logits, keys,
-                            jnp.asarray([sp.temperature]),
-                            jnp.asarray([sp.top_k], jnp.int32),
-                            jnp.asarray([sp.top_p]))
-        out.append(int(tok[0]))
-        if eos >= 0 and out[-1] == eos:
-            break
-    return np.asarray(out, np.int32)
+        return sample_tokens(logits, keys,
+                             jnp.asarray([sp.temperature]),
+                             jnp.asarray([sp.top_k], jnp.int32),
+                             jnp.asarray([sp.top_p]))
+
+    return solo_decode(prompt, max_new, pick, eos)
 
 
 class TestEngineSampling:
@@ -154,8 +141,7 @@ class TestEngineSampling:
                         "schedule")
 
     def test_sampled_matches_solo_replay_oracle(self, engine,
-                                                mini_adapter,
-                                                mini_params):
+                                                solo_decode):
         engine.reset()
         rng = np.random.RandomState(11)
         cases = [(rng.randint(0, 64, rng.randint(2, 17)), 8,
@@ -166,7 +152,7 @@ class TestEngineSampling:
                 for p, n, sp in cases]
         comps = {c.rid: c for c in engine.run(max_steps=2000)}
         for rid, (p, n, sp) in zip(rids, cases):
-            ref = _sampled_oracle(mini_adapter, mini_params, p, n, sp)
+            ref = _sampled_oracle(solo_decode, p, n, sp)
             np.testing.assert_array_equal(
                 comps[rid].tokens, ref,
                 err_msg=f"{rid} diverged from its (key, params) "
@@ -208,7 +194,8 @@ class TestEngineSampling:
         assert engine._n_sampled_active == 0
 
     def test_sampled_with_eos_freezes(self, mini_adapter, mini_params,
-                                      oracle, ragged_trace):
+                                      oracle, solo_decode,
+                                      ragged_trace):
         """EOS semantics under sampling: a sampled row emitting eos
         freezes and pads; its replay oracle agrees."""
         rng = np.random.RandomState(14)
@@ -223,8 +210,7 @@ class TestEngineSampling:
                 for p, n, sp in cases]
         comps = {c.rid: c for c in eng.run(max_steps=2000)}
         for rid, (p, n, sp) in zip(rids, cases):
-            ref = _sampled_oracle(mini_adapter, mini_params, p, n, sp,
-                                  eos=eos)
+            ref = _sampled_oracle(solo_decode, p, n, sp, eos=eos)
             np.testing.assert_array_equal(comps[rid].tokens, ref)
 
     def test_submit_rejects_non_sampling_params(self, engine):

@@ -26,7 +26,7 @@ def _smoke(*args):
     env.pop("XLA_FLAGS", None)      # one CPU device, as without a chip
     return subprocess.run(
         [sys.executable, os.path.join(_ROOT, "chip_smoke.py"), *args],
-        capture_output=True, text=True, timeout=900, cwd=_ROOT, env=env)
+        capture_output=True, text=True, timeout=280, cwd=_ROOT, env=env)
 
 
 def test_no_chip_fails_before_any_phase():
