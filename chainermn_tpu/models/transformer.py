@@ -1337,24 +1337,24 @@ def _gated(cfg: TransformerConfig, act: str, x, w1, w3, w2):
 
 def _whole_tiles(w, axis: int):
     """Held experts' weights ``(G, D, F)`` / ``(G, F, D)`` with the
-    experts' width ``F`` (``axis``) zero-padded to whole pairs of
-    128-lane tiles where it is not whole lanes; else ``w`` itself.  The
-    padded units are exact zeros through every activation here
-    (``relu(0)``, its square, ``silu(0) * 0``) and meet zero rows of
-    ``w2``, and the pad's transpose drops their gradient.  Read on the
-    chip (PERF.md section 6, PR 40): XLA's grouped-matmul kernel for
-    ``lax.ragged_dot`` took 21.6 ms a layer, forward and backward, at a
-    width of 1,856 (14.5 tiles), 22.0 at 1,920 and 12.8 at 2,048.
-    Widths of whole lanes (896, 512, 1,024: the other cells') are left
-    as they are and their steps with them; whether 896 -> 1,024 pays is
-    for a ``perf_opt`` to read on that cell.  A width under one tile is
-    a test's and stays too."""
+    experts' width ``F`` (``axis``) zero-padded to whole 128-lane tiles;
+    ``w`` itself where it is whole lanes already (896, 512, 1,024: four
+    of the five cells) or under one tile (a test's).  The padded units
+    are exact zeros through every activation here (``relu(0)``, its
+    square, ``silu(0) * 0``) and meet zero rows of ``w2``, and the pad's
+    transpose drops their gradient.  Read on the chip through the
+    kernels of ``ops/grouped_matmul.py`` (PERF.md section 6, PR 47):
+    one Nemotron layer's grouped products, forward twice and both
+    backward passes, 4.32 ms at its width of 1,856 (14.5 tiles: the
+    weights' backward pays for the half tile), 3.93 at 1,920 and 4.14
+    at 2,048, the whole PAIR of tiles that XLA's kernel for
+    ``lax.ragged_dot`` wanted (PR 40: 21.6, 22.0 and 12.8 ms) and that
+    nothing asks for since that kernel is off the TPU's path."""
     width = w.shape[axis]
     if width < 128 or width % 128 == 0:
         return w
-    pad = -width % 256
     widths = [(0, 0)] * w.ndim
-    widths[axis] = (0, pad)
+    widths[axis] = (0, -width % 128)
     return jnp.pad(w, widths)
 
 

@@ -95,7 +95,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from chainermn_tpu.ops.pallas_attention import interpret_kernels
+from chainermn_tpu.ops.kernel_common import interpret_kernels
 from chainermn_tpu.ops.recurrent import scan_slabs, slab_size
 from chainermn_tpu.utils.metrics import get_registry
 from chainermn_tpu.utils.telemetry import device_scope

@@ -58,7 +58,6 @@ Design (flash-attention v2 schedule, TPU-shaped):
 
 from __future__ import annotations
 
-import contextvars
 import functools
 from typing import Callable, NamedTuple, Optional
 
@@ -69,6 +68,12 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from chainermn_tpu.ops.kernel_common import (
+    LANE as _LANE,
+    VMEM_BUDGET as _VMEM_BUDGET,
+    interpret_kernels,
+    tracing_for_mesh,
+)
 from chainermn_tpu.utils.metrics import get_registry
 from chainermn_tpu.utils.telemetry import device_scope
 
@@ -81,37 +86,6 @@ __all__ = ["flash_attention", "flash_attention_supported",
 FLASH_RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 _NEG = -1e30
-
-# Platform of the devices the program being traced was built for; set
-# by :func:`tracing_for_mesh` around a shard_map body.  ``None`` outside
-# one: the process default backend decides.
-_TRACE_PLATFORM = contextvars.ContextVar(
-    "chainermn_tpu_trace_platform", default=None)
-
-
-def tracing_for_mesh(mesh, fn):
-    """Wrap ``fn`` (a ``shard_map`` body over ``mesh``) so kernels traced
-    inside it compile for the platform of ``mesh``'s devices, not for
-    the process's default backend — a step built on TPU devices holds
-    the compiled kernel whatever ``jax.default_backend()`` says."""
-    platform = mesh.devices.flat[0].platform
-
-    @functools.wraps(fn)
-    def traced(*args, **kwargs):
-        token = _TRACE_PLATFORM.set(platform)
-        try:
-            return fn(*args, **kwargs)
-        finally:
-            _TRACE_PLATFORM.reset(token)
-
-    return traced
-
-
-def interpret_kernels() -> bool:
-    """True when Pallas kernels traced now must run in the interpreter:
-    the target platform (see :func:`tracing_for_mesh`) is not a TPU."""
-    return (_TRACE_PLATFORM.get() or jax.default_backend()) != "tpu"
-_LANE = 128  # TPU lane width: trailing dim of lse/delta and vector scratch
 
 
 def _bcast(vec, n=_LANE):
@@ -492,11 +466,6 @@ def _flash_fwd(q3, k3, v3, offs, static_offs, scale, causal, window,
     o = checkpoint_name(o, FLASH_RESIDUAL_NAMES[0])
     lse = checkpoint_name(lse, FLASH_RESIDUAL_NAMES[1])
     return (o, lse), (q3, k3, v3, offs, o, lse)
-
-
-# What one kernel may ask of the v5e's 128 MiB of VMEM
-# (``vmem_limit_bytes``; without it the compiler's scoped default is 16).
-_VMEM_BUDGET = 100 * 2 ** 20
 
 
 def _bwd_vmem_bytes(Tq, D, Dv, block_q, block_k, dtype):

@@ -29,7 +29,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from chainermn_tpu.ops.pallas_attention import interpret_kernels
+from chainermn_tpu.ops.kernel_common import interpret_kernels
 from chainermn_tpu.parallel._compat import pcast
 
 __all__ = ["causal_conv_silu", "scan_slabs", "slab_size"]

@@ -21,7 +21,7 @@ building block").  Shape of the strategy:
 :func:`expert_parallel_moe_dropless` is the second dispatch: no capacity
 and no ``(N, E, cap)`` tensor.  The (token, choice) rows are sorted by
 expert, the experts held here run as grouped products over exactly the
-rows routed to them (``lax.ragged_dot``), and no token is dropped
+rows routed to them (:func:`grouped_dense`), and no token is dropped
 whatever the imbalance.  It can be told that it holds only a contiguous
 share of the router's experts: it then routes over all of them and
 returns its own experts' part of the result, through a sorted buffer
@@ -38,6 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from chainermn_tpu.ops.grouped_matmul import grouped_matmul
+from chainermn_tpu.ops.kernel_common import interpret_kernels
 from chainermn_tpu.utils.metrics import get_registry
 from chainermn_tpu.utils.telemetry import device_scope
 
@@ -231,16 +233,31 @@ def _top_k_biased(scores, top_k, bias):
 def grouped_dense(rows, w, group_sizes):
     """``rows[g's rows] @ w[g]`` for consecutive groups of rows:
     ``rows`` ``(R, K)`` sorted by group, ``w`` ``(G, K, M)``,
-    ``group_sizes`` ``(G,)`` int32.  On TPU ``lax.ragged_dot`` compiles
-    to a grouped-matmul kernel whose grid follows ``group_sizes``, so
-    the work is that of the rows really there; rows past the last group
-    come back undefined (see :func:`_experts_of_rows`).  What is done
-    to ``rows`` and to the result around it (a cast, an activation, the
-    cotangent's mask) passes over all ``R`` rows, held or not: the
-    dropless layer keeps ``R`` near the rows held
+    ``group_sizes`` ``(G,)`` int32.  The work is that of the rows
+    really there, and rows past the last group come back undefined,
+    forward and in the rows' cotangent (see :func:`_experts_of_rows`).
+
+    Traced for a TPU it is this repo's own Pallas kernels
+    (``ops/grouped_matmul.py``: tiles read off the shapes, the weights'
+    cotangent bounded by the groups and not by a zero cotangent);
+    anywhere else ``lax.ragged_dot``, which the TPU compiler would
+    lower to a grouped-matmul kernel of its own.  The chip decided
+    (PERF.md section 6, PR 47): one Mellum layer's products with the
+    SwiGLU between, forward twice (the block's remat) and both backward
+    passes, 39.2 ms through ``lax.ragged_dot``, 24.0 with the experts'
+    width padded to whole pairs of lane tiles, 13.5 through the
+    kernels; the other four cells' layers 2.6 to 5.0 ms for 4.5 to
+    10.5, and every one of the five cells' steps faster (Mellum's by
+    28 %, the others' by 1 to 6).
+
+    What is done to ``rows`` and to the result around it (a cast, an
+    activation, the cotangent's mask) passes over all ``R`` rows, held
+    or not: the dropless layer keeps ``R`` near the rows held
     (:func:`_buffer_rungs`)."""
-    return lax.ragged_dot(rows, w, group_sizes,
-                          preferred_element_type=rows.dtype)
+    if interpret_kernels():
+        return lax.ragged_dot(rows, w, group_sizes,
+                              preferred_element_type=rows.dtype)
+    return grouped_matmul(rows, w, group_sizes)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3,))

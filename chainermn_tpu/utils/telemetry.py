@@ -938,8 +938,8 @@ def classify_op_name(op_name: str):
     differentiated -- the optimizer, and whatever of the model depends
     on no parameter (rotary tables, masks).  ``"unnamed"`` where the
     name holds no ``jit(`` and so no name stack at all: the compiler's
-    own names (``ragged-dot-none``, the grouped-matmul kernels it makes
-    of ``lax.ragged_dot``) and ops it adds of its own.  ``path``: the
+    own names (its copies, the kernels it makes of a ``lax.ragged_dot``)
+    and ops it adds of its own.  ``path``: the
     scopes of ``DEVICE_SCOPES`` in the name, outermost first, each
     once (a remat region's ops carry the stack they were first traced
     under inside the transposed one: ``step/layers`` twice); empty where

@@ -253,6 +253,55 @@ def test_conv_kernels_compile(topo, one_chip, cell, backward):
         assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
+# the five typed cells' expert layers at their first rung, bfloat16:
+# rows of the sorted buffer, experts held, model width, experts' width
+GROUPED_CELLS = {
+    "mellum": (65536, 16, 2304, 896),
+    "laguna": (32768, 32, 2048, 512),
+    "kimi": (8192, 8, 2304, 1024),
+    "nemotron": (12288, 8, 2688, 1920),
+    "qwen3-next": (20480, 32, 2048, 512),
+}
+
+
+def _grouped_sites(text, scope="moe/experts"):
+    """The grouped-matmul kernels' call sites under ``scope`` in a
+    compiled program's text."""
+    return [line for line in text.splitlines()
+            if scope in line and "pallas_call" in line
+            and "tpu_custom_call" in line]
+
+
+@pytest.mark.parametrize("cell", list(GROUPED_CELLS))
+def test_grouped_matmul_kernels_compile(topo, one_chip, cell):
+    """``grouped_dense`` traced for the chip, through one gated expert
+    layer at a cell's real shapes and the tile rule's own tiles: three
+    kernels forward and six backward (a product's rows' backward is the
+    forward kernel on the transposed weights, its weights' backward the
+    third), every one under the scope the benchmark's readers find it
+    by, and Mosaic takes the tiles and the VMEM they ask for."""
+    from chainermn_tpu.ops.pallas_attention import tracing_for_mesh
+    from chainermn_tpu.parallel.expert import grouped_dense
+    from chainermn_tpu.utils.telemetry import device_scope
+
+    R, G, D, F = GROUPED_CELLS[cell]
+    like = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((G,), jnp.int32, sharding=one_chip)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+
+    def loss(rows, w1, w3, w2, sizes):
+        with device_scope("moe/experts"):
+            y = jax.nn.silu(grouped_dense(rows, w1, sizes)) \
+                * grouped_dense(rows, w3, sizes)
+            return jnp.sum(jnp.sin(grouped_dense(y, w2, sizes)))
+
+    fn = tracing_for_mesh(mesh, jax.grad(loss, argnums=(0, 1, 2, 3)))
+    text = _compile(fn, like(R, D), like(G, D, F), like(G, D, F),
+                    like(G, F, D), sizes).as_text()
+    assert len(_grouped_sites(text)) == 9
+    assert "ragged-dot" not in text
+
+
 def _compile_step(mc, cfg, opt, batch, seq):
     """``make_train_step`` compiled for the described devices of ``mc``
     (shapes only: there is no device to hold an array)."""
@@ -383,7 +432,7 @@ def _assert_compact_rungs(text, n_rows, rungs, layers):
         full = [len(re.findall(rf"\[{n_rows},\d+\]", b)) for b in branches]
         assert full[-1] > 0 and not any(full[:-1]), full
         # each rung runs the grouped products itself
-        assert all("ragged-dot-none" in b for b in branches)
+        assert all(_grouped_sites(b) for b in branches)
 
 
 # Slow-marked since PR 42: at 67 to 128 s each (415 s of the tier-1 run's
@@ -428,9 +477,8 @@ def test_mellum_cell_step_fits_and_holds_no_capacity_tensor(topo):
     # three grouped products forward, three recomputed, six backward,
     # in each of four layers, at each of the buffer's two sizes (a
     # quarter of the experts held: half the rows, and all of them)
-    grouped = [n for n in by_scope["moe/experts"]
-               if n.startswith("ragged-dot-none")]
-    assert len(grouped) == 4 * 12 * 2
+    assert len(_grouped_sites(text)) == 4 * 12 * 2
+    assert "ragged-dot" not in text
     tokens, experts = job["batch"] * job["seq"], cfg["router_experts"]
     _assert_compact_rungs(text, tokens * pcfg.router_top_k, 2, layers=4)
     for dims in set(re.findall(r"\[([\d,]+)\]", text)):
@@ -472,17 +520,16 @@ def test_laguna_cell_step_fits_and_pads_no_heads(topo):
     # sliding layers, the leading full layer and the period's
     assert _flash_kernels(text, "attn/sliding") == 3 * 2
     assert _flash_kernels(text, "attn/full") == 2 * 2
-    grouped = [n for n, scope in scopes.instruction_scopes(text).items()
-               if scope == "moe/experts" and n.startswith("ragged-dot-none")]
     # an eighth of the experts held: a quarter of the rows, half, all
-    assert len(grouped) == 4 * 12 * 3
+    assert len(_grouped_sites(text)) == 4 * 12 * 3
     _assert_compact_rungs(text, job["batch"] * job["seq"]
                           * pcfg.router_top_k, 3, layers=4)
     assert set(scopes_mixed.instruction_scopes(text).values()) == {
         "moe/shared", "mlp/dense"}
     # the kernels' operands: (batch x heads, 8192, 128) at 64 and at 48
     for line in text.splitlines():
-        if "pallas_call" in line and "tpu_custom_call" in line:
+        if "pallas_call" in line and "tpu_custom_call" in line \
+                and "moe/experts" not in line:
             heads = 96 if "attn/full" in line else 128
             assert f"bf16[{heads},8192,128]" in line, line[:200]
     gib = program_bytes(compiled) / 2 ** 30
@@ -531,7 +578,8 @@ def test_kimi_cell_step_fits_with_its_mixers_in_it(topo):
     # forward and backward of the one MLA layer, and no second forward
     assert _flash_kernels(text, "attn/mla") == 2
     kernels = [line for line in text.splitlines()
-               if "pallas_call" in line and "tpu_custom_call" in line]
+               if "pallas_call" in line and "tpu_custom_call" in line
+               and "moe/experts" not in line]
     inversions = [line for line in kernels if "kda.solve" in line]
     pairs = [line for line in kernels if "kda.pairs" in line]
     # the leading layer and three of the period's four: forward, the
@@ -576,8 +624,8 @@ def test_nemotron_cell_step_fits_with_its_scan_in_xla(topo):
     padded) are the step's only custom calls (the state-space scan is
     XLA's batched products: importing ``ops/ssd.py`` brings no kernel),
     K and V reach the flash kernels copied out to the 32 query heads,
-    the held experts' width of 1,856 is padded to 2,048 for XLA's
-    grouped kernel, every new scope is in the program, and the compiled
+    the held experts' width of 1,856 is padded to the 1,920 of whole
+    lane tiles, every new scope is in the program, and the compiled
     step needs between 12 and 14.5 GiB of the chip's 16 at the traffic
     file's ``loss_chunk`` (the sizing rule of
     ISSUE 40: the first branch, ``loss_chunk`` 0)."""
@@ -604,7 +652,8 @@ def test_nemotron_cell_step_fits_with_its_scan_in_xla(topo):
     # of each of the four Mamba-2 positions; no other kernel
     assert _flash_kernels(text, "attn/full") == 2
     kernels = [line for line in text.splitlines()
-               if "pallas_call" in line and "tpu_custom_call" in line]
+               if "pallas_call" in line and "tpu_custom_call" in line
+               and "moe/experts" not in line]
     convs = _conv_sites(text, "ssm/conv")
     assert len(convs) == 4 * 3 and len(kernels) == 2 + 4 * 3
     assert "ssm/scan" not in "".join(kernels)
@@ -616,8 +665,8 @@ def test_nemotron_cell_step_fits_with_its_scan_in_xla(topo):
                   "moe/shared", "moe/route"):
         assert scope in text, scope
     assert "attn.rope" not in text      # nothing is rotated
-    # the held experts meet the grouped kernel at whole pairs of tiles
-    assert "bf16[8,2688,2048]" in text and "bf16[8,2048,2688]" in text
+    # the held experts meet the grouped kernels at whole lane tiles
+    assert "bf16[8,2688,1920]" in text and "bf16[8,1920,2688]" in text
     gib = program_bytes(compiled) / 2 ** 30
     assert 12 <= gib <= 14.5, f"{gib:.2f} GiB"
 
@@ -660,7 +709,8 @@ def test_qwen3_next_cell_step_fits_with_one_kernel_in_its_delta_rule(topo):
     text = compiled.as_text()
     assert _flash_kernels(text, "attn/full") == 2
     kernels = [line for line in text.splitlines()
-               if "pallas_call" in line and "tpu_custom_call" in line]
+               if "pallas_call" in line and "tpu_custom_call" in line
+               and "moe/experts" not in line]
     assert len(_conv_sites(text, "gdn/conv")) == 3 * 3
     assert len(kernels) == 2 + 3 * 3 + 3 * 3
     assert sum("kda.solve" in line for line in kernels) == 9

@@ -360,11 +360,11 @@ def test_config_validation(kw, match):
 # -- the experts' width in whole tiles ---------------------------------- #
 
 @pytest.mark.parametrize("width,padded", [
-    (1856, 2048), (192, 256), (896, 896), (1024, 1024), (512, 512),
+    (1856, 1920), (192, 256), (896, 896), (1024, 1024), (512, 512),
     (24, 24)])
 def test_expert_width_is_padded_only_where_it_is_not_whole_lanes(
         width, padded):
-    """1,856 (14.5 tiles of 128 lanes) goes to 2,048; the accepted
+    """1,856 (14.5 tiles of 128 lanes) goes to 1,920; the accepted
     cells' widths (896, 512, 1,024) and a test's width under one tile
     stay, and ``w`` is handed back as it is."""
     w1, w2 = jnp.ones((2, 8, width)), jnp.ones((2, width, 8))
