@@ -38,6 +38,7 @@ from chainermn_tpu.ops.pallas_attention import (
     FLASH_RESIDUAL_NAMES,
     tracing_for_mesh,
 )
+from chainermn_tpu.ops.recurrent import RECURRENT_RESIDUAL_NAMES
 from chainermn_tpu.parallel.expert import (
     buffer_rows,
     expert_parallel_moe,
@@ -246,11 +247,13 @@ class TransformerConfig:
     remat: bool = True
     remat_policy: str = "full"  # "full" | "dots": what the block's
     # checkpoint keeps besides the block's input.  Both keep the flash
-    # kernel's o and lse where the block runs it (checkpoint_fn); "dots"
-    # also keeps the matmul outputs (jax dots_with_no_batch_dims_saveable)
-    # and the attention output, and recomputes only the cheap
-    # elementwise/norm ops — at 16.0 GB of temporaries for the 300M
-    # model at 8 x 2,048 (sandbox compile, PR 21) it fits no cell
+    # kernel's o and lse where the block runs it, and a recurrence's
+    # slab-start states and output where it scans one (checkpoint_fn);
+    # "dots" also keeps the matmul outputs (jax
+    # dots_with_no_batch_dims_saveable) and the attention output, and
+    # recomputes only the cheap elementwise/norm ops — at 16.0 GB of
+    # temporaries for the 300M model at 8 x 2,048 (sandbox compile,
+    # PR 21) it fits no cell
     norm_eps: float = 1e-6     # the RMSNorms' epsilon; the training path
     # reads it (decoding and serving keep 1e-6 and refuse another)
     norm_scale: str = "plain"  # "plain": y = x / rms(x) * w, w seeded 1 |
@@ -361,16 +364,29 @@ class TransformerConfig:
         plain ``jax.checkpoint`` rebuilds every residual.  A block
         without the kernel has no such names and remats as before.
 
+        It keeps as well what a chunked recurrence's backward pass reads
+        of its forward scan (``RECURRENT_RESIDUAL_NAMES``,
+        ``ops/recurrent.py`` ``scan_slabs``: the float32 state at each
+        slab's start and the op's float32 output), so a KDA, Gated
+        DeltaNet or Mamba-2 layer runs its recurrence forward twice a
+        step (the forward pass, and slab by slab inside the backward
+        scan) and the block's recompute stops at the scan's inputs: the
+        kept bytes a call are ``recurrent/residual_bytes_kept`` (the
+        output) and the op's ``*/state_bytes_kept``.  A block without a
+        recurrence has no such names either.
+
         Not under ``attention="ring"``: a block's policy reaches into
         the ``jax.checkpoint`` the ring puts around each pair, and would
         keep every pair's output (three kernels fewer in the 300M step
         on ``seq=4`` for 2.8 GiB more a device, sandbox compile, PR 29).
         Memory that grows with the ring is the wrong default at the
-        lengths the ring is for; its pairs keep rematerialising."""
+        lengths the ring is for; its pairs keep rematerialising.  The
+        recurrences' names are inside no pair and stay kept."""
         if not self.remat:
             return lambda f: f
         cp = jax.checkpoint_policies
         kept = () if self.attention == "ring" else FLASH_RESIDUAL_NAMES
+        kept += RECURRENT_RESIDUAL_NAMES
         if self.remat_policy == "dots":
             # "attn_out": see _attention
             policy = cp.save_from_both_policies(

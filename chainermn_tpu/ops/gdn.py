@@ -183,7 +183,6 @@ def gdn_chunked(q, k, v, g, beta):
     # the carry takes its varying mesh axes from the inputs
     S0 = jnp.zeros((B, Hk, rep, dk, dv), f32) \
         + jnp.sum(xs[1][0] * 0) + jnp.sum(xs[2][0] * 0)
-    _, o = scan_slabs(_slab, S0, xs)
     # (slabs, B, H_k, rep, chunks, chunk, dv) -> (B, T, H_v, dv)
-    o = jnp.moveaxis(o.swapaxes(0, 1), (2, 3), (4, 5))
-    return o.reshape(B, T, Hv, dv)
+    return scan_slabs(_slab, S0, xs, lambda o: jnp.moveaxis(
+        o.swapaxes(0, 1), (2, 3), (4, 5)).reshape(B, T, Hv, dv))

@@ -73,9 +73,10 @@ inputs; what lives only while that slab is differentiated is its
 keys), its ``T`` and its ``X``: the pair-by-pair exponents, ``SUB``
 times the size of its keys, exist in no pass outside a kernel's
 registers.  A layer makes its pair weights and inverts its systems
-once in the forward pass and once when the slab is recomputed (and
-once more where the block's own checkpoint reruns the layer); the
-backward kernel makes the weights a fourth time.
+once in the forward pass and once when the slab is recomputed, and the
+backward kernel makes the weights a third time: a block's checkpoint
+that keeps ``RECURRENT_RESIDUAL_NAMES`` (``scan_slabs``; the model's
+does) reruns the layer around the scan and not the scan.
 
 Trace-time counters (``utils.metrics`` registry, a call): ``kda/chunks``
 (chunks a sequence), ``kda/state_bytes_kept`` and
@@ -548,6 +549,6 @@ def kda_chunked(q, k, v, g, beta):
     xs = tuple(slabs(x) for x in (q, k, v, g, beta))
     # the carry takes its varying mesh axes from the inputs
     S0 = jnp.zeros((B, H, dk, dv), f32) + jnp.sum(xs[1][0] * 0)
-    _, o = scan_slabs(_slab, S0, xs)
     # (slabs, B, H, chunks, chunk, dv) -> (B, T, H, dv)
-    return jnp.moveaxis(o.swapaxes(0, 1), 2, 4).reshape(B, T, H, dv)
+    return scan_slabs(_slab, S0, xs, lambda o: jnp.moveaxis(
+        o.swapaxes(0, 1), 2, 4).reshape(B, T, H, dv))

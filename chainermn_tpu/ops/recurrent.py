@@ -1,8 +1,10 @@
 """What the recurrent token mixers share (``ops/kda.py``, ``ops/ssd.py``,
 ``ops/gdn.py`` and their layers in ``models/transformer.py``): the short
 causal convolution with SiLU in front of the recurrence, and the loop
-over slabs of chunks whose body is rematerialised.  One of each: a
-repair to either lands in every layer that has it.
+over slabs of chunks whose body is rematerialised, with the two names
+(``RECURRENT_RESIDUAL_NAMES``) by which a checkpoint around the layer
+keeps what the loop's backward pass reads.  One of each: a repair to
+either lands in every layer that has it.
 
 The convolution is bound by the bytes of its one float32 tensor, so it
 has two forms and the input's shape alone chooses.  Where the channels
@@ -26,13 +28,18 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from chainermn_tpu.ops.kernel_common import interpret_kernels
 from chainermn_tpu.parallel._compat import pcast
+from chainermn_tpu.utils.metrics import get_registry
 
-__all__ = ["causal_conv_silu", "scan_slabs", "slab_size"]
+__all__ = ["causal_conv_silu", "scan_slabs", "slab_size",
+           "RECURRENT_RESIDUAL_NAMES"]
+
+RECURRENT_RESIDUAL_NAMES = ("recurrent_state", "recurrent_out")
 
 TOKENS = 1024   # tokens a kernel step; the op's own, as ops/kda.py's CHUNK
 _LANES = 128    # channels a lane tile
@@ -438,13 +445,29 @@ def slab_size(n_chunks: int, most: int) -> int:
     return slab
 
 
-def scan_slabs(slab_fn, state, xs):
-    """``lax.scan`` of ``slab_fn(state, x) -> (state, y)`` over the
-    leading (slab) axis of ``xs`` with the body under
+def scan_slabs(slab_fn, state, xs, out):
+    """``out(ys)`` of ``lax.scan`` of ``slab_fn(state, x) -> (state,
+    y)`` over the leading (slab) axis of ``xs``, the body under
     ``jax.checkpoint``: what the backward pass keeps is the state at
     each slab's start and a slab's own inputs; everything else of a
-    slab lives only while that slab is differentiated."""
+    slab lives only while that slab is differentiated.  ``out`` lays
+    the stacked ``ys`` out as the op hands them on.
+
+    The state as it enters each slab and the op's output wear
+    ``RECURRENT_RESIDUAL_NAMES``, outside the slab's checkpoint: a
+    checkpoint around the op whose policy keeps those names
+    (``TransformerConfig.checkpoint_fn``) does not run this scan a
+    second time for what the backward pass reads of it.  The output's
+    bytes are counted a call as it is traced
+    (``recurrent/residual_bytes_kept``); the states' are the op's own
+    ``*/state_bytes_kept``, kept with or without the names."""
     # a function of this call's own: jax.checkpoint keeps a trace by
     # function and shapes, and kernels inside are traced for the
     # platform this call is traced for
-    return lax.scan(jax.checkpoint(lambda S, x: slab_fn(S, x)), state, xs)
+    slab = jax.checkpoint(lambda S, x: slab_fn(S, x))
+    _, ys = lax.scan(
+        lambda S, x: slab(checkpoint_name(S, RECURRENT_RESIDUAL_NAMES[0]), x),
+        state, xs)
+    y = checkpoint_name(out(ys), RECURRENT_RESIDUAL_NAMES[1])
+    get_registry().inc("recurrent/residual_bytes_kept", y.nbytes)
+    return y

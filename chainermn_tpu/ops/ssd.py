@@ -164,6 +164,9 @@ def ssd_chunked(x, dt, a, B, C):
     xs = tuple(slabs(v) for v in (x, dt, dt * a.astype(f32), B, C))
     # the carry takes its varying mesh axes from the inputs
     S0 = jnp.zeros((b, H, P, N), f32) + jnp.sum(xs[0][0] * 0)
-    _, y = scan_slabs(_slab, S0, xs)
-    # (slabs, b, H, chunks, chunk, P) -> (b, T, H, P)
-    return jnp.moveaxis(y.swapaxes(0, 1), 2, 4).reshape(b, T, H, P)
+    # (slabs, b, H, chunks, chunk, P) -> (b, T, H P) -> (b, T, H, P): what
+    # a block's checkpoint keeps is named with the channels flat, as the
+    # layer's gate and norm read it; a head of half a lane tile would be
+    # kept tokens-minor and turned there (scan_slabs)
+    return scan_slabs(_slab, S0, xs, lambda y: jnp.moveaxis(
+        y.swapaxes(0, 1), 2, 4).reshape(b, T, H * P)).reshape(b, T, H, P)

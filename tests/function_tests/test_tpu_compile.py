@@ -544,14 +544,15 @@ def test_kimi_cell_step_fits_with_its_mixers_in_it(topo):
     period KDA, KDA, MLA, KDA of sparse ones, 8 of 256 experts held),
     through the cell's own files and its driver's mapping: the MLA
     layer's two flash kernels take keys 192 wide and values 128 as
-    they are; each KDA position inverts its chunks' systems in three
-    call sites of the op's own kernel (forward, the block's recompute,
-    the slab's; the solve's VJP has none), 128 systems on the lanes,
-    and XLA's triangular solve is gone; the same three sites make the
-    chunks' pair weights in a kernel and a fourth, backward, makes them
-    again from q, k and G, so no slab step's pair-by-pair tensor is in
-    the program; each KDA position's convolution is a kernel under
-    ``kda/conv`` at three sites (forward, the block's recompute,
+    they are; each KDA position inverts its chunks' systems in two
+    call sites of the op's own kernel (forward and the slab's
+    recompute: the block's recompute stops at the scan, whose states
+    and output its checkpoint keeps; the solve's VJP has none), 128
+    systems on the lanes, and XLA's triangular solve is gone; the same
+    two sites make the chunks' pair weights in a kernel and a third,
+    backward, makes them again from q, k and G, so no slab step's
+    pair-by-pair tensor is in the program; each KDA position's
+    convolution is a kernel under ``kda/conv`` at three sites (forward, the block's recompute,
     backward) and pads nothing; the chunked recurrence and
     every new scope are in the program, and the compiled step needs
     between 10 and 14.5 GiB of the chip's 16 at the traffic file's
@@ -582,20 +583,20 @@ def test_kimi_cell_step_fits_with_its_mixers_in_it(topo):
                and "moe/experts" not in line]
     inversions = [line for line in kernels if "kda.solve" in line]
     pairs = [line for line in kernels if "kda.pairs" in line]
-    # the leading layer and three of the period's four: forward, the
-    # block's recompute and the slab's, and no site in the solve's VJP;
-    # the pair weights' kernel at the same three and its VJP's once
-    assert len(inversions) == 4 * 3 and len(pairs) == 4 * (3 + 1)
+    # the leading layer and three of the period's four: forward and the
+    # slab's recompute, and no site in the solve's VJP; the pair
+    # weights' kernel at the same two and its VJP's once
+    assert len(inversions) == 4 * 2 and len(pairs) == 4 * (2 + 1)
     convs = _conv_sites(text, "kda/conv")
     assert len(convs) == 4 * 3
-    assert len(kernels) == 2 + 4 * 3 + 4 * 4 + 4 * 3
+    assert len(kernels) == 2 + 4 * 2 + 4 * 3 + 4 * 3
     assert all("kda/scan" in line for line in inversions + pairs)
     assert "InvertDiagBlocksLowerTriangular" not in text
     for line in inversions:
         # a slab step's 1 x 32 x 4 systems of 64 x 64, a system a lane
         assert "= f32[64,64,128]{2,1,0" in line, line[:300]
     # a slab step's 128 blocks: A and A' out, or dq, dk and dG
-    assert sum(" = (f32[128,64,64]{" in line for line in pairs) == 4 * 3
+    assert sum(" = (f32[128,64,64]{" in line for line in pairs) == 4 * 2
     assert sum(" = (f32[128,4,16,128]{" in line for line in pairs) == 4
     for line in kernels:
         # 32 heads of one sequence: q and k 192 wide, v and o 128
@@ -680,8 +681,9 @@ def test_qwen3_next_cell_step_fits_with_one_kernel_in_its_delta_rule(topo):
     kernels (the backward at 1,024 x 1,024 like every unwindowed layer:
     it asks the compiler for the VMEM its shapes need, 58 MiB at 256 +
     256 over 16,384 queries) and, a linear
-    layer, the inversion kernel of ``ops/kda.py`` three times (forward,
-    the slab's recompute, the block's recompute) and the convolution's
+    layer, the inversion kernel of ``ops/kda.py`` twice (forward and
+    the slab's recompute: the block's checkpoint keeps the scan's
+    states and output, so its recompute stops there) and the convolution's
     kernel under ``gdn/conv`` three times (forward, the block's
     recompute, backward; nothing padded) are the step's only custom
     calls -- no pair kernel: the scalar decay's pair weights are XLA's
@@ -712,8 +714,8 @@ def test_qwen3_next_cell_step_fits_with_one_kernel_in_its_delta_rule(topo):
                if "pallas_call" in line and "tpu_custom_call" in line
                and "moe/experts" not in line]
     assert len(_conv_sites(text, "gdn/conv")) == 3 * 3
-    assert len(kernels) == 2 + 3 * 3 + 3 * 3
-    assert sum("kda.solve" in line for line in kernels) == 9
+    assert len(kernels) == 2 + 3 * 3 + 3 * 2
+    assert sum("kda.solve" in line for line in kernels) == 6
     assert "kda.pairs" not in text
     for scope in ("attn/gdn", "gdn/conv", "gdn/gate", "gdn/scan",
                   "gdn.pairs", "gdn.intra", "gdn.inter", "attn.qk_norm",

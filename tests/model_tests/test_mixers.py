@@ -5,6 +5,7 @@ mixer the table has never heard of trains with no edit of
 (``transformer -> mixers``)."""
 
 import ast
+import collections
 import dataclasses
 
 import jax
@@ -181,6 +182,158 @@ def test_a_kinds_tree_names_its_mixer_and_what_shapes_its_leaves(
     assert kind.tree == ("both", name) + shaping
     assert mixers.MIXERS[name].tree(kind) == shaping
     assert dataclasses.replace(kind, part="mlp").tree == ("mlp",)
+
+
+# -- what a block keeps of its recurrence ------------------------------ #
+
+RECURRENCES = {"kda": "kda", "gdn": "gdn", "mamba2": "ssd"}
+LONG = 64   # two slabs of two chunks of 16
+# the (slabs, B, ...) states and the (B, T, H, d_v) output, float32
+# (Mamba-2's with its heads of half a lane tile flat, as its gate reads it)
+KEPT = {
+    "kda": [(2, 2, 4, 8, 8), (2, LONG, 4, 8)],
+    "gdn": [(2, 2, 2, 2, 16, 8), (2, LONG, 4, 8)],
+    "mamba2": [(2, 2, 4, 8, 16), (2, LONG, 4 * 8)],
+    "softmax": [],
+}
+_BLOCKS = {}
+
+
+def _census(jaxpr, found=None):
+    """``{primitive name: equations}`` over ``jaxpr`` and every jaxpr
+    nested in it (a scan's body counts once, as it is traced once)."""
+    found = collections.Counter() if found is None else found
+    for eqn in jaxpr.eqns:
+        found[eqn.primitive.name] += 1
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _census(sub, found)
+    return found
+
+
+def _one_block(name):
+    """One toy block of the mixer, differentiated under the config's
+    ``checkpoint_fn`` and under plain ``jax.checkpoint`` (which keeps
+    no name: the three passes): ``(what the first saves besides its
+    arguments, the census of its gradient's jaxpr, the gradients), (the
+    second's census and gradients), the registry the first trace
+    counted into``.  Once a mixer."""
+    if name in _BLOCKS:
+        return _BLOCKS[name]
+    import contextlib
+    import importlib
+    import io
+
+    from chainermn_tpu.models.transformer import _block
+    from chainermn_tpu.ops.pallas_attention import tracing_for_mesh
+    from chainermn_tpu.utils.metrics import MetricsRegistry, set_registry
+
+    kind = KINDS[name]
+    cfg = toy_cfg((kind,), max_seq=LONG, remat=True)
+    mc = MeshConfig(devices=jax.devices()[:1], data=1)
+    blk = jax.tree.map(lambda a: a[0, 0], init_transformer(
+        jax.random.PRNGKey(0), cfg)["blocks"])
+    h = jax.random.normal(jax.random.PRNGKey(1), (2, LONG, cfg.d_model))
+
+    def under(checkpoint, saved=None):
+        def body(h, blk):
+            fn = checkpoint(lambda h, blk: _block(cfg, h, blk, kind=kind))
+            if saved is not None:
+                out = io.StringIO()
+                with contextlib.redirect_stdout(out):
+                    jax.ad_checkpoint.print_saved_residuals(fn, h, blk)
+                saved.extend(
+                    line.split()[0] for line in out.getvalue().splitlines()
+                    if "from the argument" not in line
+                    and "from a constant" not in line)   # rotary's table
+            return jax.grad(lambda *a: jnp.mean(fn(*a)[0] ** 2), (0, 1))(
+                h, blk)
+
+        traced = jax.jit(jax.shard_map(
+            tracing_for_mesh(mc.mesh, body), mesh=mc.mesh,
+            in_specs=(P(), P()), out_specs=P(),
+            check_vma=False)).trace(h, blk)
+        return _census(traced.jaxpr.jaxpr), traced.lower().compile()(h, blk)
+
+    op = RECURRENCES.get(name)
+    if op:
+        op = importlib.import_module(f"chainermn_tpu.ops.{op}")
+    patch = pytest.MonkeyPatch()
+    reg = MetricsRegistry(enabled=True)
+    prev = set_registry(reg)
+    try:
+        if op:
+            patch.setattr(op, "CHUNK", 16)
+            patch.setattr(op, "SLAB", 2)
+        saved = []
+        kept = (saved,) + under(cfg.checkpoint_fn, saved)
+        set_registry(MetricsRegistry())
+        plain = under(jax.checkpoint)
+    finally:
+        set_registry(prev)
+        patch.undo()
+    _BLOCKS[name] = kept, plain, reg
+    return _BLOCKS[name]
+
+
+@pytest.mark.parametrize("name", list(KEPT))
+def test_a_block_keeps_its_recurrences_states_and_output(name):
+    """Under ``checkpoint_fn`` a block with a recurrence saves its
+    arguments and exactly two tensors more
+    (``RECURRENT_RESIDUAL_NAMES``): the float32 state at each slab's
+    start and the op's float32 output as the layer reads it.  A
+    softmax block keeps what it kept: nothing."""
+    (saved, _, _), _, _ = _one_block(name)
+    assert saved == ["f32[%s]" % ",".join(map(str, shape))
+                     for shape in KEPT[name]]
+
+
+@pytest.mark.parametrize("name,kernels", [
+    ("kda", (7, 5)),        # pairs and inversion a pass, the pairs' VJP
+    ("gdn", (3, 2)),        # the inversion a pass
+    ("mamba2", (0, 0)),
+])
+def test_a_blocks_gradient_runs_its_recurrence_forward_twice(name, kernels):
+    """The slab scan with its chunk scan inside: forward, the block's
+    recompute and the slab's recompute under plain ``jax.checkpoint``;
+    with the two names kept the block's recompute is gone, scan and
+    kernels, and no other scan stands in for it."""
+    (_, kept, _), (plain, _), _ = _one_block(name)
+    # slab scans 3 -> 2; chunk scans 3 and their backward's 1 -> 2 and 1
+    assert (plain["scan"], kept["scan"]) == (7, 5)
+    assert (plain["pallas_call"], kept["pallas_call"]) == kernels
+
+
+@pytest.mark.parametrize("name,atol", [
+    ("kda", 5e-8),      # largest gap read: 1.9e-8 (1.2e-8 past rtol)
+    ("gdn", 5e-8),      # 2.3e-8 (1.3e-8 past rtol)
+    ("mamba2", 0.0),    # equal to the last bit
+])
+def test_keeping_the_recurrences_residuals_changes_no_gradient(name, atol):
+    """Scheduling, not arithmetic: the gradients under the policy are
+    plain ``jax.checkpoint``'s, entry by entry to 1e-6 of the entry and
+    a float32 rounding of the largest (0.14 to 0.24 here: the compiler
+    fuses the two programs differently around the two Pallas kernels'
+    layers; Mamba-2's come out equal to the last bit)."""
+    (_, _, kept), (_, plain), _ = _one_block(name)
+    for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(plain)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.isfinite(a).all() and np.abs(a).max() > 0
+        np.testing.assert_allclose(a, b, rtol=1e-6 if atol else 0, atol=atol)
+
+
+@pytest.mark.parametrize("name", list(KEPT))
+def test_residual_bytes_kept_is_counted_from_the_shapes(name):
+    """The kept output by ``scan_slabs``, the kept states by the op:
+    each tensor is counted in one place."""
+    _, _, reg = _one_block(name)
+    states, out = (
+        4 * int(np.prod(shape)) for shape in KEPT[name] or [(0,), (0,)])
+    op = {"mamba2": "ssm"}.get(name, name)
+    assert reg.counter("recurrent/residual_bytes_kept").value == out
+    assert reg.counter(f"{op}/state_bytes_kept").value == states
 
 
 # -- the arrows point one way ------------------------------------------ #
