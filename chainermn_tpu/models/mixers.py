@@ -29,7 +29,7 @@ from jax.sharding import PartitionSpec as P
 
 from chainermn_tpu.ops.gdn import gdn_chunked
 from chainermn_tpu.ops.kda import kda_chunked
-from chainermn_tpu.ops.recurrent import causal_conv_silu
+from chainermn_tpu.ops.recurrent import causal_conv_silu, gated_short_conv
 from chainermn_tpu.ops.ssd import ssd_chunked
 from chainermn_tpu.ops.pallas_attention import (
     flash_attention,
@@ -68,7 +68,7 @@ class AttentionKind:
     its values; the delta-rule layers their convolution (the
     scalar-decay one also its key heads and the two head widths); the
     state-space layer its heads' width, state size, groups and
-    convolution.
+    convolution; the short-convolution layer its taps.
     ``TransformerConfig.layer_pattern`` is a tuple of these, one per
     layer of a period (``leading_layers`` one per layer before them).
     Every field is read by the training path alone: by
@@ -112,9 +112,15 @@ class AttentionKind:
     # the delta rule with ONE scalar decay a value head over a ``d_key
     # x d_value`` state, value head j reading key head j // (n_heads /
     # key_heads); a per-head RMSNorm with one plain scale for all
-    # heads, THEN the gate ``SiLU(z)`` (norm first, gate after).
-    # None of the four takes positions: window, rotary and YaRN fields
-    # are the softmax mixer's
+    # heads, THEN the gate ``SiLU(z)`` (norm first, gate after) |
+    # "shortconv": the doubly gated short convolution
+    # (``ops/recurrent.py`` ``gated_short_conv``): one projection to
+    # ``[B | C | x]`` of ``d_model`` channels each, ``C * conv(B * x)``
+    # with a causal depthwise convolution of ``conv_taps``, no
+    # activation, no bias, no norm, no heads and no state past
+    # ``conv_taps - 1`` tokens.
+    # Of the six only softmax takes positions: window, rotary and YaRN
+    # fields and ``qk_norm`` are its own
     kv_latent: int = 0         # mla: rank of the key-value latent
     d_shared_key: int = 0      # mla: key channels shared by the heads
     d_value: int = 0           # mla: value head width; 0 => d_head.
@@ -122,8 +128,8 @@ class AttentionKind:
     key_heads: int = 0         # gdn: heads of q and k, each serving
     # n_heads / key_heads value heads
     d_key: int = 0             # gdn: a key head's width
-    conv_taps: int = 4         # kda, mamba2, gdn: taps of the short
-    # convolution
+    conv_taps: int = 4         # kda, mamba2, gdn, shortconv: taps of the
+    # short convolution
     qk_norm: bool = False      # softmax: an RMSNorm with a learned scale
     # over each head of q and of k (``q_norm``, ``k_norm``, one scale of
     # d_head each for all heads), before any rotation
@@ -795,6 +801,48 @@ def _gdn_mixer(cfg, x, blk, kind):
         return o.reshape(B, T, -1) @ blk["wo"].reshape(-1, D).astype(cd)
 
 
+def _shortconv_out_width(cfg, kind):
+    """The mixer has no heads; ``wo``'s head axis bends for it here and
+    nowhere else: the layer's head count (the config's, or the kind's
+    own) cuts ``d_model``'s channels into that many equal runs, so that
+    ``wo`` ``(heads, d_model / heads, d_model)`` is one ``(d_model,
+    d_model)`` matrix reshaped."""
+    heads = cfg.heads_of(kind)
+    if cfg.d_model % heads:
+        raise ValueError(
+            f"{kind.name}: shortconv's out-projection is d_model x d_model "
+            f"laid out by {heads} heads, which do not divide "
+            f"d_model={cfg.d_model}")
+    return cfg.d_model // heads
+
+
+def _shortconv_init(key, ks, cfg, kind):
+    D, taps = cfg.d_model, kind.conv_taps
+    # one projection to [B | C | x], each d_model wide
+    return {"w_in": _dense_init(ks[0], (D, 3 * D), D),
+            "conv": _dense_init(
+                jax.random.fold_in(key, 16), (D, taps), taps)}
+
+
+def _shortconv_mixer(cfg, x, blk, kind):
+    """The doubly gated short convolution on the normed input ``x``:
+    the layer's contribution to the residual stream, ``W_out (C *
+    conv(B * x))`` with ``[B | C | x] = x W_in``.  Projections in the
+    compute dtype with a float32 result; both gates and the convolution
+    in float32 under ``shortconv/conv`` (``ops/recurrent.py``: at whole
+    lane tiles and token blocks one Pallas kernel forward and one
+    backward, the three slices and the sum over taps otherwise)."""
+    cd = cfg.compute_dtype
+    with device_scope("attn.qkv"):
+        bcx = jnp.dot(x.astype(cd), blk["w_in"].astype(cd),
+                      preferred_element_type=jnp.float32)
+    with device_scope("shortconv/conv"):
+        y = gated_short_conv(bcx, blk["conv"])
+    o = checkpoint_name(y.astype(cd), "attn_out")
+    with device_scope("attn.out"):
+        return o @ blk["wo"].reshape(-1, x.shape[-1]).astype(cd)
+
+
 # --------------------------------------------------------------------- #
 # the table
 # --------------------------------------------------------------------- #
@@ -877,4 +925,9 @@ MIXERS = {
         out_width=lambda cfg, kind: kind.d_value,
         tree=lambda kind: (kind.key_heads, kind.d_key, kind.d_value,
                            kind.conv_taps)),
+    "shortconv": _unsplit(
+        ("w_in", "conv"),
+        init=_shortconv_init, apply=_shortconv_mixer, check=_check_conv_taps,
+        out_width=_shortconv_out_width,
+        tree=lambda kind: (kind.conv_taps,)),
 }

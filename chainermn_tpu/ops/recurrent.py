@@ -1,22 +1,26 @@
-"""What the recurrent token mixers share (``ops/kda.py``, ``ops/ssd.py``,
-``ops/gdn.py`` and their layers in ``models/transformer.py``): the short
-causal convolution with SiLU in front of the recurrence, and the loop
-over slabs of chunks whose body is rematerialised, with the two names
+"""What the token mixers with a short convolution share (``ops/kda.py``,
+``ops/ssd.py``, ``ops/gdn.py`` and the layers of ``models/mixers.py``):
+the short causal convolution, in its two forms, and the loop over slabs
+of chunks whose body is rematerialised, with the two names
 (``RECURRENT_RESIDUAL_NAMES``) by which a checkpoint around the layer
 keeps what the loop's backward pass reads.  One of each: a repair to
 either lands in every layer that has it.
 
-The convolution is bound by the bytes of its one float32 tensor, so it
-has two forms and the input's shape alone chooses.  Where the channels
-(and every part they are split into) are whole lane tiles and the
-tokens whole blocks of ``TOKENS``, a Pallas kernel reads ``y`` once and
-writes each part once, and a second kernel is its backward pass
-(``jax.custom_vjp``): what is kept is ``y``, the taps and the bias; the
-pre-activation is computed again from the tile in VMEM, and the input's
-cotangent and the taps' and bias's gradients leave in one pass over the
-cotangent and ``y``.  Any other shape takes the plain sum over taps,
-which autodiff differentiates.  Off the TPU the kernels run in the
-interpreter, as the flash and KDA kernels do.
+The convolution with SiLU (:func:`causal_conv_silu`) stands in front of
+a recurrence; the doubly gated one (:func:`gated_short_conv`, ``C *
+conv(B * x)`` with no activation and no bias) is a mixer by itself, with
+no recurrence behind it.  Both are bound by the bytes of their float32
+tensors, so each has two forms and the input's shape alone chooses.
+Where the channels (and every part they are split into) are whole lane
+tiles and the tokens whole blocks of ``TOKENS``, a Pallas kernel reads
+its input once and writes each part once, and a second kernel is its
+backward pass (``jax.custom_vjp``): what is kept is the input, the taps
+and the bias; what lies between (the pre-activation; the gated product
+and the convolution's sum) is computed again from the tile in VMEM, and
+the input's cotangent and the taps' and bias's gradients leave in one
+pass over the cotangent and the input.  Any other shape takes the plain
+sum over taps, which autodiff differentiates.  Off the TPU the kernels
+run in the interpreter, as the flash and KDA kernels do.
 """
 
 from __future__ import annotations
@@ -36,8 +40,8 @@ from chainermn_tpu.ops.kernel_common import interpret_kernels
 from chainermn_tpu.parallel._compat import pcast
 from chainermn_tpu.utils.metrics import get_registry
 
-__all__ = ["causal_conv_silu", "scan_slabs", "slab_size",
-           "RECURRENT_RESIDUAL_NAMES"]
+__all__ = ["causal_conv_silu", "gated_short_conv", "scan_slabs",
+           "slab_size", "RECURRENT_RESIDUAL_NAMES"]
 
 RECURRENT_RESIDUAL_NAMES = ("recurrent_state", "recurrent_out")
 
@@ -169,6 +173,86 @@ def _walk(tiles, turn, carry):
     return lax.fori_loop(jnp.int32(0), tiles // _UNROLL, turns, carry)
 
 
+def _rows(g):
+    """Tokens of tile ``g`` of a block."""
+    return pl.ds(pl.multiple_of(g * _ROWS, _ROWS), _ROWS)
+
+
+def _back(taps):
+    """How far back each tap reads (tap ``j`` reads ``t - back[j]``)."""
+    return [taps - 1 - j for j in range(taps)]
+
+
+def _walk_forward(tiles, w, row, before, read, put):
+    """The convolution's sum over a block, first tile to last, for both
+    forward kernels: ``read(rows)`` is the convolution's input at those
+    tokens, ``before`` its ``_ROWS`` tokens before the block, and
+    ``put(rows, conv)`` is handed the sum over taps."""
+    back = _back(len(w))
+
+    def turn(g, weighed_before):
+        rows = _rows(g)
+        tile = read(rows)
+        # a tap is the same on every sublane: weigh, then rotate
+        weighed = _rotations([w[j] * tile for j in range(len(w))], back)
+        put(rows, sum(jnp.where(row >= back[j], weighed[j], weighed_before[j])
+                      for j in range(len(w))))
+        return weighed
+
+    _walk(tiles, turn, _rotations([w[j] * before for j in range(len(w))],
+                                  back))
+
+
+def _walk_back(tiles, w, row, before, read, d_conv, put, after_ref, dw_ref,
+               biased=False):
+    """A block's tiles last to first, for both backward kernels.
+    ``read`` and ``before`` as in :func:`_walk_forward`;
+    ``d_conv(rows, shifted)`` is handed the input as each tap met it
+    and returns the cotangent of the convolution's sum there (of the
+    pre-activation, with a bias); ``put(rows, d_in)`` is handed the
+    input's cotangent.  ``after_ref`` holds what the block after this
+    one left, its first tile's ``d_conv`` rotated for each tap, and
+    takes this block's; ``dw_ref`` gains the taps' (and the bias's)
+    sums a sublane."""
+    taps = len(w)
+    back = _back(taps)
+    ahead = [(_ROWS - s) % _ROWS for s in back]     # rotation for t + back
+
+    def tile(g):
+        """Tile ``g`` of the input; -1 is the one before the block.
+        (Always through the select: binary operations inside a kernel
+        drop their operands' varying mesh axes, a plain read keeps
+        them, and a loop's carry has to leave with the type it came
+        with.)"""
+        rows = _rows(jnp.maximum(g, 0))
+        return jnp.where(g >= 0, read(rows), before)
+
+    def turn(k, carry):
+        rolled, d_after, sums = carry
+        g = tiles - 1 - k
+        rows = _rows(g)
+        rolled_before = _rotations(tile(g - 1), back)
+        shifted = [jnp.where(row >= back[j], rolled[j], rolled_before[j])
+                   for j in range(taps)]
+        d = d_conv(rows, shifted)
+        d_rolled = _rotations(d, ahead)
+        put(rows, sum(
+            w[j] * jnp.where(row < _ROWS - back[j], d_rolled[j], d_after[j])
+            for j in range(taps)))
+        sums = [s + d * x for s, x in zip(sums, shifted)] \
+            + ([sums[-1] + d] if biased else [])
+        return rolled_before, d_rolled, sums
+
+    _, d_first, sums = _walk(tiles, turn, (
+        _rotations(tile(tiles - 1), back),
+        [after_ref[j] for j in range(taps)],
+        [jnp.zeros(row.shape, jnp.float32)] * (taps + biased)))
+    for j in range(taps):
+        after_ref[j] = d_first[j]
+    for j in range(taps + biased):
+        dw_ref[0, j] += sums[j]
+
+
 def _tile(ref, rows):
     """Tokens ``rows`` of a part's block as one tile: the block is
     ``(1, tokens, lanes)``, or head by head ``(1, heads, tokens,
@@ -198,8 +282,7 @@ def _fwd_kernel(*refs, edges, biased):
     block, the block, the taps, the bias if any, then a block of each
     part, of which the one that holds this step's channels is
     written."""
-    y_ref, taps = refs[1], refs[2].shape[0]
-    back = [taps - 1 - j for j in range(taps)]      # tap j reads t - back
+    y_ref = refs[1]
     at_start = pl.program_id(1) == 0
 
     # every read below is inside a pl.when: at the kernel's top level
@@ -208,18 +291,12 @@ def _fwd_kernel(*refs, edges, biased):
     def walk(out_ref):
         w, bias, row, before = _step_values(refs, biased, at_start)
 
-        def turn(g, weighed_before):
-            rows = pl.ds(pl.multiple_of(g * _ROWS, _ROWS), _ROWS)
-            tile = y_ref[0, rows, :]
-            # a tap is the same on every sublane: weigh, then rotate
-            weighed = _rotations([w[j] * tile for j in range(taps)], back)
-            pre = sum(jnp.where(row >= back[j], weighed[j], weighed_before[j])
-                      for j in range(taps)) + bias
+        def put(rows, conv):
+            pre = conv + bias
             _put(out_ref, rows, pre * jax.nn.sigmoid(pre))
-            return weighed
 
-        _walk(y_ref.shape[1] // _ROWS, turn, _rotations(
-            [w[j] * before for j in range(taps)], back))
+        _walk_forward(y_ref.shape[1] // _ROWS, w, row, before,
+                      lambda rows: y_ref[0, rows, :], put)
 
     for inside, out_ref in zip(_part_of(pl.program_id(2), edges),
                                refs[3 + biased:]):
@@ -235,11 +312,8 @@ def _bwd_kernel(*refs, edges, biased):
     summed over the token blocks; then what the block after this one
     left: its first tile's ``d_pre``, rotated for each tap."""
     n = len(edges) - 1
-    y_ref, (taps, lanes) = refs[1], refs[2].shape
+    y_ref, taps = refs[1], refs[2].shape[0]
     dy_ref, dw_ref, after_ref = refs[3 + biased + n:]
-    tiles = y_ref.shape[1] // _ROWS
-    back = [taps - 1 - j for j in range(taps)]
-    ahead = [(_ROWS - s) % _ROWS for s in back]     # rotation for t + back
     at_start = pl.program_id(2) == pl.num_programs(2) - 1
 
     @pl.when(pl.program_id(2) == 0)                 # the LAST token block
@@ -251,43 +325,17 @@ def _bwd_kernel(*refs, edges, biased):
     def walk(ct_ref):
         w, bias, row, before = _step_values(refs, biased, at_start)
 
-        def tile(g):
-            """Tile ``g`` of the block of ``y``; -1 is the one before
-            it.  (Always through the select: binary operations inside
-            a kernel drop their operands' varying mesh axes, a plain
-            read keeps them, and a loop's carry has to leave with the
-            type it came with.)"""
-            rows = pl.ds(pl.multiple_of(
-                jnp.maximum(g, 0) * _ROWS, _ROWS), _ROWS)
-            return jnp.where(g >= 0, y_ref[0, rows, :], before)
-
-        def turn(k, carry):
-            rolled, d_after, sums = carry
-            g = tiles - 1 - k
-            rows = pl.ds(pl.multiple_of(g * _ROWS, _ROWS), _ROWS)
-            rolled_before = _rotations(tile(g - 1), back)
-            shifted = [jnp.where(row >= back[j], rolled[j], rolled_before[j])
-                       for j in range(taps)]
+        def d_pre(rows, shifted):
             pre = sum(w[j] * shifted[j] for j in range(taps)) + bias
             sig = jax.nn.sigmoid(pre)
-            d_pre = _tile(ct_ref, rows) * (sig * (1 + pre * (1 - sig)))
-            d_rolled = _rotations(d_pre, ahead)
-            dy_ref[0, rows, :] = sum(
-                w[j] * jnp.where(row < _ROWS - back[j], d_rolled[j],
-                                 d_after[j])
-                for j in range(taps))
-            sums = [s + d_pre * x for s, x in zip(sums, shifted)] \
-                + ([sums[-1] + d_pre] if biased else [])
-            return rolled_before, d_rolled, sums
+            return _tile(ct_ref, rows) * (sig * (1 + pre * (1 - sig)))
 
-        _, d_first, sums = _walk(tiles, turn, (
-            _rotations(tile(tiles - 1), back),
-            [after_ref[j] for j in range(taps)],
-            [jnp.zeros((_ROWS, lanes), jnp.float32)] * (taps + biased)))
-        for j in range(taps):
-            after_ref[j] = d_first[j]
-        for j in range(taps + biased):
-            dw_ref[0, j] += sums[j]
+        def put(rows, dy):
+            dy_ref[0, rows, :] = dy
+
+        _walk_back(y_ref.shape[1] // _ROWS, w, row, before,
+                   lambda rows: y_ref[0, rows, :], d_pre, put,
+                   after_ref, dw_ref, biased)
 
     for inside, ct_ref in zip(_part_of(pl.program_id(1), edges),
                               refs[3 + biased:3 + biased + n]):
@@ -434,6 +482,183 @@ def _backward(y, w, bias, cts, *, plan):
 _fused.defvjp(
     lambda y, w, bias, plan: (_forward(y, w, bias, plan=plan), (y, w, bias)),
     lambda plan, kept, cts: _backward(*kept, cts, plan=plan))
+
+
+# --------------------------------------------------------------------- #
+# the doubly gated convolution: C * conv(B * x), one pass each way
+# --------------------------------------------------------------------- #
+
+
+def gated_short_conv(bcx, w):
+    """``C * conv(B * x)`` for ``bcx = [B | C | x]`` ``(batch, T, 3 *
+    channels)`` side by side as one projection leaves them, and one
+    weight a channel a tap (``w``: ``(channels, taps)``): both gates and
+    the causal depthwise convolution of :func:`causal_conv_silu`
+    (``conv(z)_t = sum_j w_j z_(t - taps + 1 + j)``, the last tap on the
+    token itself, nothing before the sequence's start), with no
+    activation and no bias.  All in float32; ``(batch, T, channels)``.
+
+    Lane-aligned float32 shapes (:func:`_kernel_blocks`) run one Pallas
+    kernel forward, which reads the three parts where they lie and
+    writes the result, and one backward, which reads them and the
+    cotangent and writes the three parts' cotangents and the taps'
+    sums: ``bcx`` and ``w`` are what is kept.  Any other shape is the
+    three slices, two products and the sum over taps as written."""
+    C, taps = w.shape
+    if bcx.shape[-1] != 3 * C:
+        raise ValueError(f"{bcx.shape} is not [B | C | x] of {C} channels")
+    registry = get_registry()
+    registry.inc("shortconv/sites")
+    # what the op's backward keeps: its two arguments
+    registry.inc("shortconv/bytes_kept", bcx.nbytes + w.nbytes)
+    if _kernel_blocks(bcx.shape[1], (C,), taps) \
+            and bcx.dtype == w.dtype == jnp.float32:
+        over = tuple(jax.typeof(bcx).vma)
+        return _gated(bcx, pcast(w.T, over, to="varying"),
+                      interpret_kernels())
+    return _gated_plain(bcx, w)
+
+
+def _gated_plain(bcx, w):
+    """The operator as written: three slices, the gate ``B``, a padded
+    copy and ``taps`` shifted slices of it, the gate ``C``."""
+    (C, taps), T = w.shape, bcx.shape[1]
+    b, c, x = (bcx[..., i * C:(i + 1) * C] for i in range(3))
+    padded = jnp.pad(b * x, ((0, 0), (taps - 1, 0), (0, 0)))
+    return c * sum(padded[:, j:j + T] * w[:, j] for j in range(taps))
+
+
+def _gated_fwd_kernel(b_halo, x_halo, b_ref, c_ref, x_ref, w_ref, out_ref):
+    """One step forward: a block of ``B``, ``C`` and ``x``, the
+    ``_ROWS`` tokens of ``B`` and ``x`` before it, the taps; the block
+    of the result."""
+    at_start = pl.program_id(1) == 0
+
+    # inside a pl.when, as every read of _fwd_kernel is and for its
+    # reason (the interpreter under shard_map)
+    @pl.when(pl.program_id(1) >= 0)
+    def _():
+        def put(rows, conv):
+            out_ref[0, rows, :] = c_ref[0, rows, :] * conv
+
+        _walk_forward(
+            b_ref.shape[1] // _ROWS, _tap_rows(w_ref),
+            lax.broadcasted_iota(jnp.int32, (_ROWS, w_ref.shape[1]), 0),
+            jnp.where(at_start, 0.0, b_halo[0] * x_halo[0]),
+            lambda rows: b_ref[0, rows, :] * x_ref[0, rows, :], put)
+
+
+def _gated_bwd_kernel(b_halo, x_halo, b_ref, c_ref, x_ref, w_ref, ct_ref,
+                      db_ref, dc_ref, dx_ref, dw_ref, after_ref):
+    """One step backward, the token blocks taken last to first, as
+    :func:`_bwd_kernel` takes them: the operands of the forward step
+    and the block of the result's cotangent; then the blocks of the
+    three parts' cotangents, the taps' gradient of this batch entry and
+    these channels a sublane, summed over the token blocks; then what
+    the block after this one left: its first tile's cotangent of the
+    convolution's sum, rotated for each tap."""
+    at_start = pl.program_id(2) == pl.num_programs(2) - 1
+
+    @pl.when(pl.program_id(2) == 0)                 # the LAST token block
+    def _():
+        after_ref[...] = jnp.zeros_like(after_ref)
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    @pl.when(pl.program_id(2) >= 0)
+    def _():
+        w = _tap_rows(w_ref)
+
+        def d_sum(rows, shifted):
+            ct = ct_ref[0, rows, :]
+            dc_ref[0, rows, :] = ct * sum(
+                w[j] * shifted[j] for j in range(len(w)))
+            return ct * c_ref[0, rows, :]
+
+        def put(rows, d_gated):
+            db_ref[0, rows, :] = d_gated * x_ref[0, rows, :]
+            dx_ref[0, rows, :] = d_gated * b_ref[0, rows, :]
+
+        _walk_back(
+            b_ref.shape[1] // _ROWS, w,
+            lax.broadcasted_iota(jnp.int32, (_ROWS, w_ref.shape[1]), 0),
+            jnp.where(at_start, 0.0, b_halo[0] * x_halo[0]),
+            lambda rows: b_ref[0, rows, :] * x_ref[0, rows, :], d_sum, put,
+            after_ref, dw_ref)
+
+
+def _gated_specs(steps, taps, channel, token):
+    """Block specifications of the halos of ``B`` and ``x``, of ``B``,
+    ``C`` and ``x`` and of the taps: :func:`_operand_specs`' for each
+    part, all five read out of the one ``[B | C | x]``, part ``p``'s
+    channels starting ``p * steps`` channel steps in."""
+    (b_halo, b, w), (_, c, _), (x_halo, x, _) = (
+        _operand_specs(_LANES, taps, False,
+                       lambda *g, p=p: channel(*g) + p * steps, token)
+        for p in range(3))
+    return [b_halo, x_halo, b, c, x, w]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _gated(bcx, w, interpret):
+    """``C * conv(B * x)`` for ``bcx`` ``(batch, T, 3 C)`` and ``w``
+    ``(taps, C)``, a lane tile of channels a step (seven blocks are in
+    flight going back).  The backward pass keeps both."""
+    return _gated_forward(bcx, w, interpret=interpret)
+
+
+_ONCE_GATED = functools.partial(jax.jit, inline=True,
+                                static_argnames="interpret")
+
+
+@_ONCE_GATED
+def _gated_forward(bcx, w, *, interpret):
+    (B, T, _), (taps, C) = bcx.shape, w.shape
+    steps = C // _LANES
+    return pl.pallas_call(
+        _gated_fwd_kernel,
+        grid=(B, T // TOKENS, steps),
+        in_specs=_gated_specs(steps, taps,
+                              lambda b, i, c: c, lambda b, i, c: i),
+        out_specs=pl.BlockSpec((1, TOKENS, _LANES), lambda b, i, c: (b, i, c)),
+        out_shape=_like(bcx, (B, T, C)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
+        interpret=interpret)(*((bcx,) * 5 + (w,)))
+
+
+@_ONCE_GATED
+def _gated_backward(bcx, w, ct, *, interpret):
+    (B, T, _), (taps, C) = bcx.shape, w.shape
+    steps, blocks = C // _LANES, T // TOKENS
+
+    def token(b, c, i):
+        return blocks - 1 - i
+
+    block = pl.BlockSpec((1, TOKENS, _LANES),
+                         lambda b, c, i: (b, token(b, c, i), c))
+    db, dc, dx, sums = pl.pallas_call(
+        _gated_bwd_kernel,
+        grid=(B, steps, blocks),
+        in_specs=_gated_specs(steps, taps, lambda b, c, i: c, token)
+        + [block],
+        out_specs=[block] * 3 + [pl.BlockSpec(
+            (1, taps, _ROWS, _LANES), lambda b, c, i: (b, 0, 0, c))],
+        out_shape=[_like(bcx, (B, T, C))] * 3
+        + [_like(bcx, (B, taps, _ROWS, C))],
+        scratch_shapes=[pltpu.VMEM((taps, _ROWS, _LANES), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret)(*((bcx,) * 5 + (w, ct)))
+    # side by side again, as the projection's backward reads them
+    return (jnp.concatenate([db, dc, dx], axis=-1),
+            jnp.sum(sums, axis=(0, 2)))
+
+
+_gated.defvjp(
+    lambda bcx, w, interpret: (
+        _gated_forward(bcx, w, interpret=interpret), (bcx, w)),
+    lambda interpret, kept, ct: _gated_backward(
+        *kept, ct, interpret=interpret))
 
 
 def slab_size(n_chunks: int, most: int) -> int:

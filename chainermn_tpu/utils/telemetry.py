@@ -80,6 +80,7 @@ __all__ = [
     "DEVICE_SCOPES",
     "DEVICE_SCOPES_SSM",
     "DEVICE_SCOPES_GDN",
+    "DEVICE_SCOPES_SHORTCONV",
     "MetricsExport",
     "RequestTraceStore",
     "SpanEvent",
@@ -899,11 +900,19 @@ DEVICE_SCOPES_GDN = (
     "gdn.pairs", "gdn.intra", "gdn.inter",
     "attn.qk_norm",
 )
-# any name of the three lists; a kind is whatever ``AttentionKind`` lets
+# The short-convolution layer's one scope of its own (PR 49:
+# ``_shortconv_mixer``, ``ops/recurrent.py`` ``gated_short_conv``: both
+# gates and the convolution, whatever implements them), apart for the
+# same reason; its projections wear ``attn.qkv`` and ``attn.out``.
+DEVICE_SCOPES_SHORTCONV = (
+    "shortconv/conv",
+)
+# any name of the four lists; a kind is whatever ``AttentionKind`` lets
 # through, the placeholder itself apart
 _ANY_SCOPE = "|".join(
     r"attn/[^/()<>\s]+" if s == "attn/<kind>" else re.escape(s)
-    for s in DEVICE_SCOPES + DEVICE_SCOPES_SSM + DEVICE_SCOPES_GDN)
+    for s in DEVICE_SCOPES + DEVICE_SCOPES_SSM + DEVICE_SCOPES_GDN
+    + DEVICE_SCOPES_SHORTCONV)
 # a scope stands between the delimiters of a name stack: ``/`` and the
 # brackets of a transformation
 _SCOPE_AT = re.compile(r"(?:^|[/(])(" + _ANY_SCOPE + r")(?=$|[/)])")
@@ -911,8 +920,9 @@ _SCOPE_AT = re.compile(r"(?:^|[/(])(" + _ANY_SCOPE + r")(?=$|[/)])")
 
 def device_scope(name: str):
     """``jax.named_scope(name)`` for a name of ``DEVICE_SCOPES``,
-    ``DEVICE_SCOPES_SSM`` or ``DEVICE_SCOPES_GDN`` (any
-    ``attn/<kind>``), a ``ValueError`` for another: a scope nobody can
+    ``DEVICE_SCOPES_SSM``, ``DEVICE_SCOPES_GDN`` or
+    ``DEVICE_SCOPES_SHORTCONV`` (any ``attn/<kind>``), a
+    ``ValueError`` for another: a scope nobody can
     read back is not added by accident.  Trace-time only: it names the
     ops traced under it (through differentiation and remat) and costs
     no host call and no device op when the program runs."""

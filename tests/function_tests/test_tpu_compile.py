@@ -253,6 +253,35 @@ def test_conv_kernels_compile(topo, one_chip, cell, backward):
         assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "backward"])
+def test_gated_conv_kernels_compile(topo, one_chip, backward):
+    """``gated_short_conv``'s two kernels alone at the LFM2 cell's real
+    shape (4 x 8,192 tokens, [B | C | x] of 2,048 channels each, 3
+    taps), float32: Mosaic lowers them and the blocks fit VMEM (seven
+    of a lane tile going back); one kernel forward, a second backward,
+    and forward no pad and no tensor beside the argument and the
+    result.  (Backward the three parts' cotangents are laid side by
+    side again: alone XLA pads and adds them; in the cell's step the
+    same pass is the cast to bfloat16 the projection's backward needs
+    anyway, three in-place slices of one bfloat16 buffer.)"""
+    from chainermn_tpu.ops.pallas_attention import tracing_for_mesh
+    from chainermn_tpu.ops.recurrent import gated_short_conv
+
+    like = lambda s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    conv = tracing_for_mesh(mesh, gated_short_conv)
+    fn = jax.grad(lambda a, w: jnp.sum(jnp.sin(conv(a, w))), (0, 1)) \
+        if backward else conv
+    compiled = _compile(fn, like((4, 8192, 6144)), like((2048, 3)))
+    text = compiled.as_text()
+    assert sum("pallas_call" in line and "tpu_custom_call" in line
+               for line in text.splitlines()) == 1 + backward
+    if not backward:
+        assert not _conv_sites(text, "")[1:]       # and nothing padded
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
 # the five typed cells' expert layers at their first rung, bfloat16:
 # rows of the sorted buffer, experts held, model width, experts' width
 GROUPED_CELLS = {
@@ -723,3 +752,52 @@ def test_qwen3_next_cell_step_fits_with_one_kernel_in_its_delta_rule(topo):
         assert scope in text, scope
     gib = program_bytes(compiled) / 2 ** 30
     assert 12 <= gib <= 14.5, f"{gib:.2f} GiB"
+
+
+@pytest.mark.slow
+def test_lfm2_cell_step_fits_with_the_short_convolutions_kernels_in_it(topo):
+    """The benchmark's LFM2 cell at its real size (four sequences of
+    8,192 tokens; the convolution layer with the dense SwiGLU, then
+    attention, convolution x 3, all sparse; 8 of 64 experts held),
+    through the cell's own files and its driver's mapping: the
+    attention layer's two flash kernels, ``gated_short_conv``'s kernel
+    under ``shortconv/conv`` three times a convolution layer (forward,
+    the block's recompute, backward; nothing padded under the scope)
+    and the grouped products' kernels under ``moe/experts`` are the
+    step's only custom calls, every new scope is in the program, and
+    the compiled step needs between 9 and 14.5 GiB of the chip's 16 at
+    the traffic file's ``loss_chunk`` (the sizing rule of ISSUE 49: the
+    first branch, 10.34 GiB).  Slow from the start: the benchmark's run
+    repeats it."""
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.lib import cells
+    from benchmarks.lib.harness import build_optimizer, program_bytes
+    from chainermn_tpu.parallel import MeshConfig
+
+    cell, cfg, job = cells.load_cell("lfm2-24b-l5-ep8-train-conv-seq8192")
+    assert (job["batch"], job["seq"], job["loss_chunk"]) == (4, 8192, 0)
+    pcfg = cells.module("drivers", job["driver"])._program_config(cfg, job)
+    assert pcfg.blocks_by_position and pcfg.mixers == ["shortconv"]
+    compiled = _compile_step(
+        MeshConfig(devices=topo.devices[:cell["chips"]], **job["mesh"]),
+        pcfg, build_optimizer(cfg["optimizer"]), job["batch"], job["seq"])
+    text = compiled.as_text()
+    assert _flash_kernels(text, "attn/full") == 2
+    kernels = [line for line in text.splitlines()
+               if "pallas_call" in line and "tpu_custom_call" in line
+               and "moe/experts" not in line]
+    assert len(_conv_sites(text, "shortconv/conv")) == 4 * 3
+    assert len(kernels) == 2 + 4 * 3
+    assert len(_grouped_sites(text)) > 0 and "ragged-dot" not in text
+    for scope in ("attn/conv", "attn/full", "shortconv/conv", "attn.qkv",
+                  "attn.out", "attn.qk_norm", "attn.rope", "mlp/dense",
+                  "moe/route"):
+        assert scope in text, scope
+    assert "moe/shared" not in text
+    gib = program_bytes(compiled) / 2 ** 30
+    assert 9 <= gib <= 14.5, f"{gib:.2f} GiB"

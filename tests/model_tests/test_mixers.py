@@ -79,7 +79,7 @@ def _losses(mc, cfg, host, steps=3):
 def test_a_mixer_put_into_the_table_trains_with_no_edit_of_the_model(
         monkeypatch):
     with pytest.raises(ValueError, match=r"mixer 'fake' not in \(softmax, "
-                       "mla, kda, mamba2, gdn\\)"):
+                       "mla, kda, mamba2, gdn, shortconv\\)"):
         AttentionKind("f", mixer="fake")
     monkeypatch.setitem(mixers.MIXERS, "fake", FAKE)
     kind = AttentionKind("f", mixer="fake", n_heads=2)
@@ -130,6 +130,7 @@ KINDS = {
                             ssm_state=16, ssm_groups=2),
     "gdn": AttentionKind("gdn", mixer="gdn", n_heads=4, key_heads=2,
                          d_key=16, d_value=8),
+    "shortconv": AttentionKind("conv", mixer="shortconv", conv_taps=3),
 }
 
 

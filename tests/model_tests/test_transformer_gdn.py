@@ -251,7 +251,8 @@ def test_the_shared_experts_gate_is_one_scalar_a_token():
 def test_kind_validation(kw, match):
     with pytest.raises(ValueError, match=match):
         AttentionKind("x", **kw)
-    assert tr.MIXERS == ("softmax", "mla", "kda", "mamba2", "gdn")
+    assert tr.MIXERS == ("softmax", "mla", "kda", "mamba2", "gdn",
+                         "shortconv")
 
 
 @pytest.mark.parametrize("kw,match", [

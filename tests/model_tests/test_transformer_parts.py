@@ -323,7 +323,7 @@ def test_decoding_and_serving_refuse_the_new_fields(kw, named):
 
 @pytest.mark.parametrize("kw,match", [
     (dict(name="m", mixer="mamba"),
-     r"not in \(softmax, mla, kda, mamba2, gdn\)"),
+     r"not in \(softmax, mla, kda, mamba2, gdn, shortconv\)"),
     (dict(name="m", part="ffn"), r"not in \(both, mixer, mlp\)"),
     (dict(name="m", mixer="mamba2"), "mamba2 needs its own n_heads"),
     (dict(name="m", mixer="mamba2", n_heads=4, ssm_head_dim=8, ssm_state=16,
